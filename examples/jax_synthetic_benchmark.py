@@ -50,8 +50,8 @@ def main():
                              "via BENCH_ONE_CHIP_IMGS_PER_SEC")
     parser.add_argument("--platform", default=None,
                         help="force a jax platform (e.g. 'cpu' for "
-                             "virtual-device CI runs; overrides site "
-                             "config, must run before first device use)")
+                             "virtual-device CI runs; applied before the "
+                             "first device use)")
     args = parser.parse_args()
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
@@ -94,15 +94,14 @@ def main():
     loss = run_batch()  # compile
     for _ in range(args.num_warmup_batches):
         loss = run_batch()
-    float(loss)  # host sync — block_until_ready alone can be a no-op on
-    # remote-dispatch platforms
+    jax.block_until_ready(loss)
 
     img_secs = []
     for i in range(args.num_iters):
         t0 = time.time()
         for _ in range(max(args.num_batches_per_iter, 1)):
             loss = run_batch()
-        float(loss)
+        jax.block_until_ready(loss)
         dt = time.time() - t0
         rate = global_batch * args.num_batches_per_iter / dt
         img_secs.append(rate)

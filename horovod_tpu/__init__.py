@@ -22,12 +22,6 @@ Canonical usage (mirrors reference: examples/*.py):
 
 from horovod_tpu.version import __version__
 
-# JAX API-drift shims (jax.shard_map spelling, lax.axis_size) — must be
-# in place before any data-plane module is imported.
-from horovod_tpu.utils import compat as _compat
-
-_compat.install()
-
 # Load the metrics submodule BEFORE binding the hvd.metrics() API below:
 # the first import of a submodule sets it as a package attribute, which
 # would clobber the function whenever internal code lazily imported the

@@ -90,6 +90,9 @@ def init(
             )
 
         st.config = Config.from_env()
+        if devices is None and "HOROVOD_RANK" in os.environ \
+                and jax.process_count() > 1:
+            devices = _devices_in_launcher_order()
         st.mesh = mesh_mod.build_mesh(devices=devices, mesh_shape=mesh_shape)
 
         cross, local = st.mesh.devices.shape
@@ -186,6 +189,33 @@ def init(
 
             port = metrics_registry().serve(st.config.metrics_port)
             log.debug("metrics endpoint serving on port %d", port)
+
+
+def _devices_in_launcher_order():
+    """The global devices, ordered by the launcher rank of the process
+    that owns them.
+
+    ``jax.devices()`` orders by where the backend finds each process: on
+    TPU the runtime numbers processes by the position of their chips in
+    the topology, whatever ``process_id`` the launcher asked for (on a
+    v5e 2x2 host launcher slot 3 came up as process 0). The host data
+    plane numbers ranks by ``HOROVOD_RANK``. Collectives that name a rank
+    (a broadcast root, the order of an allgather) need the two planes to
+    agree, and the launcher's numbering is the contract — so every
+    process publishes its ``HOROVOD_RANK`` in the coordination service
+    and the mesh is laid out in that order."""
+    from horovod_tpu.runtime.coordination import _kv_client
+
+    client = _kv_client()
+    key = "horovod_tpu/launcher_rank/{}".format
+    # allow_overwrite: an elastic re-init publishes the same key again
+    client.key_value_set(key(jax.process_index()),
+                         os.environ["HOROVOD_RANK"], allow_overwrite=True)
+    launcher_rank = {
+        p: int(client.blocking_key_value_get(key(p), 60_000))
+        for p in range(jax.process_count())}
+    return sorted(jax.devices(),
+                  key=lambda d: (launcher_rank[d.process_index], d.id))
 
 
 def _jax_dist_initialized() -> bool:

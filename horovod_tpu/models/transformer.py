@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from horovod_tpu.ops.pallas._backend import shard_over_batch
 from horovod_tpu.ops.pallas.flash_attention import NEG_INF, flash_attention
 
 Dtype = Any
@@ -206,18 +207,19 @@ class SelfAttention(nn.Module):
         if self.attention_fn is not None:
             attn = self.attention_fn
         elif self.is_initializing():
-            # init trace only shapes the params; the Pallas kernel can't
-            # lower off-TPU (and interpret mode is python-speed), so the
-            # once-only init uses the plain XLA attention — enabling
-            # host-side init (training.init_on_host) on remote chips
+            # the init trace only shapes the params (their values do not
+            # depend on the attention output), so it takes the plain XLA
+            # attention: off the TPU the kernel would run in interpret
+            # mode at python speed, and on it ``model.init`` runs op by
+            # op, where a kernel launch per layer buys nothing
             from horovod_tpu.ops.pallas.flash_attention import (
                 attention_reference)
 
             attn = (lambda q, k, v, causal: attention_reference(
                 q, k, v, causal=causal))
         else:
-            attn = (lambda q, k, v, causal: flash_attention(
-                q, k, v, causal=causal))
+            attn = (lambda q, k, v, causal: shard_over_batch(
+                partial(flash_attention, causal=causal), (q, k, v)))
         o = attn(q, k, v, causal=self.causal)
         o = o.transpose(0, 2, 1, 3)  # back to (batch, seq, heads, head_dim)
         return dense(features=d_model, axis=(-2, -1), name="out")(o)

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from horovod_tpu.utils import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu import comms, flight_recorder, tracing
@@ -731,7 +730,7 @@ def _op_event(op: str, st, x, fn, name: Optional[str] = None):
     flight_recorder.emit("op_complete", op=op, shard=int(st.rank),
                          bytes=nbytes, seconds=round(total, 6))
     # comms plane: eager single-controller collectives ride the fused
-    # XLA "device" lane (docs/comms.md lane taxonomy)
+    # XLA "device" lane (docs/comms.md, the lanes)
     comms.record(op, "device", nbytes, total, world=int(st.size))
     if tracing.enabled():
         tracing.record("collective:" + str(name or op), t0_epoch, total,
@@ -863,12 +862,12 @@ def reducescatter(tensor, average: Optional[bool] = None, op: Optional[int] = No
             if red_op == Average:
                 # divide by the size of the axes actually reduced, not
                 # the global world size (they differ for axis_name='local')
-                out = out / compat.axis_size(axes)
+                out = out / lax.axis_size(axes)
             return out
         # XLA's reduce-scatter primitive is sum-only; min/max/product
         # decompose into all_to_all + local reduce — same bytes on the
         # wire as a reduce-scatter (each device sends shard j to owner j)
-        world = compat.axis_size(axes)
+        world = lax.axis_size(axes)
         if tensor.shape[0] % world != 0:
             raise ValueError(
                 f"reducescatter dim 0 ({tensor.shape[0]}) must divide "

@@ -21,8 +21,8 @@ half that composes with autodiff and checkpoints:
 Kernel gating is honest about TPU lane tiling: the channel axis must
 pack lanes exactly — ``C % 128 == 0``, or ``128 % C == 0`` (lane rows
 tile ``128/C`` whole channel groups — covers the stem/reduction convs'
-C ∈ {32, 64}). Everything else, tracers, and non-TPU backends take the
-jnp path, which is also the custom_vjp backward everywhere.
+C ∈ {32, 64}). Everything else and non-TPU backends take the jnp path,
+which is also the custom_vjp backward everywhere.
 ``HOROVOD_FUSED_BN_ACT`` forces the kernel on/off (default: auto — on
 for a TPU default backend); ``HOROVOD_PALLAS_INTERPRET`` runs the
 kernel in interpret mode for tests (same switch as the other kernels).
@@ -36,7 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from horovod_tpu.ops.pallas.fused_adamw import _use_interpret
+from horovod_tpu.ops.pallas._backend import (on_tpu, row_blocks,
+                                             shard_over_batch, use_interpret)
 from horovod_tpu.utils import env as env_mod
 
 # Same launch-worthiness floor as the other kernels.
@@ -45,8 +46,7 @@ _BLOCK_ROWS = 512
 
 
 def _use_kernel() -> bool:
-    default = jax.devices()[0].platform == "tpu"
-    return env_mod._get_bool("HOROVOD_FUSED_BN_ACT", default)
+    return env_mod._get_bool("HOROVOD_FUSED_BN_ACT", on_tpu())
 
 
 def bn_stats(x):
@@ -91,11 +91,7 @@ def _sba_pallas(x, s, b):
     if n % lanes:
         return None
     rows = n // lanes
-    block_rows = min(rows, _BLOCK_ROWS)
-    while rows % block_rows:
-        block_rows -= 1
-    if block_rows < 8:
-        return None
+    block_rows, grid = row_blocks(rows, _BLOCK_ROWS)
     if c % 128 == 0:
         # lane rows walk the channel axis in 128-wide slabs: row r covers
         # channels [(r % (c//128))*128, ...) — broadcast s/b to the same
@@ -114,13 +110,19 @@ def _sba_pallas(x, s, b):
     spec = pl.BlockSpec((block_rows, 128), lambda i: (i, 0))
     out = pl.pallas_call(
         _sba_kernel,
-        grid=(rows // block_rows,),
+        grid=(grid,),
         in_specs=[spec, spec, spec],
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((rows, 128), x.dtype),
-        interpret=_use_interpret(),
+        out_shape=jax.ShapeDtypeStruct((rows, 128), x.dtype,
+                                       vma=jax.typeof(x).vma),
+        interpret=use_interpret(),
     )(x.reshape(rows, 128), s2, b2)
     return out.reshape(x.shape)
+
+
+def _sba(x, s, b):
+    out = _sba_pallas(x, s, b)
+    return _sba_jnp(x, s, b) if out is None else out
 
 
 @jax.custom_vjp
@@ -131,10 +133,9 @@ def scale_bias_act(x, s, b):
     (see module docstring); the backward is the standard masked chain in
     jnp — XLA fuses it into the surrounding conv backward anyway."""
     if x.ndim >= 1 and _use_kernel():
-        # shape gating is static, so this composes with jit/scan traces
-        out = _sba_pallas(x, s, b)
-        if out is not None:
-            return out
+        # shape gating is static, so this composes with jit/scan traces;
+        # on several chips each runs the kernel on its rows of the batch
+        return shard_over_batch(_sba, (x,), (s, b))
     return _sba_jnp(x, s, b)
 
 

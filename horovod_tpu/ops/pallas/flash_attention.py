@@ -35,7 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.utils import compat
+from horovod_tpu.ops.pallas._backend import use_interpret
 from horovod_tpu.utils import env as env_mod
 
 NEG_INF = float("-inf")
@@ -48,11 +48,6 @@ LANES = 128
 # VPU): scores are pre-scaled by log2(e), the log-sum-exp converts back on
 # the way out.
 LOG2E = float(np.log2(np.e))
-
-
-def _use_interpret() -> bool:
-    default = jax.devices()[0].platform != "tpu"
-    return env_mod._get_bool("HOROVOD_PALLAS_INTERPRET", default)
 
 
 def _mxu_bf16(*refs) -> bool:
@@ -82,7 +77,7 @@ def _vma(*arrays) -> frozenset:
     the right vma under ``shard_map(check_vma=True)``."""
     out = frozenset()
     for a in arrays:
-        out |= getattr(jax.typeof(a), "vma", frozenset())
+        out |= jax.typeof(a).vma
     return out
 
 
@@ -352,8 +347,8 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
             in_specs=[_OFF_SPEC, _OFF_SPEC, sq_spec, sk_spec, sk_spec],
             out_specs=[sq_spec, srow_spec],
             out_shape=[
-                compat.sds(q.shape, q.dtype, vma=vma),
-                compat.sds((batch, heads, q_seq, LANES),
+                jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+                jax.ShapeDtypeStruct((batch, heads, q_seq, LANES),
                                      jnp.float32, vma=vma),
             ],
             compiler_params=pltpu.CompilerParams(
@@ -372,8 +367,8 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
         in_specs=[_OFF_SPEC, _OFF_SPEC, q_spec, k_spec, k_spec],
         out_specs=[q_spec, qrow_spec],
         out_shape=[
-            compat.sds(q.shape, q.dtype, vma=vma),
-            compat.sds((batch, heads, q_seq, LANES), jnp.float32,
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((batch, heads, q_seq, LANES), jnp.float32,
                                  vma=vma),
         ],
         scratch_shapes=[
@@ -757,9 +752,9 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
                       bh_k_spec, bh_q_spec, bh_row_spec, bh_row_spec],
             out_specs=[bh_q_spec, bh_k_spec, bh_k_spec],
             out_shape=[
-                compat.sds(q.shape, q.dtype, vma=vma),
-                compat.sds(k.shape, k.dtype, vma=vma),
-                compat.sds(v.shape, v.dtype, vma=vma),
+                jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+                jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+                jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
@@ -779,7 +774,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             in_specs=[_OFF_SPEC, _OFF_SPEC, sq_spec, sk_spec, sk_spec,
                       sq_spec, srow_spec, srow_spec],
             out_specs=sq_spec,
-            out_shape=compat.sds(q.shape, q.dtype, vma=vma),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret,
@@ -800,8 +795,8 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
                       gq_spec, grow_spec, grow_spec],
             out_specs=[gk_spec, gk_spec],
             out_shape=[
-                compat.sds(k.shape, k.dtype, vma=vma),
-                compat.sds(v.shape, v.dtype, vma=vma),
+                jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+                jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
@@ -820,7 +815,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             in_specs=[_OFF_SPEC, _OFF_SPEC, q_spec, k_spec, k_spec,
                       q_spec, qrow_spec, qrow_spec],
             out_specs=q_spec,
-            out_shape=compat.sds(q.shape, q.dtype, vma=vma),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
             scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
             compiler_params=_compiler_params(4),
             interpret=interpret,
@@ -846,8 +841,8 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
                       kq_k_spec, kq_q_spec, kq_qrow_spec, kq_qrow_spec],
             out_specs=[kq_k_spec, kq_k_spec],
             out_shape=[
-                compat.sds(k.shape, k.dtype, vma=vma),
-                compat.sds(v.shape, v.dtype, vma=vma),
+                jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+                jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, dim), jnp.float32),
@@ -870,7 +865,7 @@ def _flash(q, k, v, q_offset, k_offset, sm_scale, causal, block_q, block_k,
            bwd_block_q, bwd_block_k):
     o, _ = _flash_fwd(q, k, v, q_offset, k_offset, sm_scale=sm_scale,
                       causal=causal, block_q=block_q, block_k=block_k,
-                      interpret=_use_interpret())
+                      interpret=use_interpret())
     return o
 
 
@@ -878,7 +873,7 @@ def _flash_vjp_fwd(q, k, v, q_offset, k_offset, sm_scale, causal,
                    block_q, block_k, bwd_block_q, bwd_block_k):
     o, lse = _flash_fwd(q, k, v, q_offset, k_offset, sm_scale=sm_scale,
                         causal=causal, block_q=block_q, block_k=block_k,
-                        interpret=_use_interpret())
+                        interpret=use_interpret())
     return o, (q, k, v, o, lse, q_offset, k_offset)
 
 
@@ -888,7 +883,7 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, bwd_block_q,
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset,
                             sm_scale=sm_scale, causal=causal,
                             block_q=bwd_block_q, block_k=bwd_block_k,
-                            interpret=_use_interpret())
+                            interpret=use_interpret())
     zero = jnp.zeros((1,), jnp.int32)
     return dq, dk, dv, zero, zero
 
@@ -950,7 +945,7 @@ def flash_attention_partial(
     o, lse = _flash_fwd(q, k, v, _as_offset(q_offset), _as_offset(k_offset),
                         sm_scale=float(sm_scale), causal=bool(causal),
                         block_q=int(block_q), block_k=int(block_k),
-                        interpret=_use_interpret())
+                        interpret=use_interpret())
     return o, lse[..., 0]
 
 

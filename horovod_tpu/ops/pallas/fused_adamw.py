@@ -46,6 +46,7 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.ops.pallas._backend import row_blocks, use_interpret
 from horovod_tpu.utils import env as env_mod
 
 # Leaves smaller than this skip Pallas (a kernel launch isn't worth it for
@@ -54,11 +55,6 @@ _MIN_PALLAS = 16 * 1024
 # elements per grid step (tunable for A/B; 64k elements = 256 KB blocks,
 # 7 live blocks x double buffering ~ 3.5 MB VMEM)
 _BLOCK = env_mod._get_int("FUSED_ADAMW_BLOCK", 64 * 1024)
-
-
-def _use_interpret() -> bool:
-    default = jax.devices()[0].platform != "tpu"
-    return env_mod._get_bool("HOROVOD_PALLAS_INTERPRET", default)
 
 
 def _adamw_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, p_out, m_out, v_out,
@@ -100,27 +96,19 @@ def _leaf_update(p, m, v, g, scalars, *, eps):
         return _jnp_leaf(p, m, v, g, scalars, eps)
 
     rows = n // 128
-    block_rows = min(rows, _BLOCK // 128)
-    while rows % block_rows:
-        block_rows -= 1
-    if block_rows < 8:
-        # no decent divisor (e.g. a prime row count): a grid of ~rows
-        # 128-element kernel steps is correct but a severe perf cliff —
-        # the XLA elementwise chain is the better program for such
-        # leaves (r4 advisor finding)
-        return _jnp_leaf(p, m, v, g, scalars, eps)
+    block_rows, grid = row_blocks(rows, _BLOCK // 128)
     flat = lambda a: a.reshape((rows, 128))
     spec = pl.BlockSpec((block_rows, 128), lambda i: (i, 0))
     p2, m2, v2 = pl.pallas_call(
         functools.partial(_adamw_kernel, eps=eps),
-        grid=(rows // block_rows,),
+        grid=(grid,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   spec, spec, spec, spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((rows, 128), p.dtype),
                    jax.ShapeDtypeStruct((rows, 128), m.dtype),
                    jax.ShapeDtypeStruct((rows, 128), v.dtype)],
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
     )(scalars, flat(p), flat(m), flat(v), flat(g))
     return p2.reshape(p.shape), m2.reshape(m.shape), v2.reshape(v.shape)
 

@@ -19,10 +19,11 @@ step), so the jnp fallback and the kernel agree with the replicated
 optax chain at fp32.
 
 ``HOROVOD_SHARDED_FUSED_KERNEL`` gates the Pallas path (default: on
-when the backend is TPU, off elsewhere); the jnp fallback is always
-available and is also used for shapes Pallas can't tile well (tiny
-shards, non-multiple-of-128 lengths, stacked 2-D single-controller
-layouts where the buffer is sharded across devices).
+when the backend is TPU, off elsewhere). Which path a call takes is
+decided from its arguments alone (:func:`jnp_reason`): the jnp chain
+serves tracers, stacked 2-D single-controller layouts where the buffer
+is sharded across devices, tiny shards and non-multiple-of-128 lengths;
+everything else is the kernel, which compiles or raises.
 """
 
 from __future__ import annotations
@@ -35,8 +36,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.pallas.fused_adamw import _use_interpret
+from horovod_tpu.ops.pallas._backend import (on_tpu, row_blocks,
+                                             use_interpret)
 from horovod_tpu.utils import env as env_mod
+from horovod_tpu.utils import logging as log
 
 # Same tiling policy as fused_adamw: skip Pallas below this (launch not
 # worth it), and grid-step this many elements (256 KB f32 blocks).
@@ -45,9 +48,8 @@ _BLOCK = env_mod._get_int("FUSED_OPTIMIZER_BLOCK", 64 * 1024)
 
 
 def _use_kernel() -> bool:
-    default = jax.devices()[0].platform == "tpu"
     return env_mod._get_bool(env_mod.HOROVOD_SHARDED_FUSED_KERNEL,
-                             default)
+                             on_tpu())
 
 
 def _flat_adamw_kernel(sc_ref, mw_ref, m_ref, v_ref, g_ref,
@@ -82,6 +84,56 @@ def _jnp_flat(master, mu, nu, grad, scalars, eps, out_dtype):
     return w2.astype(out_dtype), w2, m2, v2
 
 
+def jnp_reason(master) -> str | None:
+    """Why this call takes the jnp chain, or ``None`` for the kernel."""
+    if isinstance(master, jax.core.Tracer):
+        # traced under shard_map: Pallas-per-device would need careful
+        # vmem accounting inside the spmd body
+        return "traced"
+    if master.ndim != 1:
+        # stacked 2-D single-controller buffer sharded across devices:
+        # the XLA elementwise chain is the right program
+        return "stacked 2-D buffer"
+    if not _use_kernel():
+        return "kernel off"
+    n = int(master.shape[0])
+    if n < _MIN_PALLAS:
+        return "shard below the launch-worthiness floor"
+    if n % 128:
+        return "length not a multiple of 128 lanes"
+    return None
+
+
+@functools.lru_cache(maxsize=None)
+def _log_path_once(reason: str | None) -> None:
+    log.debug("flat_adamw_shard: %s",
+              "pallas kernel" if reason is None else f"jnp ({reason})")
+
+
+def pallas_flat_adamw(master, mu, nu, grad, scalars, *, eps, out_dtype):
+    """The kernel pass over 1-D buffers whose length is a multiple of
+    128. Unlike :func:`flat_adamw_shard` it takes tracers, so a test can
+    lower it for the TPU (tests/test_tpu_compile.py)."""
+    n = int(master.shape[0])
+    rows = n // 128
+    block_rows, grid = row_blocks(rows, _BLOCK // 128)
+    flat = lambda a: a.reshape((rows, 128))
+    spec = pl.BlockSpec((block_rows, 128), lambda i: (i, 0))
+    p2, w2, m2, v2 = pl.pallas_call(
+        functools.partial(_flat_adamw_kernel, eps=eps),
+        grid=(grid,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  spec, spec, spec, spec],
+        out_specs=[spec, spec, spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((rows, 128), out_dtype),
+                   jax.ShapeDtypeStruct((rows, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, 128), jnp.float32)],
+        interpret=use_interpret(),
+    )(scalars, flat(master), flat(mu), flat(nu), flat(grad))
+    return (p2.reshape(n), w2.reshape(n), m2.reshape(n), v2.reshape(n))
+
+
 def flat_adamw_shard(master, mu, nu, grad, scalars, *, eps, out_dtype):
     """One fused AdamW pass over a flat fp32 master shard.
 
@@ -91,33 +143,9 @@ def flat_adamw_shard(master, mu, nu, grad, scalars, *, eps, out_dtype):
     ``(params_shard[out_dtype], master', mu', nu')``.
     """
     out_dtype = jnp.dtype(out_dtype)
-    if isinstance(master, jax.core.Tracer) or master.ndim != 1:
-        # traced under shard_map (Pallas-per-device would need careful
-        # vmem accounting inside the spmd body) or a stacked 2-D
-        # single-controller buffer sharded across devices: the XLA
-        # elementwise chain is the right program
+    reason = jnp_reason(master)
+    _log_path_once(reason)
+    if reason is not None:
         return _jnp_flat(master, mu, nu, grad, scalars, eps, out_dtype)
-    n = int(master.shape[0])
-    if not _use_kernel() or n < _MIN_PALLAS or n % 128:
-        return _jnp_flat(master, mu, nu, grad, scalars, eps, out_dtype)
-    rows = n // 128
-    block_rows = min(rows, _BLOCK // 128)
-    while rows % block_rows:
-        block_rows -= 1
-    if block_rows < 8:
-        return _jnp_flat(master, mu, nu, grad, scalars, eps, out_dtype)
-    flat = lambda a: a.reshape((rows, 128))
-    spec = pl.BlockSpec((block_rows, 128), lambda i: (i, 0))
-    p2, w2, m2, v2 = pl.pallas_call(
-        functools.partial(_flat_adamw_kernel, eps=eps),
-        grid=(rows // block_rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  spec, spec, spec, spec],
-        out_specs=[spec, spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((rows, 128), out_dtype),
-                   jax.ShapeDtypeStruct((rows, 128), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, 128), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, 128), jnp.float32)],
-        interpret=_use_interpret(),
-    )(scalars, flat(master), flat(mu), flat(nu), flat(grad))
-    return (p2.reshape(n), w2.reshape(n), m2.reshape(n), v2.reshape(n))
+    return pallas_flat_adamw(master, mu, nu, grad, scalars, eps=eps,
+                             out_dtype=out_dtype)

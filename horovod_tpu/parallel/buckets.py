@@ -361,7 +361,7 @@ class GradReleasePlan:
             return g
         from jax import lax
 
-        r = lax.pmean(g, axes) if self.average else lax.psum(g, axes)
+        r = dp_mod._reduce_traced(g, axes, self.average)
         if boundary:
             # chain a token through the barrier at every bucket boundary:
             # the data dependency serializes the boundaries, so XLA keeps

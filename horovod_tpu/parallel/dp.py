@@ -30,8 +30,6 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from horovod_tpu.utils import compat
-
 from horovod_tpu.compression import Compression
 from horovod_tpu.core import basics, mesh as mesh_mod
 from horovod_tpu.ops import collectives
@@ -47,11 +45,35 @@ def _bound_axes(axis_name=None) -> tuple:
     bound = []
     for a in axes:
         try:
-            compat.axis_size(a)
+            lax.axis_size(a)
         except NameError:
             continue
         bound.append(a)
     return tuple(bound)
+
+
+def _reduce_traced(g, axes, average: bool):
+    """Sum or mean of a traced per-device gradient over the bound mesh
+    ``axes``.
+
+    Under ``jax.shard_map``'s default ``check_vma=True`` the cotangent of
+    a replicated input is typed unvarying and arrives already ``psum``-ed
+    by autodiff (the transpose of the implicit replicated-to-varying
+    cast), so a ``pmean`` on top of it would hand back the sum: N times
+    the mean. Reduce only over the axes the gradient still varies over
+    and divide by the whole world. With ``check_vma=False`` nothing is
+    typed and every gradient is per-device, as before."""
+    axes = tuple(axes)
+    # axis_index is typed varying over its axis exactly when shard_map
+    # tracks varying axes (check_vma=True)
+    if axes[0] in jax.typeof(lax.axis_index(axes[0])).vma:
+        varying = tuple(a for a in axes if a in jax.typeof(g).vma)
+    else:
+        varying = axes
+    if varying == axes:
+        return lax.pmean(g, axes) if average else lax.psum(g, axes)
+    r = lax.psum(g, varying) if varying else g
+    return r / lax.axis_size(axes) if average else r
 
 
 def _allreduce_leaf(g, average, compression, axis_name,
@@ -76,8 +98,7 @@ def _allreduce_leaf(g, average, compression, axis_name,
             # average; XLA inserted the collective from the shardings.
             return g
         c, ctx = compression.compress(g)
-        red = lax.pmean(c, axes) if average else lax.psum(c, axes)
-        return compression.decompress(red, ctx)
+        return compression.decompress(_reduce_traced(c, axes, average), ctx)
     return collectives.allreduce(
         g, average=average, compression=compression, axis_name=axis_name
     )

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.utils import compat
-
 from horovod_tpu.parallel._util import consume_stage_axis
 
 
@@ -48,7 +46,7 @@ def switch_moe(x, gate_logits, expert_fn: Callable, expert_params,
             f"switch_moe takes ONE mesh axis name (got {axis_name!r}); "
             "the all_to_all routes over a single axis — reshape the mesh "
             "if experts should span multiple axes")
-    n_exp = compat.axis_size(axis_name)
+    n_exp = lax.axis_size(axis_name)
     d = x.shape[-1]
     if gate_logits.shape[-1] != n_exp:
         raise ValueError(

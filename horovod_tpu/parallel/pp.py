@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.utils import compat
-
 from horovod_tpu.parallel._util import (  # noqa: F401 — re-exported API
     consume_stage_axis,
     stack_stage_params,
@@ -50,7 +48,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x,
             "pipeline_apply takes ONE mesh axis name (the ppermute ring "
             f"is a single axis); got {axis_name!r} — reshape the mesh so "
             "the pipeline spans one axis")
-    n_stages = compat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     n_micro = x.shape[0]
     ticks = n_micro + n_stages - 1
@@ -80,9 +78,9 @@ def pipeline_apply(stage_fn: Callable, stage_params, x,
     out_shape = jax.eval_shape(stage_fn, stage_params, x[0])
     state0 = jnp.zeros(out_shape.shape, out_shape.dtype)
     outputs0 = jnp.zeros((n_micro,) + out_shape.shape, out_shape.dtype)
-    # mark device-varying over the pipeline axis (lax.pvary successor)
-    state0 = compat.pvary(state0, (axis_name,))
-    outputs0 = compat.pvary(outputs0, (axis_name,))
+    # mark device-varying over the pipeline axis
+    state0 = lax.pcast(state0, (axis_name,), to="varying")
+    outputs0 = lax.pcast(outputs0, (axis_name,), to="varying")
     (final_state, outputs), _ = lax.scan(
         tick, (state0, outputs0), jnp.arange(ticks))
     return outputs
@@ -94,7 +92,7 @@ def last_stage_value(value, axis_name: str):
     unlike a gather)."""
     from horovod_tpu.ops import collectives
 
-    n_stages = compat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     return collectives.broadcast(value, n_stages - 1, axis_name=axis_name)
 
 

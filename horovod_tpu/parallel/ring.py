@@ -34,14 +34,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.utils import compat
-
+from horovod_tpu.ops.pallas._backend import use_interpret
 from horovod_tpu.ops.pallas.flash_attention import (
     LANES,
     NEG_INF,
     _as_offset,
     _flash_bwd,
-    _use_interpret,
     compute_delta,
     flash_attention_partial,
     merge_partials,
@@ -49,7 +47,7 @@ from horovod_tpu.ops.pallas.flash_attention import (
 
 
 def _axis_perm(axis_name):
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     # send to the left neighbour: device i receives the chunk held by i+1,
     # so after s steps device i holds the chunk owned by (i + s) % n.
     return [(j, (j - 1) % n) for j in range(n)]
@@ -62,8 +60,8 @@ def _ppermute_tree(xs, axis_name, perm):
 
 def _pcast(x, axis_name):
     """Mark a freshly created array as device-varying over ``axis_name`` so
-    it can carry through a scan whose outputs vary (lax.pvary successor)."""
-    return compat.pvary(x, axis_name)
+    it can carry through a scan whose outputs vary."""
+    return lax.pcast(x, axis_name, to="varying")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -84,7 +82,7 @@ def ring_attention(q, k, v, axis_name, causal=False, sm_scale=None,
 
 
 def _ring_fwd(q, k, v, axis_name, causal, sm_scale, block_q, block_k):
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = k.shape[2]
     q_off = my * q.shape[2]
@@ -127,7 +125,7 @@ def _ring_vjp_fwd(q, k, v, axis_name, causal, sm_scale, block_q, block_k,
 def _ring_vjp_bwd(axis_name, causal, sm_scale, block_q, block_k,
                   bwd_block_q, bwd_block_k, res, do):
     q, k, v, o, lse = res
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_local = k.shape[2]
     q_off = my * q.shape[2]
@@ -146,7 +144,7 @@ def _ring_vjp_bwd(axis_name, causal, sm_scale, block_q, block_k,
             _as_offset(q_off), _as_offset(src * s_local),
             sm_scale=float(scale), causal=causal,
             block_q=bwd_block_q, block_k=bwd_block_k,
-            interpret=_use_interpret(), delta=delta)
+            interpret=use_interpret(), delta=delta)
         dq = dq + dq_p.astype(dq.dtype)
         dk_acc = dk_acc + dk_p.astype(dk_acc.dtype)
         dv_acc = dv_acc + dv_p.astype(dv_acc.dtype)

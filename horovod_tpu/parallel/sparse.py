@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.utils import compat
-
 from horovod_tpu.core import mesh as mesh_mod
 
 
@@ -150,7 +148,7 @@ def exchange_sparse_grad(sg: SparseGrad, *, average: bool,
         if bound_axes:
             world = 1
             for a in bound_axes:
-                world *= compat.axis_size(a)
+                world *= lax.axis_size(a)
             c_values, ctx = compression.compress(sg.values)
             gathered = sparse_allgather(
                 SparseGrad(sg.indices, c_values, sg.num_rows),

@@ -22,8 +22,6 @@ from typing import Callable, Optional
 import jax
 from jax import lax
 
-from horovod_tpu.utils import compat
-
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
 
 
@@ -41,7 +39,7 @@ def ulysses_attention(q, k, v, axis_name, *, causal: bool = False,
     ``attn_fn(q, k, v, causal=..., sm_scale=...)`` defaults to the Pallas
     flash kernel; it sees full-sequence inputs with ``heads/N`` heads.
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     heads = q.shape[1]
     if heads % n:
         raise ValueError(

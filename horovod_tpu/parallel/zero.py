@@ -112,7 +112,6 @@ from horovod_tpu.ops import collectives
 from horovod_tpu.ops.pallas import fused_optimizer as fused_mod
 from horovod_tpu.parallel import sparse as sparse_mod
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
-from horovod_tpu.utils import compat
 from horovod_tpu.utils import env as env_mod
 
 _UPDATES = _metrics().counter(
@@ -319,7 +318,7 @@ def _bound_axes(axis_name=None) -> tuple:
     bound = []
     for a in axes:
         try:
-            compat.axis_size(a)
+            lax.axis_size(a)
         except NameError:
             continue
         bound.append(a)
@@ -503,7 +502,7 @@ def scatter_gradients(grads, *, spec: ZeroSpec = None,
                 "scatter_gradients traced without a bound mesh axis — "
                 "use shard_map (or run eagerly)")
         if spec is None:
-            world = int(np.prod([compat.axis_size(a) for a in axes]))
+            world = int(np.prod([lax.axis_size(a) for a in axes]))
             spec = build_spec(leaves, world, -1,
                               _quantum_bytes(basics._ensure_init()),
                               partition=partition)
@@ -962,7 +961,7 @@ def sharded_update(optimizer, *, average: bool = True,
                     "shard_optimizer_states under plain jit/pjit has no "
                     "mesh axis to shard over — call it under shard_map, "
                     "eagerly, or in multi-process mode")
-            world = int(np.prod([compat.axis_size(a) for a in axes]))
+            world = int(np.prod([lax.axis_size(a) for a in axes]))
             spec = build_spec(leaves, world, -1,
                               _quantum_bytes(basics._ensure_init()),
                               partition=partition)
@@ -1342,7 +1341,7 @@ def sharded_adamw(learning_rate: float, b1: float = 0.9,
                     "sharded_adamw under plain jit/pjit has no mesh axis "
                     "to shard over — use shard_map, eager, or "
                     "multi-process mode")
-            world = int(np.prod([compat.axis_size(a) for a in axes]))
+            world = int(np.prod([lax.axis_size(a) for a in axes]))
             spec = build_spec(leaves, world, -1,
                               _quantum_bytes(basics._ensure_init()),
                               partition=partition)

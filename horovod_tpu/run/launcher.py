@@ -77,6 +77,73 @@ def get_driver_ip(slots: List[SlotInfo]) -> str:
         return socket.gethostname()
 
 
+class SlotLayoutError(ValueError):
+    """The requested slots cannot each be given a TPU chip of their own."""
+
+
+# TPU_PROCESS_BOUNDS for N one-chip processes sharing one host: the table
+# of JAX's own multi-process launcher (jax/_src/test_multiprocess.py).
+# 4 is the v5e 2x2 host that ``chip_smoke.py --chips 4`` runs; 8 has not
+# been run from here.
+_ONE_CHIP_PROCESS_BOUNDS = {4: "2,2,1", 8: "4,2,1"}
+
+
+def local_tpu_chips(env: Dict[str, str]) -> int:
+    """TPU chips attached to this host, counted from sysfs as JAX itself
+    counts them — no backend is initialised, so the launcher never holds
+    a chip. 0 when ``JAX_PLATFORMS`` keeps the workers off the TPU."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def tpu_chip_envs(slots: List[SlotInfo], chips: int,
+                  use_jax_distributed: bool) -> List[Dict[str, str]]:
+    """Per-slot ``TPU_*`` environment that gives every local slot a chip
+    of its own: a chip belongs to one process, and without this every
+    worker on a multi-chip host opens every chip and all but one hang.
+
+    One slot per host needs nothing (that process drives all its host's
+    chips). Several slots on this host get chip ``local_rank`` each:
+    joined into one global mesh under ``jax.distributed``, or as
+    isolated one-chip worlds for the socket controller. Raises
+    :class:`SlotLayoutError` for a layout it cannot serve: refusing here
+    is loud, where every worker opening every chip would hang."""
+    n = max(s.local_size for s in slots)
+    if chips == 0 or n == 1:
+        return [{} for _ in slots]
+    if not all(is_local_host(s.hostname) for s in slots):
+        raise SlotLayoutError(
+            f"{n} slots per host across several hosts is not supported "
+            f"on TPU: give each host one slot (one process drives all of "
+            f"a host's chips)")
+    if n > chips:
+        raise SlotLayoutError(
+            f"{n} local slots but this host has {chips} TPU chip(s): a "
+            f"chip belongs to one process")
+    common = {"TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+              "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+    if not use_jax_distributed:
+        return [dict(common, TPU_PROCESS_BOUNDS="1,1,1",
+                     TPU_VISIBLE_CHIPS=str(s.local_rank)) for s in slots]
+    if n not in _ONE_CHIP_PROCESS_BOUNDS:
+        raise SlotLayoutError(
+            f"{n} one-chip processes on one TPU host: supported counts "
+            f"are {sorted(_ONE_CHIP_PROCESS_BOUNDS)} (or one slot, "
+            f"driving every chip)")
+    ports = [_free_port() for _ in slots]
+    addresses = ",".join(f"localhost:{port}" for port in ports)
+    return [dict(common,
+                 TPU_PROCESS_BOUNDS=_ONE_CHIP_PROCESS_BOUNDS[n],
+                 TPU_PROCESS_ADDRESSES=addresses,
+                 TPU_PROCESS_PORT=str(ports[s.local_rank]),
+                 TPU_VISIBLE_CHIPS=str(s.local_rank),
+                 CLOUD_TPU_TASK_ID=str(s.local_rank)) for s in slots]
+
+
 def build_worker_env(slot: SlotInfo, base_env: Dict[str, str],
                      driver_ip: str, socket_port: int, http_port: int,
                      coordinator_port: int, num_processes: int,
@@ -205,6 +272,9 @@ def launch_job(command: str, slots: List[SlotInfo],
             print(f"tpurun: NIC discovery failed ({exc}); using "
                   f"{driver_ip}", file=sys.stderr)
 
+    chip_envs = tpu_chip_envs(slots, local_tpu_chips(base_env),
+                              use_jax_distributed)
+
     rendezvous = RendezvousServer()
     http_port = rendezvous.start()
     _announce_net_chaos()
@@ -232,6 +302,7 @@ def launch_job(command: str, slots: List[SlotInfo],
             coordinator_port,
             num_processes=len(slots),
             use_jax_distributed=use_jax_distributed)
+        worker_env.update(chip_envs[i])
         if elastic:
             worker_env["HOROVOD_ELASTIC"] = "1"
             worker_env["HOROVOD_ELASTIC_MIN_WORKERS"] = str(min_workers)

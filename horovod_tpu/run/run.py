@@ -509,14 +509,19 @@ def run_commandline(argv: Optional[List[str]] = None) -> int:
         discovery_script=args.host_discovery_script,
         flight_recorder_dir=args.flight_recorder_dir,
         profile_dir=args.profile_dir)
-    if args.supervise:
-        budget = (args.restart_budget if args.restart_budget is not None
-                  else 3)
-        launch_kwargs.pop("env")
-        return launcher.launch_supervised(
-            command_str, slots, restart_budget=budget, env=env,
-            **launch_kwargs)
-    return launcher.launch_job(command_str, slots, **launch_kwargs)
+    try:
+        if args.supervise:
+            budget = (args.restart_budget
+                      if args.restart_budget is not None else 3)
+            launch_kwargs.pop("env")
+            return launcher.launch_supervised(
+                command_str, slots, restart_budget=budget, env=env,
+                **launch_kwargs)
+        return launcher.launch_job(command_str, slots, **launch_kwargs)
+    except launcher.SlotLayoutError as exc:
+        # raised before anything is spawned
+        sys.stderr.write(f"tpurun: {exc}\n")
+        return 2
 
 
 def main() -> None:

@@ -18,8 +18,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import horovod_tpu as hvd  # noqa: E402
 
 

@@ -191,25 +191,17 @@ def test_bench_compare_expands_summary_and_skips_tiny(bench_compare,
     assert not any(k.startswith("summary") for k in rows)
 
 
-def test_bench_compare_real_artifacts(bench_compare):
-    """The repo's own trajectory must pass its own gate (PR 6
-    acceptance: r04 -> r05 runs clean)."""
-    r04 = os.path.join(_REPO, "BENCH_r04.json")
-    r05 = os.path.join(_REPO, "BENCH_r05.json")
-    if not (os.path.exists(r04) and os.path.exists(r05)):
-        pytest.skip("BENCH artifacts not present")
-    assert bench_compare.main([r04, r05]) == 0
-
-
-def test_bench_compare_r05_to_r06(bench_compare):
-    """ISSUE 12 acceptance: the bucket-wise gradient release round must
-    clear the gate against r05 — ResNet-50 and Inception-V3 MFU up well
-    past the 5% threshold, nothing else regressed."""
-    r05 = os.path.join(_REPO, "BENCH_r05.json")
-    r06 = os.path.join(_REPO, "BENCH_r06.json")
-    if not (os.path.exists(r05) and os.path.exists(r06)):
-        pytest.skip("BENCH artifacts not present")
-    assert bench_compare.main([r05, r06]) == 0
+@pytest.mark.parametrize("base,cand", [("BENCH_r05.json",
+                                        "BENCH_r06.json")])
+def test_bench_compare_real_artifacts(bench_compare, base, cand):
+    """The repo's own trajectory must pass its own gate, over every pair
+    of consecutive chip records the repository still holds. r05 -> r06 is
+    ISSUE 12's acceptance: the bucket-wise gradient release round clears
+    the gate against r05 — ResNet-50 and Inception-V3 MFU up well past
+    the 5% threshold, nothing else regressed. (r04 -> r05 went with the
+    r01-r04 records in PR 21.)"""
+    assert bench_compare.main([os.path.join(_REPO, base),
+                               os.path.join(_REPO, cand)]) == 0
 
 
 def test_bench_compare_memory_row_regression_fails(bench_compare,

@@ -60,19 +60,18 @@ class TestFusedAdamW:
                                    rtol=2e-5, atol=2e-6)
         assert int(state.count) == 4
 
-    def test_prime_row_leaf_takes_jnp_path(self):
-        """A leaf whose 128-lane row count is prime has no usable block
-        divisor — the r4 advisor flagged that searching down to
-        block_rows=1 builds a grid of per-row kernel steps (correct but a
-        cliff); such leaves must route to the XLA elementwise path and
-        still match optax."""
+    def test_prime_row_leaf_matches_optax(self):
+        """A leaf whose 128-lane row count is prime and taller than one
+        block has no block divisor at all: the grid's last block is
+        ragged (a divisor search would end at block_rows=1, a grid of
+        per-row kernel steps — or at a row count Mosaic refuses). Still
+        one kernel pass, still optax's numbers."""
         from horovod_tpu.ops.pallas import fused_adamw
 
         rng = np.random.RandomState(1)
-        # 131 rows of 128 lanes: >= _MIN_PALLAS (16384), n % 128 == 0,
-        # prime row count
-        params = {"prime": jnp.asarray(rng.randn(131 * 128), jnp.float32)}
-        grads = {"prime": jnp.asarray(rng.randn(131 * 128), jnp.float32)}
+        # 1049 rows of 128 lanes: prime, two whole 512-row blocks + 25
+        params = {"prime": jnp.asarray(rng.randn(1049 * 128), jnp.float32)}
+        grads = {"prime": jnp.asarray(rng.randn(1049 * 128), jnp.float32)}
         lr, wd = 1e-2, 1e-3
         ref_tx = optax.adamw(lr, weight_decay=wd)
         upd, _ = ref_tx.update(grads, ref_tx.init(params), params)
@@ -82,6 +81,52 @@ class TestFusedAdamW:
         np.testing.assert_allclose(np.asarray(p["prime"]),
                                    np.asarray(ref_p["prime"]),
                                    rtol=2e-5, atol=2e-6)
+
+    def test_flat_shard_kernel_ragged_block_matches_jnp(self):
+        """The ZeRO flat-shard kernel over a shard whose rows leave a
+        ragged last block (interpret mode here; tests/test_tpu_compile.py
+        puts the same call before the TPU compiler)."""
+        from horovod_tpu.ops.pallas import fused_optimizer
+
+        rng = np.random.RandomState(2)
+        n = 1049 * 128
+        master, mu, grad = (jnp.asarray(rng.randn(n), jnp.float32)
+                            for _ in range(3))
+        nu = jnp.asarray(rng.rand(n), jnp.float32)
+        grad = grad.astype(jnp.bfloat16)
+        scalars = jnp.asarray([0.9, 0.999, 10.0, 1000.0, 1e-3, 1e-2],
+                              jnp.float32)
+        got = fused_optimizer.pallas_flat_adamw(
+            master, mu, nu, grad, scalars, eps=1e-8,
+            out_dtype=jnp.bfloat16)
+        want = fused_optimizer._jnp_flat(
+            master, mu, nu, grad, scalars, 1e-8, jnp.dtype(jnp.bfloat16))
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_allclose(np.asarray(g, np.float32),
+                                       np.asarray(w, np.float32),
+                                       rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("shape,dtype", [
+        ((2400, 32), jnp.bfloat16),   # 128/c channel groups per lane row
+        ((300, 256), jnp.float32),    # c/128 lane rows per position
+    ])
+    def test_scale_bias_act_kernel_ragged_block_matches_jnp(self, shape,
+                                                            dtype):
+        """The fused BN+ReLU epilogue kernel at 600 lane rows: one whole
+        512-row block and a ragged one, in both lane layouts."""
+        from horovod_tpu.ops.pallas import conv_bn_act
+
+        rng = np.random.RandomState(3)
+        x = jnp.asarray(rng.randn(*shape), dtype)
+        s = jnp.asarray(rng.rand(shape[-1]) + 0.5, jnp.float32)
+        b = jnp.asarray(rng.randn(shape[-1]), jnp.float32)
+        got = conv_bn_act._sba_pallas(x, s, b)
+        assert got is not None and got.dtype == x.dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32),
+            np.asarray(conv_bn_act._sba_jnp(x, s, b), np.float32),
+            rtol=1e-5, atol=1e-6)
 
 
 class TestDistributedOptimizer:
@@ -111,6 +156,33 @@ class TestDistributedOptimizer:
         assert float(loss) < 1e-3
         np.testing.assert_allclose(
             np.asarray(params["w"]).ravel(), [2.0, -3.0], atol=0.05)
+
+    @pytest.mark.parametrize("check_vma", [True, False])
+    @pytest.mark.parametrize("average", [True, False])
+    def test_shard_map_reduction_under_either_vma_setting(self, hvd,
+                                                          check_vma,
+                                                          average):
+        """Per-device losses, replicated parameters: the wrapper hands the
+        inner optimizer the mean (or sum) of the per-device gradients
+        under shard_map's default ``check_vma=True`` — where autodiff has
+        already summed the cotangent of a replicated input — exactly as
+        under ``check_vma=False``."""
+        opt = hvd.DistributedOptimizer(optax.sgd(1.0), average=average)
+        params = {"w": jnp.ones(4)}
+
+        def inner(p, xb):
+            g = jax.grad(lambda p: (p["w"] * xb.sum()).sum())(p)
+            updates, _ = opt.update(g, opt.init(p), p)
+            return updates
+
+        f = jax.shard_map(inner, mesh=hvd.mesh(),
+                          in_specs=(P(), P(hvd.GLOBAL_AXES)),
+                          out_specs=P(), check_vma=check_vma)
+        x = jnp.arange(float(hvd.size()))
+        want = float(np.mean(np.asarray(x)) if average
+                     else np.sum(np.asarray(x)))
+        np.testing.assert_allclose(np.asarray(f(params, x)["w"]), -want,
+                                   rtol=1e-6)
 
     def test_plain_jit_noop_reduction(self, hvd):
         """Under plain jit (global batch), the wrapper must be a no-op:

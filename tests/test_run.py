@@ -353,6 +353,64 @@ def test_tpurun_no_command_errors():
     assert run_commandline(["-np", "2"]) == 2
 
 
+# ---------------------------------------------------------------------------
+# one TPU chip per local slot
+# ---------------------------------------------------------------------------
+
+def _local_slots(n):
+    return hosts.allocate([hosts.HostInfo("localhost", n)], n)
+
+
+@pytest.mark.parametrize("jax_distributed", [True, False])
+def test_each_local_slot_gets_its_own_chip(jax_distributed):
+    envs = launcher.tpu_chip_envs(_local_slots(4), chips=4,
+                                  use_jax_distributed=jax_distributed)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    if jax_distributed:
+        # joined into one global 2x2 mesh: same slice description for
+        # everyone, a task id and a port each
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+        (addresses,) = {e["TPU_PROCESS_ADDRESSES"] for e in envs}
+        assert addresses.split(",") == [
+            f"localhost:{e['TPU_PROCESS_PORT']}" for e in envs]
+        assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == list("0123")
+    else:
+        # socket controller: four isolated one-chip worlds
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+        assert not any("TPU_PROCESS_ADDRESSES" in e for e in envs)
+
+
+def test_chip_env_leaves_other_layouts_alone():
+    # no chips here (or JAX_PLATFORMS=cpu), and one slot per host: the
+    # worker environment is untouched
+    assert launcher.tpu_chip_envs(_local_slots(3), 0, True) == [{}] * 3
+    one_each = hosts.allocate([hosts.HostInfo("a", 1),
+                               hosts.HostInfo("b", 1)], 2)
+    assert launcher.tpu_chip_envs(one_each, 4, True) == [{}, {}]
+    assert launcher.local_tpu_chips({"JAX_PLATFORMS": "cpu"}) == 0
+
+
+@pytest.mark.parametrize("slots,chips,match", [
+    (lambda: _local_slots(8), 4, "8 local slots but this host has 4"),
+    (lambda: _local_slots(3), 4, "supported counts"),
+    (lambda: hosts.allocate([hosts.HostInfo("a", 2),
+                             hosts.HostInfo("b", 2)], 4), 4,
+     "across several hosts"),
+])
+def test_chip_env_refuses_what_would_hang(slots, chips, match):
+    with pytest.raises(launcher.SlotLayoutError, match=match):
+        launcher.tpu_chip_envs(slots(), chips, True)
+
+
+def test_tpurun_reports_a_refused_layout(monkeypatch, capsys):
+    monkeypatch.setattr(launcher, "local_tpu_chips", lambda env: 2)
+    code = run_commandline(["-np", "3", "-H", "localhost:3",
+                            sys.executable, "-c", "pass"])
+    assert code == 2
+    assert "3 local slots but this host has 2" in capsys.readouterr().err
+
+
 def _run_mp_worker(monkeypatch, scenario, extra_flags=()):
     """tpurun-launch mp_worker.py ranks (workers don't want the parent's
     8-fake-device XLA_FLAGS)."""

@@ -1,0 +1,229 @@
+"""The Pallas kernels of the main paths, put before the TPU compiler.
+
+Interpret mode (every other kernel test here) cannot see what Mosaic
+refuses: a block whose row count is not a multiple of the sublane tile,
+a slice off the tiling, too much VMEM. The TPU compiler is installed in
+the sandbox and compiles for a chip that is *described*, not attached
+(``jax.experimental.topologies``), so these cases lower and compile each
+kernel at the real bench shapes for one v5e chip. Nothing runs: a pass
+says the chip's compiler takes the kernel, not that its numbers are
+right (the interpret-mode tests and ``chip_smoke.py`` say that).
+
+Kernels only, skipped where the topology cannot be described, and kept
+under half a minute in total.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from horovod_tpu.ops.pallas import conv_bn_act, fused_optimizer
+from horovod_tpu.ops.pallas._backend import shard_over_batch
+from horovod_tpu.ops.pallas.flash_attention import flash_attention
+from horovod_tpu.ops.pallas.fused_adamw import _leaf_update
+from horovod_tpu.runtime.fusion_buffer import bucket_elems
+from horovod_tpu.utils.env import DEFAULT_FUSION_BUCKET_QUANTUM_BYTES
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described v5e 2x2 host, with the kernels
+    forced out of interpret mode and the persistent compile cache off (an
+    entry written for a described chip cannot be read back without one,
+    and warns on every later compile)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu / no such topology in this build
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    patch = pytest.MonkeyPatch()
+    patch.setenv("HOROVOD_PALLAS_INTERPRET", "0")
+    patch.setenv("HOROVOD_FUSED_BN_ACT", "1")
+    yield list(topo.devices)
+    patch.undo()
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(v5e):
+    return SingleDeviceSharding(v5e[0])
+
+
+def _kernels_in(fn, chip, *specs):
+    """Compile ``fn`` for the described chip at ``(shape, dtype)`` specs;
+    the number of Mosaic kernels in the compiled program."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in specs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((16, 12, 1024, 64), True),    # GPT-2-small bench: batch 16, seq 1024
+    ((8, 16, 512, 64), False),     # BERT-Large bench: batch 8, seq 512
+], ids=["gpt2", "bert-large"])
+def test_flash_attention_forward_and_backward(chip, shape, causal):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal).astype(F32).sum()
+
+    spec = (shape, BF16)
+    # forward (residual-saving) + the dq and dk/dv backward kernels
+    assert _kernels_in(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
+                       spec, spec, spec) >= 3
+
+
+def _inception_bn_act_shapes():
+    """Every activation shape Inception-V3 hands ``scale_bias_act`` at
+    the bench's batch 32 / 299x299, by walking the model abstractly."""
+    from horovod_tpu.models import InceptionV3
+
+    seen = []
+    patch = pytest.MonkeyPatch()
+    patch.setattr(conv_bn_act, "scale_bias_act",
+                  lambda x, s, b: (seen.append((x.shape, x.dtype)),
+                                   conv_bn_act._sba_jnp(x, s, b))[1])
+    try:
+        model = InceptionV3(num_classes=1000, dtype=BF16)
+        x = jax.ShapeDtypeStruct((32, 299, 299, 3), F32)
+        variables = jax.eval_shape(
+            lambda x: model.init(jax.random.PRNGKey(0), x, train=False), x)
+        seen.clear()
+        jax.eval_shape(
+            lambda v, x: model.apply(v, x, train=True,
+                                     mutable=["batch_stats"]),
+            variables, x)
+    finally:
+        patch.undo()
+    return sorted(set(seen), key=str)
+
+
+def test_scale_bias_act_at_every_inception_shape(chip):
+    """One program holding the fused BN+ReLU epilogue, forward and
+    backward, at each Inception-V3 activation shape. The stem's
+    (32,149,149,32) used to be refused: its 177,608 lane rows have no
+    divisor under 512 that is a multiple of 8."""
+    shapes = _inception_bn_act_shapes()
+    assert ((32, 149, 149, 32), BF16) in shapes
+
+    def loss(xs, ss, bs):
+        return sum(conv_bn_act.scale_bias_act(x, s, b).astype(F32).sum()
+                   for x, s, b in zip(xs, ss, bs))
+
+    def both(xs, ss, bs):
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(xs, ss, bs)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=chip)
+    xs = [sds(shape, dtype) for shape, dtype in shapes]
+    cs = [sds(shape[-1:], F32) for shape, _ in shapes]
+    text = jax.jit(both).lower(xs, cs, cs).compile().as_text()
+    # the kernel serves channel counts that pack 128 lanes exactly; the
+    # other shapes are the jnp chain by design (conv_bn_act docstring)
+    packs = [s for s, _ in shapes
+             if s[-1] % 128 == 0 or 128 % s[-1] == 0]
+    assert text.count("tpu_custom_call") == len(packs) >= 8
+
+
+# f32 parameter counts, from jax.eval_shape of model.init: GPT-2-small at
+# the padded vocab 50304 / 1024 positions, BERT-Large at 30522 / 512.
+_PARAMS = {"gpt2": 124_475_904, "bert-large": 334_090_240}
+
+
+def _zero_shard_elems(n_params, world,
+                      quantum=DEFAULT_FUSION_BUCKET_QUANTUM_BYTES):
+    """Per-chip flat f32 shard length, as ``zero.build_spec`` lays one
+    float32 group out over ``world`` chips."""
+    return bucket_elems(-(-n_params // world), 4, quantum)
+
+
+@pytest.mark.parametrize("n", [
+    pytest.param(_zero_shard_elems(_PARAMS["gpt2"], 1), id="gpt2-1chip"),
+    pytest.param(_zero_shard_elems(_PARAMS["gpt2"], 4), id="gpt2-4chips"),
+    pytest.param(_zero_shard_elems(_PARAMS["bert-large"], 4),
+                 id="bert-large-4chips"),
+    # HOROVOD_FUSION_BUCKET_QUANTUM=0 leaves shards unpadded
+    pytest.param(_zero_shard_elems(_PARAMS["bert-large"], 4, quantum=0),
+                 id="bert-large-4chips-unpadded"),
+    # a quarter of bench.py --sharded-optimizer's BERT-Large table:
+    # 652,344 lane rows, whose largest divisor under 512 is 462 — not a
+    # multiple of 8, so Mosaic refused the divisor-search block
+    pytest.param(83_500_032, id="bench-table-quarter"),
+])
+def test_flat_adamw_shard_kernel(chip, n):
+    assert n % 128 == 0
+
+    def update(master, mu, nu, grad, scalars):
+        return fused_optimizer.pallas_flat_adamw(
+            master, mu, nu, grad, scalars, eps=1e-8, out_dtype=BF16)
+
+    buf = ((n,), F32)
+    assert _kernels_in(update, chip, buf, buf, buf, ((n,), BF16),
+                       ((6,), F32)) == 1
+
+
+def test_fused_adamw_leaf_kernel(chip):
+    """The per-leaf kernel (a measured loser, ROADMAP D2) shares the
+    block-row rule; one leaf with a prime row count guards it while the
+    module stays."""
+    shape = (131 * 128 * 8 + 128,)   # 1049 lane rows: prime, > one block
+
+    def update(p, m, v, g, scalars):
+        return _leaf_update(p, m, v, g, scalars, eps=1e-8)
+
+    leaf = (shape, F32)
+    assert _kernels_in(update, chip, leaf, leaf, leaf, leaf,
+                       ((6,), F32)) == 1
+
+
+def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
+    """What ``training.make_train_step`` builds on a four-chip host: one
+    jit over the global mesh, batch sharded. XLA cannot partition a
+    Mosaic kernel, so the bare call is refused; through
+    ``shard_over_batch`` (how the models call them) the flash kernels and
+    the BN+ReLU epilogue compile, each chip on its rows."""
+    import functools
+
+    import horovod_tpu as hvd
+
+    hvd.shutdown()
+    hvd.init(devices=v5e)
+    try:
+        rows = NamedSharding(hvd.mesh(), P(hvd.GLOBAL_AXES))
+        everywhere = NamedSharding(hvd.mesh(), P())
+        qkv = jax.ShapeDtypeStruct((16, 12, 1024, 64), BF16, sharding=rows)
+        x = jax.ShapeDtypeStruct((32, 35, 35, 64), BF16, sharding=rows)
+        c = jax.ShapeDtypeStruct((64,), F32, sharding=everywhere)
+
+        def bare(q, k, v):
+            return flash_attention(q, k, v, causal=True)
+
+        with pytest.raises(NotImplementedError, match="partitioned"):
+            jax.jit(bare).lower(qkv, qkv, qkv)
+
+        def loss(q, k, v, x, s, b):
+            attn = shard_over_batch(
+                functools.partial(flash_attention, causal=True), (q, k, v))
+            return (attn.astype(F32).sum()
+                    + conv_bn_act.scale_bias_act(x, s, b).astype(F32).sum())
+
+        text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))) \
+            .lower(qkv, qkv, qkv, x, c, c).compile().as_text()
+        assert text.count("tpu_custom_call") >= 4   # 3 flash + 1 epilogue
+        assert "all-reduce" in text   # the per-channel scale/bias gradients
+    finally:
+        hvd.shutdown()

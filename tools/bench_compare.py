@@ -10,7 +10,7 @@ truncated artifact carries everything that finished). This tool parses
 both artifacts, matches headlines by metric name, and fails loudly when
 the candidate regresses past the threshold:
 
-    python tools/bench_compare.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_compare.py BENCH_r05.json BENCH_r06.json
     python tools/bench_compare.py --baseline BENCH_r05.json \
         --candidate /tmp/new.json --threshold-pct 3
 
