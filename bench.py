@@ -1790,12 +1790,12 @@ def serve_main(tiny: bool = False, prefix_heavy: bool = False):
         occ = sum(r.occupancy_sum for r in handle._replicas)
 
         # interleaved A/B overhead probe: decode-path cost with the
-        # tracing plane off vs on, doing exactly the per-step work the
-        # replica loop does — a block-step counter increment per step
-        # and ONE span record per decode block (the handle runs
-        # decode_block=4). Arms interleave so clock drift and cache
-        # effects cancel; runs on the hot decode program with the queue
-        # idle, so it must also compile nothing.
+        # tracing plane off vs on, doing the per-step span work the
+        # replica loop does — a ``serve.step`` span around the engine's
+        # own ``engine.decode`` spans (prep, dispatch, wait). Arms
+        # interleave so clock drift and cache effects cancel; runs on the
+        # hot decode program with the queue idle, so it must also compile
+        # nothing.
         from horovod_tpu import tracing as tracing_mod
 
         probe_engine = handle._replicas[0].engine
@@ -1803,20 +1803,12 @@ def serve_main(tiny: bool = False, prefix_heavy: bool = False):
         tracer = tracing_mod.tracer()
         was_enabled = tracer.enabled
         off_s, on_s = [], []
-        block_steps, block_t0 = 0, time.time()
         for i in range(2 * n_probe):
             trace_on = i % 2 == 1
             tracer.enabled = trace_on
             t_probe = time.perf_counter()
-            probe_engine.decode([0], [1], [0])
-            if trace_on:
-                block_steps += 1
-                if block_steps >= handle.policy.decode_block:
-                    t1 = time.time()
-                    tracing_mod.record(
-                        "request.decode_block", block_t0, t1 - block_t0,
-                        trace_id="bench-ab", tokens=block_steps)
-                    block_t0, block_steps = t1, 0
+            with tracing_mod.span("serve.step"):
+                probe_engine.decode([0], [1], [0])
             (on_s if trace_on else off_s).append(
                 time.perf_counter() - t_probe)
         tracer.enabled = was_enabled

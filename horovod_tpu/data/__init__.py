@@ -34,6 +34,8 @@ from typing import Iterable, Iterator, Optional
 import jax
 import numpy as np
 
+from horovod_tpu import tracing
+
 __all__ = ["ShardedSampler", "prefetch_to_device"]
 
 
@@ -125,7 +127,9 @@ def prefetch_to_device(iterator: Iterable, size: int = 2, sharding=None):
             for batch in iterator:
                 if stop.is_set():
                     return
-                q.put(put(batch))
+                with tracing.span("input.put"):
+                    batch = put(batch)
+                q.put(batch)
                 if stop.is_set():
                     return
             q.put(_END)
@@ -141,7 +145,8 @@ def prefetch_to_device(iterator: Iterable, size: int = 2, sharding=None):
         thread.start()
         try:
             while True:
-                item = q.get()
+                with tracing.span("input.wait", depth=q.qsize()):
+                    item = q.get()
                 if item is _END:
                     return
                 if isinstance(item, BaseException):

@@ -354,6 +354,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret,
+            name="flash_fwd",
         )(q_offset, k_offset, q, k, v)
         return o, lse
 
@@ -378,6 +379,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
         ],
         compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
+        name="flash_fwd",
     )(q_offset, k_offset, q, k, v)
     return o, lse  # lse lane-broadcast: (B, H, S, LANES)
 
@@ -759,6 +761,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
+            name="flash_bwd",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -778,6 +781,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret,
+            name="flash_dq",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
     else:
         dq = None
@@ -801,6 +805,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret,
+            name="flash_dkv",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
     else:
         dk = dv = None
@@ -819,6 +824,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
             compiler_params=_compiler_params(4),
             interpret=interpret,
+            name="flash_dq",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
 
     if dk is None:
@@ -850,6 +856,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
             ],
             compiler_params=_compiler_params(4),
             interpret=interpret,
+            name="flash_dkv",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
 
     return dq, dk, dv

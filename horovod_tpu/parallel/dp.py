@@ -98,7 +98,9 @@ def _allreduce_leaf(g, average, compression, axis_name,
             # average; XLA inserted the collective from the shardings.
             return g
         c, ctx = compression.compress(g)
-        return compression.decompress(_reduce_traced(c, axes, average), ctx)
+        with jax.named_scope("grad_exchange"):
+            r = _reduce_traced(c, axes, average)
+        return compression.decompress(r, ctx)
     return collectives.allreduce(
         g, average=average, compression=compression, axis_name=axis_name
     )

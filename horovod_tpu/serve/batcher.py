@@ -62,15 +62,10 @@ class ActiveRequest:
     generated: List[int] = dataclasses.field(default_factory=list)
     first_token_s: float = 0.0
     admitted_s: float = 0.0
-    # span bookkeeping (tracing.py; written by the replica loop): phase
-    # durations for the slow-request exemplar, plus the open decode-block
-    # span — block_t0 is EPOCH seconds (the trace clock), block_steps
-    # counts decode steps since the block opened
+    # phase durations for the slow-request exemplar (tracing.py; written
+    # by the replica loop)
     queue_wait_s: float = 0.0
     prefill_s: float = 0.0
-    block_t0: float = 0.0
-    block_steps: int = 0
-    blocks: int = 0
 
     def __post_init__(self):
         if self.max_tokens <= 0:

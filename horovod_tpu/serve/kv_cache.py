@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from horovod_tpu import tracing
 from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
@@ -172,17 +173,21 @@ class DecodeEngine:
                 f"prefill: prompt length {len(prompt)} outside "
                 f"(0, max_seq={self.max_seq}]")
         bucket = prompt_bucket(len(prompt), self.max_seq)
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            fn = jax.jit(self._prefill_impl)
-            self._prefill_fns[bucket] = fn
-            self._note_compile(f"prefill_{bucket}")
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(prompt)] = prompt
-        self._cache, token, max_abs = fn(
-            self._params, self._cache, jnp.asarray(padded),
-            jnp.int32(len(prompt)), jnp.int32(slot))
-        return int(token), float(max_abs)
+        with tracing.span("engine.prefill", bucket=bucket,
+                          prompt_len=len(prompt), slot=slot):
+            with tracing.span("engine.prefill.dispatch"):
+                fn = self._prefill_fns.get(bucket)
+                if fn is None:
+                    fn = jax.jit(self._prefill_impl)
+                    self._prefill_fns[bucket] = fn
+                    self._note_compile(f"prefill_{bucket}")
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :len(prompt)] = prompt
+                self._cache, token, max_abs = fn(
+                    self._params, self._cache, jnp.asarray(padded),
+                    jnp.int32(len(prompt)), jnp.int32(slot))
+            with tracing.span("engine.prefill.wait"):   # blocked on the device
+                return int(token), float(max_abs)
 
     def decode(self, slots: List[int], tokens: List[int],
                positions: List[int]) -> Tuple[List[int], List[float]]:
@@ -193,24 +198,31 @@ class DecodeEngine:
         if not self._decode_compiled:
             self._decode_compiled = True
             self._note_compile("decode")
-        step_tokens = np.zeros((self.num_slots, 1), np.int32)
-        step_pos = np.zeros((self.num_slots,), np.int32)
-        for s, t, p in zip(slots, tokens, positions):
-            if p >= self.max_seq:
-                # admission caps max_tokens so no write lands past the
-                # cache (batcher.ActiveRequest); overrunning silently
-                # would overwrite the last KV row and serve garbage
-                raise ValueError(
-                    f"decode: slot {s} position {p} >= max_seq "
-                    f"{self.max_seq} (admission cap violated)")
-            step_tokens[s, 0] = t
-            step_pos[s] = p
+        with tracing.span("engine.decode", rows=len(slots)):
+            return self._decode(slots, tokens, positions)
+
+    def _decode(self, slots, tokens, positions):
+        with tracing.span("engine.decode.prep"):
+            step_tokens = np.zeros((self.num_slots, 1), np.int32)
+            step_pos = np.zeros((self.num_slots,), np.int32)
+            for s, t, p in zip(slots, tokens, positions):
+                if p >= self.max_seq:
+                    # admission caps max_tokens so no write lands past the
+                    # cache (batcher.ActiveRequest); overrunning silently
+                    # would overwrite the last KV row and serve garbage
+                    raise ValueError(
+                        f"decode: slot {s} position {p} >= max_seq "
+                        f"{self.max_seq} (admission cap violated)")
+                step_tokens[s, 0] = t
+                step_pos[s] = p
         start = time.monotonic()
-        self._cache, ids, max_abs = self._decode_fn(
-            self._params, self._cache, jnp.asarray(step_tokens),
-            jnp.asarray(step_pos))
-        ids = np.asarray(ids)
-        max_abs = np.asarray(max_abs)
+        with tracing.span("engine.decode.dispatch"):
+            self._cache, ids, max_abs = self._decode_fn(
+                self._params, self._cache, jnp.asarray(step_tokens),
+                jnp.asarray(step_pos))
+        with tracing.span("engine.decode.wait"):   # blocked on the device
+            ids = np.asarray(ids)
+            max_abs = np.asarray(max_abs)
         ms = (time.monotonic() - start) * 1000.0
         self.decode_steps += 1
         self.step_ms_ewma = (ms if self.decode_steps == 1

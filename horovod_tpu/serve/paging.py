@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from horovod_tpu import tracing
 from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
 from horovod_tpu.serve import kv_cache as _kv
@@ -578,6 +579,11 @@ class PagedDecodeEngine:
             raise ValueError(
                 f"prefill: prompt length {len(prompt)} outside "
                 f"(0, max_seq={self.max_seq}]")
+        with tracing.span("engine.prefill", prompt_len=len(prompt),
+                          slot=slot) as span:
+            return self._prefill(slot, prompt, span)
+
+    def _prefill(self, slot: int, prompt: List[int], span):
         T = self.page_tokens
         self.release_slot(slot)   # re-prefill frees the previous occupant
         if self.prefix is not None:
@@ -617,19 +623,23 @@ class PagedDecodeEngine:
             raise
         suffix = prompt[hit_tokens:]
         bucket = prompt_bucket(len(suffix), self.max_seq)
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            fn = jax.jit(self._prefill_impl)
-            self._prefill_fns[bucket] = fn
-            self._note_compile(f"prefill_{bucket}")
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(suffix)] = suffix
-        row = np.zeros((1, self.table_width), np.int32)
-        row[0, :needed] = taken
-        self._cache, token, max_abs = fn(
-            self._params, self._cache, jnp.asarray(padded),
-            jnp.int32(hit_tokens), jnp.int32(len(suffix) - 1),
-            jnp.asarray(row))
+        span.set(bucket=bucket)
+        with tracing.span("engine.prefill.dispatch"):
+            fn = self._prefill_fns.get(bucket)
+            if fn is None:
+                fn = jax.jit(self._prefill_impl)
+                self._prefill_fns[bucket] = fn
+                self._note_compile(f"prefill_{bucket}")
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :len(suffix)] = suffix
+            row = np.zeros((1, self.table_width), np.int32)
+            row[0, :needed] = taken
+            self._cache, token, max_abs = fn(
+                self._params, self._cache, jnp.asarray(padded),
+                jnp.int32(hit_tokens), jnp.int32(len(suffix) - 1),
+                jnp.asarray(row))
+        with tracing.span("engine.prefill.wait"):   # blocked on the device
+            token, max_abs = int(token), float(max_abs)
         self._set_table(slot, taken, len(prompt))
         if hit_pages:
             _PREFIX_HITS.labels(replica=self.name).inc()
@@ -640,9 +650,9 @@ class PagedDecodeEngine:
         _PREFIX_TOKENS.labels(replica=self.name,
                               source="computed").inc(len(suffix))
         if self.prefix is not None:
-            self.prefix.insert(prompt, taken, int(token), float(max_abs))
+            self.prefix.insert(prompt, taken, token, max_abs)
         _PAGE_FREE.labels(replica=self.name).set(self.pool.free_count())
-        return int(token), float(max_abs)
+        return token, max_abs
 
     def decode(self, slots: List[int], tokens: List[int],
                positions: List[int]) -> Tuple[List[int], List[float]]:
@@ -650,33 +660,41 @@ class PagedDecodeEngine:
         Runs :meth:`prepare_step` first so every write position owns
         its page — direct callers get the same COW safety the replica
         loop's explicit prepare/preempt cycle provides."""
-        self.prepare_step(slots, positions)
         if not self._decode_compiled:
             self._decode_compiled = True
             self._note_compile("decode")
-        step_tokens = np.zeros((self.num_slots, 1), np.int32)
-        step_pos = np.zeros((self.num_slots,), np.int32)
-        # inactive rows still run (fixed shape) and write garbage KV at
-        # position 0 — in the dense engine that lands in the slot's own
-        # row, but here a mapped table would scribble on its block-0
-        # page, which may be SHARED with the prefix cache or another
-        # request. Zeroed rows route the write to the scratch page,
-        # which is only ever gathered at masked key positions.
-        step_table = np.zeros_like(self._table_arr)
-        for s, t, p in zip(slots, tokens, positions):
-            if p >= self.max_seq:
-                raise ValueError(
-                    f"decode: slot {s} position {p} >= max_seq "
-                    f"{self.max_seq} (admission cap violated)")
-            step_tokens[s, 0] = t
-            step_pos[s] = p
-            step_table[s] = self._table_arr[s]
+        with tracing.span("engine.decode", rows=len(slots)):
+            return self._decode(slots, tokens, positions)
+
+    def _decode(self, slots, tokens, positions):
+        with tracing.span("engine.decode.prep"):
+            self.prepare_step(slots, positions)
+            step_tokens = np.zeros((self.num_slots, 1), np.int32)
+            step_pos = np.zeros((self.num_slots,), np.int32)
+            # inactive rows still run (fixed shape) and write garbage KV
+            # at position 0 — in the dense engine that lands in the
+            # slot's own row, but here a mapped table would scribble on
+            # its block-0 page, which may be SHARED with the prefix cache
+            # or another request. Zeroed rows route the write to the
+            # scratch page, which is only ever gathered at masked key
+            # positions.
+            step_table = np.zeros_like(self._table_arr)
+            for s, t, p in zip(slots, tokens, positions):
+                if p >= self.max_seq:
+                    raise ValueError(
+                        f"decode: slot {s} position {p} >= max_seq "
+                        f"{self.max_seq} (admission cap violated)")
+                step_tokens[s, 0] = t
+                step_pos[s] = p
+                step_table[s] = self._table_arr[s]
         start = time.monotonic()
-        self._cache, ids, max_abs = self._decode_fn(
-            self._params, self._cache, jnp.asarray(step_tokens),
-            jnp.asarray(step_pos), jnp.asarray(step_table))
-        ids = np.asarray(ids)
-        max_abs = np.asarray(max_abs)
+        with tracing.span("engine.decode.dispatch"):
+            self._cache, ids, max_abs = self._decode_fn(
+                self._params, self._cache, jnp.asarray(step_tokens),
+                jnp.asarray(step_pos), jnp.asarray(step_table))
+        with tracing.span("engine.decode.wait"):   # blocked on the device
+            ids = np.asarray(ids)
+            max_abs = np.asarray(max_abs)
         ms = (time.monotonic() - start) * 1000.0
         self.decode_steps += 1
         self.step_ms_ewma = (ms if self.decode_steps == 1

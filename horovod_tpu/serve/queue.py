@@ -143,20 +143,18 @@ class RequestQueue:
 
     def submit(self, prompt: List[int], max_new_tokens: int,
                uid: Optional[str] = None, trace_id: str = "") -> str:
-        t0 = time.time()
         req = Request(uid=uid or uuid.uuid4().hex, prompt=list(prompt),
                       max_new_tokens=int(max_new_tokens),
                       submitted_s=time.monotonic(),
                       trace_id=trace_id or tracing.new_trace_id())
-        with self._lock:
+        with tracing.span("request.submit", trace_id=req.trace_id,
+                          uid=req.uid, prompt_len=len(req.prompt)), \
+                self._lock:
             if len(self._waiting) >= self._capacity:
                 raise QueueFull(
                     f"serve queue at capacity ({self._capacity})")
             self._waiting.append(req)
             self._submitted += 1
-        tracing.record("request.submit", t0, time.time() - t0,
-                       trace_id=req.trace_id, uid=req.uid,
-                       prompt_len=len(req.prompt))
         return req.uid
 
     def pull(self, rank: int, max_n: int) -> List[Request]:
@@ -172,9 +170,10 @@ class RequestQueue:
         return out
 
     def complete(self, completion: Completion) -> None:
-        t0 = time.time()
         now = time.monotonic()
-        with self._lock:
+        with tracing.span("request.response", trace_id=completion.trace_id,
+                          uid=completion.uid, finish=completion.finish), \
+                self._lock:
             self._inflight.pop(completion.uid, None)
             # first writer wins: a requeued duplicate that also finished
             # must not overwrite the reply the caller already saw
@@ -189,9 +188,6 @@ class RequestQueue:
             while self._expiry and self._expiry[0][0] <= now:
                 _, uid = self._expiry.popleft()
                 self._results.pop(uid, None)
-        tracing.record("request.response", t0, time.time() - t0,
-                       trace_id=completion.trace_id, uid=completion.uid,
-                       finish=completion.finish)
 
     def requeue_worker(self, rank: int) -> int:
         """Return every request in-flight on ``rank`` to the FRONT of
@@ -336,18 +332,18 @@ class KVQueueFrontend:
         the KV put, i.e. the frontend→replica wire hop."""
         if not request.trace_id:
             request.trace_id = tracing.new_trace_id()
-        t0 = time.time()
-        if rank is None:
-            live = self.live_replicas()
-            if not live:
-                raise RuntimeError("no live serve replicas")
-            rank = live[next(self._rr) % len(live)]
-        self._client.set(request.uid, request.to_json(),
-                         scope=REQ_SCOPE.format(rank=rank))
-        self._assigned[request.uid] = (rank, request)
-        tracing.record("request.submit", t0, time.time() - t0,
-                       trace_id=request.trace_id, uid=request.uid,
-                       prompt_len=len(request.prompt), to_rank=rank)
+        with tracing.span("request.submit", trace_id=request.trace_id,
+                          uid=request.uid,
+                          prompt_len=len(request.prompt)) as span:
+            if rank is None:
+                live = self.live_replicas()
+                if not live:
+                    raise RuntimeError("no live serve replicas")
+                rank = live[next(self._rr) % len(live)]
+            span.set(to_rank=rank)
+            self._client.set(request.uid, request.to_json(),
+                             scope=REQ_SCOPE.format(rank=rank))
+            self._assigned[request.uid] = (rank, request)
         return rank
 
     def _redispatch_dead(self) -> None:

@@ -218,6 +218,9 @@ class Replica:
         self.decode_iterations = 0
         self.occupancy_sum = 0
         self.page_used_sum = 0   # pool pages in use, summed per step
+        # of the open serve.step: requests admitted and pulled in it, and
+        # the passes before it that pulled, admitted and decoded nothing
+        self._admitted = self._pulled = self._idle_passes = 0
         self._stop = threading.Event()
 
     # -- lifecycle ---------------------------------------------------------
@@ -227,12 +230,15 @@ class Replica:
     def _finish(self, active, now: float) -> None:
         req = active.request
         epoch_now = time.time()
-        if active.block_steps > 0:   # close the trailing decode block
-            tracing.record(
-                "request.decode_block", active.block_t0,
-                max(epoch_now - active.block_t0, 0.0),
-                trace_id=req.trace_id, uid=req.uid, slot=active.slot,
-                block=active.blocks, tokens=active.block_steps)
+        # one decode span per request, first token to last (the
+        # serve.step spans carry which iterations it lived through)
+        decode_dur = max(now - active.first_token_s, 0.0)
+        decode_steps = len(active.generated) - 1
+        tracing.record(
+            "request.decode", epoch_now - decode_dur, decode_dur,
+            trace_id=req.trace_id, uid=req.uid, slot=active.slot,
+            tokens=len(active.generated),
+            blocks=-(-decode_steps // self.policy.decode_block))
         # "cache_limit" (not "length") when the KV cache, not the
         # request, bounded the generation — callers must be able to
         # tell a fulfilled budget from a truncated one
@@ -260,7 +266,7 @@ class Replica:
             trace_id=req.trace_id, rank=self.rank, requeues=req.requeues,
             phases={"queue_wait": active.queue_wait_s,
                     "prefill": active.prefill_s,
-                    "decode": max(now - active.first_token_s, 0.0)})
+                    "decode": decode_dur})
 
     def _reject(self, req, reason: str) -> None:
         """Complete an unservable request (empty, or prompt longer than
@@ -384,10 +390,32 @@ class Replica:
                              rank=self.rank, completed=self.completed)
 
     def _iterate(self) -> None:
-        now = time.monotonic()
+        """One pass of the loop, as one ``serve.step`` span. A pass that
+        found no rows and slept is recorded with ``decoded=0``; of a run
+        of passes that pulled, admitted and decoded nothing only the
+        first is (an idle replica spins every 2 ms and would otherwise
+        wipe the ring in seconds)."""
+        with tracing.span("serve.step") as step:
+            self._admitted = self._pulled = 0
+            decoded = self._step()
+            if decoded or self._admitted or self._pulled:
+                self._idle_passes = 0
+            else:
+                self._idle_passes += 1
+                if self._idle_passes > 1:
+                    step.discard()
+            step.set(step=self.decode_iterations, decoded=decoded,
+                     occupancy=decoded or self.batcher.occupancy(),
+                     waiting=self.batcher.waiting(),
+                     admitted=self._admitted)
+
+    def _pull(self, now: float) -> None:
         free = self.engine.num_slots - self.batcher.occupancy()
-        if free > 0 or self.batcher.waiting() == 0:
-            for req in self.transport.pull(max(free, 1)):
+        if free <= 0 and self.batcher.waiting() != 0:
+            return
+        with tracing.span("serve.pull") as pulled:
+            reqs = self.transport.pull(max(free, 1))
+            for req in reqs:
                 # unservable prompts answer immediately — an oversized
                 # prompt must never reach prefill (where it would blow
                 # up the padded copy) or circulate in requeue forever
@@ -399,22 +427,33 @@ class Replica:
                              f"max_seq {self.engine.max_seq}")
                 else:
                     self.batcher.offer(req, now)
-        _QUEUE_DEPTH.labels(replica=self.name).set(
-            self.batcher.waiting() + self.transport.depth())
+            self._pulled = len(reqs)
+            pulled.set(n=len(reqs))
+            if not reqs and self._idle_passes:
+                pulled.discard()
 
-        if self.batcher.admission_due(now):
-            for active in self.batcher.admit(now):
-                req = active.request
-                # queue-wait span: submitted -> admitted. submitted_s is
-                # a LOCAL monotonic stamp; map it onto the epoch trace
-                # clock by anchoring "now" and subtracting the wait.
-                p0 = time.time()
-                active.queue_wait_s = max(
-                    active.admitted_s - req.submitted_s, 0.0)
-                tracing.record(
-                    "request.queue_wait", p0 - active.queue_wait_s,
-                    active.queue_wait_s, trace_id=req.trace_id,
-                    uid=req.uid, requeues=req.requeues)
+    def _admit(self, now: float) -> bool:
+        """Prefill what the batcher admits. False when a prefill tripped
+        the integrity guard (the replica is quarantined)."""
+        # submitted_s and admitted_s are LOCAL monotonic stamps; this maps
+        # them onto the epoch trace clock, once for the whole batch
+        to_epoch = time.time() - time.monotonic()
+        for active in self.batcher.admit(now):
+            req = active.request
+            # queue-wait span: submitted -> admitted. A request admitted
+            # with others then waits its turn among their prefills: the
+            # gap between this span's end and its request.prefill
+            active.queue_wait_s = max(
+                active.admitted_s - req.submitted_s, 0.0)
+            tracing.record(
+                "request.queue_wait",
+                active.admitted_s - active.queue_wait_s + to_epoch,
+                active.queue_wait_s, trace_id=req.trace_id,
+                uid=req.uid, requeues=req.requeues)
+            p0 = time.monotonic()
+            with tracing.span("request.prefill", trace_id=req.trace_id,
+                              uid=req.uid, slot=active.slot,
+                              prompt_len=active.prompt_len) as prefill:
                 token = None
                 while True:
                     try:
@@ -434,29 +473,41 @@ class Replica:
                             _REQUESTS.labels(outcome="preempted").inc()
                             break
                 if token is None:
+                    prefill.set(preempted=True)
                     continue
                 if not self._guard_ok(max_abs):
                     self._quarantine("non-finite prefill logits")
-                    return
+                    return False
                 active.generated.append(token)
                 active.first_token_s = time.monotonic()
-                active.prefill_s = time.time() - p0
-                tracing.record(
-                    "request.prefill", p0, active.prefill_s,
-                    trace_id=req.trace_id, uid=req.uid,
-                    slot=active.slot, prompt_len=active.prompt_len)
-                # prefill is productive serve time too (tokens=0: the
-                # preemption exchange rate stays a pure decode cost)
-                goodput.record_serve_step(active.prefill_s)
-                # open the first decode-block span
-                active.block_t0 = p0 + active.prefill_s
-                _TOKENS.labels(kind="prefill").inc(active.prompt_len)
-                _LATENCY.labels(phase="ttft").observe(
-                    active.first_token_s - active.request.submitted_s)
-            for done in self.batcher.retire_done():  # max_new_tokens == 1
-                if self.paged:
-                    self.engine.release_slot(done.slot)
-                self._finish(done, time.monotonic())
+                active.prefill_s = active.first_token_s - p0
+            self._admitted += 1
+            # prefill is productive serve time too (tokens=0: the
+            # preemption exchange rate stays a pure decode cost)
+            goodput.record_serve_step(active.prefill_s)
+            _TOKENS.labels(kind="prefill").inc(active.prompt_len)
+            _LATENCY.labels(phase="ttft").observe(
+                active.first_token_s - active.request.submitted_s)
+        for done in self.batcher.retire_done():  # max_new_tokens == 1
+            if self.paged:
+                self.engine.release_slot(done.slot)
+            self._finish(done, time.monotonic())
+        return True
+
+    def _step(self) -> int:
+        """pull -> admit/prefill -> one decode step -> retire. Returns
+        the rows decoded (0: nothing to decode, or quarantined)."""
+        now = time.monotonic()
+        self._pull(now)
+        _QUEUE_DEPTH.labels(replica=self.name).set(
+            self.batcher.waiting() + self.transport.depth())
+
+        if self.batcher.admission_due(now):
+            with tracing.span("serve.admit") as admit:
+                ok = self._admit(now)
+                admit.set(n=self._admitted)
+            if not ok:
+                return 0
 
         slots, tokens, positions = self.batcher.batch_rows()
         if not slots:
@@ -464,7 +515,7 @@ class Replica:
             time.sleep(_IDLE_SLEEP_SECONDS)
             # goodput ledger: an empty loop iteration is queue-idle badput
             goodput.record_span("serve_queue_idle", _IDLE_SLEEP_SECONDS)
-            return
+            return 0
 
         if self.paged:
             # grow tables across block boundaries / COW shared pages
@@ -481,56 +532,47 @@ class Replica:
                     slots, tokens, positions = self.batcher.batch_rows()
                     if not slots:
                         _OCCUPANCY.labels(replica=self.name).set(0)
-                        return
+                        return 0
 
         # the serving step counter: chaos kills aim at decode step N
         self.decode_iterations += 1
         fault_inject.maybe_inject(self.decode_iterations)
         t_decode0 = time.monotonic()
         ids, max_abs = self.engine.decode(slots, tokens, positions)
-        # no short-circuit: the guard's EWMA/skip-budget state must see
-        # EVERY slot's observation, not a prefix that stops at the
-        # first failing slot
-        verdicts = [self._guard_ok(m) for m in max_abs]
-        if not all(verdicts):
-            self._quarantine("non-finite decode logits")
-            return
-        by_slot = {a.slot: a for a in self.batcher.active()}
-        for slot, token in zip(slots, ids):
-            active = by_slot[slot]
-            active.generated.append(token)
-            active.position += 1
-            active.block_steps += 1
-            if active.block_steps >= self.policy.decode_block:
-                # decode-block boundary: close this request's span and
-                # open the next (one time.time() per block, not per step)
-                t1 = time.time()
-                tracing.record(
-                    "request.decode_block", active.block_t0,
-                    max(t1 - active.block_t0, 0.0),
-                    trace_id=active.request.trace_id,
-                    uid=active.request.uid, slot=slot,
-                    block=active.blocks, tokens=active.block_steps)
-                active.blocks += 1
-                active.block_t0 = t1
-                active.block_steps = 0
-        occupancy = len(slots)
-        self.occupancy_sum += occupancy
-        if self.paged:
-            self.page_used_sum += self.engine.pool.used_count()
-        _TOKENS.labels(kind="decode").inc(occupancy)
-        _OCCUPANCY.labels(replica=self.name).set(occupancy)
-        _OCCUPANCY_HIST.observe(occupancy)
-        self.batcher.note_step()
-        now = time.monotonic()
-        # goodput ledger: one decoded token per occupied slot is the
-        # serve plane's productive unit; the step wall also refreshes the
-        # EWMA per-token cost that prices preempted work
-        goodput.record_serve_step(now - t_decode0, tokens=occupancy)
-        for done in self.batcher.retire_done():
+        with tracing.span("serve.retire") as retire:
+            # no short-circuit: the guard's EWMA/skip-budget state must
+            # see EVERY slot's observation, not a prefix that stops at
+            # the first failing slot
+            verdicts = [self._guard_ok(m) for m in max_abs]
+            if not all(verdicts):
+                self._quarantine("non-finite decode logits")
+                return 0
+            by_slot = {a.slot: a for a in self.batcher.active()}
+            for slot, token in zip(slots, ids):
+                active = by_slot[slot]
+                active.generated.append(token)
+                active.position += 1
+            occupancy = len(slots)
+            self.occupancy_sum += occupancy
             if self.paged:
-                self.engine.release_slot(done.slot)
-            self._finish(done, now)
+                self.page_used_sum += self.engine.pool.used_count()
+            _TOKENS.labels(kind="decode").inc(occupancy)
+            _OCCUPANCY.labels(replica=self.name).set(occupancy)
+            _OCCUPANCY_HIST.observe(occupancy)
+            self.batcher.note_step()
+            now = time.monotonic()
+            # goodput ledger: one decoded token per occupied slot is the
+            # serve plane's productive unit; the step wall also refreshes
+            # the EWMA per-token cost that prices preempted work
+            goodput.record_serve_step(now - t_decode0, tokens=occupancy)
+            finished = 0
+            for done in self.batcher.retire_done():
+                if self.paged:
+                    self.engine.release_slot(done.slot)
+                self._finish(done, now)
+                finished += 1
+            retire.set(n=finished)
+        return occupancy
 
     # -- introspection -----------------------------------------------------
     def stats(self) -> dict:

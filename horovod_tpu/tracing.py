@@ -14,11 +14,20 @@ This module is the Dapper-style answer:
   transports (``Request.trace_id`` is part of the KV wire format, so the
   context crosses process boundaries inside the ``serve.req.<rank>``
   record) and into every flight-recorder event on the serve path.
-* **Spans.** Each lifecycle phase — submit, queue wait, prefill, decode
-  block, response, plus the training plane's collectives — records one
+* **Spans.** Each lifecycle phase — submit, queue wait, prefill,
+  decode, response, plus the training plane's collectives — records one
   span: a small dict appended to a ``maxlen``-bounded deque (GIL-atomic,
   no lock, same hot-path philosophy as the flight recorder ring). Spans
   are recorded at END time; an abandoned phase simply never appears.
+* **Layer spans.** :func:`span` is the one primitive for what the
+  program does *between* requests: the serving loop (``serve.step`` and
+  its ``serve.pull`` / ``serve.admit`` / ``serve.retire``), the KV-cache
+  engine (``engine.prefill``, ``engine.decode`` and their ``dispatch`` /
+  ``wait`` parts) and the input feed (``input.wait``, ``input.put``).
+  A span goes to the same ring with its ``parent`` (the enclosing open
+  span of its thread) and, under the same name, into whatever
+  ``jax.profiler`` trace is running, on the clock the device events are
+  on.
   Spans serialize into the profiler dump (``request_spans``) and merge
   into the Perfetto trace as per-request lanes with flow arrows joining
   one ``trace_id`` across ranks on the ``/_time``-corrected clock
@@ -32,7 +41,7 @@ This module is the Dapper-style answer:
   slowest-request exemplars.
 
 Knobs: ``HOROVOD_TRACE`` (default on; ``0`` disables; an integer > 1
-sets the span ring capacity, default 4096), ``HOROVOD_SLO_TTFT_MS`` /
+sets the span ring capacity, default 16384), ``HOROVOD_SLO_TTFT_MS`` /
 ``HOROVOD_SLO_LATENCY_MS`` (latency objectives, ms),
 ``HOROVOD_SLO_AVAILABILITY`` (compliance target for all three
 objectives, default 0.999), ``HOROVOD_SLO_WINDOW`` (rolling window, in
@@ -43,7 +52,10 @@ fast-burn page threshold). docs/tracing.md is the full model.
 
 from __future__ import annotations
 
+import itertools
 import os
+import threading
+import time
 import uuid
 from collections import deque
 from typing import Dict, List, Optional
@@ -119,11 +131,16 @@ class Tracer:
                trace_id: str = "", **attrs) -> None:
         """Record one finished span. ``t0`` is epoch seconds (the
         package-wide trace clock domain, correctable by the rendezvous
-        ``/_time`` offset at merge time); ``dur`` is seconds."""
+        ``/_time`` offset at merge time); ``dur`` is seconds. ``parent``
+        is the :func:`span` open on this thread, if any, as its
+        ``(name, sid)``."""
         if not self.enabled:
             return
         span = {"trace_id": trace_id, "name": name, "t": t0,
                 "dur": dur, "rank": self.rank}
+        stack = getattr(_open, "stack", None)
+        if stack:
+            span["parent"] = stack[-1]
         span.update(attrs)
         self._spans.append(span)  # GIL-atomic; maxlen evicts the oldest
         _SPANS_TOTAL.inc()
@@ -133,6 +150,95 @@ class Tracer:
 
     def spans_recorded(self) -> int:
         return int(_SPANS_TOTAL.value)
+
+
+# the spans open on each thread, innermost last, as ``(name, sid)``
+_open = threading.local()
+_numbers = itertools.count(1)   # next() is GIL-atomic
+_annotation = None              # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported on the first span (this
+    module does not import jax); a stand-in that does nothing where jax
+    is not installed."""
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation as cls
+    except ImportError:
+        import contextlib
+
+        cls = lambda name, **_: contextlib.nullcontext()
+    _annotation = cls
+    return cls
+
+
+class _Span:
+    """One open :func:`span`. ``set`` adds attributes known only at the
+    end (they reach the ring, not the profiler's annotation, which is
+    written at entry)."""
+
+    __slots__ = ("name", "trace_id", "attrs", "key", "t0", "_annotated",
+                 "_keep")
+
+    def __init__(self, name: str, trace_id: str, attrs: dict) -> None:
+        self.name = name
+        self.trace_id = trace_id
+        self.attrs = attrs
+        self._keep = True
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+
+    def discard(self) -> None:
+        """Keep this span out of the ring (the profiler's annotation is
+        written all the same): for what would only flood it, such as the
+        passes of an idle loop after the first."""
+        self._keep = False
+
+    def __enter__(self) -> "_Span":
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        self.key = (self.name, next(_numbers))
+        self._annotated = (_annotation or _annotation_class())(
+            self.name, **{k: v for k, v in self.attrs.items()
+                          if isinstance(v, (int, float, str))})
+        self._annotated.__enter__()
+        stack.append(self.key)
+        self.t0 = time.time()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.time() - self.t0
+        _open.stack.pop()
+        self._annotated.__exit__(*exc)
+        if self._keep:
+            _tracer.record(self.name, self.t0, dur, trace_id=self.trace_id,
+                           sid=self.key[1], **self.attrs)
+        return False
+
+
+class _NoSpan:
+    """What :func:`span` hands back with ``HOROVOD_TRACE=0``: one shared
+    object, no clock read."""
+
+    __slots__ = ()
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def discard(self) -> None:
+        pass
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
 
 
 class SLOTracker:
@@ -339,6 +445,19 @@ def record(name: str, t0: float, dur: float, trace_id: str = "",
            **attrs) -> None:
     """Record one finished span (module-level hot-path entry point)."""
     _tracer.record(name, t0, dur, trace_id=trace_id, **attrs)
+
+
+def span(name: str, trace_id: str = "", **attrs):
+    """Context manager around one layer-boundary interval: stamps start
+    and end on the epoch clock and records ``name``, ``t``, ``dur``,
+    ``rank``, ``trace_id``, ``attrs``, its running number ``sid`` and its
+    ``parent`` (the enclosing open span of this thread) into the ring;
+    the same interval is a ``jax.profiler.TraceAnnotation`` of the same
+    name, so it lands in the host plane of any running profiler session.
+    An exception inside still closes and records it."""
+    if not _tracer.enabled:
+        return _NO_SPAN
+    return _Span(name, trace_id, attrs)
 
 
 def spans() -> List[dict]:

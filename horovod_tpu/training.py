@@ -19,6 +19,7 @@ Two SPMD styles are supported, matching ``DistributedOptimizer``:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Optional
 
@@ -85,20 +86,21 @@ def _make_one_step(model, optimizer, loss_fn, grad_release=None):
             outputs, updates = model.apply(
                 {"params": params, "batch_stats": batch_stats},
                 images, train=True, mutable=["batch_stats"])
-            return loss_fn(outputs, labels), updates.get("batch_stats", {})
+            with jax.named_scope("loss"):
+                loss = loss_fn(outputs, labels)
+            return loss, updates.get("batch_stats", {})
 
         (loss, new_stats), grads = jax.value_and_grad(
             compute, has_aux=True)(params)
         if grad_release is not None:
             grads = grad_release.gather(grads)
-            with buckets_mod.prereduced():
-                updates, new_opt_state = optimizer.update(
-                    grads, opt_state, params)
-        else:
+        prereduced = (buckets_mod.prereduced() if grad_release is not None
+                      else contextlib.nullcontext())
+        with jax.named_scope("optimizer"), prereduced:
             updates, new_opt_state = optimizer.update(
                 grads, opt_state, params)
-        return loss, optax.apply_updates(params, updates), new_stats, \
-            new_opt_state
+            new_params = optax.apply_updates(params, updates)
+        return loss, new_params, new_stats, new_opt_state
 
     return one_step
 

@@ -158,7 +158,7 @@ DEFAULT_FLIGHT_RECORDER_CAPACITY = 2048
 DEFAULT_STRAGGLER_REPORT_SECONDS = 60.0
 DEFAULT_PROFILE_HISTORY = 64
 DEFAULT_LOCK_HOLD_WARN_SECONDS = 5.0
-DEFAULT_TRACE_CAPACITY = 4096
+DEFAULT_TRACE_CAPACITY = 16384
 DEFAULT_SLO_WINDOW = 512
 
 

@@ -16,7 +16,7 @@ Pinned-down contracts:
 * Chrome conversion + flow arrows: ``merge_profile_dir`` lays out
   per-rank request lanes on the ``/_time``-corrected clock and joins one
   trace_id's spans across lanes;
-* the replica loop records queue_wait/prefill/decode_block/serve spans
+* the replica loop records queue_wait/prefill/decode/serve spans
   and scores the SLO tracker for every completion.
 
 The 2-rank half (frontend process + a real ``python -m
@@ -36,7 +36,8 @@ import pytest
 
 from horovod_tpu import flight_recorder, profiler, tracing
 from horovod_tpu.serve.queue import Completion, Request, RequestQueue
-from horovod_tpu.utils.env import (HOROVOD_SLO_AVAILABILITY,
+from horovod_tpu.utils.env import (DEFAULT_TRACE_CAPACITY,
+                                   HOROVOD_SLO_AVAILABILITY,
                                    HOROVOD_SLO_LATENCY_MS,
                                    HOROVOD_SLO_TTFT_MS, HOROVOD_SLO_WINDOW,
                                    HOROVOD_TRACE, parse_trace)
@@ -47,10 +48,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ---------------------------------------------------------------- span ring
 
 def test_parse_trace_grammar():
-    assert parse_trace(None) == (True, 4096)
-    assert parse_trace("1") == (True, 4096)
-    assert parse_trace("0") == (False, 4096)
-    assert parse_trace("off") == (False, 4096)
+    assert parse_trace(None) == (True, DEFAULT_TRACE_CAPACITY)
+    assert parse_trace("1") == (True, DEFAULT_TRACE_CAPACITY)
+    assert parse_trace("0") == (False, DEFAULT_TRACE_CAPACITY)
+    assert parse_trace("off") == (False, DEFAULT_TRACE_CAPACITY)
     assert parse_trace("128") == (True, 128)
 
 
@@ -356,7 +357,7 @@ def test_replica_records_lifecycle_spans_and_scores_slo(monkeypatch):
     names = {s["name"] for s in tracing.spans()
              if s.get("trace_id") == done.trace_id}
     assert {"request.submit", "request.queue_wait", "request.prefill",
-            "request.decode_block", "request.serve",
+            "request.decode", "request.serve",
             "request.response"} <= names
     st = slo.state()
     assert st["requests_scored"] == 1
@@ -378,6 +379,300 @@ def test_rejected_request_is_an_availability_bad_event(monkeypatch):
     st = slo.state()["slo"]
     assert st["availability"]["bad_total"] == 1
     assert st["latency"]["window_observed"] == 0
+
+
+# ------------------------------------------------------------ layer spans
+
+@pytest.fixture
+def ring(monkeypatch):
+    """A fresh, enabled tracer behind the module-level entry points."""
+    monkeypatch.delenv(HOROVOD_TRACE, raising=False)
+    fresh = tracing.Tracer()
+    monkeypatch.setattr(tracing, "_tracer", fresh)
+    return fresh
+
+
+def _by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def test_span_records_start_duration_and_parent(ring):
+    before = time.time()
+    with tracing.span("outer", trace_id="t1", k=1) as outer:
+        time.sleep(0.002)
+        with tracing.span("inner"):
+            pass
+        outer.set(late=2)
+        tracing.record("after.the.fact", before, 0.001)
+    inner, fact, out = ring.spans()        # recorded at end time
+    assert (inner["name"], fact["name"], out["name"]) == \
+        ("inner", "after.the.fact", "outer")
+    assert out["trace_id"] == "t1" and out["k"] == 1 and out["late"] == 2
+    assert before <= out["t"] <= inner["t"]
+    assert out["dur"] >= 0.002 and out["dur"] >= inner["dur"] >= 0.0
+    assert "parent" not in out
+    # both the nested span and the after-the-fact record know the span
+    # they were written in, by name and running number
+    assert inner["parent"] == ("outer", out["sid"]) == fact["parent"]
+    assert inner["sid"] != out["sid"]
+
+
+def test_span_stack_unwinds_between_siblings(ring):
+    with tracing.span("a"):
+        with tracing.span("b"):
+            pass
+        with tracing.span("c"):
+            pass
+    with tracing.span("d"):
+        pass
+    by = _by_name(ring.spans())
+    a = by["a"][0]
+    assert by["b"][0]["parent"] == by["c"][0]["parent"] == ("a", a["sid"])
+    assert "parent" not in by["d"][0]
+
+
+def test_two_threads_do_not_share_a_stack(ring):
+    import threading
+
+    inside = threading.Event()
+    release = threading.Event()
+
+    def other():
+        with tracing.span("other.outer"):
+            inside.set()
+            release.wait(5.0)
+
+    t = threading.Thread(target=other)
+    with tracing.span("main.outer"):
+        t.start()
+        assert inside.wait(5.0)
+        with tracing.span("main.inner"):   # opened while other.outer is
+            pass                           # open on the other thread
+        release.set()
+        t.join()
+    by = _by_name(ring.spans())
+    assert by["main.inner"][0]["parent"][0] == "main.outer"
+    assert "parent" not in by["other.outer"][0]
+
+
+@pytest.mark.parametrize("value", ["0", "off"])
+def test_disabled_span_is_one_shared_noop(monkeypatch, value):
+    monkeypatch.setenv(HOROVOD_TRACE, value)
+    monkeypatch.setattr(tracing, "_tracer", tracing.Tracer())
+    one = tracing.span("serve.step")
+    other = tracing.span("engine.decode", rows=3)
+    assert one is other                    # nothing allocated per span
+    with one as s:
+        s.set(decoded=1)
+        with other:
+            tracing.record("request.queue_wait", 0.0, 1.0)
+    assert tracing.spans() == []
+    assert not getattr(tracing._open, "stack", None)
+
+
+def test_exception_inside_a_span_still_records_it(ring):
+    with pytest.raises(ValueError):
+        with tracing.span("outer"):
+            with tracing.span("boom"):
+                raise ValueError("x")
+    assert [s["name"] for s in ring.spans()] == ["boom", "outer"]
+    assert tracing._open.stack == []
+    with tracing.span("next"):
+        pass
+    assert "parent" not in ring.spans()[-1]
+
+
+def test_span_lands_in_a_running_profiler_trace(ring, tmp_path):
+    """The same interval, under the same name, is a host event of the
+    jax.profiler trace: the clock the device events are on."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tracing.span("serve.step"):
+            with tracing.span("engine.decode.wait", rows=3, uid="u1"):
+                jnp.ones((64, 64)).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = {e.name: e
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name in ("serve.step", "engine.decode.wait")}
+    assert set(events) == {"serve.step", "engine.decode.wait"}
+    step, wait = events["serve.step"], events["engine.decode.wait"]
+    assert step.start_ns <= wait.start_ns
+    assert wait.start_ns + wait.duration_ns <= \
+        step.start_ns + step.duration_ns + 1
+    assert dict(wait.stats).get("rows") == 3
+    # and the ring's duration is the annotation's, to clock noise
+    ring_wait = _by_name(ring.spans())["engine.decode.wait"][0]
+    assert ring_wait["dur"] == pytest.approx(wait.duration_ns * 1e-9,
+                                             abs=2e-3)
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.transformer import Transformer
+
+    model = Transformer(vocab_size=61, d_model=32, num_layers=1,
+                        num_heads=2, d_ff=64, max_seq=48, causal=True,
+                        dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    return model, params
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_replica_loop_and_engine_spans(ring, tiny_lm, paged):
+    """The serving loop through a toy engine: one serve.step per pass
+    with its three children, the engine's spans with their dispatch and
+    wait parts, and exactly one request.decode per finished request."""
+    from test_serve import _replica
+
+    model, params = tiny_lm
+    if paged:
+        from horovod_tpu.serve.paging import PagedDecodeEngine
+
+        engine = PagedDecodeEngine(model, params, num_slots=2,
+                                   page_tokens=16, pool_pages=12)
+    else:
+        from horovod_tpu.serve.kv_cache import DecodeEngine
+
+        engine = DecodeEngine(model, params, num_slots=2)
+    q = RequestQueue()
+    uids = [q.submit([1, 2, 3], max_new_tokens=3),
+            q.submit([4, 5], max_new_tokens=4)]
+    rep = _replica(engine, q)
+    for _ in range(8):
+        rep._iterate()
+    done = [q.result(u, timeout=1.0) for u in uids]
+    by = _by_name(ring.spans())
+
+    # three passes decode (the longer request has three steps after its
+    # prefill); of the five idle passes after them only the first is kept
+    steps = by["serve.step"]
+    assert len(steps) == 4 and len(by["serve.pull"]) == 4
+    busy = [s for s in steps if s["decoded"] > 0]
+    assert len(busy) == 3
+    assert [s["step"] for s in busy] == list(range(1, len(busy) + 1))
+    assert busy[0]["admitted"] == 2 and busy[0]["occupancy"] == 2
+    assert sum(s["admitted"] for s in steps) == 2
+    assert steps[-1]["decoded"] == 0 and steps[-1]["occupancy"] == 0
+    first = ("serve.step", busy[0]["sid"])
+    assert by["serve.pull"][0]["parent"] == first
+    assert by["serve.pull"][0]["n"] == 2
+    assert by["serve.admit"][0]["parent"] == first
+    assert by["serve.admit"][0]["n"] == 2
+    assert len(by["serve.retire"]) == len(busy)
+    assert sum(s["n"] for s in by["serve.retire"]) == 2
+
+    # the engine's spans, children of the loop's
+    admit = ("serve.admit", by["serve.admit"][0]["sid"])
+    assert len(by["request.prefill"]) == 2
+    assert all(s["parent"] == admit for s in by["request.prefill"])
+    assert len(by["engine.prefill"]) == 2
+    for pre in by["engine.prefill"]:
+        assert pre["parent"][0] == "request.prefill"
+        assert pre["bucket"] == 16 and pre["prompt_len"] in (2, 3)
+        key = ("engine.prefill", pre["sid"])
+        kids = [s["name"] for s in ring.spans() if s.get("parent") == key]
+        assert kids == ["engine.prefill.dispatch", "engine.prefill.wait"]
+    assert len(by["engine.decode"]) == len(busy)
+    for dec, step in zip(by["engine.decode"], busy):
+        assert dec["parent"] == ("serve.step", step["sid"])
+        assert dec["rows"] == step["decoded"]
+        key = ("engine.decode", dec["sid"])
+        kids = [s["name"] for s in ring.spans() if s.get("parent") == key]
+        assert kids == ["engine.decode.prep", "engine.decode.dispatch",
+                        "engine.decode.wait"]
+
+    # request spans: one decode span per request, none per block
+    assert "request.decode_block" not in by
+    assert sorted(s["trace_id"] for s in by["request.decode"]) == \
+        sorted(d.trace_id for d in done)
+    for s in by["request.decode"]:
+        assert s["parent"][0] == "serve.retire"
+        assert s["tokens"] in (3, 4)
+        # decode_block=2: 2 steps -> 1 block, 3 steps -> 2 blocks
+        assert s["blocks"] == {3: 1, 4: 2}[s["tokens"]]
+    assert {s["parent"][0] for s in by["request.queue_wait"]} == \
+        {"serve.admit"}
+
+
+def test_prefetch_records_one_wait_and_one_put_per_batch(ring):
+    import numpy as np
+
+    from horovod_tpu.data import prefetch_to_device
+
+    batches = [np.full((2, 3), i, np.int32) for i in range(5)]
+    got = list(prefetch_to_device(iter(batches), size=2))
+    assert [int(b[0, 0]) for b in got] == list(range(5))
+    by = _by_name(ring.spans())
+    assert len(by["input.put"]) == 5
+    # one wait per batch handed out, and the one that met the end marker
+    assert len(by["input.wait"]) == 6
+    assert all(0 <= s["depth"] <= 2 for s in by["input.wait"])
+    assert all("parent" not in s for s in by["input.put"])
+
+
+@pytest.fixture(scope="module")
+def toy_step_text():
+    """The lowered text (with op_name locations) of a toy train step
+    under ``shard_map`` with ``DistributedOptimizer``, attention through
+    the flash kernels."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import horovod_tpu as hvd_mod
+    from horovod_tpu import training
+    from horovod_tpu.models.transformer import Transformer, causal_lm_loss
+
+    model = Transformer(vocab_size=64, d_model=32, num_layers=1,
+                        num_heads=1, d_ff=64, max_seq=128, causal=True,
+                        dtype=jnp.float32)
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:1])["params"]
+    opt = hvd_mod.DistributedOptimizer(optax.sgd(0.1), axis_name="x")
+    state = opt.init(params)
+    one_step = training._make_one_step(model, opt, causal_lm_loss)
+    mesh = Mesh(jax.devices()[:2], ("x",))
+    step = jax.jit(jax.shard_map(
+        one_step, mesh=mesh, in_specs=(P(), P(), P(), P("x"), P("x")),
+        out_specs=(P(), P(), P(), P()), check_vma=False))
+    return step.lower(params, {}, state, tokens, tokens).as_text(
+        debug_info=True)
+
+
+@pytest.mark.parametrize("scope", ["loss", "optimizer", "grad_exchange"])
+def test_train_step_scopes_reach_op_name(toy_step_text, scope):
+    import re
+
+    names = re.findall(r'loc\("([^"]+)"', toy_step_text)
+    assert any(scope in n.split("/") or f"jvp({scope})" in n
+               for n in names), scope
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_flash_kernels_are_named(toy_step_text, kernel):
+    assert kernel in toy_step_text
 
 
 # --------------------------------------------------- 2-rank merged trace
