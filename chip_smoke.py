@@ -273,6 +273,11 @@ def serve_once(hvd, model, params, cfg, prompts, paged: bool):
         if replica["quarantined"]:
             raise AssertionError(f"serve[{label}]: replica quarantined: "
                                  f"{replica}")
+        if not paged and not replica["engine"]["cache_donated"]:
+            raise AssertionError(
+                f"serve[{label}]: the runtime declined the KV cache's "
+                f"donation (a second cache is live and copied every "
+                f"call): {replica['engine']}")
         say(f"serve[{label}]: 4 requests (prompts "
             f"{[o.prompt_len for o in outs]}) x {n_new} new tokens in "
             f"{first_s:.2f} s incl. {compiles} compiles; repeat "

@@ -31,6 +31,7 @@ import optax
 
 from horovod_tpu.ops.pallas._backend import shard_over_batch
 from horovod_tpu.ops.pallas.flash_attention import NEG_INF, flash_attention
+from horovod_tpu.ops.pallas.kv_cache_write import write_token
 
 Dtype = Any
 
@@ -65,6 +66,28 @@ def cached_attention(q, k, v, q_positions):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
+def write_cache_rows(cache, new, positions):
+    """``cache`` with ``new`` written in: row ``b`` takes ``new[b]`` at
+    positions ``positions[b] .. positions[b] + n - 1``.
+
+    ``cache``: (batch, heads, head_dim, cache_len), positions last: the
+    layout both contractions of :func:`cached_attention` read;
+    ``new``: (batch, n, heads, head_dim) of the cache's dtype;
+    ``positions``: (batch,) int32.
+
+    One new token a row (the decode step) goes through the in-place
+    kernel: XLA:TPU expands a batched scatter into one serial trip per
+    row, and a select over the cache writes all of it back. Several
+    tokens a row (prefill) go in as one slice per row.
+    """
+    if new.shape[1] == 1:
+        return write_token(cache, new[:, 0], positions)
+    return jax.vmap(
+        lambda row, rows, start: jax.lax.dynamic_update_slice(
+            row, rows, (0, 0, start)))(
+                cache, new.transpose(0, 2, 3, 1), positions)
+
+
 class SelfAttention(nn.Module):
     """Multi-head self-attention on the flash kernel.
 
@@ -74,10 +97,11 @@ class SelfAttention(nn.Module):
 
     ``decode=True`` switches to the serving path: a ``cache`` variable
     collection holds per-row key/value tensors of length
-    ``max_cache_len``, new tokens are scattered in at their absolute
-    ``positions`` and attention runs masked against the whole cache
-    (:func:`cached_attention`). Parameters are identical to the training
-    module — only runtime behavior and the (non-param) cache change.
+    ``max_cache_len`` (positions last), new tokens are written in at
+    their absolute ``positions`` (:func:`write_cache_rows`) and attention
+    runs masked against the whole cache (:func:`cached_attention`).
+    Parameters are identical to the training module — only runtime
+    behavior and the (non-param) cache change.
 
     ``paged=True`` (with ``decode=True``) swaps the per-row cache for a
     POOLED one: ``(num_pages, page_tokens, heads, head_dim)`` per layer,
@@ -178,26 +202,22 @@ class SelfAttention(nn.Module):
             if self.max_cache_len <= 0:
                 raise ValueError("decode=True requires max_cache_len > 0")
             batch, new_tokens = x.shape[0], x.shape[1]
-            cache_shape = (batch, self.max_cache_len, self.num_heads,
-                           head_dim)
+            cache_shape = (batch, self.num_heads, head_dim,
+                           self.max_cache_len)
             cached_key = self.variable("cache", "cached_key", jnp.zeros,
                                        cache_shape, self.dtype)
             cached_value = self.variable("cache", "cached_value", jnp.zeros,
                                          cache_shape, self.dtype)
             pos = jnp.asarray(positions, jnp.int32)
-
-            def scatter(cache, new, start):
-                return jax.lax.dynamic_update_slice(cache, new, (start, 0, 0))
-
-            cached_key.value = jax.vmap(scatter)(
+            cached_key.value = write_cache_rows(
                 cached_key.value, k.astype(self.dtype), pos)
-            cached_value.value = jax.vmap(scatter)(
+            cached_value.value = write_cache_rows(
                 cached_value.value, v.astype(self.dtype), pos)
             q_pos = pos[:, None] + jnp.arange(new_tokens, dtype=jnp.int32)
             o = cached_attention(
                 q.transpose(0, 2, 1, 3),
-                cached_key.value.transpose(0, 2, 1, 3),
-                cached_value.value.transpose(0, 2, 1, 3), q_pos)
+                cached_key.value.transpose(0, 1, 3, 2),
+                cached_value.value.transpose(0, 1, 3, 2), q_pos)
             o = o.transpose(0, 2, 1, 3)
             return dense(features=d_model, axis=(-2, -1), name="out")(o)
 

@@ -4,18 +4,28 @@
 
 * the decode clone of the user's model (``model.clone(decode=True)`` —
   same params, plus a ``cache`` variable collection of
-  ``(slots, max_seq, heads, head_dim)`` key/value tensors per layer);
+  ``(slots, heads, head_dim, max_seq)`` key/value tensors per layer,
+  positions last: the layout attention reads);
 * ONE jitted decode program over ALL slots every step — the shape never
   changes (inactive rows run masked garbage at position 0, overwritten
   by the next prefill), so steady-state decode never recompiles;
 * one jitted prefill program PER PROMPT-LENGTH BUCKET, batch 1, which
-  writes the prompt's KV into a fresh single-row cache and scatters it
-  into the requested slot at a traced index. Bucketing reuses the
+  writes the prompt's KV into a fresh single-row cache and writes that
+  row into the requested slot at a traced index. Bucketing reuses the
   runtime's size-bucket policy (``fusion_buffer.bucket_elems``: identity
   up to the quantum, then power-of-two multiples), floored at the
   quantum so short prompts share one program — the bucket set is
   O(log(max_seq)) and after one request per bucket the program cache is
   warm: zero steady-state compiles.
+
+The cache is updated IN PLACE: every program donates its cache argument
+(the result aliases it, one cache lives on the device and no program
+copies it), the decode step writes one position per row through
+``ops/pallas/kv_cache_write`` and a prefill writes its row as one slice.
+``self._cache`` is rebound from every call's result; the arrays it held
+before are deleted, so a caller must not hold ``engine._cache`` across a
+call. ``stats()["cache_donated"]`` says whether the runtime took the
+donations (it may decline one and copy instead).
 
 Prefill padding is safe without length bookkeeping: padded positions'
 garbage KV sits at positions ``>= prompt_len``, which
@@ -96,8 +106,12 @@ class DecodeEngine:
                                   attention_fn=None)
         self._cache = self._allocate_cache()
         self._prefill_fns: Dict[int, object] = {}  # guarded-by: <replica-thread>
-        self._decode_fn = jax.jit(self._decode_impl)
+        self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_compiled = False
+        # program kind -> did its first call consume the cache it was
+        # handed (a runtime may decline a donation and copy instead);
+        # written once per kind by the replica thread, read by stats()
+        self._donated: Dict[str, bool] = {}
         self._lock = witness.make_lock("DecodeEngine._lock")
         self._compiles: Dict[str, int] = {}      # guarded-by: _lock
         self.decode_steps = 0
@@ -107,18 +121,21 @@ class DecodeEngine:
         _KV_BYTES.labels(replica=self.name).set(self.cache_bytes())
 
     # -- cache -------------------------------------------------------------
-    def _allocate_cache(self):
-        """Zero cache pytree with the decode program's shapes — derived
-        via ``eval_shape`` so allocation itself compiles nothing."""
-        tokens = jnp.zeros((self.num_slots, 1), jnp.int32)
-        pos = jnp.zeros((self.num_slots,), jnp.int32)
+    def _cache_shapes(self):
+        """The decode program's cache pytree as shapes (``eval_shape``:
+        nothing compiles, nothing is allocated)."""
+        tokens = jax.ShapeDtypeStruct((self.num_slots, 1), jnp.int32)
+        pos = jax.ShapeDtypeStruct((self.num_slots,), jnp.int32)
         _, shapes = jax.eval_shape(
             lambda p, t, q: self._model.apply(
                 {"params": p}, t, positions=q, train=False,
                 mutable=["cache"]),
             self._params, tokens, pos)
+        return shapes["cache"]
+
+    def _allocate_cache(self):
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            shapes["cache"])
+                            self._cache_shapes())
 
     def cache_bytes(self) -> int:
         return sum(int(np.prod(x.shape)) * x.dtype.itemsize
@@ -134,6 +151,24 @@ class DecodeEngine:
         with self._lock:
             return sum(self._compiles.values())
 
+    def _prefill_fn(self, bucket: int):
+        fn = self._prefill_fns.get(bucket)
+        if fn is None:
+            fn = jax.jit(self._prefill_impl, donate_argnums=(1,))
+            self._prefill_fns[bucket] = fn
+            self._note_compile(f"prefill_{bucket}")
+        return fn
+
+    def _run_donating(self, kind: str, fn, *args):
+        """Call a program whose second argument is the (donated) cache
+        and rebind ``self._cache`` to its first result; the first call
+        of each kind records whether the old leaves were consumed."""
+        old = None if kind in self._donated else jax.tree.leaves(self._cache)
+        self._cache, *rest = fn(self._params, self._cache, *args)
+        if old is not None:
+            self._donated[kind] = all(x.is_deleted() for x in old)
+        return rest
+
     def _prefill_impl(self, params, cache, tokens, prompt_len, slot):
         # batch-1 run over the padded prompt builds a fresh (1, max_seq)
         # cache (flax creates the zero cache inside the traced apply)...
@@ -141,8 +176,9 @@ class DecodeEngine:
             {"params": params}, tokens,
             positions=jnp.zeros((1,), jnp.int32), train=False,
             mutable=["cache"])
-        # ...scattered into the slot row at a traced index, so every
-        # prompt of this bucket reuses one program regardless of slot
+        # ...written into the slot row at a traced index (in place: the
+        # big cache is donated), so every prompt of this bucket reuses
+        # one program regardless of slot
         cache = jax.tree.map(
             lambda big, one: jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
@@ -176,15 +212,11 @@ class DecodeEngine:
         with tracing.span("engine.prefill", bucket=bucket,
                           prompt_len=len(prompt), slot=slot):
             with tracing.span("engine.prefill.dispatch"):
-                fn = self._prefill_fns.get(bucket)
-                if fn is None:
-                    fn = jax.jit(self._prefill_impl)
-                    self._prefill_fns[bucket] = fn
-                    self._note_compile(f"prefill_{bucket}")
+                fn = self._prefill_fn(bucket)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :len(prompt)] = prompt
-                self._cache, token, max_abs = fn(
-                    self._params, self._cache, jnp.asarray(padded),
+                token, max_abs = self._run_donating(
+                    "prefill", fn, jnp.asarray(padded),
                     jnp.int32(len(prompt)), jnp.int32(slot))
             with tracing.span("engine.prefill.wait"):   # blocked on the device
                 return int(token), float(max_abs)
@@ -217,8 +249,8 @@ class DecodeEngine:
                 step_pos[s] = p
         start = time.monotonic()
         with tracing.span("engine.decode.dispatch"):
-            self._cache, ids, max_abs = self._decode_fn(
-                self._params, self._cache, jnp.asarray(step_tokens),
+            ids, max_abs = self._run_donating(
+                "decode", self._decode_fn, jnp.asarray(step_tokens),
                 jnp.asarray(step_pos))
         with tracing.span("engine.decode.wait"):   # blocked on the device
             ids = np.asarray(ids)
@@ -238,4 +270,6 @@ class DecodeEngine:
                 "decode_steps": self.decode_steps,
                 "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
                 "cache_bytes": self.cache_bytes(),
+                "cache_donated": (self._donated.get("prefill", False)
+                                  and self._donated.get("decode", False)),
                 "slots": self.num_slots}

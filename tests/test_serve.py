@@ -309,6 +309,163 @@ def test_slot_reuse_no_stale_leak(tiny_lm):
     assert run(used, 1, short, 6) == run(fresh, 1, short, 6)
 
 
+def _generate(eng, slot, prompt, n, others=()):
+    """Prefill ``slot`` and decode to ``n`` tokens; ``others`` are
+    ``[slot, token, position]`` rows that decode alongside (advanced in
+    place), so a step writes several rows at different positions."""
+    token, _ = eng.prefill(slot, prompt)
+    out, p = [token], len(prompt)
+    while len(out) < n:
+        rows = [[slot, out[-1], p]] + [list(o) for o in others]
+        ids, _ = eng.decode(*map(list, zip(*rows)))
+        out.append(ids[0])
+        p += 1
+        for o, t in zip(others, ids[1:]):
+            o[1], o[2] = t, o[2] + 1
+    return out
+
+
+def _rows_at_different_positions(eng):
+    """Three rows in one step, each at its own position (one of them in
+    the second lane tile of a 256-position cache)."""
+    prompts = {0: [3, 1, 4], 1: list(range(1, 18)), 2: [7] * 9}
+    if eng.max_seq > 128:
+        prompts[1] = [(5 * i) % 60 + 1 for i in range(131)]
+    gen = {s: [eng.prefill(s, p)[0]] for s, p in prompts.items()}
+    for step in range(4):
+        slots = sorted(prompts)
+        ids, _ = eng.decode(slots, [gen[s][-1] for s in slots],
+                            [len(prompts[s]) + step for s in slots])
+        for s, t in zip(slots, ids):
+            gen[s].append(t)
+    return [(prompts[s], gen[s]) for s in sorted(prompts)]
+
+
+def _position_zero(eng):
+    """A row whose first write is the decode step's, at position 0."""
+    out, p = [11], 0
+    for _ in range(4):
+        (t,), _ = eng.decode([1], [out[-1]], [p])
+        out.append(t)
+        p += 1
+    return [([11], out[1:])]
+
+
+def _last_position(eng):
+    """The decode step writes the cache's last position."""
+    prompt = [(3 * i) % 60 + 1 for i in range(eng.max_seq - 1)]
+    return [(prompt, _generate(eng, 2, prompt, 2))]
+
+
+def _inactive_row_then_prefill(eng):
+    """Slot 0 idles through four steps (each writes garbage at its
+    position 0), then takes a request."""
+    busy = [5, 4, 3, 2, 1]
+    first = _generate(eng, 1, busy, 5)
+    late = [9, 8, 7]
+    return [(busy, first), (late, _generate(eng, 0, late, 4))]
+
+
+def _reuse_after_longer_occupant(eng):
+    """A short request in the slot a longer one filled, while another
+    row decodes beside it."""
+    long_prompt = list(range(1, 31))
+    _generate(eng, 1, long_prompt, 8)
+    beside = [2, 4, 6, 8, 10]
+    other = [0, eng.prefill(0, beside)[0], len(beside)]
+    short = [9, 8, 7, 6]
+    return [(short, _generate(eng, 1, short, 6, others=[other]))]
+
+
+def _across_lane_tiles(eng):
+    """Writes on both sides of a 128-position tile boundary."""
+    prompt = [(7 * i) % 60 + 1 for i in range(126)]
+    return [(prompt, _generate(eng, 0, prompt, 5))]
+
+
+@pytest.mark.parametrize("max_seq,scenario", [
+    (48, _rows_at_different_positions),
+    (256, _rows_at_different_positions),
+    (48, _position_zero),
+    (48, _last_position),
+    (256, _last_position),
+    (48, _inactive_row_then_prefill),
+    (48, _reuse_after_longer_occupant),
+    (256, _across_lane_tiles),
+], ids=lambda v: v if isinstance(v, int) else v.__name__.lstrip("_"))
+def test_cache_write_parity(tiny_lm, max_seq, scenario):
+    """What the in-place cache write serves is, token for token, what
+    the uncached ``apply`` generates."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiny_lm
+    if max_seq != model.max_seq:
+        model = model.clone(max_seq=max_seq)
+        params = model.init(jax.random.PRNGKey(1),
+                            jnp.zeros((1, 8), jnp.int32),
+                            train=False)["params"]
+    eng = DecodeEngine(model, params, num_slots=3)
+    for prompt, served in scenario(eng):
+        assert served == _uncached_greedy(model, params, prompt,
+                                          len(served)), prompt
+
+
+def test_cache_is_donated(tiny_lm):
+    """Every program consumes the cache it is handed (no second copy of
+    the KV cache lives through a call); a donation the runtime cannot
+    use warns, and fails here."""
+    import warnings
+
+    import jax
+
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiny_lm
+    eng = DecodeEngine(model, params, num_slots=2)
+    assert eng.stats()["cache_donated"] is False     # nothing ran yet
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        before = jax.tree.leaves(eng._cache)
+        token, _ = eng.prefill(0, [1, 2, 3])
+        assert all(x.is_deleted() for x in before)
+        assert eng.stats()["cache_donated"] is False  # decode not yet
+        before = jax.tree.leaves(eng._cache)
+        eng.decode([0], [token], [3])
+        assert all(x.is_deleted() for x in before)
+    assert eng.stats()["cache_donated"] is True
+    assert eng.cache_bytes() == sum(
+        x.nbytes for x in jax.tree.leaves(eng._cache))
+
+
+@pytest.mark.parametrize("cache_len,dtype", [
+    (48, "float32"), (256, "float32"), (256, "bfloat16")])
+def test_write_token_kernel(cache_len, dtype):
+    """The kernel against numpy: one position per row replaced, the rest
+    untouched — first and last position, both sides of a lane-tile
+    boundary, a position past the end clamped onto the last."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops.pallas.kv_cache_write import write_token
+
+    rng = np.random.default_rng(0)
+    rows, heads, head_dim = 6, 3, 16
+    cache = jnp.asarray(rng.normal(size=(rows, heads, head_dim, cache_len)),
+                        dtype)
+    new = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
+    last = cache_len - 1
+    positions = [0, last, min(127, last), min(128, last), 5, cache_len + 3]
+    want = np.array(cache.astype(jnp.float32))
+    for b, p in enumerate(positions):
+        want[b, :, :, min(p, last)] = np.array(new[b].astype(jnp.float32))
+    got = write_token(cache, new, jnp.asarray(positions, jnp.int32))
+    assert got.dtype == cache.dtype
+    np.testing.assert_array_equal(np.array(got.astype(jnp.float32)), want)
+
+
 def test_zero_steady_state_compiles(tiny_lm):
     from horovod_tpu.serve.kv_cache import DecodeEngine
 

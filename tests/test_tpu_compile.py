@@ -9,11 +9,14 @@ kernel at the real bench shapes for one v5e chip. Nothing runs: a pass
 says the chip's compiler takes the kernel, not that its numbers are
 right (the interpret-mode tests and ``chip_smoke.py`` say that).
 
-Kernels only, skipped where the topology cannot be described, and kept
+Kernels, and the dense serving engine's two programs (whose cost is in
+what the compiler makes of the KV-cache update, not in a kernel's
+arithmetic); skipped where the topology cannot be described, and kept
 under half a minute in total.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -188,6 +191,64 @@ def test_fused_adamw_leaf_kernel(chip):
     leaf = (shape, F32)
     assert _kernels_in(update, chip, leaf, leaf, leaf, leaf,
                        ((6,), F32)) == 1
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
+    """GPT-2-small's widths at the serving cell's size (128 slots x 1024
+    positions; two layers keep it to seconds): the program's result
+    aliases the whole donated cache, and nothing in it copies a cache
+    leaf or loops over one (XLA:TPU turns a batched scatter into one
+    serial trip per row; an undonated cache is copied whole, per leaf)."""
+    from horovod_tpu.models.transformer import Transformer
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    layers, slots = 2, 128
+    model = Transformer(vocab_size=50304, d_model=768, num_layers=layers,
+                        num_heads=12, d_ff=3072, max_seq=1024, causal=True,
+                        dtype=BF16)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                           train=False)["params"]))
+    # nothing can be allocated on a described chip: the engine holds the
+    # cache as shapes, which is all that lowering asks of it
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots, 1),
+                                       i32(slots))
+    else:
+        lowered = eng._prefill_fn(256).lower(params, eng._cache, i32(1, 256),
+                                             i32(), i32())
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+
+    leaf = r"bf16\[128,12,64,1024\]"
+    assert re.search(leaf + r"\S* parameter\(", text)   # the text names them so
+    loops = [line for line in text.splitlines()
+             if re.search(r" while\(", line) and re.search(leaf, line)]
+    copies = re.findall(rf"= {leaf}\S* copy\(", text)
+    assert not loops and not copies, (loops, copies)
+    param_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(params))
+    assert memory.alias_size_in_bytes == eng.cache_bytes()
+    # parameters (their small vectors tile-padded: a percent's slack) and
+    # one cache, whose leaves no layout pads
+    assert memory.argument_size_in_bytes <= (1.01 * param_bytes
+                                             + eng.cache_bytes())
+    # the one-token write is the in-place kernel, once per cache leaf; the
+    # prefill writes one row's slice and needs none
+    assert text.count("tpu_custom_call") == (
+        2 * layers if program == "decode" else 0)
 
 
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
