@@ -16,6 +16,7 @@ from horovod_tpu.models.resnet import (
 from horovod_tpu.models.vgg import VGG, VGG11, VGG13, VGG16, VGG19
 from horovod_tpu.models.inception import InceptionV3
 from horovod_tpu.models import moe
+from horovod_tpu.models.hybrid import HybridDecoder
 from horovod_tpu.models.transformer import (
     BertBase,
     BertLarge,
@@ -31,6 +32,7 @@ __all__ = [
     "MnistConvNet",
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "VGG", "VGG11", "VGG13", "VGG16", "VGG19", "InceptionV3", "moe",
-    "Transformer", "BertBase", "BertLarge", "GPT2Small", "GPT2Medium",
+    "HybridDecoder", "Transformer", "BertBase", "BertLarge", "GPT2Small",
+    "GPT2Medium",
     "causal_lm_loss", "masked_lm_loss", "random_tokens",
 ]

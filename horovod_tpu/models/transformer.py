@@ -317,8 +317,14 @@ class Transformer(nn.Module):
 
     @nn.compact
     def __call__(self, token_ids, train: bool = True, pos_offset=0,
-                 output: str = "logits", positions=None, page_table=None):
-        """``pos_offset`` is the global position of the first token — under
+                 output: str = "logits", positions=None, page_table=None,
+                 lengths=None):
+        """``lengths`` (serving prefill only): (batch,) the true length of
+        each padded row; the result then has one row a sequence, row
+        ``lengths - 1``, so that a prefill never builds the (bucket, vocab)
+        logits. Masked softmax needs the lengths for nothing else.
+
+        ``pos_offset`` is the global position of the first token — under
         sequence parallelism each device passes its shard's offset (e.g.
         ``lax.axis_index(axis) * seq_local``) so position embeddings stay
         global; it may be a traced scalar. ``max_seq`` must cover the
@@ -370,6 +376,10 @@ class Transformer(nn.Module):
                     page_tokens=self.page_tokens,
                     name=f"layer_{i}")(x, positions=positions,
                                        page_table=page_table)
+            if lengths is not None:
+                x = jnp.take_along_axis(
+                    x, jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0,
+                                seq - 1)[:, None, None], axis=1)
             x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
                              name="final_norm")(x)
             if output == "hidden":

@@ -27,13 +27,23 @@ before are deleted, so a caller must not hold ``engine._cache`` across a
 call. ``stats()["cache_donated"]`` says whether the runtime took the
 donations (it may decline one and copy instead).
 
-Prefill padding is safe without length bookkeeping: padded positions'
-garbage KV sits at positions ``>= prompt_len``, which
+Prefill padding is safe without length bookkeeping for keys and values:
+padded positions' garbage KV sits at positions ``>= prompt_len``, which
 ``models.transformer.cached_attention`` masks for every query that has
-not reached them — and decode overwrites each one before its query
+not reached them - and decode overwrites each one before its query
 arrives. Slot reuse is safe the same way (stale rows of the previous
 occupant are never attendable); tests/test_serve.py pins both down
 against the uncached ``apply``.
+
+The cache is whatever pytree the model's ``cache`` collection declares,
+every leaf with the slot as axis 0: ``models/hybrid.py`` keeps keys and
+values, compressed keys and a float32 recurrent state side by side
+(:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). A recurrence is not
+indifferent to padding, so a prefill hands the model the true
+``lengths``: the state it leaves is the state after the prompt, and a
+prefill overwrites every leaf's row of its slot, the state included.
+With ``lengths`` the model applies its head to the last prompt row
+alone - the (bucket, vocab) logits never exist.
 
 Sampling is greedy (argmax in-graph; only the winning token ids leave
 the device each step, plus one max-|logit| scalar per slot for the
@@ -91,6 +101,20 @@ def prompt_bucket(prompt_len: int, max_seq: int,
     return min(max_seq, bucket_elems(max(prompt_len, quantum), 1, quantum))
 
 
+# what a cache leaf holds, by the name its model gave the variable: keys
+# and values that grow with the context, compressed keys that a sparse
+# layer selects blocks by, a recurrent state that does not grow
+CACHE_KINDS = {"cached_key": "kv", "cached_value": "kv",
+               "compressed_key": "compressed", "state": "state"}
+
+
+def leaf_kind(path) -> str:
+    """``kv``, ``compressed`` or ``state`` for a cache leaf's tree path
+    (``other`` for a name :data:`CACHE_KINDS` does not know)."""
+    name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
+    return CACHE_KINDS.get(str(name), "other")
+
+
 class DecodeEngine:
     """Model programs + the slot cache for one replica."""
 
@@ -104,6 +128,9 @@ class DecodeEngine:
         self._params = params
         self._model = model.clone(decode=True, remat=False,
                                   attention_fn=None)
+        # a model with block-sparse layers selects key blocks for prompts
+        # past this length (the ``sparse`` attribute of ``engine.prefill``)
+        self._dense_len = getattr(model, "dense_len", None)
         self._cache = self._allocate_cache()
         self._prefill_fns: Dict[int, object] = {}  # guarded-by: <replica-thread>
         self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1,))
@@ -138,8 +165,16 @@ class DecodeEngine:
                             self._cache_shapes())
 
     def cache_bytes(self) -> int:
-        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                   for x in jax.tree.leaves(self._cache))
+        return sum(self.cache_bytes_by_kind().values())
+
+    def cache_bytes_by_kind(self) -> Dict[str, int]:
+        """Resident cache bytes by kind of leaf (:func:`leaf_kind`)."""
+        out = {kind: 0 for kind in CACHE_KINDS.values()}
+        for path, x in jax.tree_util.tree_leaves_with_path(self._cache):
+            kind = leaf_kind(path)
+            out[kind] = out.get(kind, 0) \
+                + int(np.prod(x.shape)) * x.dtype.itemsize
+        return out
 
     # -- programs ----------------------------------------------------------
     def _note_compile(self, program: str) -> None:
@@ -171,19 +206,21 @@ class DecodeEngine:
 
     def _prefill_impl(self, params, cache, tokens, prompt_len, slot):
         # batch-1 run over the padded prompt builds a fresh (1, max_seq)
-        # cache (flax creates the zero cache inside the traced apply)...
+        # cache (flax creates the zero cache inside the traced apply); the
+        # model is told the true length, so that a recurrent state is the
+        # state after the prompt and not after the padding, and applies
+        # its head to the last prompt row alone: logits is (1, 1, vocab)...
         logits, mutated = self._model.apply(
             {"params": params}, tokens,
-            positions=jnp.zeros((1,), jnp.int32), train=False,
-            mutable=["cache"])
+            positions=jnp.zeros((1,), jnp.int32), lengths=prompt_len[None],
+            train=False, mutable=["cache"])
         # ...written into the slot row at a traced index (in place: the
         # big cache is donated), so every prompt of this bucket reuses
         # one program regardless of slot
         cache = jax.tree.map(
             lambda big, one: jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
-        last = jax.lax.dynamic_index_in_dim(
-            logits[0], prompt_len - 1, axis=0, keepdims=False)
+        last = logits[0, 0]
         return cache, jnp.argmax(last).astype(jnp.int32), \
             jnp.max(jnp.abs(last))
 
@@ -209,8 +246,10 @@ class DecodeEngine:
                 f"prefill: prompt length {len(prompt)} outside "
                 f"(0, max_seq={self.max_seq}]")
         bucket = prompt_bucket(len(prompt), self.max_seq)
+        sparse = self._dense_len is not None \
+            and len(prompt) > self._dense_len
         with tracing.span("engine.prefill", bucket=bucket,
-                          prompt_len=len(prompt), slot=slot):
+                          prompt_len=len(prompt), slot=slot, sparse=sparse):
             with tracing.span("engine.prefill.dispatch"):
                 fn = self._prefill_fn(bucket)
                 padded = np.zeros((1, bucket), np.int32)
@@ -270,6 +309,7 @@ class DecodeEngine:
                 "decode_steps": self.decode_steps,
                 "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
                 "cache_bytes": self.cache_bytes(),
+                "cache_bytes_by_kind": self.cache_bytes_by_kind(),
                 "cache_donated": (self._donated.get("prefill", False)
                                   and self._donated.get("decode", False)),
                 "slots": self.num_slots}

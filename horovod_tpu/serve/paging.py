@@ -386,6 +386,14 @@ class PagedDecodeEngine:
                  prefix_entries: Optional[int] = None):
         if not getattr(model, "causal", True):
             raise ValueError("hvd.serve() needs a causal (decoder) model")
+        if not hasattr(model, "paged"):
+            # pages hold keys and values of positions; a recurrent state
+            # or compressed keys have no page to live in
+            raise ValueError(
+                f"the paged engine serves key/value models only: "
+                f"{type(model).__name__} has no paged cache (its cache "
+                f"holds other kinds of leaf, such as a recurrent state); "
+                f"serve it with paged=False (serve/kv_cache.py)")
         self.name = name
         self.num_slots = int(num_slots)
         self.max_seq = int(model.max_seq)

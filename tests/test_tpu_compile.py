@@ -251,6 +251,66 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
         2 * layers if program == "decode" else 0)
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_32768"])
+def test_hybrid_engine_fits_and_updates_its_cache_in_place(chip, monkeypatch,
+                                                           program):
+    """MiniCPM-SALA as the benchmark runs it (benchmark/configs/
+    minicpm-sala.json: four layers at full width, 16 slots x 32768
+    positions, bfloat16 weights): the 16-row decode step and the prefill
+    of the largest bucket compile for one v5e chip, arguments plus
+    temporaries stay under its 16 GB, the result aliases every leaf of
+    the donated cache - keys/values, compressed keys and the float32
+    state alike - and nothing copies a cache leaf."""
+    import json
+
+    from benchmark.runners.serve_sala import build_model
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "minicpm-sala.json")) as f:
+        cfg = json.load(f)["as_run"]
+    slots, seq = 16, cfg["max_seq"]
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+                           )["params"]))
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+    assert eng.cache_bytes_by_kind() == {
+        "kv": 2 * slots * 2 * 128 * seq * 2,              # 537 MB
+        "compressed": slots * 2 * 128 * 2048 * 2,         # 16.8 MB
+        "state": 3 * slots * 32 * 128 * 128 * 4}          # 101 MB
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots, 1),
+                                       i32(slots))
+    else:
+        lowered = eng._prefill_fn(seq).lower(params, eng._cache, i32(1, seq),
+                                             i32(), i32())
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == eng.cache_bytes()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16e9)
+    leaves = (rf"bf16\[{slots},2,128,{seq}\]", rf"bf16\[{slots},2,128,2048\]",
+              rf"f32\[{slots},32,128,128\]")
+    for leaf in leaves:
+        assert re.search(leaf + r"\S* parameter\(", text), leaf
+        assert not re.findall(rf"= {leaf}\S* copy\(", text), leaf
+    # the decode step writes one key, one value and one compressed key a
+    # row through the in-place kernel; the prefill writes slices
+    assert text.count("tpu_custom_call") == (3 if program == "decode" else 0)
+
+
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
     """What ``training.make_train_step`` builds on a four-chip host: one
     jit over the global mesh, batch sharded. XLA cannot partition a
