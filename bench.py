@@ -1730,10 +1730,12 @@ def serve_main(tiny: bool = False, prefix_heavy: bool = False):
             warm_lens += [tail_len, prefix_len + tail_len]
         buckets = sorted({prompt_bucket(p, model.max_seq)
                           for p in warm_lens})
+        # tuple(): the dense engine's calls return pending results;
+        # unpacking one blocks until it is on the host
         for replica in handle._replicas:
             for b in buckets:
-                replica.engine.prefill(0, [b % model.vocab_size] * b)
-            replica.engine.decode([0], [1], [0])
+                tuple(replica.engine.prefill(0, [b % model.vocab_size] * b))
+            tuple(replica.engine.decode([0], [1], [0]))
         warm_compiles = handle.compiles_total()
         warm_steps = sum(r.engine.decode_steps for r in handle._replicas)
         log(f"serve: warm ({warm_compiles} compiles across "
@@ -1808,7 +1810,7 @@ def serve_main(tiny: bool = False, prefix_heavy: bool = False):
             tracer.enabled = trace_on
             t_probe = time.perf_counter()
             with tracing_mod.span("serve.step"):
-                probe_engine.decode([0], [1], [0])
+                tuple(probe_engine.decode([0], [1], [0]))
             (on_s if trace_on else off_s).append(
                 time.perf_counter() - t_probe)
         tracer.enabled = was_enabled

@@ -36,7 +36,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 from horovod_tpu.serve.queue import Request
 
@@ -49,7 +49,14 @@ class ActiveRequest:
     (``prompt_len + max_tokens - 1 <= max_seq`` — the last generated
     token is returned, never written). Without the cap, positions past
     ``max_seq`` would silently clamp onto the last cache row and the
-    request would complete with garbage tokens."""
+    request would complete with garbage tokens.
+
+    The replica counts a token when the program that produces it is
+    ENQUEUED (``enqueued``: the prefill's first token, then one a decode
+    step) and decides ``done`` on that count: a request ends by length
+    alone, so the slot is free for the next admission before the token's
+    value has reached the host. Values land in ``generated`` when the
+    step is collected, one pass later (serve/replica.py)."""
 
     slot: int
     request: Request
@@ -59,6 +66,7 @@ class ActiveRequest:
     page_cost: int = 0       # committed KV pages charged at admission
     #                          (paged engines only; 0 under dense)
     admit_seq: int = 0       # admission order — preemption takes newest
+    enqueued: int = 0        # tokens whose program is enqueued
     generated: List[int] = dataclasses.field(default_factory=list)
     first_token_s: float = 0.0
     admitted_s: float = 0.0
@@ -81,7 +89,7 @@ class ActiveRequest:
 
     @property
     def done(self) -> bool:
-        return len(self.generated) >= self.max_tokens
+        return self.enqueued >= self.max_tokens
 
 
 class ContinuousBatcher:
@@ -209,8 +217,9 @@ class ContinuousBatcher:
         return admitted
 
     def retire_done(self) -> List[ActiveRequest]:
-        """Free the slots of finished requests (iteration-level retire:
-        called after every decode step, not at batch boundaries)."""
+        """Free the slots of the requests whose last token is enqueued
+        (iteration-level retire: called after every decode step's
+        dispatch, not at batch boundaries)."""
         done = [a for a in self._active.values() if a.done]
         for a in done:
             del self._active[a.slot]
@@ -268,16 +277,8 @@ class ContinuousBatcher:
         self._waiting.clear()
         return out
 
-    def batch_rows(self) -> Tuple[List[int], List[int], List[int]]:
-        """(slots, token_ids, positions) for the next decode step: each
-        active row's last generated token (or last prompt token right
-        after prefill) at its current position."""
-        slots, tokens, positions = [], [], []
-        for a in sorted(self._active.values(), key=lambda a: a.slot):
-            if a.done:
-                continue
-            tokens.append(a.generated[-1] if a.generated
-                          else a.request.prompt[-1])
-            positions.append(a.position)
-            slots.append(a.slot)
-        return slots, tokens, positions
+    def batch_rows(self) -> List[ActiveRequest]:
+        """The rows of the next decode step, by slot: every active
+        request with a token still to enqueue, each at its ``position``."""
+        return [a for a in sorted(self._active.values(),
+                                  key=lambda a: a.slot) if not a.done]

@@ -48,13 +48,31 @@ alone - the (bucket, vocab) logits never exist.
 Sampling is greedy (argmax in-graph; only the winning token ids leave
 the device each step, plus one max-|logit| scalar per slot for the
 integrity guard).
+
+The token FEED stays on the device: a ``(slots,)`` int32 array beside
+the cache, donated with it. A prefill writes its first token into its
+slot's entry, the decode step reads every active row's token from the
+feed and writes its argmax back, so no step waits for the ids of the one
+before it to cross to the host and back. What the host sends a decode
+step is one int32 a row: the position, or -1 for a row that is not
+active (known by count, without reading an id).
+
+Every call therefore has two halves. :meth:`DecodeEngine.prefill` and
+:meth:`DecodeEngine.decode` launch the program and return a
+:class:`Pending` result whose arrays are still on the device (their copy
+to the host is started at once); its ``collect()`` blocks until they are
+here. The serving loop enqueues step k+1 before it collects step k
+(serve/replica.py). A pending result also unpacks like the tuple it
+stands for, collecting first, so ``token, max_abs = engine.prefill(...)``
+and ``ids, max_abs = engine.decode(...)`` are the blocking calls they
+always were, for tests and tools.
 """
 
 from __future__ import annotations
 
 import time
 import weakref
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,8 +133,67 @@ def leaf_kind(path) -> str:
     return CACHE_KINDS.get(str(name), "other")
 
 
+class Pending:
+    """The results of an enqueued program: on the device until
+    :meth:`collect` has them. ``on_host`` is what the serving loop looks
+    at: false means it may enqueue more before it collects. Unpacking a
+    pending result collects it (the blocking form of the call)."""
+
+    on_host = False
+    _result = None
+
+    def collect(self) -> tuple:
+        if self._result is None:
+            self._result = self._read()
+        return self._result
+
+    def __iter__(self):
+        return iter(self.collect())
+
+
+class PendingPrefill(Pending):
+    """``collect()`` -> (first generated token id, max |logit|)."""
+
+    def __init__(self, token, max_abs, t0: float, attrs: dict):
+        self._token, self._max_abs = token, max_abs
+        self._t0, self._attrs = t0, attrs
+
+    def _read(self) -> Tuple[int, float]:
+        with tracing.span("engine.prefill.wait"):   # blocked on the device
+            out = int(self._token), float(self._max_abs)
+        # the start of the dispatch to the first token on the host
+        tracing.record("engine.prefill", self._t0, time.time() - self._t0,
+                       **self._attrs)
+        return out
+
+
+class PendingDecode(Pending):
+    """``collect()`` -> (ids, max |logit|s) of the step's rows."""
+
+    def __init__(self, engine: "DecodeEngine", slots: List[int], ids,
+                 max_abs, t0: float, number: int):
+        self._engine, self._slots = engine, slots
+        self._ids, self._max_abs = ids, max_abs
+        self._t0, self._number = t0, number
+
+    def _read(self) -> Tuple[List[int], List[float]]:
+        # ``ahead``: a later decode step was already enqueued when the
+        # wait began, so the device has work while the host reads
+        engine = self._engine
+        ahead = int(engine.decodes_enqueued > self._number)
+        with tracing.span("engine.decode.wait", ahead=ahead):
+            ids = np.asarray(self._ids)          # blocked on the device
+            max_abs = np.asarray(self._max_abs)
+        # the start of the prep to the ids on the host
+        seconds = time.time() - self._t0
+        tracing.record("engine.decode", self._t0, seconds,
+                       rows=len(self._slots))
+        engine._note_decode(seconds * 1000.0, ahead)
+        return ids[self._slots].tolist(), max_abs[self._slots].tolist()
+
+
 class DecodeEngine:
-    """Model programs + the slot cache for one replica."""
+    """Model programs + the slot cache and token feed for one replica."""
 
     def __init__(self, model, params, num_slots: int, name: str = "r0"):
         if not getattr(model, "causal", True):
@@ -132,8 +209,10 @@ class DecodeEngine:
         # past this length (the ``sparse`` attribute of ``engine.prefill``)
         self._dense_len = getattr(model, "dense_len", None)
         self._cache = self._allocate_cache()
+        # the next token of every row, on the device (module docstring)
+        self._feed = jnp.zeros((self.num_slots,), jnp.int32)
         self._prefill_fns: Dict[int, object] = {}  # guarded-by: <replica-thread>
-        self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1,))
+        self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._decode_compiled = False
         # program kind -> did its first call consume the cache it was
         # handed (a runtime may decline a donation and copy instead);
@@ -141,7 +220,11 @@ class DecodeEngine:
         self._donated: Dict[str, bool] = {}
         self._lock = witness.make_lock("DecodeEngine._lock")
         self._compiles: Dict[str, int] = {}      # guarded-by: _lock
+        # decode steps enqueued; collected; collected with their successor
+        # already enqueued (Replica.stats()["lookahead_share"])
+        self.decodes_enqueued = 0
         self.decode_steps = 0
+        self.decodes_ahead = 0
         self.step_ms_ewma = 0.0
         with _engines_lock:
             _engines.add(self)
@@ -189,22 +272,30 @@ class DecodeEngine:
     def _prefill_fn(self, bucket: int):
         fn = self._prefill_fns.get(bucket)
         if fn is None:
-            fn = jax.jit(self._prefill_impl, donate_argnums=(1,))
+            fn = jax.jit(self._prefill_impl, donate_argnums=(1, 2))
             self._prefill_fns[bucket] = fn
             self._note_compile(f"prefill_{bucket}")
         return fn
 
     def _run_donating(self, kind: str, fn, *args):
-        """Call a program whose second argument is the (donated) cache
-        and rebind ``self._cache`` to its first result; the first call
-        of each kind records whether the old leaves were consumed."""
-        old = None if kind in self._donated else jax.tree.leaves(self._cache)
-        self._cache, *rest = fn(self._params, self._cache, *args)
+        """Enqueue a program whose second and third arguments are the
+        (donated) cache and feed, rebind both to its first two results
+        and start the others' copy to the host; the first call of each
+        kind records whether the old leaves were consumed. The rebinding
+        is what orders the programs on the device: each takes the
+        results of the one before it, whether or not the host has read
+        anything."""
+        old = None if kind in self._donated \
+            else jax.tree.leaves((self._cache, self._feed))
+        self._cache, self._feed, *rest = fn(
+            self._params, self._cache, self._feed, *args)
         if old is not None:
             self._donated[kind] = all(x.is_deleted() for x in old)
+        for x in rest:
+            x.copy_to_host_async()
         return rest
 
-    def _prefill_impl(self, params, cache, tokens, prompt_len, slot):
+    def _prefill_impl(self, params, cache, feed, tokens, prompt_len, slot):
         # batch-1 run over the padded prompt builds a fresh (1, max_seq)
         # cache (flax creates the zero cache inside the traced apply); the
         # model is told the true length, so that a recurrent state is the
@@ -221,23 +312,31 @@ class DecodeEngine:
             lambda big, one: jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
         last = logits[0, 0]
-        return cache, jnp.argmax(last).astype(jnp.int32), \
-            jnp.max(jnp.abs(last))
+        token = jnp.argmax(last).astype(jnp.int32)
+        # the slot's first decode step reads its token from the feed
+        feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
+        return cache, feed, token, jnp.max(jnp.abs(last))
 
-    def _decode_impl(self, params, cache, tokens, positions):
+    def _decode_impl(self, params, cache, feed, positions):
+        # a row the host sends -1 for is not active: it runs token 0 at
+        # position 0 and leaves its feed entry alone
+        active = positions >= 0
+        tokens = jnp.where(active, feed, 0)[:, None]
         logits, mutated = self._model.apply(
             {"params": params, "cache": cache}, tokens,
-            positions=positions, train=False, mutable=["cache"])
+            positions=jnp.maximum(positions, 0), train=False,
+            mutable=["cache"])
         step_logits = logits[:, 0, :]
-        return (mutated["cache"],
-                jnp.argmax(step_logits, axis=-1).astype(jnp.int32),
+        ids = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
+        return (mutated["cache"], jnp.where(active, ids, feed), ids,
                 jnp.max(jnp.abs(step_logits), axis=-1))
 
     # -- serving ops -------------------------------------------------------
-    def prefill(self, slot: int, prompt: List[int]) -> Tuple[int, float]:
-        """Run the prompt through the bucketed prefill program, filling
-        ``slot``'s cache rows. Returns (first generated token id,
-        max |logit|) — the first token comes from prefill itself."""
+    def prefill(self, slot: int, prompt: List[int]) -> PendingPrefill:
+        """Launch the prompt's bucketed prefill program, which fills
+        ``slot``'s cache rows and puts the first generated token (it
+        comes from prefill itself) into the slot's feed entry. The
+        result collects to (first generated token id, max |logit|)."""
         if not 0 < len(prompt) <= self.max_seq:
             # callers (ServeHandle.submit, Replica._reject) screen this
             # out; fail loudly rather than let the padded copy below
@@ -248,58 +347,60 @@ class DecodeEngine:
         bucket = prompt_bucket(len(prompt), self.max_seq)
         sparse = self._dense_len is not None \
             and len(prompt) > self._dense_len
-        with tracing.span("engine.prefill", bucket=bucket,
-                          prompt_len=len(prompt), slot=slot, sparse=sparse):
-            with tracing.span("engine.prefill.dispatch"):
-                fn = self._prefill_fn(bucket)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :len(prompt)] = prompt
-                token, max_abs = self._run_donating(
-                    "prefill", fn, jnp.asarray(padded),
-                    jnp.int32(len(prompt)), jnp.int32(slot))
-            with tracing.span("engine.prefill.wait"):   # blocked on the device
-                return int(token), float(max_abs)
+        t0 = time.time()
+        with tracing.span("engine.prefill.dispatch"):
+            fn = self._prefill_fn(bucket)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :len(prompt)] = prompt
+            # numpy scalars ride along with the call; a jnp scalar would
+            # be a program of its own before it
+            token, max_abs = self._run_donating(
+                "prefill", fn, padded, np.int32(len(prompt)), np.int32(slot))
+        return PendingPrefill(token, max_abs, t0, dict(
+            bucket=bucket, prompt_len=len(prompt), slot=slot, sparse=sparse))
 
-    def decode(self, slots: List[int], tokens: List[int],
-               positions: List[int]) -> Tuple[List[int], List[float]]:
-        """One decode step over ALL cache rows (fixed shape — the one
-        compiled decode program). Active rows get their real token and
-        position; inactive rows run token 0 at position 0, whose cache
-        write lands where the next prefill overwrites it."""
+    def decode(self, slots: List[int], tokens: Optional[List[int]],
+               positions: List[int]) -> PendingDecode:
+        """Launch one decode step over ALL cache rows (fixed shape — the
+        one compiled decode program). Active rows take their token from
+        the feed at their real position; inactive rows run token 0 at
+        position 0, whose cache write lands where the next prefill
+        overwrites it. ``tokens`` is ``None`` where the rows' tokens are
+        in the feed (the serving loop, whose last programs put them
+        there); a caller that has them on the host instead passes them,
+        and they replace the feed first (one transfer more). The result
+        collects to (ids, max |logit|s) of ``slots``."""
+        if tokens is not None:
+            feed = np.zeros((self.num_slots,), np.int32)
+            feed[slots] = tokens
+            self._feed = jnp.asarray(feed)
         if not self._decode_compiled:
             self._decode_compiled = True
             self._note_compile("decode")
-        with tracing.span("engine.decode", rows=len(slots)):
-            return self._decode(slots, tokens, positions)
-
-    def _decode(self, slots, tokens, positions):
+        t0 = time.time()
         with tracing.span("engine.decode.prep"):
-            step_tokens = np.zeros((self.num_slots, 1), np.int32)
-            step_pos = np.zeros((self.num_slots,), np.int32)
-            for s, t, p in zip(slots, tokens, positions):
-                if p >= self.max_seq:
-                    # admission caps max_tokens so no write lands past the
-                    # cache (batcher.ActiveRequest); overrunning silently
-                    # would overwrite the last KV row and serve garbage
-                    raise ValueError(
-                        f"decode: slot {s} position {p} >= max_seq "
-                        f"{self.max_seq} (admission cap violated)")
-                step_tokens[s, 0] = t
-                step_pos[s] = p
-        start = time.monotonic()
+            step_pos = np.full((self.num_slots,), -1, np.int32)
+            step_pos[slots] = positions
+            if slots and step_pos.max() >= self.max_seq:
+                # admission caps max_tokens so no write lands past the
+                # cache (batcher.ActiveRequest); overrunning silently
+                # would overwrite the last KV row and serve garbage
+                slot = int(step_pos.argmax())
+                raise ValueError(
+                    f"decode: slot {slot} position {step_pos[slot]} >= "
+                    f"max_seq {self.max_seq} (admission cap violated)")
         with tracing.span("engine.decode.dispatch"):
-            ids, max_abs = self._run_donating(
-                "decode", self._decode_fn, jnp.asarray(step_tokens),
-                jnp.asarray(step_pos))
-        with tracing.span("engine.decode.wait"):   # blocked on the device
-            ids = np.asarray(ids)
-            max_abs = np.asarray(max_abs)
-        ms = (time.monotonic() - start) * 1000.0
+            ids, max_abs = self._run_donating("decode", self._decode_fn,
+                                              step_pos)
+        self.decodes_enqueued += 1
+        return PendingDecode(self, list(slots), ids, max_abs, t0,
+                             self.decodes_enqueued)
+
+    def _note_decode(self, ms: float, ahead: int) -> None:
         self.decode_steps += 1
+        self.decodes_ahead += ahead
         self.step_ms_ewma = (ms if self.decode_steps == 1
                              else 0.9 * self.step_ms_ewma + 0.1 * ms)
-        return ([int(ids[s]) for s in slots],
-                [float(max_abs[s]) for s in slots])
 
     def stats(self) -> dict:
         with self._lock:

@@ -1,18 +1,41 @@
-"""The per-replica serving loop: pull → admit/prefill → decode → retire.
+"""The per-replica serving loop: pull → admit → enqueue → collect → retire.
 
 One :class:`Replica` drives one :class:`~horovod_tpu.serve.kv_cache.
 DecodeEngine` and one :class:`~horovod_tpu.serve.batcher.
-ContinuousBatcher` on a single thread. The loop each iteration:
+ContinuousBatcher` on a single thread. The loop each pass:
 
 1. pulls new requests from the shared queue (in-process or KV-backed,
    behind a small transport adapter) into the batcher's waiting line;
 2. when admission is due (decode-block boundary, idle replica, or the
-   admission deadline — batcher.py has the policy), prefills admitted
-   prompts through the bucketed prefill programs; the first generated
-   token falls out of prefill, so TTFT is measured here;
-3. runs ONE fixed-shape decode step over all slots and retires finished
-   rows iteration-level (a retiring request frees its slot for the very
-   next admission check, not a batch boundary).
+   admission deadline — batcher.py has the policy), ENQUEUES the
+   admitted prompts' bucketed prefill programs; the first generated
+   token falls out of prefill;
+3. enqueues ONE fixed-shape decode step over all slots, counts its
+   tokens and frees the slots of the requests it ends (a request ends
+   by length, so this needs no token value: a retiring request frees
+   its slot for the very next admission check, not a batch boundary);
+4. only now COLLECTS: the ids and guard values of the decode step
+   enqueued in the pass before, then this pass's first tokens. The
+   device always has the step of (3) queued while the host reads,
+   appends, completes requests and comes round to (1) again.
+
+The loop runs ONE step ahead of its readback, no further (retirement is
+by count; a second step ahead buys nothing once the queue is never
+empty). A token's value and the guard's verdict on the step that made
+it reach the host one pass late; a :class:`Completion` is delivered only
+when every one of its values is here and every step that produced them
+passed the guard. Whatever tears the loop down (quarantine, ``stop()``,
+:class:`WorkersDownError`, a loop error) first drops the step in flight
+unread: greedy decoding regenerates it elsewhere, and the engine's cache
+is always the result of the last program enqueued.
+
+The loop is written against enqueue/collect and looks at one thing, a
+result's ``on_host``. The dense engine's ``prefill`` / ``decode`` return
+results that are still on the device, so the loop runs ahead; an engine
+whose calls block (the paged engine, which re-plans rows between
+dispatch and result: serve/paging.py) hands back plain tuples that are
+on the host, and the loop collects each at once, which is the order of a
+pass before there was a feed on the device.
 
 Reliability wiring (the serve plane rides the existing stack):
 
@@ -23,7 +46,9 @@ Reliability wiring (the serve plane rides the existing stack):
   per-step max-|logit|; a non-finite value (or an exhausted guard)
   QUARANTINES the replica — it returns every pulled request to the
   queue, stops heartbeating so the dispatcher reassigns, and parks,
-  rather than serving garbage;
+  rather than serving garbage (the verdict on step k arrives after step
+  k+1 is enqueued: that step's results are dropped unread, and no token
+  of step k or later is ever delivered);
 * a :class:`~horovod_tpu.exceptions.WorkersDownError` escaping the step
   (a model whose forward uses collectives under elastic) requeues the
   in-flight work the same way before re-raising to the elastic driver.
@@ -31,19 +56,20 @@ Reliability wiring (the serve plane rides the existing stack):
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import threading
 import time
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 from horovod_tpu import flight_recorder, goodput, tracing
 from horovod_tpu.elastic import fault_inject
 from horovod_tpu.exceptions import NumericalError, WorkersDownError
 from horovod_tpu.metrics import COUNT_BUCKETS, registry as _metrics
-from horovod_tpu.serve.batcher import ContinuousBatcher
+from horovod_tpu.serve.batcher import ActiveRequest, ContinuousBatcher
 from horovod_tpu.serve.kv_cache import DecodeEngine
 from horovod_tpu.serve.paging import PagePoolExhausted
-from horovod_tpu.serve.queue import (Completion, KVQueueReplica,
+from horovod_tpu.serve.queue import (Completion, KVQueueReplica, Request,
                                      RequestQueue, HEARTBEAT_SECONDS)
 from horovod_tpu.utils import logging as log
 
@@ -189,6 +215,35 @@ class _KVTransport:
         return 0
 
 
+class _Collected:
+    """What a blocking engine call returned, in the shape of a pending
+    result (serve/kv_cache.py ``Pending``): it is on the host already,
+    so the loop collects it at once."""
+
+    on_host = True
+
+    def __init__(self, result):
+        self._result = result
+
+    def collect(self):
+        return self._result
+
+
+def _pending(result):
+    """An engine call's result as something to ``collect()``: the dense
+    engine's is pending, any other engine's is the plain tuple."""
+    return result if hasattr(result, "collect") else _Collected(result)
+
+
+@dataclasses.dataclass
+class _DecodeStep:
+    """One decode step between its dispatch and its collection."""
+
+    pending: object              # collect() -> (ids, max |logit|s) of rows
+    rows: List[ActiveRequest]    # by slot; some may have retired since
+    dispatched_s: float          # monotonic
+
+
 class Replica:
     """One serving replica; ``run()`` is the loop, single thread."""
 
@@ -221,6 +276,13 @@ class Replica:
         # of the open serve.step: requests admitted and pulled in it, and
         # the passes before it that pulled, admitted and decoded nothing
         self._admitted = self._pulled = self._idle_passes = 0
+        # enqueued, not collected: the decode step of the pass before,
+        # this pass's prefills (with their request and dispatch time),
+        # and the requests retired by count whose values are among them
+        self._ahead: Optional[_DecodeStep] = None
+        self._first_tokens: List[Tuple[ActiveRequest, object, float]] = []
+        self._unread: List[ActiveRequest] = []
+        self._accounted_s = 0.0   # goodput: serve time told up to here
         self._stop = threading.Event()
 
     # -- lifecycle ---------------------------------------------------------
@@ -292,12 +354,7 @@ class Replica:
         parks until the fleet is stopped."""
         self.quarantined = True
         _QUARANTINED.inc()
-        victims = self.batcher.evict_all()
-        victims += self.batcher.drain_waiting()
-        if self.paged:
-            # a dead replica must not pin pool pages: every request-held
-            # page goes back (the chaos cell pins request_held == 0)
-            self.engine.release_all()
+        victims = self._evict()
         evicted = len(victims)
         requeued = self.transport.requeue_all()
         _REQUESTS.labels(outcome="requeued").inc(max(evicted, requeued))
@@ -308,6 +365,28 @@ class Replica:
         log.error("serve: replica %s QUARANTINED (%s); %d request(s) "
                   "returned for redistribution", self.name, reason,
                   max(evicted, requeued))
+
+    def _drop_in_flight(self) -> None:
+        """Forget what is enqueued and not collected, unread. The
+        engine's cache and feed are the last program's results whatever
+        the host has read, so the engine stays usable."""
+        self._ahead = None
+        self._first_tokens.clear()
+
+    def _evict(self) -> List[Request]:
+        """Drop the step in flight and give up every request this
+        replica holds - active, waiting, and retired with values still
+        unread - for requeueing; nothing of them was delivered."""
+        self._drop_in_flight()
+        victims = self.batcher.evict_all()
+        victims += [a.request for a in self._unread]
+        self._unread.clear()
+        victims += self.batcher.drain_waiting()
+        if self.paged:
+            # a dead replica must not pin pool pages: every request-held
+            # page goes back (the chaos cell pins request_held == 0)
+            self.engine.release_all()
+        return victims
 
     def _preempt_for_pages(self, exclude_slot=None) -> bool:
         """Page-pool exhaustion (paged engines): bounce the newest-
@@ -367,10 +446,7 @@ class Replica:
                 # elastic membership change mid-step: nothing is lost —
                 # the pulled work returns to the queue before the
                 # elastic driver re-forms us
-                victims = self.batcher.evict_all()
-                victims += self.batcher.drain_waiting()
-                if self.paged:
-                    self.engine.release_all()
+                victims = self._evict()
                 requeued = self.transport.requeue_all()
                 requeued += len(victims)
                 flight_recorder.emit(
@@ -386,6 +462,7 @@ class Replica:
                 log.error("serve: replica %s loop error: %r",
                           self.name, exc)
                 self._quarantine(f"loop error: {exc!r}")
+        self._drop_in_flight()
         flight_recorder.emit("serve_replica_stop", replica=self.name,
                              rank=self.rank, completed=self.completed)
 
@@ -432,9 +509,38 @@ class Replica:
             if not reqs and self._idle_passes:
                 pulled.discard()
 
+    def _enqueue_prefill(self, active: ActiveRequest):
+        """The slot's prefill as something to ``collect()``; ``None``
+        when the page pool could not take it and the admission bounced
+        back to the queue."""
+        while True:
+            try:
+                return _pending(self.engine.prefill(active.slot,
+                                                    active.request.prompt))
+            except PagePoolExhausted:
+                # prefill rolled its partial allocations back; preempt
+                # the newest OTHER request and retry. With nothing left
+                # to preempt, the admission itself bounces back to the
+                # queue front (its prefix-hit discount was optimistic)
+                if not self._preempt_for_pages(exclude_slot=active.slot):
+                    self.batcher.preempt_slot(active.slot)
+                    self.engine.note_preemption()
+                    _REQUESTS.labels(outcome="preempted").inc()
+                    return None
+
+    def _enqueue_decode(self, rows: List[ActiveRequest]):
+        # a row's last token is on the host unless the program that makes
+        # it is still in flight, and the engine that left it in flight
+        # has it in its feed
+        in_flight = self._ahead is not None or bool(self._first_tokens)
+        tokens = None if in_flight else [a.generated[-1] for a in rows]
+        return _pending(self.engine.decode(
+            [a.slot for a in rows], tokens, [a.position for a in rows]))
+
     def _admit(self, now: float) -> bool:
-        """Prefill what the batcher admits. False when a prefill tripped
-        the integrity guard (the replica is quarantined)."""
+        """Enqueue the prefills of what the batcher admits. False when a
+        first token read here (a blocking engine's) tripped the
+        integrity guard (the replica is quarantined)."""
         # submitted_s and admitted_s are LOCAL monotonic stamps; this maps
         # them onto the epoch trace clock, once for the whole batch
         to_epoch = time.time() - time.monotonic()
@@ -451,52 +557,132 @@ class Replica:
                 active.queue_wait_s, trace_id=req.trace_id,
                 uid=req.uid, requeues=req.requeues)
             p0 = time.monotonic()
-            with tracing.span("request.prefill", trace_id=req.trace_id,
-                              uid=req.uid, slot=active.slot,
-                              prompt_len=active.prompt_len) as prefill:
-                token = None
-                while True:
-                    try:
-                        token, max_abs = self.engine.prefill(
-                            active.slot, req.prompt)
-                        break
-                    except PagePoolExhausted:
-                        # prefill rolled its partial allocations back;
-                        # preempt the newest OTHER request and retry.
-                        # With nothing left to preempt, the admission
-                        # itself bounces back to the queue front (its
-                        # prefix-hit discount was optimistic)
-                        if not self._preempt_for_pages(
-                                exclude_slot=active.slot):
-                            self.batcher.preempt_slot(active.slot)
-                            self.engine.note_preemption()
-                            _REQUESTS.labels(outcome="preempted").inc()
-                            break
-                if token is None:
-                    prefill.set(preempted=True)
-                    continue
-                if not self._guard_ok(max_abs):
-                    self._quarantine("non-finite prefill logits")
-                    return False
-                active.generated.append(token)
-                active.first_token_s = time.monotonic()
-                active.prefill_s = active.first_token_s - p0
+            pending = self._enqueue_prefill(active)
+            if pending is None:
+                tracing.record(
+                    "request.prefill", p0 + to_epoch, time.monotonic() - p0,
+                    trace_id=req.trace_id, uid=req.uid, slot=active.slot,
+                    prompt_len=active.prompt_len, preempted=True)
+                continue
+            active.enqueued = 1
             self._admitted += 1
-            # prefill is productive serve time too (tokens=0: the
-            # preemption exchange rate stays a pure decode cost)
-            goodput.record_serve_step(active.prefill_s)
-            _TOKENS.labels(kind="prefill").inc(active.prompt_len)
-            _LATENCY.labels(phase="ttft").observe(
-                active.first_token_s - active.request.submitted_s)
-        for done in self.batcher.retire_done():  # max_new_tokens == 1
-            if self.paged:
-                self.engine.release_slot(done.slot)
-            self._finish(done, time.monotonic())
+            self._first_tokens.append((active, pending, p0))
+            if pending.on_host and not self._collect_first_tokens():
+                return False
+        self._retire()                  # max_new_tokens == 1
+        self._deliver(time.monotonic())
         return True
 
+    def _retire(self) -> None:
+        """Free the slots of the requests whose last token is enqueued.
+        Their values may still be on the device: they wait in
+        ``_unread`` for :meth:`_deliver`."""
+        for done in self.batcher.retire_done():
+            if self.paged:
+                self.engine.release_slot(done.slot)
+            self._unread.append(done)
+
+    def _deliver(self, now: float) -> int:
+        """Complete the retired requests whose every token value is on
+        the host (each appended after its step passed the guard)."""
+        waiting, delivered = [], 0
+        for retired in self._unread:
+            if len(retired.generated) < retired.max_tokens:
+                waiting.append(retired)
+            else:
+                self._finish(retired, now)
+                delivered += 1
+        self._unread = waiting
+        return delivered
+
+    def _productive(self, since: float, tokens: int = 0) -> None:
+        """Tell the goodput ledger of serve time it has not seen: from
+        ``since`` (a program's dispatch) or the last collection,
+        whichever is later, to now. Programs overlap; wall time is
+        counted once."""
+        now = time.monotonic()
+        goodput.record_serve_step(now - max(since, self._accounted_s),
+                                  tokens=tokens)
+        self._accounted_s = now
+
+    def _collect_first_tokens(self) -> bool:
+        """Read the enqueued prefills' first tokens, oldest first. False
+        when one tripped the integrity guard (the replica is
+        quarantined)."""
+        to_epoch = time.time() - time.monotonic()
+        while self._first_tokens:
+            active, pending, p0 = self._first_tokens.pop(0)
+            token, max_abs = pending.collect()
+            if not self._guard_ok(max_abs):
+                self._quarantine("non-finite prefill logits")
+                return False
+            req = active.request
+            active.generated.append(token)
+            active.first_token_s = time.monotonic()
+            active.prefill_s = active.first_token_s - p0
+            # dispatch to first token on the host (after the fact: other
+            # prefills and a decode step are enqueued in between)
+            tracing.record(
+                "request.prefill", p0 + to_epoch, active.prefill_s,
+                trace_id=req.trace_id, uid=req.uid, slot=active.slot,
+                prompt_len=active.prompt_len)
+            # prefill is productive serve time too (tokens=0: the
+            # preemption exchange rate stays a pure decode cost)
+            self._productive(p0)
+            _TOKENS.labels(kind="prefill").inc(active.prompt_len)
+            _LATENCY.labels(phase="ttft").observe(
+                active.first_token_s - req.submitted_s)
+        return True
+
+    def _collect_decode(self, step: _DecodeStep) -> bool:
+        """Read a decode step's ids, put it before the guard and append
+        the values to its rows. False when the guard tripped (the
+        replica is quarantined, nothing of the step is kept)."""
+        ids, max_abs = step.pending.collect()
+        # no short-circuit: the guard's EWMA/skip-budget state must
+        # see EVERY slot's observation, not a prefix that stops at
+        # the first failing slot
+        verdicts = [self._guard_ok(m) for m in max_abs]
+        if not all(verdicts):
+            self._quarantine("non-finite decode logits")
+            return False
+        for active, token in zip(step.rows, ids):
+            active.generated.append(token)
+        occupancy = len(step.rows)
+        self.occupancy_sum += occupancy
+        if self.paged:
+            self.page_used_sum += self.engine.pool.used_count()
+        _TOKENS.labels(kind="decode").inc(occupancy)
+        _OCCUPANCY.labels(replica=self.name).set(occupancy)
+        _OCCUPANCY_HIST.observe(occupancy)
+        # goodput ledger: one decoded token per occupied slot is the
+        # serve plane's productive unit; the step wall also refreshes
+        # the EWMA per-token cost that prices preempted work
+        self._productive(step.dispatched_s, tokens=occupancy)
+        return True
+
+    def _prepare_pages(self, rows: List[ActiveRequest]
+                       ) -> List[ActiveRequest]:
+        """Paged engines: grow tables across block boundaries / COW
+        shared pages BEFORE the step; exhaustion preempts newest-
+        admitted until the survivors fit (admission guarantees a sole
+        request always does). The rows that are left."""
+        while rows:
+            try:
+                self.engine.prepare_step([a.slot for a in rows],
+                                         [a.position for a in rows])
+                break
+            except PagePoolExhausted:
+                if not self._preempt_for_pages():
+                    raise   # nothing left to shed: quarantine path
+                rows = self.batcher.batch_rows()
+        return rows
+
     def _step(self) -> int:
-        """pull -> admit/prefill -> one decode step -> retire. Returns
-        the rows decoded (0: nothing to decode, or quarantined)."""
+        """pull -> admit (enqueue prefills) -> enqueue one decode step ->
+        collect the step before it and this pass's first tokens ->
+        retire. Returns the rows enqueued (0: nothing to decode, or
+        quarantined)."""
         now = time.monotonic()
         self._pull(now)
         _QUEUE_DEPTH.labels(replica=self.name).set(
@@ -509,70 +695,45 @@ class Replica:
             if not ok:
                 return 0
 
-        slots, tokens, positions = self.batcher.batch_rows()
-        if not slots:
+        rows = self.batcher.batch_rows()
+        if rows and self.paged:
+            rows = self._prepare_pages(rows)
+            if not rows:
+                _OCCUPANCY.labels(replica=self.name).set(0)
+                return 0
+        if not rows and self._ahead is None and not self._first_tokens:
             _OCCUPANCY.labels(replica=self.name).set(0)
             time.sleep(_IDLE_SLEEP_SECONDS)
             # goodput ledger: an empty loop iteration is queue-idle badput
             goodput.record_span("serve_queue_idle", _IDLE_SLEEP_SECONDS)
             return 0
 
-        if self.paged:
-            # grow tables across block boundaries / COW shared pages
-            # BEFORE the step; exhaustion preempts newest-admitted until
-            # the survivors fit (admission guarantees a sole request
-            # always does)
-            while True:
-                try:
-                    self.engine.prepare_step(slots, positions)
-                    break
-                except PagePoolExhausted:
-                    if not self._preempt_for_pages():
-                        raise   # nothing left to shed: quarantine path
-                    slots, tokens, positions = self.batcher.batch_rows()
-                    if not slots:
-                        _OCCUPANCY.labels(replica=self.name).set(0)
-                        return 0
-
-        # the serving step counter: chaos kills aim at decode step N
-        self.decode_iterations += 1
-        fault_inject.maybe_inject(self.decode_iterations)
-        t_decode0 = time.monotonic()
-        ids, max_abs = self.engine.decode(slots, tokens, positions)
-        with tracing.span("serve.retire") as retire:
-            # no short-circuit: the guard's EWMA/skip-budget state must
-            # see EVERY slot's observation, not a prefix that stops at
-            # the first failing slot
-            verdicts = [self._guard_ok(m) for m in max_abs]
-            if not all(verdicts):
-                self._quarantine("non-finite decode logits")
-                return 0
-            by_slot = {a.slot: a for a in self.batcher.active()}
-            for slot, token in zip(slots, ids):
-                active = by_slot[slot]
-                active.generated.append(token)
+        step = None
+        if rows:
+            # the serving step counter: chaos kills aim at decode step N
+            self.decode_iterations += 1
+            fault_inject.maybe_inject(self.decode_iterations)
+            dispatched_s = time.monotonic()
+            step = _DecodeStep(self._enqueue_decode(rows), rows,
+                               dispatched_s)
+            for active in rows:      # counted at dispatch: by length alone
+                active.enqueued += 1
                 active.position += 1
-            occupancy = len(slots)
-            self.occupancy_sum += occupancy
-            if self.paged:
-                self.page_used_sum += self.engine.pool.used_count()
-            _TOKENS.labels(kind="decode").inc(occupancy)
-            _OCCUPANCY.labels(replica=self.name).set(occupancy)
-            _OCCUPANCY_HIST.observe(occupancy)
             self.batcher.note_step()
-            now = time.monotonic()
-            # goodput ledger: one decoded token per occupied slot is the
-            # serve plane's productive unit; the step wall also refreshes
-            # the EWMA per-token cost that prices preempted work
-            goodput.record_serve_step(now - t_decode0, tokens=occupancy)
-            finished = 0
-            for done in self.batcher.retire_done():
-                if self.paged:
-                    self.engine.release_slot(done.slot)
-                self._finish(done, now)
-                finished += 1
-            retire.set(n=finished)
-        return occupancy
+            self._retire()
+        # only now read: the device has this pass's programs queued while
+        # the host is blocked on, and then works through, the ones before
+        previous, self._ahead = self._ahead, step
+        with tracing.span("serve.retire") as retire:
+            ok = (previous is None or self._collect_decode(previous)) \
+                and self._collect_first_tokens()
+            if ok and step is not None and step.pending.on_host:
+                self._ahead = None
+                ok = self._collect_decode(step)
+            if not ok:
+                return 0
+            retire.set(n=self._deliver(time.monotonic()))
+        return len(rows)
 
     # -- introspection -----------------------------------------------------
     def stats(self) -> dict:
@@ -584,6 +745,11 @@ class Replica:
                "waiting": self.batcher.waiting(),
                "decode_steps": self.engine.decode_steps,
                "avg_occupancy": round(self.occupancy_sum / steps, 3),
+               # decode steps whose successor was enqueued before they
+               # were collected, over decode steps: ~1.0 in a steady
+               # loop, 0 where the engine's calls block
+               "lookahead_share": round(
+                   getattr(self.engine, "decodes_ahead", 0) / steps, 3),
                # memory plane: resident KV bytes + the slot-occupancy-
                # weighted share of the cache that did useful work
                "kv_cache_bytes": self.engine.cache_bytes(),

@@ -57,3 +57,15 @@ def hvd_flat():
     hvd_mod.init(mesh_shape=(1, 8))
     yield hvd_mod
     hvd_mod.shutdown()
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """A fresh, enabled span ring behind ``horovod_tpu.tracing``'s
+    module-level entry points."""
+    from horovod_tpu import tracing
+
+    monkeypatch.delenv("HOROVOD_TRACE", raising=False)
+    fresh = tracing.Tracer()
+    monkeypatch.setattr(tracing, "_tracer", fresh)
+    return fresh

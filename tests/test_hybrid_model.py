@@ -274,8 +274,8 @@ def test_cache_kinds_and_the_sparse_attribute(served):
     assert engine.cache_bytes() == sum(by_kind.values())
     for path, leaf in jax.tree_util.tree_leaves_with_path(engine._cache):
         assert leaf.shape[0] == 2, path         # the slot is axis 0
-    engine.prefill(0, tokens(41).tolist())
-    engine.prefill(1, tokens(141).tolist())
+    engine.prefill(0, tokens(41).tolist()).collect()
+    engine.prefill(1, tokens(141).tolist()).collect()
     flags = {s["prompt_len"]: s["sparse"] for s in tracing.spans()
              if s["name"] == "engine.prefill"
              and s["prompt_len"] in (41, 141)}

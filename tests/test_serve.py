@@ -66,7 +66,7 @@ class TestBatcherPolicy:
         assert b.admission_due(9.0)
         assert b.admit(9.0) == []
         # a retired request frees budget; the head then admits
-        b.active()[0].generated.extend([1, 2, 3, 4])
+        b.active()[0].enqueued = 4
         assert [a.request.uid for a in b.retire_done()] == ["a"]
         assert [a.request.uid for a in b.admit(9.0)] == ["c"]
 
@@ -116,8 +116,9 @@ class TestBatcherPolicy:
         assert fits.max_tokens == 10 and not fits.capped
         # the budget charges the EFFECTIVE commitment, not the asked-for
         assert b.committed_tokens() == (12 + 5) + (4 + 10)
-        capped.generated.extend([1] * 5)
-        assert capped.done                   # done at the cap
+        capped.enqueued = 5
+        assert capped.done                   # done at the cap, by count:
+        assert not capped.generated          # no value has to be here
 
     def test_slots_cap(self):
         b = self._batcher(slots=2)
@@ -132,16 +133,16 @@ class TestBatcherPolicy:
         b.offer(_req("b", prompt_len=5, max_new=9), now=0.0)
         b.offer(_req("c"), now=0.0)
         b.admit(0.0)
-        # right after prefill-less admit: last prompt token, position
-        # = prompt_len (where the next token writes)
-        slots, tokens, positions = b.batch_rows()
-        assert slots == [0, 1] and tokens == [3, 5] and positions == [3, 5]
-        a = b.active()[0]
-        a.generated.extend([7, 8])
+        # right after admission: by slot, position = prompt_len (where
+        # the next token writes)
+        rows = b.batch_rows()
+        assert [(r.slot, r.position) for r in rows] == [(0, 3), (1, 5)]
+        a = rows[0]
+        a.enqueued += 2              # counted at dispatch: no value yet
         a.position += 2
+        assert b.batch_rows() == rows[1:]    # done rows are left out
         assert [d.request.uid for d in b.retire_done()] == ["a"]
-        slots, tokens, positions = b.batch_rows()
-        assert slots == [1] and tokens == [5]
+        assert [r.slot for r in b.batch_rows()] == [1]
         assert [r.uid for r in b.evict_all()] == ["b"]
         assert [r.uid for r in b.drain_waiting()] == ["c"]
         assert b.occupancy() == 0 and b.waiting() == 0
@@ -331,7 +332,10 @@ def _rows_at_different_positions(eng):
     prompts = {0: [3, 1, 4], 1: list(range(1, 18)), 2: [7] * 9}
     if eng.max_seq > 128:
         prompts[1] = [(5 * i) % 60 + 1 for i in range(131)]
-    gen = {s: [eng.prefill(s, p)[0]] for s, p in prompts.items()}
+    gen = {}
+    for s, p in prompts.items():
+        token, _ = eng.prefill(s, p)
+        gen[s] = [token]
     for step in range(4):
         slots = sorted(prompts)
         ids, _ = eng.decode(slots, [gen[s][-1] for s in slots],
@@ -372,7 +376,8 @@ def _reuse_after_longer_occupant(eng):
     long_prompt = list(range(1, 31))
     _generate(eng, 1, long_prompt, 8)
     beside = [2, 4, 6, 8, 10]
-    other = [0, eng.prefill(0, beside)[0], len(beside)]
+    token, _ = eng.prefill(0, beside)
+    other = [0, token, len(beside)]
     short = [9, 8, 7, 6]
     return [(short, _generate(eng, 1, short, 6, others=[other]))]
 
@@ -480,9 +485,13 @@ def test_zero_steady_state_compiles(tiny_lm):
         eng.prefill(step % 2, [7, 8, 9])
         eng.decode([0, 1], [1, 2], [4, 5])
     assert eng.compiles_total() == warm
-    assert eng.prefill(0, list(range(1, 20)))  # new bucket DOES compile
+    eng.prefill(0, list(range(1, 20)))         # new bucket DOES compile
     assert eng.compiles_total() == warm + 1
-    assert eng.stats()["decode_steps"] == 6
+    # enqueued six steps, none collected yet: a step counts when it is read
+    assert eng.decodes_enqueued == 6 and eng.stats()["decode_steps"] == 0
+    last = eng.decode([0], [1], [5])
+    assert last.collect() is last.collect()   # read once, kept
+    assert eng.stats()["decode_steps"] == 1
 
 
 def test_noncausal_model_rejected(tiny_lm):
@@ -626,6 +635,246 @@ def test_healthy_replica_completes():
     done = q.result(uid, timeout=1.0)
     assert done.tokens == [1, 2, 2] and done.rank == 0
     assert not rep.quarantined and rep.completed == 1
+
+
+# ------------------------------------------- the loop, one step ahead
+
+def _ahead_replica(engine, queue, max_new_tokens=64):
+    """A replica that checks admission in every pass (so a slot freed in
+    one pass is prefilled in the next), over a real engine."""
+    from horovod_tpu.serve.api import ServePolicy
+    from horovod_tpu.serve.replica import Replica, _LocalTransport
+
+    return Replica(engine, _LocalTransport(queue, 0),
+                   ServePolicy(slots=engine.num_slots, admission_ms=0.0,
+                               decode_block=1,
+                               max_new_tokens=max_new_tokens), rank=0)
+
+
+def _blocking_greedy(eng, slot, prompt, n):
+    """``n`` tokens through the engine's blocking calls, each step fed
+    from the host."""
+    token, _ = eng.prefill(slot, prompt)
+    out = [token]
+    while len(out) < n:
+        (token,), _ = eng.decode([slot], [out[-1]], [len(prompt) + len(out) - 1])
+        out.append(token)
+    return out
+
+
+def _toy(kind, request):
+    if kind == "transformer":
+        return request.getfixturevalue("tiny_lm")
+    from test_hybrid_model import ALL, build_model, weights
+
+    cfg, params = weights(ALL)
+    return build_model(cfg), params
+
+
+@pytest.mark.parametrize("kind", ["transformer", "hybrid"])
+def test_loop_ahead_serves_what_the_blocking_calls_produce(kind, request):
+    """Admissions, retirements by count, a one-token request, and slots
+    prefilled in the pass after the one that freed them: every request's
+    tokens are what ``prefill()`` / ``decode()`` give it alone, fed from
+    the host at every step."""
+    import numpy as np
+
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = _toy(kind, request)
+    rng = np.random.default_rng(3)
+    sizes = [(5, 3), (17, 6), (9, 1), (3, 4), (20, 2), (12, 5)]
+    prompts = [rng.integers(1, 60, n).tolist() for n, _ in sizes]
+    eng = DecodeEngine(model, params, num_slots=2)
+    q = RequestQueue()
+    uids = [q.submit(p, max_new_tokens=new)
+            for p, (_, new) in zip(prompts, sizes)]
+    rep = _ahead_replica(eng, q)
+
+    events = []                     # (pass, "prefill" | "decode", slots)
+    passes = [0]
+    prefill, decode = eng.prefill, eng.decode
+
+    def prefill_logged(slot, prompt):
+        events.append((passes[0], "prefill", (slot,)))
+        return prefill(slot, prompt)
+
+    def decode_logged(slots, tokens, positions):
+        assert tokens is None          # the loop never feeds from the host
+        events.append((passes[0], "decode", tuple(slots)))
+        return decode(slots, tokens, positions)
+
+    eng.prefill, eng.decode = prefill_logged, decode_logged
+    while rep.completed < len(uids) and passes[0] < 40:
+        passes[0] += 1
+        rep._iterate()
+    assert rep.completed == len(uids) and not rep.quarantined
+
+    fresh = DecodeEngine(model, params, num_slots=2)
+    for uid, prompt, (_, new) in zip(uids, prompts, sizes):
+        done = q.result(uid, timeout=1.0)
+        assert done.finish == "length" and len(done.tokens) == new
+        assert done.tokens == _blocking_greedy(fresh, 1, prompt, new), uid
+    # a slot that a decode step of pass p still wrote is prefilled in
+    # pass p + 1, before that step's ids were read
+    decoded = {p: slots for p, what, slots in events if what == "decode"}
+    reused = [(p, slot) for p, what, (slot, *_) in events
+              if what == "prefill" and slot in decoded.get(p - 1, ())]
+    assert len(reused) >= 2, events
+    stats = rep.stats()
+    assert stats["decode_steps"] == len(decoded)
+    assert 0.5 < stats["lookahead_share"] < 1.0
+    assert stats["engine"]["cache_donated"]
+
+
+def test_steady_loop_enqueues_step_k_before_it_reads_step_k_minus_1(
+        ring, tiny_lm):
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiny_lm
+    eng = DecodeEngine(model, params, num_slots=2)
+    q = RequestQueue()
+    uids = [q.submit([3, 1, 4], max_new_tokens=40),
+            q.submit([1, 5, 9, 2], max_new_tokens=40)]
+    rep = _ahead_replica(eng, q)
+    for _ in range(45):
+        rep._iterate()
+    assert [len(q.result(u, timeout=1.0).tokens) for u in uids] == [40, 40]
+    spans = ring.spans()
+    dispatches = [s for s in spans if s["name"] == "engine.decode.dispatch"]
+    waits = [s for s in spans if s["name"] == "engine.decode.wait"]
+    steps = {("serve.step", s["sid"]): s for s in spans
+             if s["name"] == "serve.step"}
+    assert len(dispatches) == len(waits) == 39
+    for k in range(1, 39):
+        # inside one serve.step: step k goes out, then step k-1 comes in
+        sent, read = dispatches[k], waits[k - 1]
+        assert sent["t"] + sent["dur"] <= read["t"]
+        assert sent["parent"] in steps
+        retire = next(s for s in spans if s["name"] == "serve.retire"
+                      and ("serve.retire", s["sid"]) == read["parent"])
+        assert retire["parent"] == sent["parent"]
+        assert read["ahead"] == 1
+    assert waits[-1]["ahead"] == 0        # nothing left to enqueue
+    assert rep.stats()["lookahead_share"] >= 0.95
+    assert rep.stats()["avg_occupancy"] == 2.0
+
+
+class _Poisoned:
+    """A pending decode step whose guard values read non-finite."""
+
+    on_host = False
+
+    def __init__(self, pending):
+        self._pending = pending
+
+    def collect(self):
+        ids, max_abs = self._pending.collect()
+        return ids, [float("inf")] * len(max_abs)
+
+
+def test_guard_one_step_late_delivers_nothing_of_the_bad_step(tiny_lm):
+    """A non-finite logit in decode step 3 is seen after step 4 is
+    enqueued: step 4 is never read, the request whose last token step 3
+    made is not delivered, and everything the replica holds goes back to
+    the queue once. What was complete before step 3 is delivered."""
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiny_lm
+    eng = DecodeEngine(model, params, num_slots=3)
+    q = RequestQueue()
+    prompts = [[5, 4, 3], [2, 7, 1, 8], [6, 6], [9, 1], [4, 2, 4], [7]]
+    news = [3, 4, 10, 10, 10, 10]   # the first ends in step 2, the second in 3
+    uids = [q.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    rep = _ahead_replica(eng, q)
+
+    collected = []
+    decode = eng.decode
+
+    class _Counted:
+        on_host = False
+
+        def __init__(self, pending, number):
+            self._pending, self._number = pending, number
+
+        def collect(self):
+            collected.append(self._number)
+            return self._pending.collect()
+
+    def poisoning(slots, tokens, positions):
+        number = eng.decodes_enqueued + 1
+        pending = decode(slots, tokens, positions)
+        return _Counted(_Poisoned(pending) if number == 3 else pending,
+                        number)
+
+    eng.decode = poisoning
+    for _ in range(3):
+        rep._iterate()
+    assert not rep.quarantined and rep.completed == 1
+    held = {a.request.uid: a for a in rep.batcher.active() + rep._unread}
+    assert set(held) == set(uids[1:4]) and rep.batcher.waiting() == 1
+    rep._iterate()                               # enqueues 4, reads 3
+    assert rep.quarantined and rep.completed == 1
+    assert eng.decodes_enqueued == 4 and collected == [1, 2, 3]
+    assert rep._ahead is None and not rep._unread and not rep._first_tokens
+    first = q.result(uids[0], timeout=1.0)
+    assert first.tokens == _uncached_greedy(model, params, prompts[0], 3)
+    for uid in uids[1:]:
+        with pytest.raises(TimeoutError):
+            q.result(uid, timeout=0)
+    # nothing of step 3 or later was appended to any request
+    assert len(held[uids[1]].generated) == 3     # prefill + steps 1, 2
+    assert len(held[uids[2]].generated) == 3
+    assert q.depth() == 5 and q.stats()["requeued"] == 5
+    assert [r.requeues for r in q.pull(1, 8)] == [1] * 5
+    # the engine is whole: its cache is the last program's result
+    del eng.decode
+    assert _blocking_greedy(eng, 0, prompts[2], 5) == \
+        _uncached_greedy(model, params, prompts[2], 5)
+
+
+@pytest.mark.parametrize("how", ["stop", "workers_down"])
+def test_teardown_with_a_step_in_flight(tiny_lm, how):
+    """``stop()`` and a ``WorkersDownError`` find a decode step enqueued
+    and unread: it is dropped, the engine stays usable, and the elastic
+    path requeues everything once (``stop()`` requeues nothing, as
+    ever)."""
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiny_lm
+    eng = DecodeEngine(model, params, num_slots=2)
+    q = RequestQueue()
+    prompts = [[3, 1, 4, 1], [5, 9], [2, 6, 5]]
+    for p in prompts:
+        q.submit(p, max_new_tokens=30)
+    rep = _ahead_replica(eng, q)
+    decode = eng.decode
+
+    def interrupted(slots, tokens, positions):
+        if eng.decodes_enqueued == 3:
+            assert rep._ahead is not None        # step 3 is in flight
+            if how == "stop":
+                rep.stop()
+            else:
+                raise WorkersDownError("reform")
+        return decode(slots, tokens, positions)
+
+    eng.decode = interrupted
+    if how == "stop":
+        rep.run()
+        assert eng.decodes_enqueued == 4 and eng.decode_steps == 3
+        assert q.stats()["requeued"] == 0 and rep.completed == 0
+    else:
+        with pytest.raises(WorkersDownError):
+            rep.run()
+        assert eng.decodes_enqueued == 3 and eng.decode_steps == 2
+        assert q.depth() == 3 and q.stats()["requeued"] == 3
+        assert rep.batcher.occupancy() == 0 and rep.batcher.waiting() == 0
+    assert not rep.quarantined
+    assert rep._ahead is None and not rep._first_tokens
+    del eng.decode
+    assert _blocking_greedy(eng, 1, prompts[0], 6) == \
+        _uncached_greedy(model, params, prompts[0], 6)
 
 
 # ----------------------------------------------------------- policy / api

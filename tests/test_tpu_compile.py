@@ -193,13 +193,25 @@ def test_fused_adamw_leaf_kernel(chip):
                        ((6,), F32)) == 1
 
 
+def _feed_is_aliased(text, params, cache):
+    """The compiled module's header aliases the token feed's parameter
+    (the first after the parameters' and the cache's leaves) to the
+    result after the cache's leaves."""
+    n_params = len(jax.tree.leaves(params))
+    n_cache = len(jax.tree.leaves(cache))
+    header = text.split("\n", 1)[0]
+    assert "input_output_alias" in header
+    return f"{{{n_cache}}}: ({n_params + n_cache}, {{}}, may-alias)" in header
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
     """GPT-2-small's widths at the serving cell's size (128 slots x 1024
     positions; two layers keep it to seconds): the program's result
-    aliases the whole donated cache, and nothing in it copies a cache
-    leaf or loops over one (XLA:TPU turns a batched scatter into one
-    serial trip per row; an undonated cache is copied whole, per leaf)."""
+    aliases the whole donated cache and the token feed, and nothing in
+    it copies a cache leaf or loops over one (XLA:TPU turns a batched
+    scatter into one serial trip per row; an undonated cache is copied
+    whole, per leaf)."""
     from horovod_tpu.models.transformer import Transformer
     from horovod_tpu.serve.kv_cache import DecodeEngine
 
@@ -224,11 +236,11 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
     if program == "decode":
-        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots, 1),
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
                                        i32(slots))
     else:
-        lowered = eng._prefill_fn(256).lower(params, eng._cache, i32(1, 256),
-                                             i32(), i32())
+        lowered = eng._prefill_fn(256).lower(params, eng._cache, i32(slots),
+                                             i32(1, 256), i32(), i32())
     compiled = lowered.compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
 
@@ -240,7 +252,9 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
     assert not loops and not copies, (loops, copies)
     param_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(params))
-    assert memory.alias_size_in_bytes == eng.cache_bytes()
+    feed_bytes = 4 * slots
+    assert memory.alias_size_in_bytes == eng.cache_bytes() + feed_bytes
+    assert _feed_is_aliased(text, params, eng._cache)
     # parameters (their small vectors tile-padded: a percent's slack) and
     # one cache, whose leaves no layout pads
     assert memory.argument_size_in_bytes <= (1.01 * param_bytes
@@ -260,7 +274,7 @@ def test_hybrid_engine_fits_and_updates_its_cache_in_place(chip, monkeypatch,
     of the largest bucket compile for one v5e chip, arguments plus
     temporaries stay under its 16 GB, the result aliases every leaf of
     the donated cache - keys/values, compressed keys and the float32
-    state alike - and nothing copies a cache leaf."""
+    state alike - and the token feed, and nothing copies a cache leaf."""
     import json
 
     from benchmark.runners.serve_sala import build_model
@@ -291,14 +305,15 @@ def test_hybrid_engine_fits_and_updates_its_cache_in_place(chip, monkeypatch,
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
     if program == "decode":
-        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots, 1),
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
                                        i32(slots))
     else:
-        lowered = eng._prefill_fn(seq).lower(params, eng._cache, i32(1, seq),
-                                             i32(), i32())
+        lowered = eng._prefill_fn(seq).lower(params, eng._cache, i32(slots),
+                                             i32(1, seq), i32(), i32())
     compiled = lowered.compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
-    assert memory.alias_size_in_bytes == eng.cache_bytes()
+    assert 0 < memory.alias_size_in_bytes - eng.cache_bytes() <= 4096
+    assert _feed_is_aliased(text, params, eng._cache)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 16e9)
     leaves = (rf"bf16\[{slots},2,128,{seq}\]", rf"bf16\[{slots},2,128,2048\]",

@@ -383,15 +383,6 @@ def test_rejected_request_is_an_availability_bad_event(monkeypatch):
 
 # ------------------------------------------------------------ layer spans
 
-@pytest.fixture
-def ring(monkeypatch):
-    """A fresh, enabled tracer behind the module-level entry points."""
-    monkeypatch.delenv(HOROVOD_TRACE, raising=False)
-    fresh = tracing.Tracer()
-    monkeypatch.setattr(tracing, "_tracer", fresh)
-    return fresh
-
-
 def _by_name(spans):
     out = {}
     for s in spans:
@@ -542,7 +533,10 @@ def tiny_lm():
 def test_replica_loop_and_engine_spans(ring, tiny_lm, paged):
     """The serving loop through a toy engine: one serve.step per pass
     with its three children, the engine's spans with their dispatch and
-    wait parts, and exactly one request.decode per finished request."""
+    wait parts, and exactly one request.decode per finished request.
+    The dense engine's calls are enqueued in one place and collected in
+    another, one pass later for a decode step; the paged engine's block,
+    and its spans nest as they always did."""
     from test_serve import _replica
 
     model, params = tiny_lm
@@ -562,10 +556,15 @@ def test_replica_loop_and_engine_spans(ring, tiny_lm, paged):
     for _ in range(8):
         rep._iterate()
     done = [q.result(u, timeout=1.0) for u in uids]
-    by = _by_name(ring.spans())
+    spans = ring.spans()
+    by = _by_name(spans)
+
+    def kids(key):
+        return [s["name"] for s in spans if s.get("parent") == key]
 
     # three passes decode (the longer request has three steps after its
     # prefill); of the five idle passes after them only the first is kept
+    # (the dense loop reads its last step's ids in it)
     steps = by["serve.step"]
     assert len(steps) == 4 and len(by["serve.pull"]) == 4
     busy = [s for s in steps if s["decoded"] > 0]
@@ -579,28 +578,58 @@ def test_replica_loop_and_engine_spans(ring, tiny_lm, paged):
     assert by["serve.pull"][0]["n"] == 2
     assert by["serve.admit"][0]["parent"] == first
     assert by["serve.admit"][0]["n"] == 2
-    assert len(by["serve.retire"]) == len(busy)
-    assert sum(s["n"] for s in by["serve.retire"]) == 2
+    retires = by["serve.retire"]
+    assert [r["parent"] for r in retires] == [
+        ("serve.step", s["sid"]) for s in (busy if paged else steps)]
+    assert sum(s["n"] for s in retires) == 2
 
     # the engine's spans, children of the loop's
     admit = ("serve.admit", by["serve.admit"][0]["sid"])
     assert len(by["request.prefill"]) == 2
-    assert all(s["parent"] == admit for s in by["request.prefill"])
     assert len(by["engine.prefill"]) == 2
-    for pre in by["engine.prefill"]:
-        assert pre["parent"][0] == "request.prefill"
-        assert pre["bucket"] == 16 and pre["prompt_len"] in (2, 3)
-        key = ("engine.prefill", pre["sid"])
-        kids = [s["name"] for s in ring.spans() if s.get("parent") == key]
-        assert kids == ["engine.prefill.dispatch", "engine.prefill.wait"]
     assert len(by["engine.decode"]) == len(busy)
-    for dec, step in zip(by["engine.decode"], busy):
-        assert dec["parent"] == ("serve.step", step["sid"])
-        assert dec["rows"] == step["decoded"]
-        key = ("engine.decode", dec["sid"])
-        kids = [s["name"] for s in ring.spans() if s.get("parent") == key]
-        assert kids == ["engine.decode.prep", "engine.decode.dispatch",
-                        "engine.decode.wait"]
+    assert [d["rows"] for d in by["engine.decode"]] == \
+        [s["decoded"] for s in busy]
+    for pre in by["engine.prefill"]:
+        assert pre["bucket"] == 16 and pre["prompt_len"] in (2, 3)
+    if paged:
+        assert all(s["parent"] == admit for s in by["request.prefill"])
+        for pre in by["engine.prefill"]:
+            assert pre["parent"] == admit
+            assert kids(("engine.prefill", pre["sid"])) == [
+                "engine.prefill.dispatch", "engine.prefill.wait"]
+        for dec, step in zip(by["engine.decode"], busy):
+            assert dec["parent"] == ("serve.step", step["sid"])
+            assert kids(("engine.decode", dec["sid"])) == [
+                "engine.decode.prep", "engine.decode.dispatch",
+                "engine.decode.wait"]
+    else:
+        # enqueued under serve.admit / serve.step, collected under the
+        # pass's serve.retire; engine.prefill and engine.decode are
+        # written after the fact, dispatch to result on the host
+        collect = ("serve.retire", retires[0]["sid"])
+        assert kids(admit).count("engine.prefill.dispatch") == 2
+        assert kids(collect)[:6] == [
+            "engine.prefill.wait", "engine.prefill", "request.prefill"] * 2
+        for pre, part in zip(by["engine.prefill"],
+                             by["engine.prefill.dispatch"]):
+            assert pre["t"] <= part["t"] and pre["dur"] >= part["dur"]
+        for step, prep, part in zip(busy, by["engine.decode.prep"],
+                                    by["engine.decode.dispatch"]):
+            assert prep["parent"] == part["parent"] == \
+                ("serve.step", step["sid"])
+        # step k's ids are read in pass k + 1, after step k + 1 went out
+        waits = by["engine.decode.wait"]
+        assert [w["parent"] for w in waits] == [
+            ("serve.retire", r["sid"]) for r in retires[1:]]
+        assert [w["ahead"] for w in waits] == [1, 1, 0]
+        for wait, part in zip(waits, by["engine.decode.dispatch"][1:]):
+            assert part["t"] + part["dur"] <= wait["t"]
+        for dec, part, wait in zip(by["engine.decode"],
+                                   by["engine.decode.prep"], waits):
+            assert dec["parent"] == wait["parent"]
+            assert dec["t"] <= part["t"]
+            assert dec["t"] + dec["dur"] >= wait["t"] + wait["dur"]
 
     # request spans: one decode span per request, none per block
     assert "request.decode_block" not in by
