@@ -9,8 +9,8 @@ exchanges tf.IndexedSlices (reference: horovod/tensorflow/__init__.py:
 64-75; examples/pytorch_synthetic_benchmark.py is the harness shape).
 
 Torch executes on CPU in this stack (the TPU compute path is JAX — for
-the chip-rate BERT-Large headline run ``python bench.py --model
-bert-large``); this example demonstrates config #5's *exchange
+the chip rate of BERT-Large see the ``bertl-train-c1`` cell of
+``benchmark/run.py``); this example demonstrates config #5's *exchange
 semantics* end-to-end under the launcher:
 
     tpurun -np 2 python examples/pytorch_bert_large_sparse.py \
@@ -98,7 +98,7 @@ def main():
         assert torch.equal(digest[0], digest[r]), "ranks diverged"
 
     print(f"rank {hvd.rank()}: {tokens_done / dt:.1f} tokens/s "
-          f"(torch CPU; chip headline: bench.py --model bert-large) — "
+          f"(torch CPU; chip rate: benchmark/run.py bertl-train-c1) — "
           f"lockstep OK", flush=True)
 
 

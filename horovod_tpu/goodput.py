@@ -51,9 +51,7 @@ Surfaces (mirroring the established planes end-to-end):
 merged Perfetto trace (profiler.merge_profile_dir); a goodput/incident
 panel in tools/hvd_top.py; :func:`format_goodput_report` — the
 cross-rank postmortem section naming fleet goodput %, the dominant
-badput category, and the costliest incident (``tpurun --postmortem``);
-and a ``goodput_fraction`` headline in bench.py rows gated
-higher-is-better by bench_compare.py.
+badput category, and the costliest incident (``tpurun --postmortem``).
 
 Env knobs (registered in utils/env.py, table in docs/goodput.md):
 ``HOROVOD_GOODPUT`` (accounting on/off, default on),

@@ -47,7 +47,7 @@ class ConvBN(nn.Module):
 class SpaceToDepthStem(nn.Module):
     """Inception's 3x3/2 VALID stem conv on (299,299,3), reparametrized
     for the MXU like ResNet's (models/resnet.py SpaceToDepthConvInit,
-    tools/conv0_s2d.py): pad the 299 image one row/col at the END to
+    docs/perf_experiments.md): pad the 299 image one row/col at the END to
     300, 2x2 space-to-depth to (150,150,12), and fold the 3x3 stride-2
     kernel into a 2x2 stride-1 kernel over 12 channels — output is the
     identical 149x149x32 (the folded tap that would read the padded

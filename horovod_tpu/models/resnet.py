@@ -86,7 +86,7 @@ class SpaceToDepthConvInit(nn.Module):
     kernel into a 4x4 stride-1 kernel over 12 channels with asymmetric
     [(2,1),(2,1)] padding — identical output, 4x the contraction depth
     per MXU pass (the classic TPU MLPerf ResNet transform; measured
-    1.43x on this layer, tools/conv0_s2d.py). The parameter KEEPS the
+    1.43x on this layer, docs/perf_experiments.md). The parameter KEEPS the
     canonical (7,7,3,filters) shape — checkpoints interchange freely
     with the direct path — and the fold is a tiny reshape per step."""
 

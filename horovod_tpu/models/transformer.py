@@ -121,7 +121,6 @@ class SelfAttention(nn.Module):
     causal: bool = False
     dtype: Dtype = jnp.bfloat16
     attention_fn: Optional[Callable] = None
-    fused_qkv: bool = False
     decode: bool = False
     max_cache_len: int = 0
     paged: bool = False
@@ -140,18 +139,9 @@ class SelfAttention(nn.Module):
                         param_dtype=jnp.float32)
 
         qkv_shape = (self.num_heads, head_dim)
-        if self.fused_qkv:
-            # one (d_model, 3*d_model) matmul instead of three separate
-            # (d_model, d_model) ones: reads the activations from HBM
-            # once and gives XLA a single taller MXU tile. Changes the
-            # checkpoint layout (param "qkv" replaces query/key/value),
-            # so it is opt-in.
-            qkv = dense(features=(3,) + qkv_shape, name="qkv")(x)
-            q, k, v = (qkv[..., i, :, :] for i in range(3))
-        else:
-            q = dense(features=qkv_shape, name="query")(x)
-            k = dense(features=qkv_shape, name="key")(x)
-            v = dense(features=qkv_shape, name="value")(x)
+        q = dense(features=qkv_shape, name="query")(x)
+        k = dense(features=qkv_shape, name="key")(x)
+        v = dense(features=qkv_shape, name="value")(x)
 
         if self.decode and self.paged:
             if positions is None:
@@ -268,7 +258,6 @@ class TransformerLayer(nn.Module):
     causal: bool = False
     dtype: Dtype = jnp.bfloat16
     attention_fn: Optional[Callable] = None
-    fused_qkv: bool = False
     decode: bool = False
     max_cache_len: int = 0
     paged: bool = False
@@ -280,8 +269,8 @@ class TransformerLayer(nn.Module):
         ln = partial(nn.LayerNorm, dtype=self.dtype, param_dtype=jnp.float32)
         x = x + SelfAttention(
             num_heads=self.num_heads, causal=self.causal, dtype=self.dtype,
-            attention_fn=self.attention_fn, fused_qkv=self.fused_qkv,
-            decode=self.decode, max_cache_len=self.max_cache_len,
+            attention_fn=self.attention_fn, decode=self.decode,
+            max_cache_len=self.max_cache_len,
             paged=self.paged, num_pages=self.num_pages,
             page_tokens=self.page_tokens,
             name="attention")(ln()(x), positions=positions,
@@ -309,7 +298,6 @@ class Transformer(nn.Module):
     dtype: Dtype = jnp.bfloat16
     remat: bool = False
     attention_fn: Optional[Callable] = None
-    fused_qkv: bool = False
     decode: bool = False
     paged: bool = False
     num_pages: int = 0
@@ -369,8 +357,7 @@ class Transformer(nn.Module):
                 x = TransformerLayer(
                     num_heads=self.num_heads, d_ff=self.d_ff,
                     causal=self.causal, dtype=self.dtype,
-                    attention_fn=self.attention_fn,
-                    fused_qkv=self.fused_qkv, decode=True,
+                    attention_fn=self.attention_fn, decode=True,
                     max_cache_len=self.max_seq, paged=self.paged,
                     num_pages=self.num_pages,
                     page_tokens=self.page_tokens,
@@ -409,7 +396,6 @@ class Transformer(nn.Module):
             x = layer(num_heads=self.num_heads, d_ff=self.d_ff,
                       causal=self.causal, dtype=self.dtype,
                       attention_fn=self.attention_fn,
-                      fused_qkv=self.fused_qkv,
                       name=f"layer_{i}")(x)
 
         x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
@@ -488,56 +474,6 @@ def causal_lm_loss(logits, token_ids):
     loss = optax.softmax_cross_entropy_with_integer_labels(
         logits[:, :-1], token_ids[:, 1:])
     return loss.mean()
-
-
-def causal_lm_loss_chunked(hidden, embed_matrix, token_ids,
-                           chunk: int = 128):
-    """Next-token cross-entropy computed seq-chunk at a time, vocab
-    projection applied INSIDE the chunk loop — the (batch, seq, vocab)
-    float32 logits tensor never exists (3.3 GB at GPT-2 bench shapes;
-    unlike MLM, causal LM needs every position's logits, but never all
-    at once).
-
-    MEASURED (docs/perf_experiments.md round 4): 5.8-8.1% SLOWER than
-    the full-logits path on the GPT-2 bench — the chunk scan trades one
-    large efficient (B·S, d)x(d, vocab) matmul for several smaller
-    ones, and XLA streams the big tensor better than the hand loop.
-    Kept for memory-constrained configurations (long seq x large vocab
-    where the logits tensor itself OOMs), NOT as a throughput move.
-
-    ``hidden``: (batch, seq, d) from ``model(..., output="hidden")``;
-    ``embed_matrix``: the tied (vocab, d) token embedding;
-    ``token_ids``: (batch, seq) int labels. Exactly equals
-    ``causal_lm_loss(model.apply(...), token_ids)`` up to f32 summation
-    order (tested). ``chunk`` must divide seq."""
-    b, s, d = hidden.shape
-    if s % chunk:
-        raise ValueError(f"chunk ({chunk}) must divide seq ({s})")
-    emb = embed_matrix.astype(hidden.dtype)
-    # predictions at positions [0, s-1) predict tokens [1, s); weight the
-    # final position 0 so the scan body is uniform across chunks
-    labels = jnp.concatenate(
-        [token_ids[:, 1:], jnp.zeros((b, 1), token_ids.dtype)], axis=1)
-    valid = jnp.concatenate(
-        [jnp.ones((b, s - 1), jnp.float32), jnp.zeros((b, 1), jnp.float32)],
-        axis=1)
-
-    h_c = hidden.reshape(b, s // chunk, chunk, d).transpose(1, 0, 2, 3)
-    lab_c = labels.reshape(b, s // chunk, chunk).transpose(1, 0, 2)
-    w_c = valid.reshape(b, s // chunk, chunk).transpose(1, 0, 2)
-
-    # remat the body: without it, scan's backward stores each chunk's
-    # softmax residuals — stacked, that is the full (batch, seq, vocab)
-    # tensor again and the memory benefit evaporates under value_and_grad
-    @jax.checkpoint
-    def body(acc, xs):
-        h, lab, w = xs
-        logits = (h @ emb.T).astype(jnp.float32)
-        loss = optax.softmax_cross_entropy_with_integer_labels(logits, lab)
-        return acc + jnp.sum(loss * w), None
-
-    total, _ = jax.lax.scan(body, jnp.float32(0.0), (h_c, lab_c, w_c))
-    return total / (b * (s - 1))
 
 
 def random_tokens(rng: np.random.Generator, batch: int, seq: int,

@@ -6,7 +6,6 @@ from horovod_tpu.ops.pallas.flash_attention import (
     flash_attention_partial,
     merge_partials,
 )
-from horovod_tpu.ops.pallas.fused_adamw import FusedAdamW, fused_adamw
 from horovod_tpu.ops.pallas.fused_optimizer import flat_adamw_shard
 from horovod_tpu.ops.pallas.conv_bn_act import (
     FusedBatchNormAct,
@@ -19,8 +18,6 @@ __all__ = [
     "flash_attention_partial",
     "merge_partials",
     "attention_reference",
-    "fused_adamw",
-    "FusedAdamW",
     "flat_adamw_shard",
     "FusedBatchNormAct",
     "bn_stats",

@@ -1,12 +1,12 @@
 """Fused batch-norm + activation epilogue for the conv models.
 
-Productization of the ``tools/pallas_conv_bn.py`` prototype: the
-Inception/ResNet decompositions (tools/*_decompose.py) show the conv
-stacks spend a measurable slice of every ConvBN in the *elementwise
-tail* — normalize, scale/shift, ReLU — which XLA emits as its own
-HBM-bound loop over the conv output. The prototype measured the win of
-folding that tail into one pass; this module ships the production
-half that composes with autodiff and checkpoints:
+The Inception/ResNet decompositions of the rounds before the ledger
+(docs/perf_experiments.md) show the conv stacks spend a measurable slice
+of every ConvBN in the *elementwise tail* — normalize, scale/shift, ReLU
+— which XLA emits as its own HBM-bound loop over the conv output. A
+prototype measured the win of folding that tail into one pass; this
+module ships the production half that composes with autodiff and
+checkpoints:
 
 * :func:`bn_stats` — one-pass per-channel mean/variance in f32 (sum and
   sum-of-squares in the same sweep, the prototype's epilogue contract).

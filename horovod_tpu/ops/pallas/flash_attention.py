@@ -36,7 +36,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.ops.pallas._backend import use_interpret
-from horovod_tpu.utils import env as env_mod
 
 NEG_INF = float("-inf")
 
@@ -48,28 +47,6 @@ LANES = 128
 # VPU): scores are pre-scaled by log2(e), the log-sum-exp converts back on
 # the way out.
 LOG2E = float(np.log2(np.e))
-
-
-def _mxu_bf16(*refs) -> bool:
-    """``FLASH_MXU_BF16=1``: feed the MXU dots bf16 operands (f32
-    accumulation) instead of up-casting everything to f32 first — the
-    standard TPU flash-kernel layout (softmax max/exp2/normalise stays f32
-    on the VPU; dot operands, including the probability/ds intermediates,
-    round to bf16). Measured on the BERT-Large bench shape (B8 H16 S512
-    D64): NO speedup — 24-layer fwd 7.79→8.01 ms, fwd+bwd 13.12→13.24 ms
-    (docs/perf_experiments.md round 4) — the kernel's cost at this shape is
-    VPU/softmax-bound, not MXU-rate-bound, so the default stays the f32
-    path (better p/ds precision for free). Kept as a measured-excluded
-    counter-move and for A/B on future shapes where the MXU term dominates
-    (longer head_dim, causal long-seq).
-
-    NOTE (r4 advisor): the env var is read at KERNEL TRACE time — step
-    functions already compiled under jax.jit keep the path they were
-    traced with (jit caches don't key on env). Toggle it before the
-    first call, or restart the process, for a clean A/B; the bench
-    scripts do this via fresh processes."""
-    return (env_mod._get_bool("FLASH_MXU_BF16", False)
-            and all(r.dtype == jnp.bfloat16 for r in refs))
 
 
 def _vma(*arrays) -> frozenset:
@@ -120,22 +97,12 @@ def _fwd_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def update(masked):
         # Scores and the running max are tracked in base 2 (pre-scaled by
         # LOG2E) so the inner loop uses exp2, which is cheaper on the VPU.
-        bf16 = _mxu_bf16(q_ref, k_ref, v_ref)
-        if bf16:
-            # bf16 operands straight from HBM; scale moves after the dot
-            # (algebraically identical — the accumulator is f32 either way)
-            q = q_ref[0, 0, :, :]
-            k = k_ref[0, 0, :, :]
-            v = v_ref[0, 0, :, :]
-        else:
-            q = q_ref[0, 0, :, :].astype(jnp.float32) * (sm_scale * LOG2E)
-            k = k_ref[0, 0, :, :].astype(jnp.float32)  # (bk, d)
-            v = v_ref[0, 0, :, :].astype(jnp.float32)
+        q = q_ref[0, 0, :, :].astype(jnp.float32) * (sm_scale * LOG2E)
+        k = k_ref[0, 0, :, :].astype(jnp.float32)  # (bk, d)
+        v = v_ref[0, 0, :, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)  # (bq, bk)
-        if bf16:
-            s = s * (sm_scale * LOG2E)
         if masked:
             q_ids = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -151,15 +118,7 @@ def _fwd_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         alpha = jnp.exp2(m_prev - m_safe)
         p = jnp.exp2(s - m_safe[:, None])
-        if bf16:
-            # the SAME bf16-rounded p feeds both the PV numerator and the
-            # l denominator (summed f32), so the softmax normalisation is
-            # exactly consistent (r4 advisor finding)
-            p = p.astype(jnp.bfloat16)
-            l_new = l_prev * alpha + jnp.sum(p.astype(jnp.float32),
-                                             axis=-1)
-        else:
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -202,7 +161,7 @@ def _fwd_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
     """Single-k-block forward: the whole key sequence is resident, so the
     softmax is direct — no m/l/acc scratch, no revolving online-softmax
     arithmetic, no @pl.when machinery. Measured r5 (B8 H16 S512 D64,
-    tools/flash_vpu_probe.py): 0.130 ms/call vs 0.321 ms for the general
+    docs/perf_experiments.md): 0.130 ms/call vs 0.321 ms for the general
     online-softmax kernel at the same shape — 2.5x — with the general
     kernel already 2.8x faster than the stock pallas flash kernel and
     1.3x faster than unfused XLA attention. The win is the removed
@@ -217,19 +176,11 @@ def _fwd_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
         # bk: static k extent — the causal wedge passes block_k//2 so
         # q blocks whose rows never see the upper half of the keys skip
         # half the dots and half the softmax arithmetic
-        bf16 = _mxu_bf16(q_ref, k_ref, v_ref)
-        if bf16:
-            q = q_ref[0, 0, :, :]
-            k = k_ref[0, 0, :bk, :]
-            v = v_ref[0, 0, :bk, :]
-        else:
-            q = q_ref[0, 0, :, :].astype(jnp.float32) * (sm_scale * LOG2E)
-            k = k_ref[0, 0, :bk, :].astype(jnp.float32)
-            v = v_ref[0, 0, :bk, :].astype(jnp.float32)
+        q = q_ref[0, 0, :, :].astype(jnp.float32) * (sm_scale * LOG2E)
+        k = k_ref[0, 0, :bk, :].astype(jnp.float32)
+        v = v_ref[0, 0, :bk, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        if bf16:
-            s = s * (sm_scale * LOG2E)
         if causal:
             q_ids = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, bk), 0)
@@ -240,13 +191,7 @@ def _fwd_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
         # fully-masked rows: m = -inf; shift by 0 so p is 0, not NaN
         m_safe = jnp.where(m == NEG_INF, 0.0, m)
         p = jnp.exp2(s - m_safe[:, None])
-        if bf16:
-            # same bf16-rounded p for numerator and denominator (r4
-            # advisor)
-            p = p.astype(jnp.bfloat16)
-            l = jnp.sum(p.astype(jnp.float32), axis=-1)
-        else:
-            l = jnp.sum(p, axis=-1)
+        l = jnp.sum(p, axis=-1)
         empty = l == 0.0
         l_safe = jnp.where(empty, 1.0, l)
         o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
@@ -405,9 +350,7 @@ def _bwd_dq_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
     def update(masked):
-        bf16 = _mxu_bf16(q_ref, k_ref, v_ref, do_ref)
-        cast = (lambda r: r[0, 0, :, :]) if bf16 else \
-            (lambda r: r[0, 0, :, :].astype(jnp.float32))
+        cast = lambda r: r[0, 0, :, :].astype(jnp.float32)
         q = cast(q_ref)
         do = cast(do_ref)
         lse = lse_ref[0, 0, :, 0]
@@ -432,8 +375,7 @@ def _bwd_dq_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * sm_scale
         dq_acc_ref[...] = dq_acc_ref[...] + jax.lax.dot_general(
-            ds.astype(jnp.bfloat16) if bf16 else ds, k,
-            (((1,), (0,)), ((), ())),
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if causal:
@@ -467,9 +409,7 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     def update(masked):
-        bf16 = _mxu_bf16(q_ref, k_ref, v_ref, do_ref)
-        cast = (lambda r: r[0, 0, :, :]) if bf16 else \
-            (lambda r: r[0, 0, :, :].astype(jnp.float32))
+        cast = lambda r: r[0, 0, :, :].astype(jnp.float32)
         k = cast(k_ref)
         v = cast(v_ref)
         q = cast(q_ref)
@@ -487,17 +427,15 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_ids >= k_ids, s, NEG_INF)
         p = jnp.exp2(s - lse_safe[:, None])
-        pcast = p.astype(jnp.bfloat16) if bf16 else p
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
-            pcast, do, (((0,), (0,)), ((), ())),
+            p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * sm_scale
         dk_acc_ref[...] = dk_acc_ref[...] + jax.lax.dot_general(
-            ds.astype(jnp.bfloat16) if bf16 else ds, q,
-            (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if causal:
@@ -530,9 +468,7 @@ def _bwd_dq_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
     last_q = q_start + block_q - 1
 
     def compute(bk):
-        bf16 = _mxu_bf16(q_ref, k_ref, v_ref, do_ref)
-        cast = (lambda r, n: r[0, 0, :n, :]) if bf16 else \
-            (lambda r, n: r[0, 0, :n, :].astype(jnp.float32))
+        cast = lambda r, n: r[0, 0, :n, :].astype(jnp.float32)
         q = cast(q_ref, block_q)
         do = cast(do_ref, block_q)
         k = cast(k_ref, bk)
@@ -555,8 +491,7 @@ def _bwd_dq_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * sm_scale
         dq_ref[0, 0, :, :] = jax.lax.dot_general(
-            ds.astype(jnp.bfloat16) if bf16 else ds, k,
-            (((1,), (0,)), ((), ())),
+            ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(dq_ref.dtype)
 
     if causal:
@@ -598,9 +533,7 @@ def _bwd_dkv_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
     last_q = q_start + block_q - 1
 
     def compute():
-        bf16 = _mxu_bf16(q_ref, k_ref, v_ref, do_ref)
-        cast = (lambda r: r[0, 0, :, :]) if bf16 else \
-            (lambda r: r[0, 0, :, :].astype(jnp.float32))
+        cast = lambda r: r[0, 0, :, :].astype(jnp.float32)
         q = cast(q_ref)
         k = cast(k_ref)
         v = cast(v_ref)
@@ -618,17 +551,15 @@ def _bwd_dkv_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_ids >= k_ids, s, NEG_INF)
         p = jnp.exp2(s - lse_safe[:, None])
-        pcast = p.astype(jnp.bfloat16) if bf16 else p
         dv_ref[0, 0, :, :] = jax.lax.dot_general(
-            pcast, do, (((0,), (0,)), ((), ())),
+            p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(dv_ref.dtype)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None]) * sm_scale
         dk_ref[0, 0, :, :] = jax.lax.dot_general(
-            ds.astype(jnp.bfloat16) if bf16 else ds, q,
-            (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(dk_ref.dtype)
 
     if causal:
@@ -648,60 +579,6 @@ def _bwd_dkv_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
         compute()
 
 
-def _bwd_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
-                       lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                       *, sm_scale, causal, block_q, block_k):
-    """Single-block fused backward: dq, dk AND dv from ONE kernel — s and
-    p computed once instead of once per output kernel.
-
-    MEASURED AND EXCLUDED (r5, tools/flash_vpu_probe.py): fwd+bwd
-    0.503 ms vs 0.408 for the two-kernel bwd at B8 H16 S512 D64, and
-    2.453 vs 1.828 at the GPT-2 shape — the fused kernel's strictly
-    sequential dot chain (dv needs p, ds needs dp, dq/dk need ds) with
-    three 1-4 MB live intermediates pipelines WORSE across grid steps
-    than two lean kernels that each recompute s. Kept behind
-    FLASH_FUSED_BWD=1 (trace-time env, default off) as the measured
-    counter-example."""
-    q_start = q_off_ref[0]
-    k_start = k_off_ref[0]
-    bf16 = _mxu_bf16(q_ref, k_ref, v_ref, do_ref)  # same A/B semantics
-    cast = (lambda r: r[0, 0, :, :]) if bf16 else \
-        (lambda r: r[0, 0, :, :].astype(jnp.float32))
-    q = cast(q_ref)
-    k = cast(k_ref)
-    v = cast(v_ref)
-    do = cast(do_ref)
-    lse = lse_ref[0, 0, :, 0]
-    delta = delta_ref[0, 0, :, 0]
-    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse) * LOG2E
-
-    s = (sm_scale * LOG2E) * jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if causal:
-        q_ids = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_ids = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-    p = jnp.exp2(s - lse_safe[:, None])
-    pcast = p.astype(jnp.bfloat16) if bf16 else p
-    dv_ref[0, 0, :, :] = jax.lax.dot_general(
-        pcast, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * sm_scale
-    dscast = ds.astype(jnp.bfloat16) if bf16 else ds
-    dq_ref[0, 0, :, :] = jax.lax.dot_general(
-        dscast, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_ref[0, 0, :, :] = jax.lax.dot_general(
-        dscast, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-
-
 def compute_delta(o, do) -> jax.Array:
     """The backward's per-row correction term, lane-broadcast: delta_i =
     sum_d do[i,d]·o[i,d], shape (B, H, S, LANES). Depends only on the final
@@ -718,14 +595,11 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
     block_q = _pick_block(q_seq, block_q)
     block_k = _pick_block(kv_seq, block_k)
     if (causal and kv_seq == block_k and block_q == q_seq
-            and q_seq >= 1024 and (q_seq // 2) % 128 == 0
-            and not env_mod._get_bool("FLASH_FUSED_BWD", False)):
+            and q_seq >= 1024 and (q_seq // 2) % 128 == 0):
         # single-k-block causal: two q blocks let the dq wedge skip the
         # first block's upper-half dots (measured r5 at the GPT-2
         # shape: fwd+bwd 1.697 -> 1.555 ms, incl. the dkv kernel
-        # falling back to the general path). Skipped under the
-        # FLASH_FUSED_BWD A/B so that flag still reaches its fused
-        # kernel at these shapes.
+        # falling back to the general path).
         block_q = q_seq // 2
 
     if delta is None:
@@ -734,36 +608,6 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
     q_spec, k_spec, qrow_spec = _make_specs(block_q, block_k, dim)
 
     vma = _vma(q, k, v, do, q_offset, k_offset)
-
-    if (q_seq == block_q and kv_seq == block_k
-            and env_mod._get_bool("FLASH_FUSED_BWD", False)):
-        # whole (q, k) extent resident: one fused kernel computes s and
-        # p once and writes dq, dk, dv together (see _bwd_single_kernel)
-        bh_q_spec = pl.BlockSpec((1, 1, block_q, dim),
-                                 lambda b, h: (b, h, 0, 0))
-        bh_k_spec = pl.BlockSpec((1, 1, block_k, dim),
-                                 lambda b, h: (b, h, 0, 0))
-        bh_row_spec = pl.BlockSpec((1, 1, block_q, LANES),
-                                   lambda b, h: (b, h, 0, 0))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_single_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k),
-            grid=(batch, heads),
-            in_specs=[_OFF_SPEC, _OFF_SPEC, bh_q_spec, bh_k_spec,
-                      bh_k_spec, bh_q_spec, bh_row_spec, bh_row_spec],
-            out_specs=[bh_q_spec, bh_k_spec, bh_k_spec],
-            out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
-                jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
-                jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
-            interpret=interpret,
-            name="flash_bwd",
-        )(q_offset, k_offset, q, k, v, do, lse, delta)
-        return dq, dk, dv
 
     if kv_seq == block_k:
         # scratch-free single-k-block dq (with causal wedge), any nq

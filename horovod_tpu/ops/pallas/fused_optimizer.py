@@ -1,13 +1,12 @@
 """Fused flat-buffer optimizer passes for ZeRO-1 shards.
 
-Companion to :mod:`horovod_tpu.ops.pallas.fused_adamw`, reshaped for the
-sharded data plane (:mod:`horovod_tpu.parallel.zero`): instead of one
-kernel per parameter leaf, ONE kernel runs over the whole flat fp32
-master/moment shard of a dtype group. That removes the per-leaf launch
-overhead that sank the per-leaf fused AdamW (docs/perf_experiments.md
+Shaped for the sharded data plane (:mod:`horovod_tpu.parallel.zero`):
+instead of one kernel per parameter leaf, ONE kernel runs over the whole
+flat fp32 master/moment shard of a dtype group. That removes the per-leaf
+launch overhead that sank a per-leaf fused AdamW (docs/perf_experiments.md
 round 4 — ~400 sequential pallas_calls forfeit XLA's cross-leaf
-scheduling): a BERT-Large f32 group is a single ~83M-element buffer, a
-single grid. The minimum HBM traffic per element is read master, mu, nu
+scheduling; its code left the tree in PR 29): a BERT-Large f32 group is a
+single ~83M-element buffer, a single grid. The minimum HBM traffic per element is read master, mu, nu
 (f32) + grad and write all four again — and only 1/N of it happens on
 each chip.
 
@@ -41,8 +40,8 @@ from horovod_tpu.ops.pallas._backend import (on_tpu, row_blocks,
 from horovod_tpu.utils import env as env_mod
 from horovod_tpu.utils import logging as log
 
-# Same tiling policy as fused_adamw: skip Pallas below this (launch not
-# worth it), and grid-step this many elements (256 KB f32 blocks).
+# Skip Pallas below this (launch not worth it), and grid-step this many
+# elements (256 KB f32 blocks).
 _MIN_PALLAS = 16 * 1024
 _BLOCK = env_mod._get_int("FUSED_OPTIMIZER_BLOCK", 64 * 1024)
 

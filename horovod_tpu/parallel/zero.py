@@ -1226,9 +1226,8 @@ class FlatAdamState(NamedTuple):
 
 class ShardedAdamW(NamedTuple):
     """Step-level sharded fused AdamW: ``apply(params, state, grads) ->
-    (new_params, new_state)`` (same shape of API as
-    ``ops.pallas.fused_adamw`` — the delta contract would break fp32
-    master-weight semantics in bf16)."""
+    (new_params, new_state)`` (not optax's ``update -> deltas``: the
+    delta contract would break fp32 master-weight semantics in bf16)."""
 
     init: callable
     apply: callable

@@ -36,8 +36,9 @@ At pipeline depth 1 the overlap window is empty — a synchronous
 allreduce reports ~0; at depth ≥ 2 the window of bin k contains bin
 k+1's whole dispatch, so overlap shows up as a positive fraction.
 
-``set_flops_per_step()`` (wired by bench.py, which knows model FLOPs and
-the per-chip peak) turns step wall time into a rolling in-process MFU.
+``set_flops_per_step()`` (called by a training script that knows its
+model FLOPs and the per-chip peak) turns step wall time into a rolling
+in-process MFU.
 
 Every rank with profiling enabled dumps ``profile-rank-N.json`` — the
 last ``HOROVOD_PROFILE_HISTORY`` step breakdowns plus Chrome-trace step
@@ -205,7 +206,7 @@ class StepProfiler:
         """Model-FLOPs hint: per-chip FLOPs executed by one profiled step
         (forward + backward + update). With a per-chip peak the profiler
         maintains the rolling ``horovod_mfu`` gauge; without one MFU stays
-        unset (the CPU fallback in bench.py does the same)."""
+        unset."""
         self._flops_per_step = flops
         if peak_flops_per_chip is not None:
             self._peak_flops = peak_flops_per_chip
@@ -390,7 +391,7 @@ class StepProfiler:
 
     def summary(self) -> dict:
         """Aggregate over the step history: mean wall/phase seconds and
-        comm-hidden fractions (what bench.py embeds per headline)."""
+        comm-hidden fractions."""
         steps = list(self._steps)
         if not steps:
             return {"steps": 0, "wall_seconds": 0.0,

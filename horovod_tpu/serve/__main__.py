@@ -5,8 +5,8 @@ given: each rank builds the demo model (random weights, deterministic
 seed — every replica must hold identical params), registers with the
 rendezvous KV queue, and serves until a dispatcher publishes the stop
 key. Point a :class:`~horovod_tpu.serve.queue.KVQueueFrontend` at the
-same rendezvous server to drive it (bench.py's ``--serve`` load
-generator, or the chaos matrix's ``serve_chaos_worker.py``).
+same rendezvous server to drive it (as the chaos matrix's
+``serve_chaos_worker.py`` does).
 
 Model shape flags exist so smoke runs stay tiny; a real deployment
 replaces this module with its own worker that loads trained params and

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point that wants compiled programs to survive
-the process (``bench.py``, ``chip_smoke.py``): the operator's
+the process (``chip_smoke.py``): the operator's
 ``JAX_COMPILATION_CACHE_DIR`` wins, untouched; otherwise a fixed
 directory in the checkout. The path is part of the cache key, so it
 never derives from a pid, a time or ``tempfile``.

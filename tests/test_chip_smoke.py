@@ -1,8 +1,7 @@
 """chip_smoke.py's contract, as far as a machine without a chip can hold
 it: the rehearsal switch runs the whole control flow at toy sizes on the
 CPU; without the switch, no accelerator is a failure with no result line.
-Plus the compile-cache rule the script, ``bench.py`` and nothing else
-share."""
+Plus the compile-cache rule the script uses."""
 
 import json
 import os

@@ -9,6 +9,8 @@ after warmup — the acceptance criterion for the pipelined data plane);
 (3) host staging slabs are reused, not reallocated, across cycles.
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -187,7 +189,7 @@ class TestSteadyStateProgramCache:
     def _one_cycle(self, hvd, rt, threshold_bytes, step):
         """Enqueue 4 named tensors inside one held cycle, then release the
         loop with ``fusion_threshold_bytes`` set so bin-packing groups
-        them as the threshold dictates."""
+        them as the threshold dictates. Returns the reduced values."""
         from horovod_tpu.core import state
 
         st = state.global_state()
@@ -212,8 +214,69 @@ class TestSteadyStateProgramCache:
             expected = np.mean([i + j + step for i in range(hvd.size())])
             np.testing.assert_allclose(out, np.full((300,), expected),
                                        rtol=1e-6)
+        return outs
 
-    def test_varying_bins_zero_compiles_after_warmup(self, hvd, monkeypatch):
+    def _steady_cycles(self, hvd, rt, around=contextlib.nullcontext,
+                       after=lambda outs: None):
+        """Regrouped bins {t0,t1},{t2,t3} (never seen in the warmup) plus
+        the warmup grouping again; the reduced values of every cycle."""
+        values = []
+        for step, threshold in ((1, 20000), (2, 20000), (3, 20000),
+                                (4, 30000)):
+            with around():
+                outs = self._one_cycle(hvd, rt, threshold_bytes=threshold,
+                                       step=step)
+            after(outs)
+            values.extend(outs)
+        return values
+
+    @staticmethod
+    def _switch_on(plane, monkeypatch):
+        """Turn one telemetry plane on the way its users do. Returns what
+        brackets a cycle, what follows it, and a count of what the plane
+        has seen since (so a plane that never ran cannot pass)."""
+        from horovod_tpu import comms, goodput, memory, profiler
+        from horovod_tpu.integrity import digest as integ_digest
+
+        around, after = contextlib.nullcontext, lambda outs: None
+        if plane == "integrity":  # a digest at every dispatch
+            monkeypatch.setenv("HOROVOD_INTEGRITY", "1")
+            monkeypatch.setenv("HOROVOD_INTEGRITY_INTERVAL", "1")
+            count = lambda: integ_digest._CHECKS.value
+        elif plane == "memory":   # the per-step push and one sweep
+            tracker = memory.tracker()
+            tracker.enabled = True
+
+            def after(outs):
+                tracker.note_tree_bytes("grads", outs)
+                tracker.sample()
+            count = lambda: memory._SAMPLES.value  # the ring is bounded
+        elif plane == "comms":
+            tracker = comms.tracker()
+            tracker.enabled = True
+            count = lambda: sum(lane["ops_total"] for lane in
+                                tracker.ledger()["lanes"].values())
+        elif plane == "goodput":  # its step hook rides the profiler's
+            tracker = goodput.tracker()
+            tracker.enabled = profiler._profiler.enabled = True
+            around = profiler.step
+
+            def count():  # seconds accounted, productive or badput
+                ledger = tracker.ledger()
+                return (ledger["productive_seconds"]
+                        + sum(ledger["badput_seconds"].values()))
+        start = count()
+        return around, after, lambda: count() - start
+
+    @pytest.mark.parametrize(
+        "plane", ["none", "integrity", "memory", "comms", "goodput"])
+    def test_varying_bins_zero_compiles_after_warmup(self, hvd, monkeypatch,
+                                                     plane):
+        """Steady-state cycles compile nothing, with no telemetry plane
+        and with each one switched on after the warmup; a plane watches
+        the wire and the clock, so the reduced values stay bit-identical
+        to the plane-off values."""
+        from horovod_tpu import comms, goodput, memory, profiler
         from horovod_tpu.runtime import executor as ex_mod
         from horovod_tpu.runtime.runtime import get_runtime
 
@@ -223,17 +286,31 @@ class TestSteadyStateProgramCache:
         # bin (2400B/row) both land in the 4096B bucket
         monkeypatch.setattr(rt.executor, "fusion_buffers",
                             FusionBufferManager(256))
+        for tracker in (memory.tracker(), comms.tracker(),
+                        goodput.tracker(), profiler._profiler):
+            monkeypatch.setattr(tracker, "enabled", False)
+        monkeypatch.delenv("HOROVOD_INTEGRITY", raising=False)
+        if plane == "integrity":
+            # the per-bucket digest is a compiled program of its own: it
+            # belongs to the warmup, and the plane goes off again for the
+            # reference values
+            self._switch_on(plane, monkeypatch)
         # warmup: one grouping {t0,t1,t2},{t3} compiles the 4096B and
         # 2048B buckets (per-tensor request is 8*300*4 = 9600B)
         self._one_cycle(hvd, rt, threshold_bytes=30000, step=0)
+        monkeypatch.delenv("HOROVOD_INTEGRITY", raising=False)
         compiles_after_warmup = ex_mod._PROGRAM_COMPILES.value
         hits0 = ex_mod._PROGRAM_CACHE_HITS.value
         allocs0 = fb._BUF_ALLOCS.value
-        # steady state: regrouped bins {t0,t1},{t2,t3} (never seen before)
-        # plus the warmup grouping again — all hit the warmed buckets
-        for step in range(1, 4):
-            self._one_cycle(hvd, rt, threshold_bytes=20000, step=step)
-        self._one_cycle(hvd, rt, threshold_bytes=30000, step=4)
+
+        plane_off = self._steady_cycles(hvd, rt)
+        if plane == "none":
+            plane_on = self._steady_cycles(hvd, rt)
+        else:
+            around, after, seen = self._switch_on(plane, monkeypatch)
+            plane_on = self._steady_cycles(hvd, rt, around, after)
+            assert seen() > 0, f"the {plane} plane saw no steady cycle"
+
         assert ex_mod._PROGRAM_COMPILES.value == compiles_after_warmup, \
             "steady-state cycles must not trigger new XLA compiles"
         assert ex_mod._PROGRAM_CACHE_HITS.value > hits0
@@ -241,6 +318,8 @@ class TestSteadyStateProgramCache:
         # never stage through (or allocate) host fusion-buffer slabs
         assert fb._BUF_ALLOCS.value == allocs0, \
             "device-path cycles must not allocate host staging slabs"
+        for off, on in zip(plane_off, plane_on):
+            np.testing.assert_array_equal(off, on)
 
     @pytest.mark.parametrize("depth", [1, 3])
     def test_pipeline_depth_preserves_results(self, hvd, monkeypatch, depth):

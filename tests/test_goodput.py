@@ -1,8 +1,8 @@
 """Goodput ledger (ISSUE 19): category accounting that sums to
 wall-clock, the bounded incident ledger and replay attribution, the
 surfaces (/goodput route + the JSON route index, merged-trace counter +
-incident lanes, hvd_top panel, cross-rank postmortem report), the knob
-plumbing, and the bench_compare goodput_fraction gate.
+incident lanes, hvd_top panel, cross-rank postmortem report) and the
+knob plumbing.
 
 Tier-1 safe: everything here drives the tracker directly — no devices,
 no timing sensitivity (spans are injected, not measured). The real
@@ -504,83 +504,6 @@ class TestMergedTrace:
         snap = profiler._profiler.snapshot()
         assert snap["goodput_samples"]
         assert snap["goodput_incidents"][-1]["cause"] == "rollback"
-
-
-# ---------------------------------------------------------------------------
-# bench surfaces
-# ---------------------------------------------------------------------------
-
-@pytest.fixture
-def bench_compare():
-    repo_tools = os.path.join(_REPO, "tools")
-    if repo_tools not in sys.path:
-        sys.path.insert(0, repo_tools)
-    import bench_compare as mod
-
-    return mod
-
-
-def _artifact(path, rows):
-    tail = "\n".join(["bench log noise"] + [json.dumps(r) for r in rows])
-    with open(path, "w") as f:
-        json.dump({"n": 1, "cmd": "python bench.py", "rc": 0,
-                   "tail": tail}, f)
-    return str(path)
-
-
-_BASE_ROW = {"metric": "images/sec/chip (ResNet-50 synthetic)",
-             "value": 2000.0, "unit": "images/sec/chip"}
-
-
-def test_bench_compare_collapsed_goodput_fails(bench_compare, tmp_path,
-                                               capsys):
-    """ISSUE 19 satellite: goodput_fraction is a higher-is-better
-    fraction — a candidate that burns its wall-clock on stalls and
-    replays gates like a throughput regression even when the step
-    latency headline holds."""
-    base_row = dict(_BASE_ROW, goodput_fraction=0.92)
-    base = _artifact(tmp_path / "base.json", [base_row])
-    cand_row = dict(base_row, goodput_fraction=0.55)
-    cand = _artifact(tmp_path / "cand.json", [cand_row])
-    assert bench_compare.main([base, cand]) == 1
-    out = capsys.readouterr().out
-    assert "goodput_fraction" in out
-    assert "higher is better" in out
-
-
-def test_bench_compare_goodput_row_clean_pass(bench_compare, tmp_path,
-                                              capsys):
-    row = dict(_BASE_ROW, goodput_fraction=0.92)
-    base = _artifact(tmp_path / "base.json", [row])
-    cand = _artifact(tmp_path / "cand.json", [dict(row)])
-    assert bench_compare.main([base, cand]) == 0
-    assert "[goodput_fraction]" in capsys.readouterr().out
-
-
-@pytest.fixture
-def bench(hvd):
-    if _REPO not in sys.path:
-        sys.path.insert(0, _REPO)
-    import bench as bench_mod
-
-    return bench_mod
-
-
-def test_goodput_suite_tiny(bench, capsys):
-    """ISSUE 19 satellite shape: ``bench.py --goodput --tiny`` runs the
-    interleaved tracker-off/tracker-on A/B and reports the overhead
-    headline as one JSON line with zero steady-state compiles."""
-    result = bench.goodput_main(tiny=True)
-    assert result["tiny"] is True
-    assert result["unit"] == "%"
-    assert result["goal"] == "< 1%"
-    assert result["p50_ms_goodput_off"] > 0
-    assert result["p50_ms_goodput_on"] > 0
-    assert result["steady_state_compiles"] == 0
-    assert result["steps_productive"] > 0
-    assert 0.0 <= result["goodput_fraction"] <= 1.0
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    assert json.loads(line)["value"] == result["value"]
 
 
 # ---------------------------------------------------------------------------

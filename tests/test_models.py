@@ -33,8 +33,8 @@ class TestResNet:
         """The MXU-friendly input-conv reparametrization must compute
         the SAME function as the direct 7x7/2 conv on the same
         (7,7,3,64) parameter — checkpoint-interchangeable by
-        construction (tools/conv0_s2d.py measures the 1.43x layer
-        speedup on chip)."""
+        construction (docs/perf_experiments.md has the 1.43x layer
+        speedup read on an earlier machine)."""
         from horovod_tpu.models.resnet import ResNet50
 
         x = jnp.asarray(np.random.RandomState(0).uniform(
@@ -188,58 +188,6 @@ class TestTransformer:
         np.testing.assert_allclose(float(gathered), float(full),
                                    rtol=1e-6)
 
-    def test_chunked_causal_loss_matches_full_logits(self, hvd_flat):
-        """causal_lm_loss_chunked (projection inside the chunk loop, no
-        full logits tensor) must equal causal_lm_loss on the same model
-        — an algebraic rearrangement, not an approximation."""
-        from horovod_tpu.models.transformer import (causal_lm_loss,
-                                                    causal_lm_loss_chunked)
-
-        model = self._tiny(causal=True)
-        rng = np.random.RandomState(11)
-        tokens = jnp.asarray(rng.randint(0, 64, (3, 16)), jnp.int32)
-        variables = model.init(jax.random.PRNGKey(0), tokens, train=False)
-
-        full = causal_lm_loss(
-            model.apply(variables, tokens, train=False), tokens)
-        hidden = model.apply(variables, tokens, train=False,
-                             output="hidden")
-        emb = variables["params"]["token_embed"]["embedding"]
-        for chunk in (4, 8, 16):
-            chunked = causal_lm_loss_chunked(hidden, emb, tokens,
-                                             chunk=chunk)
-            np.testing.assert_allclose(float(chunked), float(full),
-                                       rtol=1e-6)
-        with pytest.raises(ValueError):
-            causal_lm_loss_chunked(hidden, emb, tokens, chunk=5)
-
-    def test_fused_qkv_matches_unfused(self, hvd_flat):
-        """fused_qkv=True is the same function: stacking the unfused
-        query/key/value kernels (and biases) into the fused 'qkv' param
-        must reproduce the unfused model's logits exactly."""
-        rng = np.random.RandomState(5)
-        tokens = jnp.asarray(rng.randint(0, 64, (2, 16)), jnp.int32)
-
-        unfused = self._tiny(causal=False)
-        fused = self._tiny(causal=False, fused_qkv=True)
-        uv = unfused.init(jax.random.PRNGKey(0), tokens, train=False)
-        fv = fused.init(jax.random.PRNGKey(0), tokens, train=False)
-        fparams = jax.tree_util.tree_map(np.asarray, fv)
-        for lyr in ("layer_0", "layer_1"):
-            at = uv["params"][lyr]["attention"]
-            dst = fparams["params"][lyr]["attention"]["qkv"]
-            dst["kernel"] = np.stack(
-                [np.asarray(at[n]["kernel"]) for n in
-                 ("query", "key", "value")], axis=1)  # (d, 3, h, hd)
-            dst["bias"] = np.stack(
-                [np.asarray(at[n]["bias"]) for n in
-                 ("query", "key", "value")], axis=0)  # (3, h, hd)
-
-        a = unfused.apply(uv, tokens, train=False)
-        b = fused.apply(fparams, tokens, train=False)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-5)
-
     def test_bert_large_param_count(self, hvd_flat):
         from horovod_tpu.models.transformer import BertLarge
 
@@ -265,7 +213,7 @@ class TestTransformer:
 
 class _TinyBnNet:
     """Conv+BatchNorm model so the scan carries non-empty batch_stats
-    (the path bench.py's ResNet-50 relies on)."""
+    (the path ``make_train_round`` takes for ResNet-50)."""
 
     def __new__(cls):
         import flax.linen as nn

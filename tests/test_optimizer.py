@@ -20,68 +20,7 @@ def _make_data(key, n=64):
     return x, x @ w_true
 
 
-class TestFusedAdamW:
-    def test_matches_optax_adamw(self, hvd_flat):
-        """The Pallas single-pass adamw must track optax.adamw step for
-        step (same hyperparameters, same state layout) within f32
-        round-off over several updates, on a tree with both Pallas-sized
-        and small (jnp fallback) leaves."""
-        from horovod_tpu.ops.pallas import fused_adamw
-
-        rng = np.random.RandomState(0)
-        params = {
-            "big": jnp.asarray(rng.randn(16384 * 2), jnp.float32),
-            "mat": jnp.asarray(rng.randn(256, 128), jnp.float32),
-            "small": jnp.asarray(rng.randn(7), jnp.float32),
-        }
-        lr, wd = 1e-2, 1e-3
-        ref_tx = optax.adamw(lr, weight_decay=wd)
-        ref_state = ref_tx.init(params)
-        fused = fused_adamw(lr, weight_decay=wd)
-        state = fused.init(params)
-
-        ref_p = params
-        p = params
-        for i in range(4):
-            grads = jax.tree_util.tree_map(
-                lambda a, s=i: jnp.asarray(
-                    np.random.RandomState(10 + s).randn(*a.shape),
-                    jnp.float32), params)
-            upd, ref_state = ref_tx.update(grads, ref_state, ref_p)
-            ref_p = optax.apply_updates(ref_p, upd)
-            p, state = fused.apply(p, state, grads)
-            for k in params:
-                np.testing.assert_allclose(
-                    np.asarray(p[k]), np.asarray(ref_p[k]),
-                    rtol=2e-5, atol=2e-6, err_msg=f"step {i} leaf {k}")
-        # state interop: same ScaleByAdamState layout
-        np.testing.assert_allclose(np.asarray(state.mu["mat"]),
-                                   np.asarray(ref_state[0].mu["mat"]),
-                                   rtol=2e-5, atol=2e-6)
-        assert int(state.count) == 4
-
-    def test_prime_row_leaf_matches_optax(self):
-        """A leaf whose 128-lane row count is prime and taller than one
-        block has no block divisor at all: the grid's last block is
-        ragged (a divisor search would end at block_rows=1, a grid of
-        per-row kernel steps — or at a row count Mosaic refuses). Still
-        one kernel pass, still optax's numbers."""
-        from horovod_tpu.ops.pallas import fused_adamw
-
-        rng = np.random.RandomState(1)
-        # 1049 rows of 128 lanes: prime, two whole 512-row blocks + 25
-        params = {"prime": jnp.asarray(rng.randn(1049 * 128), jnp.float32)}
-        grads = {"prime": jnp.asarray(rng.randn(1049 * 128), jnp.float32)}
-        lr, wd = 1e-2, 1e-3
-        ref_tx = optax.adamw(lr, weight_decay=wd)
-        upd, _ = ref_tx.update(grads, ref_tx.init(params), params)
-        ref_p = optax.apply_updates(params, upd)
-        fused = fused_adamw(lr, weight_decay=wd)
-        p, _ = fused.apply(params, fused.init(params), grads)
-        np.testing.assert_allclose(np.asarray(p["prime"]),
-                                   np.asarray(ref_p["prime"]),
-                                   rtol=2e-5, atol=2e-6)
-
+class TestPallasRaggedBlocks:
     def test_flat_shard_kernel_ragged_block_matches_jnp(self):
         """The ZeRO flat-shard kernel over a shard whose rows leave a
         ragged last block (interpret mode here; tests/test_tpu_compile.py

@@ -116,20 +116,14 @@ def test_flash_grads_match_reference(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bf16_mxu_path(causal, monkeypatch):
-    """FLASH_MXU_BF16=1 feeds the MXU dots bf16 operands (f32
-    accumulation). Outputs and grads must match the f32 reference
-    computed on the same (bf16-rounded) inputs to bf16-appropriate
-    tolerance; the default (flag off) keeps the f32-cast path."""
+def test_flash_bf16_inputs(causal):
+    """bf16 q/k/v (what the models feed the kernels): operands are
+    up-cast to f32 inside the kernel. Outputs and grads must match the
+    f32 reference computed on the same (bf16-rounded) inputs to
+    bf16-appropriate tolerance."""
     q, k, v = _qkv(7, dtype=jnp.bfloat16)
     ref = attention_reference(q, k, v, causal=causal).astype(jnp.float32)
 
-    # default: f32-cast path
-    o_f32 = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
-    np.testing.assert_allclose(np.asarray(o_f32, np.float32), ref,
-                               atol=2e-2)
-
-    monkeypatch.setenv("FLASH_MXU_BF16", "1")
     o = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(o, np.float32), ref, atol=2e-2)
 

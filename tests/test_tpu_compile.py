@@ -29,7 +29,6 @@ from jax.sharding import SingleDeviceSharding
 from horovod_tpu.ops.pallas import conv_bn_act, fused_optimizer
 from horovod_tpu.ops.pallas._backend import shard_over_batch
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
-from horovod_tpu.ops.pallas.fused_adamw import _leaf_update
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
 from horovod_tpu.utils.env import DEFAULT_FUSION_BUCKET_QUANTUM_BYTES
 
@@ -162,7 +161,7 @@ def _zero_shard_elems(n_params, world,
     # HOROVOD_FUSION_BUCKET_QUANTUM=0 leaves shards unpadded
     pytest.param(_zero_shard_elems(_PARAMS["bert-large"], 4, quantum=0),
                  id="bert-large-4chips-unpadded"),
-    # a quarter of bench.py --sharded-optimizer's BERT-Large table:
+    # a quarter of a BERT-Large flat f32 table (334M parameters):
     # 652,344 lane rows, whose largest divisor under 512 is 462 — not a
     # multiple of 8, so Mosaic refused the divisor-search block
     pytest.param(83_500_032, id="bench-table-quarter"),
@@ -176,20 +175,6 @@ def test_flat_adamw_shard_kernel(chip, n):
 
     buf = ((n,), F32)
     assert _kernels_in(update, chip, buf, buf, buf, ((n,), BF16),
-                       ((6,), F32)) == 1
-
-
-def test_fused_adamw_leaf_kernel(chip):
-    """The per-leaf kernel (a measured loser, ROADMAP D2) shares the
-    block-row rule; one leaf with a prime row count guards it while the
-    module stays."""
-    shape = (131 * 128 * 8 + 128,)   # 1049 lane rows: prime, > one block
-
-    def update(p, m, v, g, scalars):
-        return _leaf_update(p, m, v, g, scalars, eps=1e-8)
-
-    leaf = (shape, F32)
-    assert _kernels_in(update, chip, leaf, leaf, leaf, leaf,
                        ((6,), F32)) == 1
 
 

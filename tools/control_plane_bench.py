@@ -19,8 +19,7 @@ tests/test_multiprocess.py) and measures on the host:
     (reference: parameter_manager.cc:142-176 bytes/us scoring)
 
 Run:  python tools/control_plane_bench.py [--np 4]
-Emits one JSON object on stdout (also written per-metric lines by
-``bench.py --control-plane``'s caller).
+Emits one JSON object on stdout.
 """
 
 import argparse
@@ -38,13 +37,12 @@ SMALL = 1024          # elements per small tensor (4 KiB fp32)
 N_TENSORS = 16        # tensors per fusion step
 STEPS = 15            # timed steps per phase (1-core CI boxes are slow)
 WARMUP = 3
-# --fast (the bench.py no-flag sweep): fewer steps, no autotune launch.
-# The lines bench.py reports (ctrl bytes/op, ring steps/op) are protocol
-# counters, but ops-per-cycle batching depends on scheduler timing, so
-# short windows amortize fixed per-window costs less (measured: 5 steps
-# reads amortization 1.94x vs 2.44x at 15) — 10 steps keeps the drift
-# small while cutting the 5.5-min full protocol (a third of the r4
-# driver window) to ~2 min.
+# --fast: fewer steps, no autotune launch. The headline lines (ctrl
+# bytes/op, ring steps/op) are protocol counters, but ops-per-cycle
+# batching depends on scheduler timing, so short windows amortize fixed
+# per-window costs less (measured: 5 steps reads amortization 1.94x vs
+# 2.44x at 15) — 10 steps keeps the drift small while cutting the
+# 5.5-min full protocol to ~2 min.
 FAST_STEPS = 10
 FAST_WARMUP = 2
 

@@ -26,9 +26,8 @@ world over the native wire like tools/control_plane_bench.py):
                       (acceptance: converges within ~5%)
 
 Run:  python tools/hierarchy_bench.py [--np 4] [--tiny]
-Emits one JSON object on stdout; ``bench.py --hierarchy`` wraps it into
-per-metric lines. The throttled-hop speedup row is emitted with unit
-"x" so tools/bench_compare.py gates it higher-is-better.
+Emits one JSON object on stdout. The throttled-hop speedup row is
+emitted with unit "x": higher is better.
 """
 
 import argparse
