@@ -30,6 +30,8 @@ import numpy as np
 import optax
 
 from horovod_tpu.ops.pallas._backend import shard_over_batch
+from horovod_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                     takes_kernel)
 from horovod_tpu.ops.pallas.flash_attention import NEG_INF, flash_attention
 from horovod_tpu.ops.pallas.kv_cache_write import write_token
 
@@ -52,9 +54,11 @@ def cached_attention(q, k, v, q_positions):
     at positions the current request has not reached, padded prefill
     rows are overwritten by decode before a query passes them.
 
-    Plain XLA einsum + f32 softmax (the shapes are decode-sized: one or
-    a few queries against ``max_seq`` keys — no flash-kernel tiling to
-    win, and it must run everywhere, CPU tests included).
+    Plain XLA einsum + f32 softmax over the WHOLE cache, whatever the
+    rows' lengths: right for a prefill (bucket-many queries against a
+    fresh cache) and for the paged engine's gathered view. The dense
+    decode step, one query a row, goes through
+    :func:`attend_cache`, which reads only the live part of each row.
     """
     head_dim = q.shape[-1]
     scale = 1.0 / float(np.sqrt(head_dim))
@@ -64,6 +68,32 @@ def cached_attention(q, k, v, q_positions):
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def attend_cache(q, k_cache, v_cache, positions):
+    """Attention of a step's new tokens against the positions-last cache
+    they were just written into.
+
+    ``q``: (batch, new, heads, head_dim); ``k_cache``/``v_cache``:
+    (batch, heads, head_dim, cache_len); ``positions``: (batch,) int32,
+    the first new token's. Returns (batch, new, heads, head_dim).
+
+    Selected by shape, as :func:`write_cache_rows` selects its kernel:
+    one new token a row against a cache of whole lane tiles (the dense
+    decode step) goes through ``ops/pallas/decode_attention``, which
+    fetches the lane tiles ``0 .. position // 128`` of each row and none
+    past them; anything else is :func:`cached_attention` over the whole
+    cache.
+    """
+    new_tokens = q.shape[1]
+    if takes_kernel(new_tokens, k_cache.shape[-1]):
+        return decode_attention(q[:, 0], k_cache, v_cache,
+                                positions)[:, None]
+    q_pos = positions[:, None] + jnp.arange(new_tokens, dtype=jnp.int32)
+    o = cached_attention(
+        q.transpose(0, 2, 1, 3), k_cache.transpose(0, 1, 3, 2),
+        v_cache.transpose(0, 1, 3, 2), q_pos)
+    return o.transpose(0, 2, 1, 3)
 
 
 def write_cache_rows(cache, new, positions):
@@ -99,7 +129,9 @@ class SelfAttention(nn.Module):
     collection holds per-row key/value tensors of length
     ``max_cache_len`` (positions last), new tokens are written in at
     their absolute ``positions`` (:func:`write_cache_rows`) and attention
-    runs masked against the whole cache (:func:`cached_attention`).
+    runs against the cache, masked past each query's position
+    (:func:`attend_cache`: the live part of each row for a one-token
+    step, the whole cache otherwise).
     Parameters are identical to the training module — only runtime
     behavior and the (non-param) cache change.
 
@@ -191,8 +223,7 @@ class SelfAttention(nn.Module):
                 raise ValueError("decode=True requires per-row positions")
             if self.max_cache_len <= 0:
                 raise ValueError("decode=True requires max_cache_len > 0")
-            batch, new_tokens = x.shape[0], x.shape[1]
-            cache_shape = (batch, self.num_heads, head_dim,
+            cache_shape = (x.shape[0], self.num_heads, head_dim,
                            self.max_cache_len)
             cached_key = self.variable("cache", "cached_key", jnp.zeros,
                                        cache_shape, self.dtype)
@@ -203,12 +234,7 @@ class SelfAttention(nn.Module):
                 cached_key.value, k.astype(self.dtype), pos)
             cached_value.value = write_cache_rows(
                 cached_value.value, v.astype(self.dtype), pos)
-            q_pos = pos[:, None] + jnp.arange(new_tokens, dtype=jnp.int32)
-            o = cached_attention(
-                q.transpose(0, 2, 1, 3),
-                cached_key.value.transpose(0, 1, 3, 2),
-                cached_value.value.transpose(0, 1, 3, 2), q_pos)
-            o = o.transpose(0, 2, 1, 3)
+            o = attend_cache(q, cached_key.value, cached_value.value, pos)
             return dense(features=d_model, axis=(-2, -1), name="out")(o)
 
         # (batch, seq, heads, head_dim) -> (batch, heads, seq, head_dim)

@@ -32,6 +32,22 @@ def use_interpret() -> bool:
     return env_mod._get_bool("HOROVOD_PALLAS_INTERPRET", not on_tpu())
 
 
+def kernels_in(jaxpr) -> list:
+    """The ``name`` of every ``pallas_call`` in a (closed) jaxpr, nested
+    calls included: what a traced program holds, before any compile."""
+    names = []
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for value in eqn.params.values():
+            inner = value if isinstance(value, (tuple, list)) else (value,)
+            names.extend(name for sub in inner
+                         if hasattr(getattr(sub, "jaxpr", sub), "eqns")
+                         for name in kernels_in(sub))
+    return names
+
+
 def row_blocks(rows: int, max_block_rows: int) -> tuple[int, int]:
     """``(block_rows, grid)`` for an elementwise pass over a
     ``(rows, 128)`` view.

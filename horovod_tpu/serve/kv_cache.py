@@ -81,6 +81,8 @@ import numpy as np
 from horovod_tpu import tracing
 from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
+from horovod_tpu.ops.pallas import decode_attention
+from horovod_tpu.ops.pallas._backend import kernels_in
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
 
 # prompt-length bucket quantum (tokens). Not a knob: the policy is the
@@ -171,10 +173,10 @@ class PendingDecode(Pending):
     """``collect()`` -> (ids, max |logit|s) of the step's rows."""
 
     def __init__(self, engine: "DecodeEngine", slots: List[int], ids,
-                 max_abs, t0: float, number: int):
+                 max_abs, t0: float, number: int, attrs: dict):
         self._engine, self._slots = engine, slots
         self._ids, self._max_abs = ids, max_abs
-        self._t0, self._number = t0, number
+        self._t0, self._number, self._attrs = t0, number, attrs
 
     def _read(self) -> Tuple[List[int], List[float]]:
         # ``ahead``: a later decode step was already enqueued when the
@@ -187,7 +189,7 @@ class PendingDecode(Pending):
         # the start of the prep to the ids on the host
         seconds = time.time() - self._t0
         tracing.record("engine.decode", self._t0, seconds,
-                       rows=len(self._slots))
+                       rows=len(self._slots), **self._attrs)
         engine._note_decode(seconds * 1000.0, ahead)
         return ids[self._slots].tolist(), max_abs[self._slots].tolist()
 
@@ -208,6 +210,13 @@ class DecodeEngine:
         # a model with block-sparse layers selects key blocks for prompts
         # past this length (the ``sparse`` attribute of ``engine.prefill``)
         self._dense_len = getattr(model, "dense_len", None)
+        # does the decode program read its key/value rows through
+        # ops/pallas/decode_attention (set by _cache_shapes, from the
+        # program itself), and the lane tiles its steps read of a leaf
+        # over the tiles of all rows (stats()["decode_kv_read_share"])
+        self._reads_live_tiles = False
+        self.kv_tiles_read = 0
+        self.kv_tiles_held = 0
         self._cache = self._allocate_cache()
         # the next token of every row, on the device (module docstring)
         self._feed = jnp.zeros((self.num_slots,), jnp.int32)
@@ -232,15 +241,17 @@ class DecodeEngine:
 
     # -- cache -------------------------------------------------------------
     def _cache_shapes(self):
-        """The decode program's cache pytree as shapes (``eval_shape``:
-        nothing compiles, nothing is allocated)."""
+        """The decode program's cache pytree as shapes (one abstract
+        trace: nothing compiles, nothing is allocated). The same trace
+        says whether the program holds the decode-attention kernel."""
         tokens = jax.ShapeDtypeStruct((self.num_slots, 1), jnp.int32)
         pos = jax.ShapeDtypeStruct((self.num_slots,), jnp.int32)
-        _, shapes = jax.eval_shape(
+        program, (_, shapes) = jax.make_jaxpr(
             lambda p, t, q: self._model.apply(
                 {"params": p}, t, positions=q, train=False,
-                mutable=["cache"]),
-            self._params, tokens, pos)
+                mutable=["cache"]), return_shape=True)(
+                    self._params, tokens, pos)
+        self._reads_live_tiles = "decode_attention" in kernels_in(program)
         return shapes["cache"]
 
     def _allocate_cache(self):
@@ -389,12 +400,21 @@ class DecodeEngine:
                 raise ValueError(
                     f"decode: slot {slot} position {step_pos[slot]} >= "
                     f"max_seq {self.max_seq} (admission cap violated)")
+            attrs = {}
+            if self._reads_live_tiles:
+                # what the kernel will fetch: a row that is not active
+                # runs at position 0 and costs one tile
+                read, held = decode_attention.live_tiles(step_pos,
+                                                         self.max_seq)
+                self.kv_tiles_read += read
+                self.kv_tiles_held += held
+                attrs["kv_read_share"] = round(read / held, 4)
         with tracing.span("engine.decode.dispatch"):
             ids, max_abs = self._run_donating("decode", self._decode_fn,
                                               step_pos)
         self.decodes_enqueued += 1
         return PendingDecode(self, list(slots), ids, max_abs, t0,
-                             self.decodes_enqueued)
+                             self.decodes_enqueued, attrs)
 
     def _note_decode(self, ms: float, ahead: int) -> None:
         self.decode_steps += 1
@@ -413,4 +433,11 @@ class DecodeEngine:
                 "cache_bytes_by_kind": self.cache_bytes_by_kind(),
                 "cache_donated": (self._donated.get("prefill", False)
                                   and self._donated.get("decode", False)),
+                # lane tiles of a key/value leaf the decode steps read
+                # over the tiles of all rows; None where the decode
+                # program reads whole rows (no decode-attention kernel
+                # in it) or has not run
+                "decode_kv_read_share": (
+                    round(self.kv_tiles_read / self.kv_tiles_held, 4)
+                    if self.kv_tiles_held else None),
                 "slots": self.num_slots}

@@ -760,5 +760,8 @@ class PagedDecodeEngine:
                 "decode_steps": self.decode_steps,
                 "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
                 "cache_bytes": self.cache_bytes(),
+                # the paged decode step gathers every mapped page into a
+                # row view and attends over all of it
+                "decode_kv_read_share": None,
                 "slots": self.num_slots,
                 "pages": self.page_stats()}

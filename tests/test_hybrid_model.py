@@ -18,6 +18,7 @@ Tolerances, on logits whose standard deviation is about 0.06 here:
 """
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -280,6 +281,23 @@ def test_cache_kinds_and_the_sparse_attribute(served):
              if s["name"] == "engine.prefill"
              and s["prompt_len"] in (41, 141)}
     assert flags == {41: False, 141: True}
+
+
+def test_hybrid_decode_reports_no_kv_read_share(served):
+    """``HybridDecoder`` has its own attention over the whole row (its
+    256-position cache is two whole lane tiles): no decode-attention
+    kernel in its program, and the engine says None, not 1.0."""
+    _, params, model = served
+    engine = DecodeEngine(model, params, num_slots=2)
+    assert model.max_seq % 128 == 0 and not engine._reads_live_tiles
+    first, _ = engine.prefill(0, tokens(41).tolist())
+    began = time.time()
+    engine.decode([0], [first], [41]).collect()
+    assert engine.stats()["decode_steps"] == 1
+    assert engine.stats()["decode_kv_read_share"] is None
+    mine = [s for s in tracing.spans()
+            if s["name"] == "engine.decode" and s["t"] >= began]
+    assert len(mine) == 1 and "kv_read_share" not in mine[0]
 
 
 def test_the_paged_engine_refuses_a_model_without_pages(served):

@@ -383,6 +383,33 @@ def test_release_all_reclaims_every_request_page(tiny_lm):
         == eng.pool.allocatable
 
 
+def test_paged_engine_reports_no_kv_read_share():
+    """The paged decode step gathers every mapped page into a row view
+    and attends over all of it (``cached_attention``), also where that
+    view is whole lane tiles long (7 + 1 pages of 16): no kernel in its
+    program, no share in its stats."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.transformer import Transformer
+    from horovod_tpu.ops.pallas._backend import kernels_in
+
+    model = Transformer(vocab_size=61, d_model=32, num_layers=1,
+                        num_heads=2, d_ff=64, max_seq=112, causal=True,
+                        dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    eng = _engine(model, params, pool_pages=24)
+    assert eng._table_arr.shape[1] * eng.page_tokens == 128
+    _generate(eng, 0, [1, 2, 3], 4)
+    assert eng.stats()["decode_steps"] == 3
+    assert eng.stats()["decode_kv_read_share"] is None
+    program = jax.make_jaxpr(eng._decode_impl)(
+        eng._params, eng._cache, jnp.zeros((3, 1), jnp.int32),
+        jnp.zeros((3,), jnp.int32), jnp.asarray(eng._table_arr))
+    assert kernels_in(program) == []
+
+
 def test_paged_pool_bytes_in_memory_ledger(tiny_lm):
     """kv_pages is a first-class device subsystem: the pool registry
     feeds memory.py's ledger and the reconciliation set."""

@@ -471,6 +471,181 @@ def test_write_token_kernel(cache_len, dtype):
     np.testing.assert_array_equal(np.array(got.astype(jnp.float32)), want)
 
 
+_RAGGED = {
+    # both sides of a lane-tile boundary, the first and the last position
+    "ragged": lambda rows, last: [0, 127, 128, 129, last, 5, 300][:rows],
+    "all-equal": lambda rows, last: [200] * rows,
+    # what a row that is not active runs at
+    "inactive": lambda rows, last: [0] * rows,
+}
+
+
+def _stale_cache(rng, shape, positions, dtype):
+    """A cache whose rows hold sound values up to their position and
+    large finite garbage past it (an earlier occupant's), in the live
+    tile and in the dead ones; and the same cache with zeros there."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    sound = rng.normal(size=shape)
+    stale = np.arange(shape[-1])[None, :] > np.asarray(positions)[:, None]
+    stale = stale[:, None, None, :]
+    garbage = rng.choice([-3e4, 3e4], size=shape)
+    return (jnp.asarray(np.where(stale, garbage, sound), dtype),
+            jnp.asarray(np.where(stale, 0.0, sound), dtype))
+
+
+@pytest.mark.parametrize("pattern", sorted(_RAGGED))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_decode_attention_kernel(dtype, pattern):
+    """The kernel (interpret mode) against ``cached_attention`` on the
+    same cache: equal to rounding, and what lies past a row's position
+    changes nothing, bit for bit."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models.transformer import cached_attention
+    from horovod_tpu.ops.pallas.decode_attention import decode_attention
+
+    rng = np.random.default_rng(3)
+    rows, heads, head_dim, cache_len = 7, 3, 16, 384
+    positions = _RAGGED[pattern](rows, cache_len - 1)
+    pos = jnp.asarray(positions, jnp.int32)
+    shape = (rows, heads, head_dim, cache_len)
+    k_stale, k_clean = _stale_cache(rng, shape, positions, dtype)
+    v_stale, v_clean = _stale_cache(rng, shape, positions, dtype)
+    q = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
+
+    got = decode_attention(q, k_stale, v_stale, pos)
+    assert got.shape == q.shape and got.dtype == v_stale.dtype
+    np.testing.assert_array_equal(
+        np.array(got.astype(jnp.float32)),
+        np.array(decode_attention(q, k_clean, v_clean, pos)
+                 .astype(jnp.float32)))
+    want = cached_attention(
+        q[:, :, None, :], k_stale.transpose(0, 1, 3, 2),
+        v_stale.transpose(0, 1, 3, 2), pos[:, None])[:, :, 0]
+    # XLA rounds its scores and probabilities to the cache's dtype; the
+    # kernel keeps both in float32
+    tol = 3e-2 if dtype == "bfloat16" else 2e-6
+    np.testing.assert_allclose(np.array(got.astype(jnp.float32)),
+                               np.array(want.astype(jnp.float32)),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("cache_len,new_tokens,kernel", [
+    (128, 1, True),      # the dense decode step
+    (96, 1, False),      # a cache length off the lane tile
+    (128, 3, False),     # several new tokens a row: a prefill
+], ids=["decode", "off-tile", "prefill"])
+def test_attend_cache_selects_by_shape(cache_len, new_tokens, kernel):
+    """One new token a row against whole lane tiles goes through the
+    kernel; anything else is the masked whole-row contraction. By
+    result and by what the traced program holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models.transformer import attend_cache, cached_attention
+    from horovod_tpu.ops.pallas._backend import kernels_in
+
+    rng = np.random.default_rng(5)
+    rows, heads, head_dim = 3, 2, 8
+    shape = (rows, heads, head_dim, cache_len)
+    positions = [0, cache_len - new_tokens, 40]
+    k, _ = _stale_cache(rng, shape, [p + new_tokens - 1 for p in positions],
+                        "float32")
+    v, _ = _stale_cache(rng, shape, [p + new_tokens - 1 for p in positions],
+                        "float32")
+    q = jnp.asarray(rng.normal(size=(rows, new_tokens, heads, head_dim)),
+                    jnp.float32)
+    pos = jnp.asarray(positions, jnp.int32)
+    names = kernels_in(jax.make_jaxpr(attend_cache)(q, k, v, pos))
+    assert names == (["decode_attention"] if kernel else [])
+    q_pos = pos[:, None] + jnp.arange(new_tokens)
+    want = cached_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 1, 3, 2),
+        v.transpose(0, 1, 3, 2), q_pos).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(np.array(attend_cache(q, k, v, pos)),
+                               np.array(want), atol=2e-6, rtol=2e-6)
+
+
+@pytest.fixture(scope="module")
+def tiled_lm():
+    """A toy decoder whose cache is two lane tiles long, so that its
+    decode step takes the decode-attention kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.transformer import Transformer
+
+    model = Transformer(vocab_size=61, d_model=32, num_layers=2,
+                        num_heads=2, d_ff=64, max_seq=256, causal=True,
+                        dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    return model, params
+
+
+def test_dense_engine_decodes_as_the_masked_path(tiled_lm, monkeypatch):
+    """Forty decode steps of three rows that cross a tile boundary, a
+    row left inactive: the engine on the kernel serves the tokens the
+    same model serves on the masked whole-row path."""
+    from horovod_tpu.models import transformer
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiled_lm
+    prompts = [list(range(1, 101)), [7, 8, 9], list(range(20, 50))]
+
+    def serve(engine):
+        firsts = [engine.prefill(slot, prompt).collect()[0]
+                  for slot, prompt in enumerate(prompts)]
+        rows, out = [0, 1, 2], [firsts]
+        for step in range(40):
+            ids, _ = engine.decode(
+                rows, out[-1], [len(p) + step for p in prompts])
+            out.append(ids)
+        return out
+
+    kernel = DecodeEngine(model, params, num_slots=4)
+    assert kernel._reads_live_tiles
+    got = serve(kernel)
+    monkeypatch.setattr(transformer, "takes_kernel", lambda *_: False)
+    masked = DecodeEngine(model, params, num_slots=4)
+    assert not masked._reads_live_tiles
+    assert masked.stats()["decode_kv_read_share"] is None
+    assert serve(masked) == got
+    assert masked.stats()["decode_kv_read_share"] is None
+
+
+def test_decode_kv_read_share_counts_live_tiles(tiled_lm, tiny_lm):
+    """The counter against the share worked out by hand: each row reads
+    ``position // 128 + 1`` of its two tiles, a row that is not active
+    one; None where the decode program holds no kernel."""
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model, params = tiled_lm
+    engine = DecodeEngine(model, params, num_slots=4)
+    assert engine.stats()["decode_kv_read_share"] is None   # no step yet
+    engine.prefill(0, [1, 2, 3])
+    engine.prefill(2, list(range(1, 131)))
+    engine.decode([0], [5], [3])            # rows at 3, -, -, -: 4 of 8
+    assert engine.stats()["decode_kv_read_share"] == 0.5
+    engine.decode([0, 2], [5, 6], [4, 130])     # 4, -, 130, -: 5 of 8
+    engine.decode([2], [6], [127])              # -, -, 127, -: 4 of 8
+    engine.decode([0, 2], [5, 6], [128, 255])   # 128, -, 255, -: 6 of 8
+    assert engine.stats()["decode_kv_read_share"] == round(19 / 32, 4)
+    assert (engine.kv_tiles_read, engine.kv_tiles_held) == (19, 32)
+
+    # 48 positions are no whole lane tile: the masked path, no share
+    model, params = tiny_lm
+    off_tile = DecodeEngine(model, params, num_slots=2)
+    off_tile.prefill(0, [1, 2, 3])
+    off_tile.decode([0], [5], [3])
+    assert off_tile.stats()["decode_kv_read_share"] is None
+
+
 def test_zero_steady_state_compiles(tiny_lm):
     from horovod_tpu.serve.kv_cache import DecodeEngine
 

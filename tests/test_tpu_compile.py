@@ -196,7 +196,9 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
     aliases the whole donated cache and the token feed, and nothing in
     it copies a cache leaf or loops over one (XLA:TPU turns a batched
     scatter into one serial trip per row; an undonated cache is copied
-    whole, per leaf)."""
+    whole, per leaf). In the decode step nothing but the kernels touches
+    a leaf at all: the attention reads the live lane tiles of each row
+    (ops/pallas/decode_attention), not the whole of it."""
     from horovod_tpu.models.transformer import Transformer
     from horovod_tpu.serve.kv_cache import DecodeEngine
 
@@ -244,10 +246,25 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
     # one cache, whose leaves no layout pads
     assert memory.argument_size_in_bytes <= (1.01 * param_bytes
                                              + eng.cache_bytes())
-    # the one-token write is the in-place kernel, once per cache leaf; the
-    # prefill writes one row's slice and needs none
+    # a layer of the decode step is two in-place writes (one a cache leaf)
+    # and one attention that fetches the rows' live lane tiles itself;
+    # the prefill writes one row's slice, attends over the whole of its
+    # fresh single-row cache and needs no kernel
     assert text.count("tpu_custom_call") == (
-        2 * layers if program == "decode" else 0)
+        3 * layers if program == "decode" else 0)
+    if program == "decode":
+        assert len(re.findall(r"%decode_attention[.\d]* = \S+ custom-call\(",
+                              text)) == layers
+        # nothing but the kernels (and the result tuple) takes a whole
+        # leaf: no fusion reads all 1,024 positions of every slot
+        leaves = set(re.findall(rf"(%[\w.\-]+) = {leaf}", text))
+        readers = [
+            line for line in text.splitlines()
+            if " = " in line and "custom-call(" not in line
+            and " tuple(" not in line
+            and leaves & set(re.findall(r"%[\w.\-]+",
+                                        line.split(" = ", 1)[1]))]
+        assert len(leaves) == 4 * layers and not readers, readers
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_32768"])

@@ -529,6 +529,38 @@ def tiny_lm():
     return model, params
 
 
+@pytest.mark.parametrize("max_seq,shares", [
+    (256, [0.5, 0.75, 1.0]),      # two lane tiles a row: the kernel
+    (48, [None, None, None]),     # off the lane tile: the masked path
+], ids=["kernel", "masked"])
+def test_engine_decode_span_carries_kv_read_share(ring, max_seq, shares):
+    """``engine.decode`` says what share of the rows' lane tiles the
+    step's attention read, where the decode program holds the
+    decode-attention kernel, and carries no such attribute where it
+    reads whole rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models.transformer import Transformer
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    model = Transformer(vocab_size=61, d_model=32, num_layers=1,
+                        num_heads=2, d_ff=64, max_seq=max_seq, causal=True,
+                        dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)["params"]
+    engine = DecodeEngine(model, params, num_slots=2)
+    last = max_seq - 1
+    engine.decode([0], [1], [3]).collect()             # 3, -: 2 of 4
+    engine.decode([0, 1], [1, 2], [4, last]).collect()     # 3 of 4
+    engine.decode([0, 1], [1, 2], [last, last]).collect()  # 4 of 4
+    got = [s.get("kv_read_share") for s in ring.spans()
+           if s["name"] == "engine.decode"]
+    assert got == shares
+    assert [s["rows"] for s in ring.spans()
+            if s["name"] == "engine.decode"] == [1, 2, 2]
+
+
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_replica_loop_and_engine_spans(ring, tiny_lm, paged):
     """The serving loop through a toy engine: one serve.step per pass
