@@ -1,17 +1,22 @@
 """Decoders whose layers differ in kind (flax): block-sparse softmax
-attention beside linear ("lightning") attention, on a modern trunk -
-RMSNorm, SiLU-gated MLP, rotary positions, grouped key/value heads,
-per-head QK-norm, sigmoid output gates, an untied head and muP scalings.
+attention, linear ("lightning") attention with a fixed decay and power
+retention (gated, normalised linear attention of degree 2), on a modern
+trunk - RMSNorm, SiLU-gated MLP, rotary positions, grouped key/value
+heads, per-head QK-norm, sigmoid output gates, an untied head and
+(optional) muP scalings.
 
 :class:`HybridDecoder` reads its layer kinds from ``mixers`` and is what
 ``hvd.serve()`` runs for MiniCPM-SALA (``benchmark/configs/
 minicpm-sala.json``; the plain reference is
-``benchmark/reference_sala.py``). The blocks (:class:`RMSNorm`,
-:class:`GatedMlp`, :func:`rope`, :class:`BlockSparseAttention`,
-:class:`LightningAttention`) are not tied to that model.
+``benchmark/reference_sala.py``) and for Brumby-14B-Base
+(``benchmark/configs/brumby-14b.json``, ``benchmark/
+reference_brumby.py``). The blocks (:class:`RMSNorm`, :class:`GatedMlp`,
+:func:`rope`, :class:`BlockSparseAttention`, :class:`LightningAttention`,
+:class:`PowerRetention`) are not tied to those models.
 
-Serving (``decode=True``) keeps a ``cache`` collection with three kinds
-of leaf, every one with the slot as axis 0:
+Serving (``decode=True``) keeps a ``cache`` collection whose leaves all
+have the slot as axis 0; which kinds it holds depends on the mixers (a
+model of power-retention layers alone has no leaf with a position axis):
 
 * ``cached_key`` / ``cached_value`` ``(slots, kv_heads, head_dim,
   max_seq)`` - a sparse layer's keys and values, positions last, the
@@ -19,7 +24,13 @@ of leaf, every one with the slot as axis 0:
 * ``compressed_key`` ``(slots, kv_heads, head_dim, windows)`` - the
   means of the key windows that block selection scores;
 * ``state`` ``(slots, heads, head_dim, head_dim)`` float32 - a lightning
-  layer's recurrent state, which does not grow with the context.
+  layer's recurrent state, which does not grow with the context;
+* ``state`` ``(slots, kv_heads, head_dim / 2 + 1, head_dim, head_dim)``
+  and ``state_norm`` ``(slots, kv_heads, head_dim / 2 + 1, head_dim)``
+  float32 - a power-retention layer's state, ``D = head_dim (head_dim +
+  1) / 2`` rows of ``head_dim`` values, and its normaliser, both padded to
+  whole rows of distances (``power_features``; 8,256 to 8,320 at width
+  128) in the layout ``ops/pallas/power_retention`` reads.
 
 A call with one token a row is a decode step; a call with more is a
 prefill from position 0, which computes the prompt without the cache and
@@ -28,10 +39,13 @@ softmax is, so a prefill takes the true ``lengths``: the state it leaves
 is the state after ``lengths`` tokens, and with ``lengths`` given the
 head runs on row ``lengths - 1`` alone.
 
-Everything is XLA: the sparse layer computes masked dense attention in
-blocks of queries (each query's selected key blocks are a mask over all
-causal keys), the lightning layer scans chunks. PERF.md says what that
-costs and what a kernel would save.
+The sparse layer computes masked dense attention in blocks of queries
+(each query's selected key blocks are a mask over all causal keys) and
+the lightning layer scans chunks, both in XLA; PERF.md says what that
+costs and what a kernel would save. The power-retention layer scans
+chunks too, and reads its state through two kernels
+(``ops/pallas/power_retention``): XLA would write every query's 8,256
+features to memory first.
 """
 
 from __future__ import annotations
@@ -45,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import write_cache_rows
+from horovod_tpu.ops.pallas import power_retention
 from horovod_tpu.ops.pallas.kv_cache_write import LANES, write_token
 
 Dtype = Any
@@ -52,6 +67,7 @@ F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_SPARSE, LIGHTNING = "block_sparse", "lightning"
+POWER_RETENTION = "power_retention"
 # a masked score: finite, so that a row with nothing to see stays a number
 NEG_INF = -1e30
 
@@ -239,6 +255,229 @@ class LightningAttention(nn.Module):
         o = norm(name="o_norm")(o).reshape(batch, seq, heads * d)
         o = o * jax.nn.sigmoid(gate.astype(F32)).astype(self.dtype)
         return dense(d_model, name="out")(o)
+
+
+# --------------------------------------------------------- power retention
+
+def power_features(x):
+    """``phi(x)``, the symmetric half of ``x (x) x``, scaled so that
+    ``phi(x) . phi(y) = (x . y)^2 / d`` exactly: ``d (d + 1) / 2`` entries
+    (``x_a^2`` once, ``sqrt(2) x_a x_b`` once for each pair ``a != b``),
+    in ``(d/2 + 1) d`` places, whole lane tiles.
+
+    The pairs are laid out by their distance round the head: entry
+    ``o d + a`` is ``x_a x_((a + o) mod d)`` for ``o = 0 .. d/2``; the
+    last distance pairs each ``a < d/2`` with ``a + d/2``, and the other
+    half of its row is zeros. A row of distances is then ``x`` times a
+    rotation of ``x``, and all the rotations at once are one product of
+    ``x`` with a matrix of zeros and ones (exact: in one pass where ``x``
+    is bfloat16, at the highest precision otherwise), so nothing is
+    gathered and nothing is copied lane by lane. ``x``: (..., d) with
+    ``d`` even; float32 out."""
+    d = x.shape[-1]
+    turns = power_retention.turns(d)
+    source = jnp.arange(d)[:, None, None]
+    turn = jnp.arange(turns)[None, :, None]
+    at = jnp.arange(d)[None, None, :]
+    turned = jnp.dot(
+        x, (source == (at + turn) % d).astype(x.dtype).reshape(d, turns * d),
+        precision=None if x.dtype == jnp.bfloat16 else HIGHEST
+    ).reshape(x.shape[:-1] + (turns, d))      # [..., o, a] = x[(a + o) % d]
+    once = (turn < turns - 1) | (at < d // 2)
+    out = (x.astype(F32)[..., None, :] * turned.astype(F32)
+           * jnp.where(once[0], power_retention.turn_weights(d), 0.0))
+    return out.reshape(x.shape[:-1] + (turns * d,))
+
+
+def retention_step(state, norm, q, k, v, log_gate, eps=1e-6):
+    """One token of power retention: ``S = e^g S + phi(k) v^T``,
+    ``z = e^g z + phi(k)``, ``y = phi(q)^T S / (phi(q)^T z + eps)``, in
+    one pass over the state (``ops/pallas/power_retention.step_state``).
+
+    ``state``: (batch, kv_heads, turns, d, d) and ``norm``: (batch,
+    kv_heads, turns, d), float32, the cache's layout (:func:`cache_state`);
+    ``q``: (batch, heads, d), query head ``h`` reading the state of
+    key/value head ``h // (heads / kv_heads)``; ``k``/``v``: (batch,
+    kv_heads, d); ``log_gate``: (batch, kv_heads) float32, at most 0.
+    Everything is float32. Returns the new state and normaliser and ``y``
+    (batch, heads, d)."""
+    batch, heads, d = q.shape
+    groups = k.shape[1]
+    state, norm, num, den = power_retention.step_state(
+        state, norm,
+        q.astype(F32).reshape(batch, groups, heads // groups, d),
+        k.astype(F32), v.astype(F32), jnp.exp(log_gate.astype(F32)))
+    return state, norm, (num / (den[..., None] + eps)).reshape(
+        batch, heads, d)
+
+
+def cache_state(state, norm):
+    """A prefill's state (batch, kv_heads, turns d, d) and normaliser
+    (batch, kv_heads, turns d), both padded to whole rows of distances,
+    as the cache keeps them: (batch, kv_heads, turns, d, d) with the
+    values before the pairs (``[o, e, a]``) and (batch, kv_heads, turns,
+    d)."""
+    d = state.shape[-1]
+    return (jnp.swapaxes(state.reshape(state.shape[:2] + (-1, d, d)), -1, -2),
+            norm.reshape(norm.shape[:2] + (-1, d)))
+
+
+def retention_chunked(q, k, v, log_gate, lengths=None, chunk=256, eps=1e-6,
+                      dtype=jnp.bfloat16):
+    """The same recurrence over a whole sequence from a zero state, by
+    chunks: inside a chunk the masked squares ``(q_i . k_j)^2 / d`` with
+    ``exp(b_i - b_j)``, ``b`` the running sum of the chunk's log-gates;
+    between chunks the state and its normaliser. ``q``: (batch, seq,
+    heads, d); ``k``/``v``: (batch, seq, kv_heads, d); ``log_gate``:
+    (batch, seq, kv_heads) float32.
+
+    A position at or past ``lengths`` neither decays the state nor
+    enters it (its gate is taken as 1 and its key as absent), so the
+    state returned is the state after ``lengths`` tokens of each row;
+    rows of the output past ``lengths`` mean nothing. Matrix operands
+    are ``dtype`` (the features and the state of the between-chunk
+    products among them) and every sum float32; the normaliser sums the
+    features as the state's product rounded them, so that an output is
+    the same weighted mean above and below the line. Every exponent is at
+    most 0. Returns the outputs (batch, seq, heads, d) in ``dtype`` and
+    the state and the normaliser as the cache keeps them
+    (:func:`cache_state`), both float32."""
+    batch, seq, heads, d = q.shape
+    groups = k.shape[2]
+    per = heads // groups
+    # features, state and normaliser keep whole lane tiles throughout
+    # (``power_features``): the places past D are zeros
+    turns = power_retention.turns(d)
+    turn_weights = power_retention.turn_weights(d)
+    chunk = min(chunk, seq)
+    pad = -seq % chunk
+    if pad:
+        q, k, v, log_gate = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, log_gate))
+    if lengths is None:
+        lengths = jnp.full((batch,), seq, jnp.int32)
+    n = (seq + pad) // chunk
+    present = (jnp.arange(n * chunk, dtype=jnp.int32)[None, :]
+               < lengths[:, None])                        # (batch, seq)
+    log_gate = jnp.where(present[..., None], log_gate.astype(F32), 0.0)
+
+    def cut(t, inner):
+        """(batch, seq, kv_heads, ...) as (chunks, batch, kv_heads, chunk,
+        ...): key/value heads before positions, as every product below
+        batches them, so that a chunk's features are made in the order
+        their product reads them and are never transposed."""
+        t = t.reshape((batch, n, chunk, groups) + inner)
+        return jnp.moveaxis(jnp.moveaxis(t, 3, 1), 2, 0)
+
+    at = jnp.arange(chunk)
+    causal = at[:, None] >= at[None, :]                   # (i, j)
+    scale = 1.0 / math.sqrt(d)
+
+    def one(carry, xs):
+        state, norm = carry
+        q_c, k_c, v_c, g_c, here = xs     # (b, g, i, r, d) (b, g, j, d) ...
+        since = jnp.cumsum(g_c, axis=-1)                  # b: (batch, g, i)
+        whole = since[..., -1]                            # (batch, g)
+        # inside the chunk
+        s = jnp.einsum("bgird,bgjd->bgrij", q_c, k_c,
+                       preferred_element_type=F32) * scale
+        apart = since[..., :, None] - since[..., None, :]     # (b, g, i, j)
+        seen = causal & here[:, None, None, :]
+        weight = jnp.where(seen, jnp.exp(jnp.minimum(apart, 0.0)), 0.0)
+        a = s * s * weight[:, :, None]                    # (b, g, r, i, j)
+        num = jnp.einsum("bgrij,bgje->bgire", a.astype(dtype), v_c,
+                         preferred_element_type=F32)
+        den = jnp.moveaxis(a.sum(axis=-1), 2, 3)          # (b, g, i, r)
+        # what came before the chunk: phi(q)^T S and phi(q)^T z, the
+        # features made inside the kernel and their weights put on the
+        # state's side
+        before, total = power_retention.read_state(
+            q_c.reshape(batch * groups, chunk * per, d),
+            (state.reshape(batch * groups, turns, d, d)
+             * turn_weights[..., None]).astype(dtype),
+            norm.reshape(batch * groups, turns, d) * turn_weights)
+        grown = jnp.exp(since)[..., None]                 # (b, g, i, 1)
+        num = num + grown[..., None] * before.reshape(num.shape)
+        den = den + grown * total.reshape(den.shape)
+        out = (num / (den[..., None] + eps)).astype(dtype)
+        # the state after the chunk's present tokens
+        left = jnp.where(here[:, None, :],
+                         jnp.exp(whole[..., None] - since), 0.0)  # (b, g, j)
+        f_k = (power_features(k_c) * left[..., None]).astype(dtype)
+        #                                                   (b, g, j, n)
+        kept = jnp.exp(whole)
+        state = kept[..., None, None] * state + jnp.einsum(
+            "bgjn,bgje->bgne", f_k, v_c, preferred_element_type=F32)
+        norm = kept[..., None] * norm + f_k.astype(F32).sum(axis=2)
+        return (state, norm), out
+
+    carry = (jnp.zeros((batch, groups, turns * d, d), F32),
+             jnp.zeros((batch, groups, turns * d), F32))
+    (state, norm), out = jax.lax.scan(one, carry, (
+        cut(q, (per, d)), cut(k, (d,)), cut(v, (d,)), cut(log_gate, ()),
+        jnp.moveaxis(present.reshape(batch, n, chunk), 1, 0)))
+    # (chunks, batch, g, chunk, r, d) -> (batch, positions, heads, d)
+    out = out.transpose(1, 0, 3, 2, 4, 5).reshape(batch, n * chunk, heads, d)
+    return (out[:, :seq],) + cache_state(state, norm)
+
+
+class PowerRetention(nn.Module):
+    """Power retention of degree 2 over grouped heads: QK-norm, rotary
+    positions, one log-sigmoid gate a token a key/value head (float32,
+    with a bias), the recurrence and its normaliser; no output gate and
+    no output norm beyond the division."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    den_eps: float = 1e-6
+    chunk: int = 256
+    decode: bool = False
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = F32
+
+    @nn.compact
+    def __call__(self, x, positions, lengths=None):
+        batch, seq, d_model = x.shape
+        heads, groups, d = self.num_heads, self.num_kv_heads, self.head_dim
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        norm = partial(RMSNorm, eps=self.eps, dtype=self.dtype,
+                       param_dtype=self.param_dtype)
+        q = norm(name="q_norm")(
+            dense(heads * d, name="query")(x).reshape(batch, seq, heads, d))
+        k = norm(name="k_norm")(
+            dense(groups * d, name="key")(x).reshape(batch, seq, groups, d))
+        v = dense(groups * d, name="value")(x).reshape(batch, seq, groups, d)
+        log_gate = jax.nn.log_sigmoid(nn.Dense(
+            groups, dtype=F32, param_dtype=self.param_dtype,
+            name="gate")(x))
+        at = positions[:, None] + jnp.arange(seq, dtype=jnp.int32)[None, :]
+        q = rope(q, at, self.rope_theta).astype(self.dtype)
+        k = rope(k, at, self.rope_theta).astype(self.dtype)
+        turns = power_retention.turns(d)
+        if self.decode:
+            state = self.variable("cache", "state", jnp.zeros,
+                                  (batch, groups, turns, d, d), F32)
+            total = self.variable("cache", "state_norm", jnp.zeros,
+                                  (batch, groups, turns, d), F32)
+        if self.decode and seq == 1:
+            with jax.named_scope("retention_step"):
+                state.value, total.value, o = retention_step(
+                    state.value, total.value, q[:, 0], k[:, 0], v[:, 0],
+                    log_gate[:, 0], self.den_eps)
+            o = o.astype(self.dtype)[:, None]
+        else:
+            with jax.named_scope("retention_chunk"):
+                o, last, last_norm = retention_chunked(
+                    q, k, v, log_gate, lengths, self.chunk, self.den_eps,
+                    self.dtype)
+            if self.decode:
+                state.value, total.value = last, last_norm
+        return dense(d_model, name="out")(o.reshape(batch, seq, heads * d))
 
 
 # ------------------------------------------------------------- block sparse
@@ -510,8 +749,8 @@ class BlockSparseAttention(nn.Module):
 
 class HybridLayer(nn.Module):
     """``h += a Mixer(norm(h)); h += a Mlp(norm(h))`` with the residual
-    scale ``a`` (muP's ``scale_depth / sqrt(depth)``); ``kind`` says
-    which mixer, ``mixer_args`` are its fields."""
+    scale ``a`` (muP's ``scale_depth / sqrt(depth)``, or 1); ``kind``
+    says which mixer, ``mixer_args`` are its fields."""
 
     kind: str
     mixer_args: Any
@@ -526,7 +765,8 @@ class HybridLayer(nn.Module):
         common = dict(eps=self.eps, dtype=self.dtype,
                       param_dtype=self.param_dtype)
         mixer = {LIGHTNING: LightningAttention,
-                 BLOCK_SPARSE: BlockSparseAttention}[self.kind](
+                 BLOCK_SPARSE: BlockSparseAttention,
+                 POWER_RETENTION: PowerRetention}[self.kind](
                      name="mixer", **dict(self.mixer_args), **common)
         norm = partial(RMSNorm, **common)
         a = jnp.asarray(self.residual_scale, self.dtype)
@@ -539,12 +779,14 @@ class HybridLayer(nn.Module):
 
 class HybridDecoder(nn.Module):
     """Embedding, ``len(mixers)`` layers of the kinds ``mixers`` names
-    (``"block_sparse"`` / ``"lightning"``), final RMSNorm, untied head.
+    (``"block_sparse"`` / ``"lightning"`` / ``"power_retention"``), final
+    RMSNorm, untied head.
 
     ``layer_indices`` gives each layer's index in the published model
     (a lightning layer's decay depends on it) and ``published_depth`` the
     published number of layers, which the residual scale and the decay
-    keep when the depth is cut. ``causal``, ``max_seq``, ``vocab_size``
+    keep when the depth is cut. The muP scalings are neutral at their
+    defaults, ``scale_depth=None`` meaning a residual scale of 1. ``causal``, ``max_seq``, ``vocab_size``
     and ``clone(decode=..., remat=..., attention_fn=...)`` are what
     ``serve.kv_cache.DecodeEngine`` asks of a model (``remat`` and
     ``attention_fn`` are accepted for that and not used)."""
@@ -560,7 +802,7 @@ class HybridDecoder(nn.Module):
     layer_indices: Optional[Tuple[int, ...]] = None
     published_depth: Optional[int] = None
     scale_emb: float = 1.0
-    scale_depth: float = 1.0
+    scale_depth: Optional[float] = 1.0
     dim_model_base: Optional[int] = None
     rope_theta: float = 10000.0
     eps: float = 1e-6
@@ -592,6 +834,11 @@ class HybridDecoder(nn.Module):
                         num_kv_heads=self.num_kv_heads,
                         head_dim=self.head_dim, sparse=self.sparse,
                         max_cache_len=self.max_seq, decode=self.decode)
+        if kind == POWER_RETENTION:
+            return dict(num_heads=self.num_heads,
+                        num_kv_heads=self.num_kv_heads,
+                        head_dim=self.head_dim, rope_theta=self.rope_theta,
+                        decode=self.decode)
         raise ValueError(f"unknown mixer {kind!r}")
 
     @nn.compact
@@ -624,7 +871,8 @@ class HybridDecoder(nn.Module):
             h = HybridLayer(
                 kind=kind, mixer_args=self._mixer_args(i, kind),
                 d_ff=self.d_ff,
-                residual_scale=self.scale_depth / math.sqrt(depth),
+                residual_scale=(1.0 if self.scale_depth is None
+                                else self.scale_depth / math.sqrt(depth)),
                 eps=self.eps, dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name=f"layer_{i}")(h, positions, lengths)

@@ -3,9 +3,10 @@
 :class:`DecodeEngine` owns everything jax about one replica:
 
 * the decode clone of the user's model (``model.clone(decode=True)`` —
-  same params, plus a ``cache`` variable collection of
-  ``(slots, heads, head_dim, max_seq)`` key/value tensors per layer,
-  positions last: the layout attention reads);
+  same params, plus a ``cache`` variable collection: for a softmax layer
+  ``(slots, heads, head_dim, max_seq)`` key/value tensors, positions
+  last, the layout attention reads; for a recurrent layer its state,
+  which has no position axis);
 * ONE jitted decode program over ALL slots every step — the shape never
   changes (inactive rows run masked garbage at position 0, overwritten
   by the next prefill), so steady-state decode never recompiles;
@@ -37,11 +38,18 @@ against the uncached ``apply``.
 
 The cache is whatever pytree the model's ``cache`` collection declares,
 every leaf with the slot as axis 0: ``models/hybrid.py`` keeps keys and
-values, compressed keys and a float32 recurrent state side by side
-(:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). A recurrence is not
-indifferent to padding, so a prefill hands the model the true
-``lengths``: the state it leaves is the state after the prompt, and a
-prefill overwrites every leaf's row of its slot, the state included.
+values, compressed keys and float32 recurrent states side by side, or
+states alone - a model of power-retention layers has no leaf with a
+position axis at all, and its decode step rewrites its whole cache
+(:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). Nothing here asks a leaf
+for more than the slot axis. A recurrence is not indifferent to padding,
+so a prefill hands the model the true ``lengths``: the state it leaves
+is the state after the prompt, and a prefill overwrites every leaf's row
+of its slot, the state included. A row that is not active still runs
+(token 0 at position 0, every step): its keys land where the next
+prefill overwrites them, and its state is a gated running sum of one
+token's features, which stays finite, until the next prefill replaces
+it.
 With ``lengths`` the model applies its head to the last prompt row
 alone - the (bucket, vocab) logits never exist.
 
@@ -123,9 +131,12 @@ def prompt_bucket(prompt_len: int, max_seq: int,
 
 # what a cache leaf holds, by the name its model gave the variable: keys
 # and values that grow with the context, compressed keys that a sparse
-# layer selects blocks by, a recurrent state that does not grow
+# layer selects blocks by, a recurrent state (and a normalised one's
+# running sum of features) that does not grow. A model need not have
+# every kind: one of recurrent layers alone holds states and nothing else
 CACHE_KINDS = {"cached_key": "kv", "cached_value": "kv",
-               "compressed_key": "compressed", "state": "state"}
+               "compressed_key": "compressed", "state": "state",
+               "state_norm": "state"}
 
 
 def leaf_kind(path) -> str:
@@ -436,7 +447,7 @@ class DecodeEngine:
                 # lane tiles of a key/value leaf the decode steps read
                 # over the tiles of all rows; None where the decode
                 # program reads whole rows (no decode-attention kernel
-                # in it) or has not run
+                # in it), holds no keys or values at all, or has not run
                 "decode_kv_read_share": (
                     round(self.kv_tiles_read / self.kv_tiles_held, 4)
                     if self.kv_tiles_held else None),

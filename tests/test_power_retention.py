@@ -1,0 +1,496 @@
+"""Power retention (``horovod_tpu/models/hybrid.py``: gated, normalised
+linear attention of degree 2 over grouped heads) and the dense serving
+engine over a cache of states alone, against the plain reference
+(``benchmark/reference_brumby.py``: the attention form in float32, which
+squares ``q . k`` and never builds a feature, a state or a normaliser) at
+toy sizes with seeded weights.
+
+Tolerances, on logits whose standard deviation is about 0.23 here:
+
+* ``F32_TOL`` 5e-5 - program and reference both in float32 on the CPU.
+  What differs is the order of float32 sums and, more than in any other
+  model here, the *form*: the reference squares one 32-term dot product,
+  the program sums 528 products of features whose terms cancel, so its
+  weights carry an absolute error of about 1e-7 where the reference's
+  carry a relative one. Measured 2e-6 to 1e-5 on logits.
+* ``BF16_TOL`` 6e-2 - the program in bfloat16 (activations and matrix
+  operands, the features and the state of a prefill's between-chunk
+  products among them) against the float32 reference: measured 1.7e-2 to
+  2.0e-2 over three token draws, so three times the sound reading. The
+  float8 control (the reference's dense multiplications in float8_e4m3)
+  reads 0.13 to 0.17 on the same inputs, so it fails both.
+* the forms against one another (no model round them): 1e-4 on outputs
+  of size ~3 where the normaliser is at least 0.1; under it the
+  recurrent form's absolute error in a weight (about 1e-6 here) is no
+  longer small beside the sum of the weights, and what is compared is
+  the error times the normaliser, that is the numerator.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_brumby as ref
+from benchmark import weights_brumby
+from benchmark.runners.serve_brumby import build_model
+from horovod_tpu.models import hybrid
+from horovod_tpu.serve.kv_cache import DecodeEngine, prompt_bucket
+
+F32_TOL, BF16_TOL = 5e-5, 6e-2
+CFG = dict(vocab_size=512, d_model=128, d_ff=256, num_heads=4,
+           num_kv_heads=2, head_dim=32, num_layers=2, layer_indices=[0, 1],
+           published_depth=40, rope_theta=1000000, rms_norm_eps=1e-6,
+           retention_eps=1e-6, dim_model_base=None, max_seq=1024,
+           dtype="float32",
+           param_dtype="bfloat16")
+SEED = 7
+HEADS, GROUPS, D = 4, 2, 32
+WIDTH = D * (D + 1) // 2
+TURNS = D // 2 + 1          # rows of distances: the cache pads to these
+
+_forward = jax.jit(ref.forward, static_argnums=(2, 3))
+
+
+@functools.lru_cache(maxsize=None)
+def weights():
+    return weights_brumby.make_params(CFG, SEED)
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(1, CFG["vocab_size"], n)
+
+
+def reference(toks, precision="f32"):
+    return np.asarray(_forward(weights(), jnp.asarray(toks, jnp.int32),
+                               ref.frozen(CFG), precision))
+
+
+@pytest.fixture(scope="module")
+def served():
+    return weights(), build_model(CFG)
+
+
+# ----------------------------------------------------------- the mechanism
+
+def test_features_square_the_dot_product():
+    """``phi(x) . phi(y) = (x . y)^2 / d`` to float32 rounding, for every
+    pair of 64 random vectors, and ``phi`` has d (d + 1) / 2 entries in
+    its d/2 + 1 rows of d places: the last row's other half is zeros."""
+    rng = np.random.default_rng(0)
+    for d in (32, 128):
+        x = jnp.asarray(rng.normal(size=(64, d)), jnp.float32)
+        f = np.asarray(hybrid.power_features(x), np.float64)
+        assert f.shape == (64, (d // 2 + 1) * d)
+        assert (f[:, d * (d + 1) // 2:] == 0).all()
+        assert (f[:, :d * (d + 1) // 2] != 0).all()
+        x = np.asarray(x, np.float64)
+        want = (x @ x.T) ** 2 / d
+        assert np.abs(f @ f.T - want).max() < 1e-5 * want.max()
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+def test_the_read_kernel_is_the_product_with_the_features(dtype, tol):
+    """``ops/pallas/power_retention.read_state`` (interpret mode here)
+    against ``phi(q)^T S`` and ``phi(q)^T z`` with the features written
+    out: the kernel makes each row of distances as ``q`` times a rotation
+    of ``q`` and takes the rows' weights from the state's side. In
+    bfloat16 both round the features, not in the same order (the kernel
+    before the weight, the written-out form after it)."""
+    from horovod_tpu.ops.pallas import power_retention
+
+    rng = np.random.default_rng(6)
+    batch, rows = 3, 512
+    turns = power_retention.turns(D)
+    q = jnp.asarray(rng.normal(size=(batch, rows, D)), dtype)
+    state = jnp.asarray(rng.normal(size=(batch, WIDTH, D)), jnp.float32)
+    norm = jnp.asarray(rng.random(size=(batch, WIDTH)), jnp.float32)
+    grow = ((0, 0), (0, turns * D - WIDTH))
+    weights = power_retention.turn_weights(D)
+    num, den = power_retention.read_state(
+        q, (jnp.pad(state, grow + ((0, 0),)).reshape(batch, turns, D, D)
+            * weights[..., None]).astype(dtype),
+        jnp.pad(norm, grow).reshape(batch, turns, D) * weights)
+    f_q = hybrid.power_features(q)[..., :WIDTH].astype(dtype).astype(
+        jnp.float32)
+    rounded = state.astype(dtype).astype(jnp.float32)
+    want_num = np.einsum("bmn,bne->bme", f_q, rounded)
+    want_den = np.einsum("bmn,bn->bm", f_q, norm)
+    assert np.abs(np.asarray(num) - want_num).max() \
+        < tol * np.abs(want_num).max()
+    assert np.abs(np.asarray(den) - want_den).max() \
+        < tol * np.abs(want_den).max()
+    # 640 queries (a 128-token chunk of five heads a group) go in blocks
+    # of 160; a count past a block that no 16 divides is refused
+    more = jnp.concatenate([q, q[:, :128]], axis=1)
+    padded = (jnp.pad(state, grow + ((0, 0),)).reshape(batch, turns, D, D)
+              * weights[..., None]).astype(dtype)
+    again, _ = power_retention.read_state(
+        more, padded, jnp.pad(norm, grow).reshape(batch, turns, D) * weights)
+    assert np.array_equal(np.asarray(again[:, :rows]), np.asarray(num))
+    with pytest.raises(ValueError, match="multiple of 16"):
+        power_retention.read_state(more[:, :300], padded, norm)
+
+
+def gates(kind, rng, shape):
+    """Log-gates: spread over (-1.5, 0); all but open (a memory of ten
+    thousand tokens); all but shut (each token forgets the last)."""
+    return {"random": -0.5 * np.abs(rng.normal(size=shape)),
+            "near_0": -1e-4 * rng.random(shape),
+            "near_-10": -10.0 + 0.1 * rng.normal(size=shape)}[kind]
+
+
+def token_by_token(q, k, v, g):
+    """The recurrence over (batch, seq, ...) inputs from a zero state:
+    the outputs (batch, seq, heads, d), the state and the normaliser
+    after every token (seq, batch, ...) and every query's normalising
+    sum (batch, seq, heads, 1)."""
+    out, states, norms, den = map(np.asarray, _token_by_token(q, k, v, g))
+    return (np.moveaxis(out, 0, 1), states, norms,
+            np.moveaxis(den, 0, 1)[..., None])
+
+
+@jax.jit
+def _token_by_token(q, k, v, g):
+    batch = q.shape[0]
+
+    def one(carry, xs):
+        q_t, k_t, v_t, g_t = xs
+        state, norm, o = hybrid.retention_step(*carry, q_t, k_t, v_t, g_t)
+        f_q = hybrid.power_features(q_t).reshape(
+            batch, GROUPS, -1, TURNS * D)
+        den = jnp.einsum("bgrn,bgn->bgr", f_q,
+                         norm.reshape(batch, GROUPS, -1)).reshape(batch, HEADS)
+        return (state, norm), (o, state, norm, den)
+
+    carry = (jnp.zeros((batch, GROUPS, TURNS, D, D), jnp.float32),
+             jnp.zeros((batch, GROUPS, TURNS, D), jnp.float32))
+    return jax.lax.scan(
+        one, carry, tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g)))[1]
+
+
+@pytest.mark.parametrize("kind", ["random", "near_0", "near_-10"])
+@pytest.mark.parametrize("length,chunk", [(75, 16), (64, 64), (128, 256)])
+def test_the_three_forms_agree(kind, length, chunk):
+    """Attention form (the reference's) against chunked against token by
+    token, and the chunked form's state after each row's own length
+    against the recurrence's after as many tokens."""
+    rng = np.random.default_rng(1)
+    q = jnp.asarray(rng.normal(size=(2, length, HEADS, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, length, GROUPS, D)), jnp.float32)
+            for _ in range(2))
+    g = jnp.asarray(gates(kind, rng, (2, length, GROUPS)), jnp.float32)
+    lengths = [length, length - 9]
+    want = np.stack([np.asarray(ref.retention(q[b], k[b], v[b], g[b], 1e-6))
+                     for b in range(2)])
+    out, last, last_norm = hybrid.retention_chunked(
+        q, k, v, g, jnp.asarray(lengths, jnp.int32), chunk, 1e-6,
+        jnp.float32)
+    steps, states, norms, den = token_by_token(q, k, v, g)
+    for got, rows in ((steps, (length, length)), (np.asarray(out), lengths)):
+        for b, n in enumerate(rows):
+            err = np.abs(got[b, :n] - want[b, :n])
+            small = np.broadcast_to(den[b, :n] < 0.1, err.shape)
+            assert err[~small].max() < 1e-4
+            assert (err * den[b, :n])[small].max(initial=0.0) < 1e-5
+    # the state after each row's own length, not after the padding
+    for b, n in enumerate(lengths):
+        for got, then in ((last, states), (last_norm, norms)):
+            assert np.abs(np.asarray(got[b]) - then[n - 1, b]).max() < 1e-4
+    assert np.abs(np.asarray(last[1]) - states[-1, 1]).max() > 1e-2
+
+
+def test_grouped_heads_read_their_own_groups_state():
+    """Two query heads a key/value head: heads 0 and 1 read group 0,
+    heads 2 and 3 group 1. With group 1's values zeroed its heads'
+    outputs vanish and group 0's do not change, in every form."""
+    rng = np.random.default_rng(2)
+    length = 40
+    q = jnp.asarray(rng.normal(size=(1, length, HEADS, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, length, GROUPS, D)), jnp.float32)
+            for _ in range(2))
+    g = jnp.asarray(gates("random", rng, (1, length, GROUPS)), jnp.float32)
+    cut = v.at[:, :, 1].set(0.0)
+    whole, _, _ = hybrid.retention_chunked(q, k, v, g, None, 16, 1e-6,
+                                           jnp.float32)
+    part, _, _ = hybrid.retention_chunked(q, k, cut, g, None, 16, 1e-6,
+                                          jnp.float32)
+    want = np.asarray(ref.retention(q[0], k[0], cut[0], g[0], 1e-6))
+    assert np.abs(np.asarray(part)[0] - want).max() < 1e-4
+    assert np.abs(np.asarray(part)[0, :, 2:]).max() == 0.0
+    assert np.array_equal(np.asarray(part)[0, :, :2],
+                          np.asarray(whole)[0, :, :2])
+    assert np.abs(np.asarray(whole)[0, :, 2:]).max() > 0.1
+    steps = token_by_token(q, k, cut, g)[0]
+    assert np.abs(steps[0, :, 2:]).max() == 0.0
+    assert np.abs(steps[0] - want).max() < 1e-4
+
+
+def test_a_padded_prefill_leaves_the_unpadded_ones_state():
+    """``lengths`` in a padded batch: the state, the normaliser and the
+    rows before the length are those of the same tokens unpadded (to the
+    order of float32 sums: the chunks fall elsewhere), whatever the
+    padding holds."""
+    rng = np.random.default_rng(3)
+    n, padded = 83, 128
+    q = jnp.asarray(rng.normal(size=(1, padded, HEADS, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, padded, GROUPS, D)), jnp.float32)
+            for _ in range(2))
+    g = jnp.asarray(gates("random", rng, (1, padded, GROUPS)), jnp.float32)
+    short = hybrid.retention_chunked(q[:, :n], k[:, :n], v[:, :n], g[:, :n],
+                                     None, 32, 1e-6, jnp.float32)
+    long = hybrid.retention_chunked(q, k, v, g, jnp.asarray([n], jnp.int32),
+                                    32, 1e-6, jnp.float32)
+    assert np.abs(np.asarray(long[0])[:, :n]
+                  - np.asarray(short[0])).max() < 1e-5
+    for a, b in zip(long[1:], short[1:]):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-5
+    unmasked = hybrid.retention_chunked(q, k, v, g, None, 32, 1e-6,
+                                        jnp.float32)
+    assert np.abs(np.asarray(unmasked[1]) - np.asarray(short[1])).max() > 1e-2
+
+
+# --------------------------------------------------------------- the model
+
+@pytest.mark.parametrize("length", [100, 301])
+def test_forward_matches_the_plain_reference(served, length):
+    params, model = served
+    toks = tokens(length)
+    got = np.asarray(model.apply({"params": params},
+                                 jnp.asarray(toks)[None]))[0]
+    want = reference(toks)
+    assert np.abs(got - want).max() < F32_TOL
+    control = reference(toks, "fp8")
+    assert np.abs(control - want).max() > 100 * F32_TOL
+
+
+def test_parameter_layout_is_the_weight_makers(served):
+    params, model = served
+    init = model.init(jax.random.PRNGKey(0),
+                      jnp.zeros((1, 8), jnp.int32))["params"]
+    assert jax.tree.structure(init) == jax.tree.structure(params)
+    assert all(a.shape == b.shape for a, b in zip(
+        jax.tree.leaves(init), jax.tree.leaves(params)))
+    assert weights_brumby.count(CFG) == sum(
+        x.size for x in jax.tree.leaves(params))
+    # the published widths: 330.3M parameters a layer, 1,555.8M in the
+    # embedding and the head
+    full = dict(vocab_size=151936, d_model=5120, d_ff=17408, num_heads=40,
+                num_kv_heads=8, head_dim=128)
+    one, none = (weights_brumby.count(dict(full, num_layers=n))
+                 for n in (1, 0))
+    assert none == 2 * 151936 * 5120 + 5120
+    assert 330.3e6 < one - none < 330.4e6
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_bfloat16_forward_stays_within_rounding_of_the_reference(served,
+                                                                 seed):
+    params, _ = served
+    toks = tokens(301, seed=seed)
+    got = np.asarray(build_model(dict(CFG, dtype="bfloat16")).apply(
+        {"params": params}, jnp.asarray(toks)[None]))[0]
+    want = reference(toks)
+    assert np.abs(got - want).max() < BF16_TOL
+    assert np.abs(reference(toks, "fp8") - want).max() > BF16_TOL
+
+
+def test_neutral_scalings_leave_the_trunk_alone():
+    """``scale_depth=None`` is a residual scale of 1 whatever the depth
+    (the model has no muP scalings), not ``1 / sqrt(depth)``."""
+    toks = jnp.asarray(tokens(20))[None]
+    plain = build_model(CFG)
+    scaled = plain.clone(scale_depth=1.0)
+    params = weights()
+    a = np.asarray(plain.apply({"params": params}, toks))
+    b = np.asarray(scaled.apply({"params": params}, toks))
+    assert np.abs(a - b).max() > 1e-2
+
+
+# -------------------------------------------------------------- the engine
+
+@functools.lru_cache(maxsize=None)
+def _step(model):
+    return jax.jit(lambda p, c, t, q: model.apply(
+        {"params": p, "cache": c}, t, positions=q, train=False,
+        mutable=["cache"]))
+
+
+def step_logits(engine, step_tokens, positions):
+    """One decode step over all of the engine's rows, as ``_decode_impl``
+    runs it, returning the logits it would take the argmax of."""
+    logits, mutated = _step(engine._model)(
+        engine._params, engine._cache,
+        jnp.asarray(step_tokens, jnp.int32)[:, None],
+        jnp.asarray(positions, jnp.int32))
+    engine._cache = mutated["cache"]
+    return np.asarray(logits[:, 0])
+
+
+# 203 is no multiple of the chunk (256 at this width: one chunk) and its
+# bucket is 256; 300's is 512, two chunks, the second mostly padding
+@pytest.mark.parametrize("prompt_len", [203, 61, 300])
+def test_prefill_then_decode_is_the_references_one_forward(served,
+                                                           prompt_len):
+    params, model = served
+    total = prompt_len + 40
+    toks = tokens(total, seed=prompt_len)
+    want = reference(toks)
+    engine = DecodeEngine(model, params, num_slots=3)
+    assert prompt_bucket(prompt_len, model.max_seq) > prompt_len
+    first, max_abs = engine.prefill(1, toks[:prompt_len].tolist())
+    assert first == want[prompt_len - 1].argmax()
+    assert abs(max_abs - np.abs(want[prompt_len - 1]).max()) < F32_TOL
+    for t in range(prompt_len, total):       # teacher forced
+        step = np.zeros(3, np.int64)
+        at = np.zeros(3, np.int64)
+        step[1], at[1] = toks[t], t
+        got = step_logits(engine, step, at)[1]
+        assert np.abs(got - want[t]).max() < F32_TOL, t
+
+
+def test_the_cache_holds_states_and_nothing_else(served):
+    """No leaf with a position axis: every byte is ``state``, the
+    key/value read share is ``None`` (nothing to divide by, and no
+    warning), and the donation is taken."""
+    import warnings
+
+    params, model = served
+    engine = DecodeEngine(model, params, num_slots=2)
+    by_kind = engine.cache_bytes_by_kind()
+    assert by_kind == {"kv": 0, "compressed": 0,
+                       "state": 2 * 2 * GROUPS * TURNS * D * (D + 1) * 4}
+    assert engine.cache_bytes() == by_kind["state"]
+    leaves = jax.tree_util.tree_leaves_with_path(engine._cache)
+    assert sorted(x.shape for _, x in leaves) == sorted(
+        [(2, GROUPS, TURNS, D, D), (2, GROUPS, TURNS, D)] * 2)
+    assert not engine._reads_live_tiles and engine._dense_len is None
+    first, _ = engine.prefill(0, tokens(41).tolist())
+    engine.decode([0], [first], [41]).collect()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        stats = engine.stats()
+    assert stats["decode_kv_read_share"] is None
+    assert stats["cache_bytes_by_kind"] == by_kind
+    assert stats["cache_donated"] is True
+
+
+def test_the_state_after_the_padding_would_be_seen(served):
+    """The broken path the true length guards against: a prefill that
+    hands the model the bucket in place of the prompt's length leaves the
+    state after the padding, and the next logits are off by far more
+    than any tolerance here."""
+    params, model = served
+    toks = tokens(204, seed=30)
+    want = reference(toks)
+    engine = DecodeEngine(model, params, num_slots=1)
+    padded = np.zeros((1, 256), np.int32)
+    padded[0, :203] = toks[:203]
+    _, mutated = engine._model.apply(
+        {"params": params}, jnp.asarray(padded),
+        positions=jnp.zeros((1,), jnp.int32),
+        lengths=jnp.asarray([256], jnp.int32), train=False,
+        mutable=["cache"])
+    engine._cache = mutated["cache"]
+    got = step_logits(engine, [toks[203]], [203])[0]
+    assert np.abs(got - want[203]).max() > 100 * F32_TOL
+
+
+def test_a_slot_is_reused_after_a_longer_occupant(served):
+    params, model = served
+    engine = DecodeEngine(model, params, num_slots=2)
+    long = tokens(330, seed=20)
+    engine.prefill(0, long[:300].tolist())
+    for t in range(300, 330):
+        engine.decode([0], [int(long[t])], [t])
+    short = tokens(170, seed=21)
+    want = reference(short)
+    first, _ = engine.prefill(0, short[:150].tolist())
+    assert first == want[149].argmax()
+    for t in range(150, 170):
+        got = step_logits(engine, [short[t], 0], [t, 0])[0]
+        assert np.abs(got - want[t]).max() < F32_TOL, t
+
+
+def test_an_idle_slots_state_stays_finite(served):
+    """The decode program runs every slot every step: a row that is not
+    active runs token 0 at position 0 and rewrites its state. That state
+    is a geometric series in the token's own gates (the slowest keeps
+    0.999 a step), so it nears a finite fixed point: after three
+    thousand steps it is finite and has all but stopped growing, and the
+    active row beside it is untouched by it."""
+    params, model = served
+    engine = DecodeEngine(model, params, num_slots=2)
+    toks = tokens(60, seed=40)
+    want = reference(toks)
+    first, _ = engine.prefill(0, toks[:20].tolist())
+    assert first == want[19].argmax()
+
+    @jax.jit
+    def idle(cache, n):
+        def one(_, cache):
+            _, mutated = engine._model.apply(
+                {"params": params, "cache": cache},
+                jnp.zeros((2, 1), jnp.int32),
+                positions=jnp.zeros((2,), jnp.int32), train=False,
+                mutable=["cache"])
+            # only row 1 idles: row 0 keeps the prompt's state
+            return jax.tree.map(lambda old, new: old.at[1].set(new[1]),
+                                cache, mutated["cache"])
+        return jax.lax.fori_loop(0, n, one, cache)
+
+    before = idle(engine._cache, 2000)
+    engine._cache = idle(before, 1000)
+    for (_, then), (_, now) in zip(
+            jax.tree_util.tree_leaves_with_path(before),
+            jax.tree_util.tree_leaves_with_path(engine._cache)):
+        now, then = np.asarray(now[1]), np.asarray(then[1])
+        assert np.isfinite(now).all()
+        assert np.abs(now).max() <= 1.5 * np.abs(then).max()
+    for t in range(20, 30):
+        got = step_logits(engine, [toks[t], 0], [t, 0])[0]
+        assert np.abs(got - want[t]).max() < F32_TOL, t
+
+
+def test_the_paged_engine_refuses_a_model_without_pages(served):
+    from horovod_tpu.serve.paging import PagedDecodeEngine
+
+    params, model = served
+    with pytest.raises(ValueError, match="has no paged cache .* recurrent "
+                                         "state"):
+        PagedDecodeEngine(model, params, num_slots=2)
+
+
+def test_serving_through_hvd_serve(served):
+    """The model behind the public entry point: ``hvd.serve()`` ->
+    ``Replica`` -> ``ContinuousBatcher`` -> ``DecodeEngine``, the same
+    path as every dense model; more requests than slots, so that slots
+    are reused."""
+    import horovod_tpu as hvd
+
+    params, model = served
+    hvd.init()
+    try:
+        handle = hvd.serve(model, params, slots=2, max_new_tokens=8,
+                           max_batch_tokens=2048)
+        try:
+            prompts = [tokens(n, seed=n).tolist() for n in (150, 37, 260)]
+            uids = [handle.submit(p, max_new_tokens=8) for p in prompts]
+            for prompt, uid in zip(prompts, uids):
+                done = handle.result(uid, timeout=300.0)
+                full = np.asarray(prompt + list(done.tokens))
+                want = reference(full)
+                rows = want[len(prompt) - 1:len(full) - 1]
+                assert list(done.tokens) == rows.argmax(-1).tolist()
+            engine = handle.stats()["replicas"][0]["engine"]
+            assert engine["cache_bytes_by_kind"]["state"] \
+                == engine["cache_bytes"] > 0
+            assert engine["decode_kv_read_share"] is None
+            with pytest.raises(ValueError, match="no paged cache"):
+                hvd.serve(model, params, slots=2, paged=True)
+        finally:
+            handle.close()
+    finally:
+        hvd.shutdown()

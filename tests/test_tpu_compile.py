@@ -12,7 +12,7 @@ right (the interpret-mode tests and ``chip_smoke.py`` say that).
 Kernels, and the dense serving engine's two programs (whose cost is in
 what the compiler makes of the KV-cache update, not in a kernel's
 arithmetic); skipped where the topology cannot be described, and kept
-under half a minute in total.
+to about a minute in total.
 """
 
 import os
@@ -326,6 +326,84 @@ def test_hybrid_engine_fits_and_updates_its_cache_in_place(chip, monkeypatch,
     # the decode step writes one key, one value and one compressed key a
     # row through the in-place kernel; the prefill writes slices
     assert text.count("tpu_custom_call") == (3 if program == "decode" else 0)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_16384"])
+def test_retention_engine_fits_and_rewrites_its_cache_in_place(
+        chip, monkeypatch, capsys, program):
+    """Brumby-14B-Base as the benchmark runs it (benchmark/configs/
+    brumby-14b.json: four power-retention layers at full width, 32 slots,
+    bfloat16 weights): the 32-row decode step and the prefill of the
+    largest bucket the cell reaches compile for one v5e chip - Mosaic
+    takes both kernels of ops/pallas/power_retention at these shapes -
+    arguments plus temporaries stay under its 16 GB (printed: run with
+    ``-s``), the result aliases every leaf of the donated cache - which
+    holds states and normalisers and no leaf with a position axis - and
+    the token feed, and nothing copies a state or a normaliser: the
+    decode step's kernel rewrites all 4.4 GB of it where it lies."""
+    import json
+
+    from benchmark.runners.serve_brumby import build_model
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "brumby-14b.json")) as f:
+        cfg = json.load(f)["as_run"]
+    layers, slots, bucket, turns = 4, 32, 16384, 65
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+                           )["params"]))
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+    # 8,256 rows of 128 values and a normaliser of 8,256 a key/value
+    # head, padded to 65 whole rows of distances: 4.40 GB
+    assert eng.cache_bytes_by_kind() == {
+        "kv": 0, "compressed": 0,
+        "state": layers * slots * 8 * turns * 128 * (128 + 1) * 4}
+    assert not eng._reads_live_tiles
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
+                                       i32(slots))
+    else:
+        lowered = eng._prefill_fn(bucket).lower(
+            params, eng._cache, i32(slots), i32(1, bucket), i32(), i32())
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nbrumby {program}: arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{memory.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{memory.alias_size_in_bytes / 1e9:.3f} GB")
+    assert 0 < memory.alias_size_in_bytes - eng.cache_bytes() <= 4096
+    assert _feed_is_aliased(text, params, eng._cache)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16e9)
+    state, norm = (rf"f32\[{slots},8,{turns},128,128\]",
+                   rf"f32\[{slots},8,{turns},128\]")
+    for leaf in (state, norm):
+        assert len(re.findall(rf"= {leaf}\S* parameter\(\d+\), sharding",
+                              text)) == layers, leaf
+    # the refusal is for the state. A normaliser is 0.8% of it (8.5 MB a
+    # layer): it is aliased like the state (the sizes above), and the
+    # compiler may stage it through VMEM on its way into the kernel
+    assert not re.findall(rf"= {state}\S* copy(-start)?\(", text)
+    # a layer is one kernel: the step's pass over the state, or the
+    # prefill's read of the state between chunks (inside its scan)
+    kernel = "retention_step" if program == "decode" else "retention_read"
+    assert text.count("tpu_custom_call") == layers
+    assert len(re.findall(rf"%{kernel}[.\d]* = [^\n]*? custom-call\(",
+                          text)) == layers
 
 
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
