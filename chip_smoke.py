@@ -282,7 +282,8 @@ def serve_once(hvd, model, params, cfg, prompts, paged: bool):
             f"{[o.prompt_len for o in outs]}) x {n_new} new tokens in "
             f"{first_s:.2f} s incl. {compiles} compiles; repeat "
             f"identical in {repeat_s:.3f} s, compiles flat; "
-            f"{replica['decode_steps']} decode steps")
+            f"{replica['decode_steps']} decode steps; decode_write_fused "
+            f"{replica['engine']['decode_write_fused']}")
     finally:
         handle.close()
     return [o.tokens for o in outs]

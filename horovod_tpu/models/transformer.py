@@ -58,7 +58,7 @@ def cached_attention(q, k, v, q_positions):
     rows' lengths: right for a prefill (bucket-many queries against a
     fresh cache) and for the paged engine's gathered view. The dense
     decode step, one query a row, goes through
-    :func:`attend_cache`, which reads only the live part of each row.
+    :func:`write_and_attend`, which reads only the live part of each row.
     """
     head_dim = q.shape[-1]
     scale = 1.0 / float(np.sqrt(head_dim))
@@ -70,32 +70,6 @@ def cached_attention(q, k, v, q_positions):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def attend_cache(q, k_cache, v_cache, positions):
-    """Attention of a step's new tokens against the positions-last cache
-    they were just written into.
-
-    ``q``: (batch, new, heads, head_dim); ``k_cache``/``v_cache``:
-    (batch, heads, head_dim, cache_len); ``positions``: (batch,) int32,
-    the first new token's. Returns (batch, new, heads, head_dim).
-
-    Selected by shape, as :func:`write_cache_rows` selects its kernel:
-    one new token a row against a cache of whole lane tiles (the dense
-    decode step) goes through ``ops/pallas/decode_attention``, which
-    fetches the lane tiles ``0 .. position // 128`` of each row and none
-    past them; anything else is :func:`cached_attention` over the whole
-    cache.
-    """
-    new_tokens = q.shape[1]
-    if takes_kernel(new_tokens, k_cache.shape[-1]):
-        return decode_attention(q[:, 0], k_cache, v_cache,
-                                positions)[:, None]
-    q_pos = positions[:, None] + jnp.arange(new_tokens, dtype=jnp.int32)
-    o = cached_attention(
-        q.transpose(0, 2, 1, 3), k_cache.transpose(0, 1, 3, 2),
-        v_cache.transpose(0, 1, 3, 2), q_pos)
-    return o.transpose(0, 2, 1, 3)
-
-
 def write_cache_rows(cache, new, positions):
     """``cache`` with ``new`` written in: row ``b`` takes ``new[b]`` at
     positions ``positions[b] .. positions[b] + n - 1``.
@@ -105,10 +79,13 @@ def write_cache_rows(cache, new, positions):
     ``new``: (batch, n, heads, head_dim) of the cache's dtype;
     ``positions``: (batch,) int32.
 
-    One new token a row (the decode step) goes through the in-place
-    kernel: XLA:TPU expands a batched scatter into one serial trip per
-    row, and a select over the cache writes all of it back. Several
-    tokens a row (prefill) go in as one slice per row.
+    One new token a row goes through the in-place kernel: XLA:TPU
+    expands a batched scatter into one serial trip per row, and a select
+    over the cache writes all of it back. (The dense decode step on a
+    cache of whole lane tiles does not come here: :func:`write_and_attend`
+    writes from its attention kernel. ``models/hybrid.py`` and a cache
+    length off the tile do.) Several tokens a row (prefill) go in as one
+    slice per row.
     """
     if new.shape[1] == 1:
         return write_token(cache, new[:, 0], positions)
@@ -116,6 +93,38 @@ def write_cache_rows(cache, new, positions):
         lambda row, rows, start: jax.lax.dynamic_update_slice(
             row, rows, (0, 0, start)))(
                 cache, new.transpose(0, 2, 3, 1), positions)
+
+
+def write_and_attend(q, k, v, k_cache, v_cache, positions):
+    """A serving step's new tokens written into the positions-last cache
+    and attended against it: ``(o, k_cache, v_cache)``.
+
+    ``q``/``k``/``v``: (batch, new, heads, head_dim), the keys and values
+    of the cache's dtype; ``k_cache``/``v_cache``: (batch, heads,
+    head_dim, cache_len); ``positions``: (batch,) int32, the first new
+    token's. ``o`` is (batch, new, heads, head_dim).
+
+    One algorithm (write the columns, attend up to them), in the form
+    the step's shape allows: one new token a row against a cache of
+    whole lane tiles (the dense decode step) is one kernel,
+    ``ops/pallas/decode_attention``, which fetches the lane tiles
+    ``0 .. position // 128`` of each row and none past them, and puts
+    the new column into the last of them while it is there. Anything
+    else is :func:`write_cache_rows` a leaf, then
+    :func:`cached_attention` over the whole cache.
+    """
+    new_tokens = q.shape[1]
+    if takes_kernel(new_tokens, k_cache.shape[-1]):
+        o, k_cache, v_cache = decode_attention(
+            q[:, 0], k[:, 0], v[:, 0], k_cache, v_cache, positions)
+        return o[:, None], k_cache, v_cache
+    k_cache = write_cache_rows(k_cache, k, positions)
+    v_cache = write_cache_rows(v_cache, v, positions)
+    q_pos = positions[:, None] + jnp.arange(new_tokens, dtype=jnp.int32)
+    o = cached_attention(
+        q.transpose(0, 2, 1, 3), k_cache.transpose(0, 1, 3, 2),
+        v_cache.transpose(0, 1, 3, 2), q_pos)
+    return o.transpose(0, 2, 1, 3), k_cache, v_cache
 
 
 class SelfAttention(nn.Module):
@@ -128,10 +137,11 @@ class SelfAttention(nn.Module):
     ``decode=True`` switches to the serving path: a ``cache`` variable
     collection holds per-row key/value tensors of length
     ``max_cache_len`` (positions last), new tokens are written in at
-    their absolute ``positions`` (:func:`write_cache_rows`) and attention
-    runs against the cache, masked past each query's position
-    (:func:`attend_cache`: the live part of each row for a one-token
-    step, the whole cache otherwise).
+    their absolute ``positions`` and attention runs against the cache,
+    masked past each query's position (:func:`write_and_attend`: for a
+    one-token step one kernel that reads the live part of each row and
+    writes the new column into it, else a write a leaf and attention
+    over the whole cache).
     Parameters are identical to the training module — only runtime
     behavior and the (non-param) cache change.
 
@@ -230,11 +240,9 @@ class SelfAttention(nn.Module):
             cached_value = self.variable("cache", "cached_value", jnp.zeros,
                                          cache_shape, self.dtype)
             pos = jnp.asarray(positions, jnp.int32)
-            cached_key.value = write_cache_rows(
-                cached_key.value, k.astype(self.dtype), pos)
-            cached_value.value = write_cache_rows(
-                cached_value.value, v.astype(self.dtype), pos)
-            o = attend_cache(q, cached_key.value, cached_value.value, pos)
+            o, cached_key.value, cached_value.value = write_and_attend(
+                q, k.astype(self.dtype), v.astype(self.dtype),
+                cached_key.value, cached_value.value, pos)
             return dense(features=d_model, axis=(-2, -1), name="out")(o)
 
         # (batch, seq, heads, head_dim) -> (batch, heads, seq, head_dim)

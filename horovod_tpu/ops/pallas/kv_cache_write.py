@@ -10,6 +10,13 @@ row per grid step, fetches only the lane tile that holds the row's
 position (``heads x head_dim x 128`` elements), replaces one lane and
 writes the tile back into the same buffer (the cache operand is aliased
 to the result, so with the cache donated nothing else moves).
+
+Who calls it: ``models/hybrid.py`` for the key, value and compressed-key
+leaves of its sparse layers (it has its own attention), and
+``models/transformer.py`` ``write_cache_rows`` for a one-token step on a
+cache whose length is no whole number of lane tiles. The dense decode
+step on whole tiles does not: ``ops/pallas/decode_attention`` fetches the
+same tile to attend over it and writes the new column from there.
 """
 
 from __future__ import annotations

@@ -224,8 +224,12 @@ class DecodeEngine:
         # does the decode program read its key/value rows through
         # ops/pallas/decode_attention (set by _cache_shapes, from the
         # program itself), and the lane tiles its steps read of a leaf
-        # over the tiles of all rows (stats()["decode_kv_read_share"])
+        # over the tiles of all rows (stats()["decode_kv_read_share"]);
+        # and does that kernel write the new columns itself: no
+        # kv_cache_write beside it (stats()["decode_write_fused"]; None
+        # where the program holds no such kernel)
         self._reads_live_tiles = False
+        self._write_fused = None
         self.kv_tiles_read = 0
         self.kv_tiles_held = 0
         self._cache = self._allocate_cache()
@@ -262,7 +266,10 @@ class DecodeEngine:
                 {"params": p}, t, positions=q, train=False,
                 mutable=["cache"]), return_shape=True)(
                     self._params, tokens, pos)
-        self._reads_live_tiles = "decode_attention" in kernels_in(program)
+        kernels = kernels_in(program)
+        self._reads_live_tiles = "decode_attention" in kernels
+        self._write_fused = ("kv_cache_write" not in kernels
+                             if self._reads_live_tiles else None)
         return shapes["cache"]
 
     def _allocate_cache(self):
@@ -420,6 +427,7 @@ class DecodeEngine:
                 self.kv_tiles_read += read
                 self.kv_tiles_held += held
                 attrs["kv_read_share"] = round(read / held, 4)
+                attrs["write_fused"] = int(self._write_fused)
         with tracing.span("engine.decode.dispatch"):
             ids, max_abs = self._run_donating("decode", self._decode_fn,
                                               step_pos)
@@ -451,4 +459,8 @@ class DecodeEngine:
                 "decode_kv_read_share": (
                     round(self.kv_tiles_read / self.kv_tiles_held, 4)
                     if self.kv_tiles_held else None),
+                # the attention kernel writes the step's new key and
+                # value columns itself (the decode program holds no
+                # kv_cache_write); None where it holds no such kernel
+                "decode_write_fused": self._write_fused,
                 "slots": self.num_slots}

@@ -763,5 +763,6 @@ class PagedDecodeEngine:
                 # the paged decode step gathers every mapped page into a
                 # row view and attends over all of it
                 "decode_kv_read_share": None,
+                "decode_write_fused": None,
                 "slots": self.num_slots,
                 "pages": self.page_stats()}

@@ -286,10 +286,19 @@ def test_cache_kinds_and_the_sparse_attribute(served):
 def test_hybrid_decode_reports_no_kv_read_share(served):
     """``HybridDecoder`` has its own attention over the whole row (its
     256-position cache is two whole lane tiles): no decode-attention
-    kernel in its program, and the engine says None, not 1.0."""
+    kernel in its program, which still writes its key, value and
+    compressed-key leaves through ``kv_cache_write``, and the engine
+    says None, not 1.0 (and None, not False, of a fused write)."""
+    from horovod_tpu.ops.pallas._backend import kernels_in
+
     _, params, model = served
     engine = DecodeEngine(model, params, num_slots=2)
     assert model.max_seq % 128 == 0 and not engine._reads_live_tiles
+    program = jax.make_jaxpr(engine._decode_impl)(
+        params, engine._cache, engine._feed, jnp.zeros((2,), jnp.int32))
+    kernels = set(kernels_in(program))
+    assert "kv_cache_write" in kernels and "decode_attention" not in kernels
+    assert engine.stats()["decode_write_fused"] is None
     first, _ = engine.prefill(0, tokens(41).tolist())
     began = time.time()
     engine.decode([0], [first], [41]).collect()
@@ -298,6 +307,7 @@ def test_hybrid_decode_reports_no_kv_read_share(served):
     mine = [s for s in tracing.spans()
             if s["name"] == "engine.decode" and s["t"] >= began]
     assert len(mine) == 1 and "kv_read_share" not in mine[0]
+    assert "write_fused" not in mine[0]
 
 
 def test_the_paged_engine_refuses_a_model_without_pages(served):

@@ -404,6 +404,7 @@ def test_paged_engine_reports_no_kv_read_share():
     _generate(eng, 0, [1, 2, 3], 4)
     assert eng.stats()["decode_steps"] == 3
     assert eng.stats()["decode_kv_read_share"] is None
+    assert eng.stats()["decode_write_fused"] is None
     program = jax.make_jaxpr(eng._decode_impl)(
         eng._params, eng._cache, jnp.zeros((3, 1), jnp.int32),
         jnp.zeros((3,), jnp.int32), jnp.asarray(eng._table_arr))

@@ -495,79 +495,171 @@ def _stale_cache(rng, shape, positions, dtype):
             jnp.asarray(np.where(stale, 0.0, sound), dtype))
 
 
-@pytest.mark.parametrize("pattern", sorted(_RAGGED))
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_decode_attention_kernel(dtype, pattern):
-    """The kernel (interpret mode) against ``cached_attention`` on the
-    same cache: equal to rounding, and what lies past a row's position
-    changes nothing, bit for bit."""
+def _decode_case(dtype, pattern):
+    """A decode step's operands at seven rows of three lane tiles: the
+    query and the new key and value columns, both leaves with an earlier
+    occupant's garbage from each row's position on (the position itself
+    is the new token's: not yet written) and with zeros there."""
     import jax.numpy as jnp
     import numpy as np
-
-    from horovod_tpu.models.transformer import cached_attention
-    from horovod_tpu.ops.pallas.decode_attention import decode_attention
 
     rng = np.random.default_rng(3)
     rows, heads, head_dim, cache_len = 7, 3, 16, 384
     positions = _RAGGED[pattern](rows, cache_len - 1)
-    pos = jnp.asarray(positions, jnp.int32)
     shape = (rows, heads, head_dim, cache_len)
-    k_stale, k_clean = _stale_cache(rng, shape, positions, dtype)
-    v_stale, v_clean = _stale_cache(rng, shape, positions, dtype)
-    q = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
+    before = [p - 1 for p in positions]
+    k_stale, k_clean = _stale_cache(rng, shape, before, dtype)
+    v_stale, v_clean = _stale_cache(rng, shape, before, dtype)
+    q, k_new, v_new = (
+        jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
+        for _ in range(3))
+    return (jnp.asarray(positions, jnp.int32), q, k_new, v_new,
+            (k_stale, v_stale), (k_clean, v_clean))
 
-    got = decode_attention(q, k_stale, v_stale, pos)
-    assert got.shape == q.shape and got.dtype == v_stale.dtype
+
+def _bits(x):
+    import jax.numpy as jnp
+    import numpy as np
+
+    return np.array(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("pattern", sorted(_RAGGED))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_decode_attention_kernel(dtype, pattern):
+    """The kernel (interpret mode) against ``cached_attention`` over the
+    cache with the new columns written in: equal to rounding, and what
+    lies past a row's position changes nothing, bit for bit."""
+    import numpy as np
+
+    from horovod_tpu.models.transformer import cached_attention
+    from horovod_tpu.ops.pallas.decode_attention import decode_attention
+    from horovod_tpu.ops.pallas.kv_cache_write import write_token
+
+    pos, q, k_new, v_new, stale, clean = _decode_case(dtype, pattern)
+    got, _, _ = decode_attention(q, k_new, v_new, *stale, pos)
+    assert got.shape == q.shape and got.dtype == stale[1].dtype
     np.testing.assert_array_equal(
-        np.array(got.astype(jnp.float32)),
-        np.array(decode_attention(q, k_clean, v_clean, pos)
-                 .astype(jnp.float32)))
+        _bits(got), _bits(decode_attention(q, k_new, v_new, *clean, pos)[0]))
     want = cached_attention(
-        q[:, :, None, :], k_stale.transpose(0, 1, 3, 2),
-        v_stale.transpose(0, 1, 3, 2), pos[:, None])[:, :, 0]
+        q[:, :, None, :],
+        write_token(stale[0], k_new, pos).transpose(0, 1, 3, 2),
+        write_token(stale[1], v_new, pos).transpose(0, 1, 3, 2),
+        pos[:, None])[:, :, 0]
     # XLA rounds its scores and probabilities to the cache's dtype; the
     # kernel keeps both in float32
     tol = 3e-2 if dtype == "bfloat16" else 2e-6
-    np.testing.assert_allclose(np.array(got.astype(jnp.float32)),
-                               np.array(want.astype(jnp.float32)),
-                               atol=tol, rtol=tol)
+    np.testing.assert_allclose(_bits(got), _bits(want), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("cache_len,new_tokens,kernel", [
-    (128, 1, True),      # the dense decode step
-    (96, 1, False),      # a cache length off the lane tile
-    (128, 3, False),     # several new tokens a row: a prefill
+@pytest.mark.parametrize("pattern", sorted(_RAGGED))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_decode_attention_writes_as_write_token(dtype, pattern):
+    """Both leaves come back bit for bit as ``write_token`` leaves them:
+    the new column in lane ``position % 128`` of the row's last live
+    tile, every other lane of that tile (the garbage past the position
+    included) and every other tile as they went in. And the output is
+    bit for bit what the kernel gives over a cache that ``write_token``
+    wrote first (placing the same column again changes nothing): the
+    score sees the column as a later step reads it back."""
+    import numpy as np
+
+    from horovod_tpu.ops.pallas.decode_attention import decode_attention
+    from horovod_tpu.ops.pallas.kv_cache_write import write_token
+
+    pos, q, k_new, v_new, (k_cache, v_cache), _ = _decode_case(dtype, pattern)
+    got, k_got, v_got = decode_attention(q, k_new, v_new, k_cache, v_cache,
+                                         pos)
+    for leaf, new, cache in ((k_got, k_new, k_cache), (v_got, v_new, v_cache)):
+        assert leaf.dtype == cache.dtype
+        want = _bits(cache)
+        for row, p in enumerate(np.array(pos)):
+            want[row, :, :, p] = _bits(new[row])
+        np.testing.assert_array_equal(_bits(leaf), want)
+        np.testing.assert_array_equal(
+            _bits(leaf), _bits(write_token(cache, new, pos)))
+    again, k_again, v_again = decode_attention(q, k_new, v_new, k_got, v_got,
+                                               pos)
+    np.testing.assert_array_equal(_bits(got), _bits(again))
+    np.testing.assert_array_equal(_bits(k_again), _bits(k_got))
+    np.testing.assert_array_equal(_bits(v_again), _bits(v_got))
+
+
+def test_decode_attention_rounds_the_new_columns_first():
+    """Float32 columns into a bfloat16 cache are scored as the cache
+    holds them, and a position past the end is clamped onto the last."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops.pallas.decode_attention import decode_attention
+
+    pos, q, k_new, v_new, (k_cache, v_cache), _ = _decode_case(
+        "float32", "ragged")
+    pos = pos.at[4].set(k_cache.shape[-1] + 3)
+    k_cache, v_cache = (x.astype(jnp.bfloat16) for x in (k_cache, v_cache))
+    got = decode_attention(q, k_new, v_new, k_cache, v_cache, pos)
+    rounded = decode_attention(q, k_new.astype(jnp.bfloat16),
+                               v_new.astype(jnp.bfloat16), k_cache, v_cache,
+                               pos)
+    for x, y in zip(got, rounded):
+        assert x.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(_bits(x), _bits(y))
+    np.testing.assert_array_equal(_bits(got[1][4, :, :, -1]),
+                                  _bits(k_new[4].astype(jnp.bfloat16)))
+
+
+@pytest.mark.parametrize("cache_len,new_tokens,kernels", [
+    # the dense decode step: one kernel, and no write kernel beside it
+    (128, 1, ["decode_attention"]),
+    # a cache length off the lane tile: a write a leaf, masked attention
+    (96, 1, ["kv_cache_write", "kv_cache_write"]),
+    # several new tokens a row, a prefill: a slice a row, no kernel
+    (128, 3, []),
 ], ids=["decode", "off-tile", "prefill"])
-def test_attend_cache_selects_by_shape(cache_len, new_tokens, kernel):
-    """One new token a row against whole lane tiles goes through the
-    kernel; anything else is the masked whole-row contraction. By
-    result and by what the traced program holds."""
+def test_write_and_attend_selects_by_shape(cache_len, new_tokens, kernels):
+    """One new token a row against whole lane tiles goes through the one
+    kernel, which writes too; anything else is a write a leaf and the
+    masked whole-row contraction. By result, by the leaves and by what
+    the traced program holds."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models.transformer import attend_cache, cached_attention
+    from horovod_tpu.models.transformer import (cached_attention,
+                                                write_and_attend)
     from horovod_tpu.ops.pallas._backend import kernels_in
 
     rng = np.random.default_rng(5)
     rows, heads, head_dim = 3, 2, 8
     shape = (rows, heads, head_dim, cache_len)
     positions = [0, cache_len - new_tokens, 40]
-    k, _ = _stale_cache(rng, shape, [p + new_tokens - 1 for p in positions],
-                        "float32")
-    v, _ = _stale_cache(rng, shape, [p + new_tokens - 1 for p in positions],
-                        "float32")
-    q = jnp.asarray(rng.normal(size=(rows, new_tokens, heads, head_dim)),
-                    jnp.float32)
+    k_cache, _ = _stale_cache(rng, shape, [p - 1 for p in positions],
+                              "float32")
+    v_cache, _ = _stale_cache(rng, shape, [p - 1 for p in positions],
+                              "float32")
+    q, k, v = (jnp.asarray(rng.normal(
+        size=(rows, new_tokens, heads, head_dim)), jnp.float32)
+        for _ in range(3))
     pos = jnp.asarray(positions, jnp.int32)
-    names = kernels_in(jax.make_jaxpr(attend_cache)(q, k, v, pos))
-    assert names == (["decode_attention"] if kernel else [])
+    names = kernels_in(jax.make_jaxpr(write_and_attend)(
+        q, k, v, k_cache, v_cache, pos))
+    assert names == kernels
+    k_want, v_want = np.array(k_cache), np.array(v_cache)
+    for row, p in enumerate(positions):
+        k_want[row, :, :, p:p + new_tokens] = np.array(
+            k[row]).transpose(1, 2, 0)
+        v_want[row, :, :, p:p + new_tokens] = np.array(
+            v[row]).transpose(1, 2, 0)
+    got, k_got, v_got = write_and_attend(q, k, v, k_cache, v_cache, pos)
+    np.testing.assert_array_equal(np.array(k_got), k_want)
+    np.testing.assert_array_equal(np.array(v_got), v_want)
     q_pos = pos[:, None] + jnp.arange(new_tokens)
     want = cached_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 1, 3, 2),
-        v.transpose(0, 1, 3, 2), q_pos).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(np.array(attend_cache(q, k, v, pos)),
-                               np.array(want), atol=2e-6, rtol=2e-6)
+        q.transpose(0, 2, 1, 3), jnp.asarray(k_want).transpose(0, 1, 3, 2),
+        jnp.asarray(v_want).transpose(0, 1, 3, 2), q_pos
+    ).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(np.array(got), np.array(want),
+                               atol=2e-6, rtol=2e-6)
 
 
 @pytest.fixture(scope="module")
@@ -610,11 +702,13 @@ def test_dense_engine_decodes_as_the_masked_path(tiled_lm, monkeypatch):
 
     kernel = DecodeEngine(model, params, num_slots=4)
     assert kernel._reads_live_tiles
+    assert kernel.stats()["decode_write_fused"] is True
     got = serve(kernel)
     monkeypatch.setattr(transformer, "takes_kernel", lambda *_: False)
     masked = DecodeEngine(model, params, num_slots=4)
     assert not masked._reads_live_tiles
     assert masked.stats()["decode_kv_read_share"] is None
+    assert masked.stats()["decode_write_fused"] is None
     assert serve(masked) == got
     assert masked.stats()["decode_kv_read_share"] is None
 

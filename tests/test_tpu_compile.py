@@ -197,8 +197,10 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
     it copies a cache leaf or loops over one (XLA:TPU turns a batched
     scatter into one serial trip per row; an undonated cache is copied
     whole, per leaf). In the decode step nothing but the kernels touches
-    a leaf at all: the attention reads the live lane tiles of each row
-    (ops/pallas/decode_attention), not the whole of it."""
+    a leaf at all, and a layer has one: the attention reads the live
+    lane tiles of each row (ops/pallas/decode_attention), not the whole
+    of it, and writes the new key and value columns into the last of
+    them itself."""
     from horovod_tpu.models.transformer import Transformer
     from horovod_tpu.serve.kv_cache import DecodeEngine
 
@@ -246,14 +248,14 @@ def test_dense_engine_updates_its_cache_in_place(chip, monkeypatch, program):
     # one cache, whose leaves no layout pads
     assert memory.argument_size_in_bytes <= (1.01 * param_bytes
                                              + eng.cache_bytes())
-    # a layer of the decode step is two in-place writes (one a cache leaf)
-    # and one attention that fetches the rows' live lane tiles itself;
+    # a layer of the decode step is one kernel, which fetches the rows'
+    # live lane tiles and writes the new columns of both leaves in place;
     # the prefill writes one row's slice, attends over the whole of its
     # fresh single-row cache and needs no kernel
     assert text.count("tpu_custom_call") == (
-        3 * layers if program == "decode" else 0)
+        layers if program == "decode" else 0)
     if program == "decode":
-        assert len(re.findall(r"%decode_attention[.\d]* = \S+ custom-call\(",
+        assert len(re.findall(r"%decode_attention[.\d]* = .* custom-call\(",
                               text)) == layers
         # nothing but the kernels (and the result tuple) takes a whole
         # leaf: no fusion reads all 1,024 positions of every slot

@@ -535,9 +535,11 @@ def tiny_lm():
 ], ids=["kernel", "masked"])
 def test_engine_decode_span_carries_kv_read_share(ring, max_seq, shares):
     """``engine.decode`` says what share of the rows' lane tiles the
-    step's attention read, where the decode program holds the
-    decode-attention kernel, and carries no such attribute where it
-    reads whole rows."""
+    step's attention read, and that the same kernel wrote the step's new
+    columns (``write_fused``), where the decode program holds the
+    decode-attention kernel; it carries neither attribute where the
+    program reads whole rows and writes through ``kv_cache_write``. The
+    engine's ``stats()`` say the same of the program as a whole."""
     import jax
     import jax.numpy as jnp
 
@@ -557,6 +559,10 @@ def test_engine_decode_span_carries_kv_read_share(ring, max_seq, shares):
     got = [s.get("kv_read_share") for s in ring.spans()
            if s["name"] == "engine.decode"]
     assert got == shares
+    kernel = shares[0] is not None
+    assert [s.get("write_fused") for s in ring.spans()
+            if s["name"] == "engine.decode"] == [1 if kernel else None] * 3
+    assert engine.stats()["decode_write_fused"] is (True if kernel else None)
     assert [s["rows"] for s in ring.spans()
             if s["name"] == "engine.decode"] == [1, 2, 2]
 
