@@ -1,18 +1,25 @@
 """Decoders whose layers differ in kind (flax): block-sparse softmax
-attention, linear ("lightning") attention with a fixed decay and power
-retention (gated, normalised linear attention of degree 2), on a modern
-trunk - RMSNorm, SiLU-gated MLP, rotary positions, grouped key/value
-heads, per-head QK-norm, sigmoid output gates, an untied head and
-(optional) muP scalings.
+attention, linear ("lightning") attention with a fixed decay, power
+retention (gated, normalised linear attention of degree 2) and latent
+attention (MLA: one low-rank latent and one rotary key a token, shared by
+the heads), on a modern trunk - RMSNorm, a SiLU-gated MLP or a top-k
+router over such MLPs ("experts") beside a shared one, rotary positions
+(plain or YaRN), grouped key/value heads, per-head QK-norm, sigmoid
+output gates, one residual stream or several hyper-connected ones, an
+untied head and (optional) muP scalings.
 
 :class:`HybridDecoder` reads its layer kinds from ``mixers`` and is what
 ``hvd.serve()`` runs for MiniCPM-SALA (``benchmark/configs/
 minicpm-sala.json``; the plain reference is
-``benchmark/reference_sala.py``) and for Brumby-14B-Base
+``benchmark/reference_sala.py``), for Brumby-14B-Base
 (``benchmark/configs/brumby-14b.json``, ``benchmark/
-reference_brumby.py``). The blocks (:class:`RMSNorm`, :class:`GatedMlp`,
-:func:`rope`, :class:`BlockSparseAttention`, :class:`LightningAttention`,
-:class:`PowerRetention`) are not tied to those models.
+reference_brumby.py``) and for Xing4.0-29B-A4B (``benchmark/configs/
+xing4-29b-a4b.json``, ``benchmark/reference_xing.py``). The blocks
+(:class:`RMSNorm`, :class:`GatedMlp`, :func:`rope`,
+:class:`BlockSparseAttention`, :class:`LightningAttention`,
+:class:`PowerRetention`, :class:`LatentAttention`,
+:class:`RoutedExperts`, :class:`HyperConnection`) are not tied to those
+models.
 
 Serving (``decode=True``) keeps a ``cache`` collection whose leaves all
 have the slot as axis 0; which kinds it holds depends on the mixers (a
@@ -30,7 +37,14 @@ model of power-retention layers alone has no leaf with a position axis):
   float32 - a power-retention layer's state, ``D = head_dim (head_dim +
   1) / 2`` rows of ``head_dim`` values, and its normaliser, both padded to
   whole rows of distances (``power_features``; 8,256 to 8,320 at width
-  128) in the layout ``ops/pallas/power_retention`` reads.
+  128) in the layout ``ops/pallas/power_retention`` reads;
+* ``latent`` ``(slots, kv_rank, max_seq)`` and ``rope_key`` ``(slots,
+  rope_dim, max_seq)`` - a latent-attention layer's normed latent and
+  rotated key, positions last, nothing a head.
+
+An expert layer also keeps ``expert_counts`` ``(3, experts)`` uint32 there,
+a running count and the one leaf that is no slot's row (the engine adds a
+prefill's counts to it and reads it for ``stats()`` alone).
 
 A call with one token a row is a decode step; a call with more is a
 prefill from position 0, which computes the prompt without the cache and
@@ -59,7 +73,8 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import write_cache_rows
-from horovod_tpu.ops.pallas import power_retention
+from horovod_tpu.ops.pallas import latent_attention, power_retention
+from horovod_tpu.ops.pallas.flash_attention import flash_attention
 from horovod_tpu.ops.pallas.kv_cache_write import LANES, write_token
 
 Dtype = Any
@@ -68,6 +83,9 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 BLOCK_SPARSE, LIGHTNING = "block_sparse", "lightning"
 POWER_RETENTION = "power_retention"
+LATENT = "latent"
+# a layer's MLP: one gated MLP, or a router over experts beside a shared one
+DENSE_MLP, EXPERTS_MLP = "dense", "experts"
 # a masked score: finite, so that a row with nothing to see stays a number
 NEG_INF = -1e30
 
@@ -113,12 +131,38 @@ class GatedMlp(nn.Module):
         return dense(x.shape[-1], name="down")(h)
 
 
-def rope(x, positions, theta):
+def yarn_frequencies(dim, theta, factor, original, beta_fast, beta_slow):
+    """The ``dim / 2`` rotary frequencies of YaRN (arXiv:2309.00071, as
+    DeepSeek-V2 publishes it): a pair that turns more than ``beta_fast``
+    times over the ``original`` context keeps its frequency
+    ``theta^(-2i/dim)``, one that turns fewer than ``beta_slow`` times
+    has it divided by ``factor``, and between the two correction
+    dimensions (``dim ln(original / (2 pi turns)) / (2 ln theta)``,
+    floor of the fast one, ceiling of the slow one) a linear ramp mixes
+    them."""
+    half = dim // 2
+    plain = theta ** (-jnp.arange(half, dtype=F32) / half)
+
+    def turning(turns):
+        return dim * math.log(original / (turns * 2.0 * math.pi)) \
+            / (2.0 * math.log(theta))
+
+    low = max(math.floor(turning(beta_fast)), 0)
+    high = min(math.ceil(turning(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=F32) - low)
+                    / (high - low if high > low else 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rope(x, positions, theta, frequencies=None):
     """Rotary positions over the whole head width, halves paired
     (``x[..., i]`` with ``x[..., i + d/2]``). ``x``: (batch, seq, heads,
-    d); ``positions``: (batch, seq) absolute. Float32 in and out."""
+    d); ``positions``: (batch, seq) absolute; ``frequencies``: (d / 2,)
+    in place of ``theta^(-2i/d)`` (:func:`yarn_frequencies`). Float32 in
+    and out."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    freq = (theta ** (-jnp.arange(half, dtype=F32) / half)
+            if frequencies is None else frequencies)
     angle = positions.astype(F32)[..., None, None] * freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     x = x.astype(F32)
@@ -745,42 +789,481 @@ class BlockSparseAttention(nn.Module):
         return dense(d_model, name="out")(o)
 
 
+# --------------------------------------------------------- latent attention
+
+def latent_scale(qk_dim, yarn=None):
+    """The softmax scale ``qk_dim^-0.5``, times YaRN's ``mscale^2``
+    (``mscale = 0.1 mscale_all_dim ln(factor) + 1``) where the positions
+    are stretched."""
+    scale = qk_dim ** -0.5
+    if yarn and yarn["factor"] > 1:
+        mscale = 0.1 * yarn.get("mscale_all_dim", 0) \
+            * math.log(yarn["factor"]) + 1.0
+        scale *= mscale * mscale
+    return scale
+
+
+def latent_step_attention(q_nope, q_rope, kv_b, latent, rope_key, positions,
+                          scale, dtype):
+    """One decode step of latent attention in its absorbed form: the
+    queries go into the latent's space (``qt_h = q_nope_h W_K,h^T``), the
+    scores and the weighted sum are taken against the cached latents
+    themselves, and the sum comes out through ``W_V,h``. No cached
+    position is ever expanded to keys and values.
+
+    ``q_nope``: (batch, heads, nope); ``q_rope``: (batch, heads, rope_dim),
+    rotated; ``kv_b``: (rank, heads, nope + v); ``latent``: (batch, rank,
+    max_seq) and ``rope_key``: (batch, rope_dim, max_seq), positions last;
+    ``positions``: (batch,) the new token's, already written. Returns
+    (batch, heads, v) in ``dtype``.
+
+    The middle part is one kernel (``ops/pallas/latent_attention``) that
+    reads each row's live position tiles once."""
+    nope = q_nope.shape[-1]
+    w_k, w_v = kv_b[..., :nope], kv_b[..., nope:]
+    qt = jnp.einsum("bhn,khn->bhk", q_nope, w_k,
+                    preferred_element_type=F32).astype(dtype)
+    ot = latent_attention.latent_decode_attention(
+        qt, q_rope, latent, rope_key, positions, scale).astype(dtype)
+    return jnp.einsum("bhk,khv->bhv", ot, w_v,
+                      preferred_element_type=F32).astype(dtype)
+
+
+def latent_prompt_attention(q_nope, q_rope, kv_b, c, k_rope, scale, dtype):
+    """Causal attention of a whole prompt from position 0 with keys and
+    values expanded from the prompt's own latents (no cache is read):
+    ``[k_nope_h, v_h] = c W_kvb``, a key is ``[k_nope_h, k_rope]`` with
+    the one rotary key shared by the heads. Runs through the flash kernel,
+    which has one width for queries, keys and values: all three are
+    padded with zeros to a whole number of lane tiles.
+
+    ``q_nope``/``q_rope``: (batch, seq, heads, .); ``c``: (batch, seq,
+    rank); ``k_rope``: (batch, seq, 1, rope_dim). Returns (batch, seq,
+    heads, v) in ``dtype``."""
+    batch, seq, heads, nope = q_nope.shape
+    kv = jnp.einsum("bsk,khn->bshn", c, kv_b,
+                    preferred_element_type=F32).astype(dtype)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1]
+                                  + k_rope.shape[-1:])], axis=-1)
+    width = -(-max(q.shape[-1], v.shape[-1]) // LANES) * LANES
+
+    def fit(t):     # (batch, seq, heads, d) -> (batch, heads, seq, width)
+        return jnp.pad(t.transpose(0, 2, 1, 3),
+                       [(0, 0)] * 3 + [(0, width - t.shape[-1])])
+
+    o = flash_attention(fit(q), fit(k), fit(v), causal=True, sm_scale=scale)
+    return o[..., :v.shape[-1]].transpose(0, 2, 1, 3)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2's MLA): queries through a
+    low-rank pair ``q_a``, ``q_b`` with a norm between; keys and values
+    through one ``rank``-wide latent a token (normed) that ``kv_b``
+    expands a head, plus one rotary key a token shared by the heads.
+    The cache holds the latent and the rotated key and nothing a head.
+
+    ``yarn`` is the published ``rope_scaling`` group (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale_all_dim``) or ``None`` for plain rotary positions."""
+
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10000.0
+    yarn: Any = None
+    eps: float = 1e-6
+    decode: bool = False
+    max_cache_len: int = 0
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = F32
+
+    @nn.compact
+    def __call__(self, x, positions, lengths=None):
+        del lengths       # masked softmax: padded keys are never attended
+        batch, seq, d_model = x.shape
+        heads, rank, nope, turned = (self.num_heads, self.kv_rank,
+                                     self.nope_dim, self.rope_dim)
+        yarn = dict(self.yarn) if self.yarn else None
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        norm = partial(RMSNorm, eps=self.eps, dtype=self.dtype,
+                       param_dtype=self.param_dtype)
+        q = dense(heads * (nope + turned), name="q_b")(
+            norm(name="q_norm")(dense(self.q_rank, name="q_a")(x)))
+        q = q.reshape(batch, seq, heads, nope + turned)
+        kv = dense(rank + turned, name="kv_a")(x)
+        c = norm(name="kv_norm")(kv[..., :rank])
+        kv_b = self.param("kv_b", nn.initializers.normal(0.02),
+                          (rank, heads, nope + self.v_dim),
+                          self.param_dtype).astype(self.dtype)
+        freq = yarn and yarn_frequencies(
+            turned, self.rope_theta, yarn["factor"],
+            yarn["original_max_position_embeddings"], yarn["beta_fast"],
+            yarn["beta_slow"])
+        at = positions[:, None] + jnp.arange(seq, dtype=jnp.int32)[None, :]
+        q_nope = q[..., :nope]
+        q_rope = rope(q[..., nope:], at, self.rope_theta,
+                      freq).astype(self.dtype)
+        k_rope = rope(kv[..., None, rank:], at, self.rope_theta,
+                      freq).astype(self.dtype)            # (b, s, 1, turned)
+        scale = latent_scale(nope + turned, yarn)
+
+        if self.decode:
+            latent = self.variable(
+                "cache", "latent", jnp.zeros,
+                (batch, rank, self.max_cache_len), self.dtype)
+            rope_key = self.variable(
+                "cache", "rope_key", jnp.zeros,
+                (batch, turned, self.max_cache_len), self.dtype)
+            latent.value = write_cache_rows(
+                latent.value[:, None], c[:, :, None], positions)[:, 0]
+            rope_key.value = write_cache_rows(
+                rope_key.value[:, None], k_rope, positions)[:, 0]
+        if self.decode and seq == 1:
+            with jax.named_scope("latent_step"):
+                o = latent_step_attention(
+                    q_nope[:, 0], q_rope[:, 0], kv_b, latent.value,
+                    rope_key.value, positions, scale, self.dtype)[:, None]
+        else:
+            with jax.named_scope("latent_prompt"):
+                o = latent_prompt_attention(q_nope, q_rope, kv_b, c, k_rope,
+                                            scale, self.dtype)
+        return dense(d_model, name="out")(
+            o.reshape(batch, seq, heads * self.v_dim))
+
+
+# ----------------------------------------------------------- routed experts
+
+# at or under this many (token, expert) pairs a held expert, every held
+# expert multiplies every row under a 0/1 weight (experts_masked); above
+# it the pairs are sorted and grouped (experts_grouped). The one point
+# measured on the chip is 64 rows x top-4 over 64 experts, 4 pairs an
+# expert, where the masked product runs within 8-16% of the time of
+# reading the experts (PERF.md, PR 34); nothing smaller can gain from
+# grouping, and nothing larger has been measured in the masked form.
+MASKED_PAIRS = 4
+
+def route(x, router, bias, top_k, scaling):
+    """DeepSeek-V3's ``noaux_tc`` router without group limits: scores
+    ``g = sigmoid(x W_r)``, the ``top_k`` largest of ``g + bias`` chosen,
+    their weights ``scaling g_i / (sum of the chosen g + 1e-20)``. The
+    product and the scores are float32 at the highest precision: the
+    fourth and the fifth score can lie closer than bfloat16 resolves.
+
+    ``x``: (..., d); ``router``: (d, experts); ``bias``: (experts,).
+    Returns the chosen experts (..., top_k) int32 and their weights
+    (..., top_k) float32."""
+    g = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.astype(F32),
+                               precision=HIGHEST))
+    _, chosen = jax.lax.top_k(g + bias.astype(F32), top_k)
+    picked = jnp.take_along_axis(g, chosen, axis=-1)
+    weights = scaling * picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights
+
+
+def experts_masked(x, weights, gate, up, down):
+    """``sum_e weights[t, e] E_e(x_t)`` as products over every held
+    expert, the weight of an expert a token did not choose being 0: few
+    rows against many experts (a decode step) are bound by reading the
+    experts, which this does once. ``x``: (tokens, d); ``weights``:
+    (tokens, experts) float32; ``gate``/``up``: (experts, d, f);
+    ``down``: (experts, f, d). Returns (tokens, d) float32."""
+    h = nn.silu(jnp.einsum("td,edf->tef", x, gate)) \
+        * jnp.einsum("td,edf->tef", x, up)
+    h = (h.astype(F32) * weights[..., None]).astype(x.dtype)
+    return jnp.einsum("tef,efd->td", h, down, preferred_element_type=F32)
+
+
+def experts_grouped(x, chosen, weights, gate, up, down):
+    """The same sum as a grouped product: the (token, expert) pairs
+    sorted by expert, each expert's rows multiplied by its own matrices
+    (``jax.lax.ragged_dot``: on the chip one kernel that visits each
+    group's row tiles), and the rows gathered back to their tokens. No
+    pair is dropped and none is padded to a capacity. ``chosen``:
+    (tokens, top_k) int32, an entry equal to the number of held experts
+    meaning "not here" (another chip's expert, or a padded token): such
+    pairs sort last, lie past the last group and cost no product.
+    Returns (tokens, d) float32."""
+    tokens, top_k = chosen.shape
+    experts = gate.shape[0]
+    pair_expert = chosen.reshape(-1)
+    order = jnp.argsort(pair_expert, stable=True)
+    sizes = jnp.sum(pair_expert[:, None] == jnp.arange(experts)[None, :],
+                    axis=0, dtype=jnp.int32)
+    rows = x[order // top_k]
+    h = nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
+        * jax.lax.ragged_dot(rows, up, sizes)
+    y = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=F32)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype)).reshape(chosen.shape)
+    # a token's k-th pair at a time: rows gathered as rows, never laid out
+    # as (tokens, top_k, d); a pair that is not here reads a row past the
+    # last group, which is whatever the product left there
+    return sum(jnp.where(chosen[:, k, None] < experts,
+                         y[back[:, k]] * weights[:, k, None], 0.0)
+               for k in range(top_k))
+
+
+class RoutedExperts(nn.Module):
+    """A router over ``num_experts`` SiLU-gated MLPs of width ``d_ff``,
+    ``top_k`` of them a token (:func:`route`), beside ``shared`` experts
+    that every token takes (one :class:`GatedMlp` of ``shared * d_ff``).
+    No token is dropped and there is no capacity factor.
+
+    The layer holds experts ``first .. first + count - 1`` (``count``
+    ``None``: all of them): it routes over all ``num_experts`` router
+    outputs and computes the part of the sum its own experts give, which
+    is the whole sum where it holds them all. Across chips the parts are
+    added; here nothing stands in for that exchange.
+
+    With ``decode=True`` the ``cache`` collection holds ``expert_counts``
+    (3, count) uint32, a running count and no slot's row: ``[0]`` the
+    (token, expert) pairs routed to each held expert by both programs,
+    ``[1]`` the decode steps in which some counted row chose it, ``[2]``
+    the decode steps. A prompt's padding (``lengths``) and a decode
+    step's rows outside ``active`` are not counted. The counts are never
+    reset and run modulo 2**32 (weeks of serving at a thousand pairs a
+    second an expert): a reader takes the difference of two readings in
+    uint32, which is right across a wrap."""
+
+    num_experts: int
+    top_k: int
+    d_ff: int
+    shared: int = 1
+    scaling: float = 1.0
+    first: int = 0
+    count: Optional[int] = None
+    decode: bool = False
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = F32
+
+    @nn.compact
+    def __call__(self, x, lengths=None, active=None):
+        batch, seq, d = x.shape
+        count = self.num_experts if self.count is None else self.count
+        init = nn.initializers.normal(0.02)
+        # logits of a standard deviation near 1 whatever the width
+        router = self.param("router", nn.initializers.normal(d ** -0.5),
+                            (d, self.num_experts), self.param_dtype)
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (self.num_experts,), self.param_dtype)
+        gate, up = (self.param(name, init, (count, d, self.d_ff),
+                               self.param_dtype).astype(self.dtype)
+                    for name in ("experts_gate", "experts_up"))
+        down = self.param("experts_down", init, (count, self.d_ff, d),
+                          self.param_dtype).astype(self.dtype)
+        chosen, weights = route(x, router, bias, self.top_k, self.scaling)
+        counted = jnp.ones((batch, seq), bool)
+        if lengths is not None:
+            counted = jnp.arange(seq, dtype=jnp.int32)[None, :] \
+                < lengths[:, None]
+        if active is not None:
+            counted = counted & active[:, None]
+        here = chosen - self.first
+        here = jnp.where((here >= 0) & (here < count) & counted[..., None],
+                         here, count)                 # count: not here
+        hit = here[..., None] == jnp.arange(count, dtype=jnp.int32)
+        if self.decode:
+            counts = self.variable("cache", "expert_counts", jnp.zeros,
+                                   (3, count), jnp.uint32)
+            pairs = jnp.sum(hit, axis=(0, 1, 2), dtype=jnp.uint32)
+            step = jnp.full((count,), int(seq == 1), jnp.uint32)
+            counts.value = counts.value + jnp.stack(
+                [pairs, jnp.minimum(pairs, 1) * step, step])
+        flat = x.reshape(batch * seq, d)
+        if batch * seq * self.top_k <= MASKED_PAIRS * count:
+            y = experts_masked(
+                flat, jnp.sum(hit * weights[..., None], axis=2).reshape(
+                    batch * seq, count), gate, up, down)
+        else:
+            y = experts_grouped(flat, here.reshape(-1, self.top_k),
+                                weights.reshape(-1, self.top_k), gate, up,
+                                down)
+        y = y.reshape(batch, seq, d)
+        if self.shared:
+            y = y + GatedMlp(self.shared * self.d_ff, dtype=self.dtype,
+                             param_dtype=self.param_dtype,
+                             name="shared")(x).astype(F32)
+        return y.astype(self.dtype)
+
+
+# -------------------------------------------------------- hyper-connections
+
+def sinkhorn(z, iters, eps):
+    """``exp(z)`` made (nearly) doubly stochastic: ``iters`` times each
+    row divided by (its sum + ``eps``), then each column. ``z``: (n, n,
+    ...), rows first; float32. The passes are unrolled: they are a chain
+    of small elementwise operations that fuse, where a loop would launch
+    each pass on its own."""
+    m = jnp.exp(z)
+
+    def one(_, m):
+        m = m / (m.sum(axis=1, keepdims=True) + eps)
+        return m / (m.sum(axis=0, keepdims=True) + eps)
+
+    return jax.lax.fori_loop(0, iters, one, m, unroll=True)
+
+
+class HyperConnection(nn.Module):
+    """The manifold-constrained hyper-connection round one sublayer
+    (mHC, arXiv:2512.24880, on hyper-connections, arXiv:2409.19606):
+    from the ``n`` residual streams ``X`` of a token (``n x C``) three
+    maps, each a static part plus ``alpha`` times a projection ``phi`` of
+    the normalised streams: ``H_pre`` (n, a sigmoid) mixes the streams
+    into the sublayer's input, ``H_post`` (n, twice a sigmoid) spreads
+    its output over them, ``H_res`` (n x n, Sinkhorn of the clamped
+    exponent) mixes the streams themselves. All of it float32; ``X`` is
+    kept in ``dtype``, the stream before the position: (batch, n, seq,
+    C), so that each stream is an activation of the usual shape and
+    nothing is laid out anew to mix them.
+
+    The module gives the three maps for ``X``; :func:`hyper_mix` and
+    :func:`hyper_update` apply them."""
+
+    streams: int
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: Tuple[float, float] = (-30.0, 30.0)
+    norm_eps: float = 1e-6
+    param_dtype: Dtype = F32
+
+    @nn.compact
+    def __call__(self, streams):
+        """``streams``: (batch, n, seq, C). Returns ``H_pre`` (n, batch,
+        seq), ``H_post`` (n, batch, seq) and ``H_res`` (n, n, batch,
+        seq). The projection is a sum of one product a stream, and the
+        normalisation (one number a token) is applied to its 24 results,
+        not to the streams; the maps are made with the tokens as the
+        last axis (all of them: a decode step has one a row), so that the
+        Sinkhorn passes run along the lanes."""
+        batch, n, seq, width = streams.shape
+        phi = self.param("phi", nn.initializers.normal(1.0),
+                         (n * width, n * n + 2 * n), self.param_dtype)
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                           self.param_dtype).astype(F32)
+        bias = self.param("bias", nn.initializers.zeros, (n * n + 2 * n,),
+                          self.param_dtype).astype(F32)
+        x = streams.astype(F32)
+        phi = phi.astype(F32).reshape(n, width, -1)
+        m = sum(jnp.einsum("bsc,ck->bsk", x[:, i], phi[i], precision=HIGHEST)
+                for i in range(n))
+        m = m * jax.lax.rsqrt(jnp.mean(x * x, axis=(1, 3))
+                              + self.norm_eps)[..., None]
+        m = m.reshape(batch * seq, -1).T                   # (n^2 + 2n, T)
+        at = lambda lo, hi: bias[lo:hi, None]
+        pre = jax.nn.sigmoid(alpha[0] * m[:n] + at(0, n))
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + at(n, 2 * n))
+        z = jnp.clip(alpha[2] * m[2 * n:] + at(2 * n, None), *self.clamp)
+        res = sinkhorn(z.reshape(n, n, batch * seq), self.sinkhorn_iters,
+                       self.eps)
+        return (pre.reshape(n, batch, seq), post.reshape(n, batch, seq),
+                res.reshape(n, n, batch, seq))
+
+
+def hyper_mix(pre, streams):
+    """``H_pre @ X``: (batch, seq, C) float32."""
+    x = streams.astype(F32)
+    return sum(pre[i][..., None] * x[:, i] for i in range(x.shape[1]))
+
+
+def hyper_update(post, res, streams, y):
+    """``X' = H_res @ X + H_post^T y`` in float32, kept in ``X``'s dtype."""
+    x, y = streams.astype(F32), y.astype(F32)
+    n = x.shape[1]
+    out = [sum(res[i, j][..., None] * x[:, j] for j in range(n))
+           + post[i][..., None] * y for i in range(n)]
+    return jnp.stack(out, axis=1).astype(streams.dtype)
+
+
 # ------------------------------------------------------------------- trunk
 
 class HybridLayer(nn.Module):
     """``h += a Mixer(norm(h)); h += a Mlp(norm(h))`` with the residual
     scale ``a`` (muP's ``scale_depth / sqrt(depth)``, or 1); ``kind``
-    says which mixer, ``mixer_args`` are its fields."""
+    says which mixer, ``mixer_args`` are its fields; ``mlp`` says which
+    MLP (``"dense"``: a :class:`GatedMlp` of ``d_ff``; ``"experts"``:
+    :class:`RoutedExperts` with the fields ``mlp_args``).
+
+    With ``streams`` > 1 the residual is ``streams`` streams a token
+    (``h``: (batch, streams, seq, C)) and each sublayer sits inside a
+    :class:`HyperConnection` (fields ``hyper_args``): ``X' = H_res X +
+    H_post^T F(norm(H_pre X))``; there is no ``a`` then."""
 
     kind: str
     mixer_args: Any
     d_ff: int
     residual_scale: float = 1.0
+    mlp: str = DENSE_MLP
+    mlp_args: Any = None
+    streams: int = 1
+    hyper_args: Any = None
     eps: float = 1e-6
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = F32
 
     @nn.compact
-    def __call__(self, h, positions, lengths=None):
+    def __call__(self, h, positions, lengths=None, active=None):
         common = dict(eps=self.eps, dtype=self.dtype,
                       param_dtype=self.param_dtype)
         mixer = {LIGHTNING: LightningAttention,
                  BLOCK_SPARSE: BlockSparseAttention,
-                 POWER_RETENTION: PowerRetention}[self.kind](
+                 POWER_RETENTION: PowerRetention,
+                 LATENT: LatentAttention}[self.kind](
                      name="mixer", **dict(self.mixer_args), **common)
         norm = partial(RMSNorm, **common)
-        a = jnp.asarray(self.residual_scale, self.dtype)
-        h = h + a * mixer(norm(name="input_norm")(h), positions, lengths)
-        with jax.named_scope("mlp"):
-            return h + a * GatedMlp(
+        if self.mlp == EXPERTS_MLP:
+            scope = "moe"
+            mlp = lambda u: RoutedExperts(
+                dtype=self.dtype, param_dtype=self.param_dtype, name="moe",
+                **dict(self.mlp_args))(u, lengths, active)
+        else:
+            scope = "mlp"
+            mlp = lambda u: GatedMlp(
                 self.d_ff, dtype=self.dtype, param_dtype=self.param_dtype,
-                name="mlp")(norm(name="post_norm")(h))
+                name="mlp")(u)
+        if self.streams == 1:
+            a = jnp.asarray(self.residual_scale, self.dtype)
+            h = h + a * mixer(norm(name="input_norm")(h), positions, lengths)
+            with jax.named_scope(scope):
+                return h + a * mlp(norm(name="post_norm")(h))
+
+        def scoped_mlp(u):
+            with jax.named_scope(scope):
+                return mlp(u)
+
+        for name, before, sublayer in (
+                ("hyper_mixer", "input_norm",
+                 lambda u: mixer(u, positions, lengths)),
+                ("hyper_mlp", "post_norm", scoped_mlp)):
+            with jax.named_scope("hyper"):
+                pre, post, res = HyperConnection(
+                    self.streams, norm_eps=self.eps,
+                    param_dtype=self.param_dtype, name=name,
+                    **dict(self.hyper_args or {}))(h)
+                u = hyper_mix(pre, h)
+            y = sublayer(norm(name=before)(u))
+            with jax.named_scope("hyper"):
+                h = hyper_update(post, res, h, y)
+        return h
 
 
 class HybridDecoder(nn.Module):
     """Embedding, ``len(mixers)`` layers of the kinds ``mixers`` names
-    (``"block_sparse"`` / ``"lightning"`` / ``"power_retention"``), final
-    RMSNorm, untied head.
+    (``"block_sparse"`` / ``"lightning"`` / ``"power_retention"`` /
+    ``"latent"``), final RMSNorm, untied head.
+
+    ``mlps`` names each layer's MLP (``"dense"``, the default, or
+    ``"experts"``: :class:`RoutedExperts` with the fields ``experts``);
+    ``latent`` holds :class:`LatentAttention`'s ranks and widths;
+    ``streams`` > 1 repeats the embedding into that many residual
+    streams, puts a :class:`HyperConnection` (fields ``hyper``) round
+    every sublayer and sums the streams before the final norm.
 
     ``layer_indices`` gives each layer's index in the published model
     (a lightning layer's decay depends on it) and ``published_depth`` the
@@ -799,6 +1282,11 @@ class HybridDecoder(nn.Module):
     head_dim: int
     mixers: Tuple[str, ...]
     sparse: Any = None                  # a mapping; see BlockSparseAttention
+    latent: Any = None                  # a mapping; see LatentAttention
+    mlps: Optional[Tuple[str, ...]] = None
+    experts: Any = None                 # a mapping; see RoutedExperts
+    streams: int = 1
+    hyper: Any = None                   # a mapping; see HyperConnection
     layer_indices: Optional[Tuple[int, ...]] = None
     published_depth: Optional[int] = None
     scale_emb: float = 1.0
@@ -822,6 +1310,12 @@ class HybridDecoder(nn.Module):
             return None
         return dict(self.sparse)["dense_len"]
 
+    @property
+    def counts_active_rows(self):
+        """Whether a decode step wants ``active`` (the rows that hold a
+        request): the expert layers count the pairs they route."""
+        return EXPERTS_MLP in (self.mlps or ())
+
     def _mixer_args(self, i, kind):
         depth = self.published_depth or len(self.mixers)
         if kind == LIGHTNING:
@@ -839,15 +1333,22 @@ class HybridDecoder(nn.Module):
                         num_kv_heads=self.num_kv_heads,
                         head_dim=self.head_dim, rope_theta=self.rope_theta,
                         decode=self.decode)
+        if kind == LATENT:
+            return dict(num_heads=self.num_heads,
+                        rope_theta=self.rope_theta,
+                        max_cache_len=self.max_seq, decode=self.decode,
+                        **dict(self.latent))
         raise ValueError(f"unknown mixer {kind!r}")
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
-                 lengths=None, output: str = "logits"):
+                 lengths=None, output: str = "logits", active=None):
         """``positions``: (batch,) the absolute position of each row's
         first token (decode steps; a prefill starts at 0). ``lengths``:
         (batch,) the true length of each padded row; with it the result
-        has one row a sequence, row ``lengths - 1``."""
+        has one row a sequence, row ``lengths - 1``. ``active``: (batch,)
+        bool, the rows that hold a request (only what is counted looks at
+        it: :attr:`counts_active_rows`)."""
         del train
         if token_ids.ndim != 2:
             raise ValueError("expected (batch, seq) int token ids")
@@ -867,15 +1368,24 @@ class HybridDecoder(nn.Module):
                      embedding_init=nn.initializers.normal(0.02),
                      name="token_embed")(token_ids)
         h = h * jnp.asarray(self.scale_emb, self.dtype)
+        if self.streams > 1:
+            h = jnp.repeat(h[:, None], self.streams, axis=1)
+        mlps = self.mlps or (DENSE_MLP,) * len(self.mixers)
         for i, kind in enumerate(self.mixers):
             h = HybridLayer(
                 kind=kind, mixer_args=self._mixer_args(i, kind),
                 d_ff=self.d_ff,
                 residual_scale=(1.0 if self.scale_depth is None
                                 else self.scale_depth / math.sqrt(depth)),
+                mlp=mlps[i],
+                mlp_args=(dict(self.experts, decode=self.decode)
+                          if mlps[i] == EXPERTS_MLP else None),
+                streams=self.streams, hyper_args=self.hyper,
                 eps=self.eps, dtype=self.dtype,
                 param_dtype=self.param_dtype,
-                name=f"layer_{i}")(h, positions, lengths)
+                name=f"layer_{i}")(h, positions, lengths, active)
+        if self.streams > 1:
+            h = h.astype(F32).sum(axis=1).astype(self.dtype)
         if lengths is not None:
             h = jnp.take_along_axis(
                 h, jnp.clip(lengths - 1, 0, seq - 1)[:, None, None], axis=1)

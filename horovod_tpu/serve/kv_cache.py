@@ -41,8 +41,16 @@ every leaf with the slot as axis 0: ``models/hybrid.py`` keeps keys and
 values, compressed keys and float32 recurrent states side by side, or
 states alone - a model of power-retention layers has no leaf with a
 position axis at all, and its decode step rewrites its whole cache
-(:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). Nothing here asks a leaf
-for more than the slot axis. A recurrence is not indifferent to padding,
+(:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). A latent-attention
+layer keeps one latent and one rotary key a position and nothing a head
+(kind ``latent``). Nothing here asks a leaf for more than the slot axis,
+with one exception: a leaf of kind ``counter`` (an expert layer's
+``expert_counts``) is a running count and no slot's row, so a prefill
+adds its fresh counts to it where it overwrites a row of every other
+leaf; it rides in the donated cache, no step reads it back, and
+``stats()`` alone copies it to the host. A model that counts
+(``counts_active_rows``) is told a decode step's ``active`` rows, so
+that rows without a request are not counted. A recurrence is not indifferent to padding,
 so a prefill hands the model the true ``lengths``: the state it leaves
 is the state after the prompt, and a prefill overwrites every leaf's row
 of its slot, the state included. A row that is not active still runs
@@ -78,6 +86,7 @@ always were, for tests and tools.
 
 from __future__ import annotations
 
+import re
 import time
 import weakref
 from typing import Dict, List, Optional, Tuple
@@ -89,7 +98,7 @@ import numpy as np
 from horovod_tpu import tracing
 from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
-from horovod_tpu.ops.pallas import decode_attention
+from horovod_tpu.ops.pallas import decode_attention, latent_attention
 from horovod_tpu.ops.pallas._backend import kernels_in
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
 
@@ -133,15 +142,20 @@ def prompt_bucket(prompt_len: int, max_seq: int,
 # and values that grow with the context, compressed keys that a sparse
 # layer selects blocks by, a recurrent state (and a normalised one's
 # running sum of features) that does not grow. A model need not have
-# every kind: one of recurrent layers alone holds states and nothing else
+# every kind: one of recurrent layers alone holds states and nothing else.
+# A latent-attention layer's two leaves (one latent and one rotary key a
+# position, nothing a head) are ``latent``; an expert layer's running
+# counts are ``counter``: no slot's row (module docstring)
 CACHE_KINDS = {"cached_key": "kv", "cached_value": "kv",
                "compressed_key": "compressed", "state": "state",
-               "state_norm": "state"}
+               "state_norm": "state", "latent": "latent",
+               "rope_key": "latent", "expert_counts": "counter"}
 
 
 def leaf_kind(path) -> str:
-    """``kv``, ``compressed`` or ``state`` for a cache leaf's tree path
-    (``other`` for a name :data:`CACHE_KINDS` does not know)."""
+    """``kv``, ``compressed``, ``state``, ``latent`` or ``counter`` for a
+    cache leaf's tree path (``other`` for a name :data:`CACHE_KINDS` does
+    not know)."""
     name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
     return CACHE_KINDS.get(str(name), "other")
 
@@ -221,6 +235,9 @@ class DecodeEngine:
         # a model with block-sparse layers selects key blocks for prompts
         # past this length (the ``sparse`` attribute of ``engine.prefill``)
         self._dense_len = getattr(model, "dense_len", None)
+        # a model whose expert layers count what they route is told a
+        # decode step's active rows
+        self._counts = bool(getattr(model, "counts_active_rows", False))
         # does the decode program read its key/value rows through
         # ops/pallas/decode_attention (set by _cache_shapes, from the
         # program itself), and the lane tiles its steps read of a leaf
@@ -232,6 +249,12 @@ class DecodeEngine:
         self._write_fused = None
         self.kv_tiles_read = 0
         self.kv_tiles_held = 0
+        # the same for a latent cache read through ops/pallas/
+        # latent_attention (its tiles are wider), and the positions its
+        # steps attended (stats()["decode_positions_read"]): what a
+        # roofline of that kernel counts bytes by
+        self._reads_live_latents = False
+        self.positions_read = 0
         self._cache = self._allocate_cache()
         # the next token of every row, on the device (module docstring)
         self._feed = jnp.zeros((self.num_slots,), jnp.int32)
@@ -243,6 +266,10 @@ class DecodeEngine:
         # written once per kind by the replica thread, read by stats()
         self._donated: Dict[str, bool] = {}
         self._lock = witness.make_lock("DecodeEngine._lock")
+        # held while a program is enqueued and the cache rebound, and
+        # while stats() copies the counters out of it: a leaf that is
+        # being read is not donated under the reader
+        self._cache_lock = witness.make_lock("DecodeEngine._cache_lock")
         self._compiles: Dict[str, int] = {}      # guarded-by: _lock
         # decode steps enqueued; collected; collected with their successor
         # already enqueued (Replica.stats()["lookahead_share"])
@@ -270,6 +297,7 @@ class DecodeEngine:
         self._reads_live_tiles = "decode_attention" in kernels
         self._write_fused = ("kv_cache_write" not in kernels
                              if self._reads_live_tiles else None)
+        self._reads_live_latents = "latent_decode_attention" in kernels
         return shapes["cache"]
 
     def _allocate_cache(self):
@@ -280,8 +308,10 @@ class DecodeEngine:
         return sum(self.cache_bytes_by_kind().values())
 
     def cache_bytes_by_kind(self) -> Dict[str, int]:
-        """Resident cache bytes by kind of leaf (:func:`leaf_kind`)."""
-        out = {kind: 0 for kind in CACHE_KINDS.values()}
+        """Resident cache bytes by kind of leaf (:func:`leaf_kind`):
+        ``kv``, ``compressed`` and ``state`` always, another kind where
+        the model has such a leaf."""
+        out = {"kv": 0, "compressed": 0, "state": 0}
         for path, x in jax.tree_util.tree_leaves_with_path(self._cache):
             kind = leaf_kind(path)
             out[kind] = out.get(kind, 0) \
@@ -316,8 +346,9 @@ class DecodeEngine:
         anything."""
         old = None if kind in self._donated \
             else jax.tree.leaves((self._cache, self._feed))
-        self._cache, self._feed, *rest = fn(
-            self._params, self._cache, self._feed, *args)
+        with self._cache_lock:
+            self._cache, self._feed, *rest = fn(
+                self._params, self._cache, self._feed, *args)
         if old is not None:
             self._donated[kind] = all(x.is_deleted() for x in old)
         for x in rest:
@@ -336,9 +367,12 @@ class DecodeEngine:
             train=False, mutable=["cache"])
         # ...written into the slot row at a traced index (in place: the
         # big cache is donated), so every prompt of this bucket reuses
-        # one program regardless of slot
-        cache = jax.tree.map(
-            lambda big, one: jax.lax.dynamic_update_index_in_dim(
+        # one program regardless of slot; a counter is no slot's row: the
+        # prompt's counts are added to it
+        cache = jax.tree_util.tree_map_with_path(
+            lambda path, big, one: big + one
+            if leaf_kind(path) == "counter"
+            else jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
         last = logits[0, 0]
         token = jnp.argmax(last).astype(jnp.int32)
@@ -351,10 +385,11 @@ class DecodeEngine:
         # position 0 and leaves its feed entry alone
         active = positions >= 0
         tokens = jnp.where(active, feed, 0)[:, None]
+        counted = {"active": active} if self._counts else {}
         logits, mutated = self._model.apply(
             {"params": params, "cache": cache}, tokens,
             positions=jnp.maximum(positions, 0), train=False,
-            mutable=["cache"])
+            mutable=["cache"], **counted)
         step_logits = logits[:, 0, :]
         ids = jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
         return (mutated["cache"], jnp.where(active, ids, feed), ids,
@@ -428,6 +463,13 @@ class DecodeEngine:
                 self.kv_tiles_held += held
                 attrs["kv_read_share"] = round(read / held, 4)
                 attrs["write_fused"] = int(self._write_fused)
+            elif self._reads_live_latents:
+                read, held, attended = latent_attention.live_tiles(
+                    step_pos, self.max_seq)
+                self.kv_tiles_read += read
+                self.kv_tiles_held += held
+                self.positions_read += attended
+                attrs["kv_read_share"] = round(read / held, 4)
         with tracing.span("engine.decode.dispatch"):
             ids, max_abs = self._run_donating("decode", self._decode_fn,
                                               step_pos)
@@ -441,9 +483,31 @@ class DecodeEngine:
         self.step_ms_ewma = (ms if self.decode_steps == 1
                              else 0.9 * self.step_ms_ewma + 0.1 * ms)
 
+    def expert_counts(self) -> Optional[np.ndarray]:
+        """The expert layers' running counts, (layers, 3, experts)
+        uint32 in layer order (``models/hybrid.py`` ``RoutedExperts``:
+        pairs routed by both programs, decode steps that hit the expert,
+        decode steps); ``None`` for a model that counts nothing. The one
+        place a counter is read. The counts run modulo 2**32: subtract
+        two readings as uint32. Under the lock only a copy on the device
+        is enqueued (no program: nothing compiles), behind the step in
+        flight; this thread then waits for it without holding the
+        engine's next dispatch."""
+        if not self._counts:
+            return None
+        with self._cache_lock:
+            found = [(jax.tree_util.keystr(path),
+                      jax.device_put(x, may_alias=False)) for path, x
+                     in jax.tree_util.tree_leaves_with_path(self._cache)
+                     if leaf_kind(path) == "counter"]
+        by_layer = sorted(found, key=lambda kv: [
+            int(n) for n in re.findall(r"\d+", kv[0])])
+        return np.stack([np.asarray(x) for _, x in by_layer])
+
     def stats(self) -> dict:
         with self._lock:
             compiles = dict(self._compiles)
+        counts = self.expert_counts()
         return {"compiles": compiles,
                 "compiles_total": sum(compiles.values()),
                 "decode_steps": self.decode_steps,
@@ -463,4 +527,14 @@ class DecodeEngine:
                 # value columns itself (the decode program holds no
                 # kv_cache_write); None where it holds no such kernel
                 "decode_write_fused": self._write_fused,
+                # positions the latent kernel's steps attended (a row
+                # that is not active: one), None without that kernel
+                "decode_positions_read": (self.positions_read
+                                          if self._reads_live_latents
+                                          else None),
+                # (layers, 3, experts) as nested lists: pairs, decode
+                # steps that hit the expert, decode steps, each modulo
+                # 2**32 (None: the model has no expert layer)
+                "expert_counts": (None if counts is None
+                                  else counts.tolist()),
                 "slots": self.num_slots}

@@ -121,6 +121,16 @@ class TestBitParity:
 
         for s in range(3):  # warmup: compile the size-bucketed programs
             one_step(float(s + 1))
+        # the two buckets meet in one cycle only when the cycle thread is
+        # late, and are then reduced as one fused payload of a third
+        # size: warm that program too, or a loaded machine's first
+        # fusion falls into a steady step
+        from horovod_tpu.ops import collectives
+
+        fused = collectives.stack_per_worker(
+            jnp.ones((hvd.size(), 512 + 256), jnp.float32))
+        hvd.synchronize(collectives.grouped_allreduce_async(
+            [fused], names=["warm.fused"], reduce_op="average")[0])
         before = executor_mod._PROGRAM_COMPILES.value
         for s in range(4):
             one_step(float(s + 10))

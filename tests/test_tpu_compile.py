@@ -408,6 +408,93 @@ def test_retention_engine_fits_and_rewrites_its_cache_in_place(
                           text)) == layers
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_8192"])
+def test_latent_experts_engine_fits_and_updates_its_cache_in_place(
+        chip, monkeypatch, capsys, program):
+    """Xing4.0-29B-A4B as the benchmark runs it (benchmark/configs/
+    xing4-29b-a4b.json: one dense and five expert layers at full width,
+    all 64 experts, four residual streams, 64 slots of 8,192 positions,
+    bfloat16 weights): the 64-row decode step and the prefill of the
+    largest bucket compile for one v5e chip, arguments plus temporaries
+    stay under its 16 GB (printed: run with ``-s``), the result aliases
+    every leaf of the donated cache - one 512-wide latent and one
+    64-wide rotary key a position a layer, nothing a head, and the
+    expert layers' counters - and the token feed, and nothing copies a
+    latent leaf. The decode step writes its columns through
+    ``kv_cache_write`` (two leaves a layer) and attends through
+    ``ops/pallas/latent_attention`` (Mosaic takes it at 32 heads, a
+    512-wide latent and tiles of 1,024 positions); the prefill attends
+    through the flash kernel at a padded width of 256
+    and groups its pairs through ``ragged_dot``, which the TPU compiler
+    turns into its own grouped-product kernel."""
+    import json
+
+    from benchmark.runners.serve_xing import build_model
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4-29b-a4b.json")) as f:
+        cfg = json.load(f)["as_run"]
+    layers, slots, bucket, seq = 6, 64, 8192, 8192
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+                           )["params"]))
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+    assert eng.cache_bytes_by_kind() == {
+        "kv": 0, "compressed": 0, "state": 0,
+        "latent": layers * slots * seq * (512 + 64) * 2,     # 3.62 GB
+        "counter": (layers - 1) * 3 * 64 * 4}
+    assert not eng._reads_live_tiles and eng._counts
+    assert eng._reads_live_latents
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
+                                       i32(slots))
+    else:
+        lowered = eng._prefill_fn(bucket).lower(
+            params, eng._cache, i32(slots), i32(1, bucket), i32(), i32())
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nxing {program}: arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{memory.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{memory.alias_size_in_bytes / 1e9:.3f} GB")
+    assert 0 < memory.alias_size_in_bytes - eng.cache_bytes() <= 8192
+    assert _feed_is_aliased(text, params, eng._cache)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16e9)
+    latent, rope_key = (rf"bf16\[{slots},512,{seq}\]",
+                        rf"bf16\[{slots},64,{seq}\]")
+    for leaf in (latent, rope_key):
+        assert len(re.findall(rf"= {leaf}\S* parameter\(\d+\), sharding",
+                              text)) == layers, leaf
+        assert not re.findall(rf"= {leaf}\S* copy(-start)?\(", text), leaf
+    if program == "decode":
+        # two column writes and one attention a layer, no other kernel
+        assert text.count("tpu_custom_call") == 3 * layers
+        assert len(re.findall(
+            r"%latent_decode_attention[.\d]* = [^\n]*? custom-call\(",
+            text)) == layers
+    else:
+        # one flash kernel a layer; three grouped products an expert
+        # layer, each with the kernel that lays out its groups
+        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
+                              text)) >= 3 * (layers - 1)
+        assert text.count("tpu_custom_call") >= layers + 3 * (layers - 1)
+
+
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
     """What ``training.make_train_step`` builds on a four-chip host: one
     jit over the global mesh, batch sharded. XLA cannot partition a
