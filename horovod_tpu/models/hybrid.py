@@ -48,10 +48,13 @@ prefill's counts to it and reads it for ``stats()`` alone).
 
 A call with one token a row is a decode step; a call with more is a
 prefill from position 0, which computes the prompt without the cache and
-then fills it. A recurrence is not indifferent to padding as masked
-softmax is, so a prefill takes the true ``lengths``: the state it leaves
-is the state after ``lengths`` tokens, and with ``lengths`` given the
-head runs on row ``lengths - 1`` alone.
+then fills it; a model of power-retention layers alone, handed a slot's
+cache and the ``positions`` where the pieces before ended, continues the
+prompt from there (``HybridDecoder.resumable_prefill``), so that the
+engine may run a prompt in pieces. A recurrence is not indifferent to
+padding as masked softmax is, so a prefill takes the true ``lengths``:
+the state it leaves is the state after ``lengths`` tokens, and with
+``lengths`` given the head runs on row ``lengths - 1`` alone.
 
 The sparse layer computes masked dense attention in blocks of queries
 (each query's selected key blocks are a mask over all causal keys) and
@@ -367,13 +370,16 @@ def cache_state(state, norm):
 
 
 def retention_chunked(q, k, v, log_gate, lengths=None, chunk=256, eps=1e-6,
-                      dtype=jnp.bfloat16):
-    """The same recurrence over a whole sequence from a zero state, by
-    chunks: inside a chunk the masked squares ``(q_i . k_j)^2 / d`` with
-    ``exp(b_i - b_j)``, ``b`` the running sum of the chunk's log-gates;
-    between chunks the state and its normaliser. ``q``: (batch, seq,
-    heads, d); ``k``/``v``: (batch, seq, kv_heads, d); ``log_gate``:
-    (batch, seq, kv_heads) float32.
+                      dtype=jnp.bfloat16, initial=None):
+    """The same recurrence over a whole sequence, by chunks: inside a
+    chunk the masked squares ``(q_i . k_j)^2 / d`` with ``exp(b_i -
+    b_j)``, ``b`` the running sum of the chunk's log-gates; between
+    chunks the state and its normaliser. ``q``: (batch, seq, heads, d);
+    ``k``/``v``: (batch, seq, kv_heads, d); ``log_gate``: (batch, seq,
+    kv_heads) float32. The sequence starts from a zero state, or from
+    ``initial``: the state and the normaliser an earlier call returned,
+    so that a sequence cut into pieces at multiples of ``chunk`` makes
+    the products of the whole one in the same order.
 
     A position at or past ``lengths`` neither decays the state nor
     enters it (its gate is taken as 1 and its key as absent), so the
@@ -456,8 +462,14 @@ def retention_chunked(q, k, v, log_gate, lengths=None, chunk=256, eps=1e-6,
         norm = kept[..., None] * norm + f_k.astype(F32).sum(axis=2)
         return (state, norm), out
 
-    carry = (jnp.zeros((batch, groups, turns * d, d), F32),
-             jnp.zeros((batch, groups, turns * d), F32))
+    if initial is None:
+        carry = (jnp.zeros((batch, groups, turns * d, d), F32),
+                 jnp.zeros((batch, groups, turns * d), F32))
+    else:       # out of the cache's layout: the inverse of cache_state
+        state, norm = initial
+        carry = (
+            jnp.swapaxes(state, -1, -2).reshape(batch, groups, turns * d, d),
+            norm.reshape(batch, groups, turns * d))
     (state, norm), out = jax.lax.scan(one, carry, (
         cut(q, (per, d)), cut(k, (d,)), cut(v, (d,)), cut(log_gate, ()),
         jnp.moveaxis(present.reshape(batch, n, chunk), 1, 0)))
@@ -503,6 +515,9 @@ class PowerRetention(nn.Module):
         q = rope(q, at, self.rope_theta).astype(self.dtype)
         k = rope(k, at, self.rope_theta).astype(self.dtype)
         turns = power_retention.turns(d)
+        # a prompt's piece that was handed a cache continues from it; one
+        # that was handed none starts from zeros and leaves a fresh cache
+        resumed = self.decode and self.has_variable("cache", "state")
         if self.decode:
             state = self.variable("cache", "state", jnp.zeros,
                                   (batch, groups, turns, d, d), F32)
@@ -518,7 +533,8 @@ class PowerRetention(nn.Module):
             with jax.named_scope("retention_chunk"):
                 o, last, last_norm = retention_chunked(
                     q, k, v, log_gate, lengths, self.chunk, self.den_eps,
-                    self.dtype)
+                    self.dtype,
+                    (state.value, total.value) if resumed else None)
             if self.decode:
                 state.value, total.value = last, last_norm
         return dense(d_model, name="out")(o.reshape(batch, seq, heads * d))
@@ -1311,6 +1327,15 @@ class HybridDecoder(nn.Module):
         return dict(self.sparse)["dense_len"]
 
     @property
+    def resumable_prefill(self):
+        """Whether a prefill that is handed a slot's cache continues
+        from it (``positions`` the piece's offset), so that a prompt may
+        be run in pieces: true when every mixer can. A power-retention
+        layer carries its state and its normaliser from piece to piece;
+        the other kinds start every prefill from an empty cache."""
+        return all(kind == POWER_RETENTION for kind in self.mixers)
+
+    @property
     def counts_active_rows(self):
         """Whether a decode step wants ``active`` (the rows that hold a
         request): the expert layers count the pairs they route."""
@@ -1344,7 +1369,8 @@ class HybridDecoder(nn.Module):
     def __call__(self, token_ids, train: bool = False, positions=None,
                  lengths=None, output: str = "logits", active=None):
         """``positions``: (batch,) the absolute position of each row's
-        first token (decode steps; a prefill starts at 0). ``lengths``:
+        first token (decode steps; a prefill starts at 0, or where the
+        pieces before it ended: :attr:`resumable_prefill`). ``lengths``:
         (batch,) the true length of each padded row; with it the result
         has one row a sequence, row ``lengths - 1``. ``active``: (batch,)
         bool, the rows that hold a request (only what is counted looks at

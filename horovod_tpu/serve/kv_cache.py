@@ -17,7 +17,18 @@
   up to the quantum, then power-of-two multiples), floored at the
   quantum so short prompts share one program — the bucket set is
   O(log(max_seq)) and after one request per bucket the program cache is
-  warm: zero steady-state compiles.
+  warm: zero steady-state compiles;
+* for a model whose prefill can resume from its cache
+  (``resumable_prefill``: layers that carry a state and nothing with a
+  position axis), two programs of one shape in place of those:
+  a prompt is cut into pieces of :data:`PREFILL_CHUNK` tokens, enqueued
+  back to back. ``prefill_chunk`` (every piece but the last) reads the
+  slot's row of every leaf - zeros for the first piece - continues the
+  model from it at the piece's offset and writes the row back, with no
+  head; ``prefill_last`` does the same with the true length of the last
+  piece and the head on its last true row. A prompt pads to the next
+  piece, not to the next power of two, and a call stays one call: one
+  pending result, one ``engine.prefill`` span (``chunks``).
 
 The cache is updated IN PLACE: every program donates its cache argument
 (the result aliases it, one cache lives on the device and no program
@@ -105,6 +116,13 @@ from horovod_tpu.runtime.fusion_buffer import bucket_elems
 # prompt-length bucket quantum (tokens). Not a knob: the policy is the
 # runtime's, only the unit differs (tokens, not bytes).
 PREFILL_BUCKET_QUANTUM = 16
+# tokens a piece of a prompt, where the model's prefill can resume from
+# the slot's cache (``resumable_prefill``): a prompt pads to the next
+# piece, not to the next bucket. A multiple of a recurrent mixer's own
+# chunk (256), and rows enough that a piece's matrix products stay bound
+# by compute beside the layers' weights it reads again (PERF.md section 6
+# has the readings at 512, 1,024 and 2,048).
+PREFILL_CHUNK = 1024
 
 _COMPILES = _metrics().counter(
     "horovod_serve_compiles_total",
@@ -238,6 +256,10 @@ class DecodeEngine:
         # a model whose expert layers count what they route is told a
         # decode step's active rows
         self._counts = bool(getattr(model, "counts_active_rows", False))
+        # a model whose prefill continues from the slot's cache has its
+        # prompts run in pieces of PREFILL_CHUNK, through two programs
+        self._resumes = bool(getattr(model, "resumable_prefill", False))
+        self._chunk = min(PREFILL_CHUNK, self.max_seq)
         # does the decode program read its key/value rows through
         # ops/pallas/decode_attention (set by _cache_shapes, from the
         # program itself), and the lane tiles its steps read of a leaf
@@ -258,9 +280,16 @@ class DecodeEngine:
         self._cache = self._allocate_cache()
         # the next token of every row, on the device (module docstring)
         self._feed = jnp.zeros((self.num_slots,), jnp.int32)
-        self._prefill_fns: Dict[int, object] = {}  # guarded-by: <replica-thread>
+        # by bucket, or by name where a prompt runs in pieces
+        self._prefill_fns: Dict[object, object] = {}  # guarded-by: <replica-thread>
         self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._decode_compiled = False
+        # prefill programs enqueued, the positions they computed (padding
+        # and all) and the prompts' own tokens: positions / tokens is the
+        # padding a deployment pays
+        self.prefill_chunks = 0
+        self.prefill_positions = 0
+        self.prefill_tokens = 0
         # program kind -> did its first call consume the cache it was
         # handed (a runtime may decline a donation and copy instead);
         # written once per kind by the replica thread, read by stats()
@@ -328,13 +357,24 @@ class DecodeEngine:
         with self._lock:
             return sum(self._compiles.values())
 
-    def _prefill_fn(self, bucket: int):
-        fn = self._prefill_fns.get(bucket)
+    def _program(self, key, impl, name: str):
+        fn = self._prefill_fns.get(key)
         if fn is None:
-            fn = jax.jit(self._prefill_impl, donate_argnums=(1, 2))
-            self._prefill_fns[bucket] = fn
-            self._note_compile(f"prefill_{bucket}")
+            fn = self._prefill_fns[key] = jax.jit(impl,
+                                                  donate_argnums=(1, 2))
+            self._note_compile(name)
         return fn
+
+    def _prefill_fn(self, bucket: int):
+        return self._program(bucket, self._prefill_impl, f"prefill_{bucket}")
+
+    def _piece_fn(self, program: str):
+        """``prefill_chunk`` or ``prefill_last``, the two programs of a
+        prompt run in pieces; one shape each, whatever the prompt."""
+        return self._program(
+            program, {"prefill_chunk": self._prefill_chunk_impl,
+                      "prefill_last": self._prefill_last_impl}[program],
+            program)
 
     def _run_donating(self, kind: str, fn, *args):
         """Enqueue a program whose second and third arguments are the
@@ -380,6 +420,49 @@ class DecodeEngine:
         feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
         return cache, feed, token, jnp.max(jnp.abs(last))
 
+    def _piece(self, params, cache, tokens, offset, length, slot, output):
+        """One piece of ``slot``'s prompt, ``tokens`` (1, PREFILL_CHUNK)
+        of which ``length`` are the prompt's, from position ``offset``:
+        the model continues from the slot's row of every leaf - a row of
+        zeros for the prompt's first piece, whatever the slot's last
+        request left - and the row it leaves is written back in place. A
+        counter is no slot's row: the model adds to it as it stands."""
+        def row(path, big):
+            if leaf_kind(path) == "counter":
+                return big
+            one = jax.lax.dynamic_index_in_dim(big, slot, axis=0)
+            return jnp.where(offset == 0, 0, one)
+
+        out, mutated = self._model.apply(
+            {"params": params,
+             "cache": jax.tree_util.tree_map_with_path(row, cache)},
+            tokens, positions=offset[None], lengths=length[None],
+            train=False, mutable=["cache"], output=output)
+        cache = jax.tree_util.tree_map_with_path(
+            lambda path, big, one: one if leaf_kind(path) == "counter"
+            else jax.lax.dynamic_update_index_in_dim(
+                big, one[0], slot, axis=0), cache, mutated["cache"])
+        return out, cache
+
+    def _prefill_chunk_impl(self, params, cache, feed, tokens, offset, slot):
+        # a whole piece with more of the prompt to come: the state after
+        # it, and no head (nobody reads a token before the prompt's end)
+        _, cache = self._piece(
+            params, cache, tokens, offset,
+            jnp.asarray(tokens.shape[1], jnp.int32), slot, "hidden")
+        return cache, feed
+
+    def _prefill_last_impl(self, params, cache, feed, tokens, offset, length,
+                           slot):
+        # the prompt's last piece (or its only one), the head on its last
+        # true row, and the rest as _prefill_impl has it
+        logits, cache = self._piece(params, cache, tokens, offset, length,
+                                    slot, "logits")
+        last = logits[0, 0]
+        token = jnp.argmax(last).astype(jnp.int32)
+        feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
+        return cache, feed, token, jnp.max(jnp.abs(last))
+
     def _decode_impl(self, params, cache, feed, positions):
         # a row the host sends -1 for is not active: it runs token 0 at
         # position 0 and leaves its feed entry alone
@@ -397,10 +480,13 @@ class DecodeEngine:
 
     # -- serving ops -------------------------------------------------------
     def prefill(self, slot: int, prompt: List[int]) -> PendingPrefill:
-        """Launch the prompt's bucketed prefill program, which fills
-        ``slot``'s cache rows and puts the first generated token (it
-        comes from prefill itself) into the slot's feed entry. The
-        result collects to (first generated token id, max |logit|)."""
+        """Launch the prompt's prefill, which fills ``slot``'s cache rows
+        and puts the first generated token (it comes from prefill
+        itself) into the slot's feed entry: one program of the prompt's
+        bucket, or, where the model's prefill resumes from its cache,
+        the prompt's pieces of ``PREFILL_CHUNK`` tokens back to back
+        through ``prefill_chunk`` and, the last one, ``prefill_last``.
+        The result collects to (first generated token id, max |logit|)."""
         if not 0 < len(prompt) <= self.max_seq:
             # callers (ServeHandle.submit, Replica._reject) screen this
             # out; fail loudly rather than let the padded copy below
@@ -408,20 +494,41 @@ class DecodeEngine:
             raise ValueError(
                 f"prefill: prompt length {len(prompt)} outside "
                 f"(0, max_seq={self.max_seq}]")
-        bucket = prompt_bucket(len(prompt), self.max_seq)
         sparse = self._dense_len is not None \
             and len(prompt) > self._dense_len
+        if self._resumes:
+            chunk = self._chunk
+            chunks = -(-len(prompt) // chunk)
+            bucket = chunks * chunk
+        else:
+            chunks, bucket = 1, prompt_bucket(len(prompt), self.max_seq)
         t0 = time.time()
         with tracing.span("engine.prefill.dispatch"):
-            fn = self._prefill_fn(bucket)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(prompt)] = prompt
             # numpy scalars ride along with the call; a jnp scalar would
             # be a program of its own before it
-            token, max_abs = self._run_donating(
-                "prefill", fn, padded, np.int32(len(prompt)), np.int32(slot))
+            if self._resumes:
+                last = bucket - chunk
+                for at in range(0, last, chunk):
+                    self._run_donating(
+                        "prefill", self._piece_fn("prefill_chunk"),
+                        padded[:, at:at + chunk], np.int32(at),
+                        np.int32(slot))
+                token, max_abs = self._run_donating(
+                    "prefill", self._piece_fn("prefill_last"),
+                    padded[:, last:], np.int32(last),
+                    np.int32(len(prompt) - last), np.int32(slot))
+            else:
+                token, max_abs = self._run_donating(
+                    "prefill", self._prefill_fn(bucket), padded,
+                    np.int32(len(prompt)), np.int32(slot))
+        self.prefill_chunks += chunks
+        self.prefill_positions += bucket
+        self.prefill_tokens += len(prompt)
         return PendingPrefill(token, max_abs, t0, dict(
-            bucket=bucket, prompt_len=len(prompt), slot=slot, sparse=sparse))
+            bucket=bucket, chunks=chunks, prompt_len=len(prompt), slot=slot,
+            sparse=sparse))
 
     def decode(self, slots: List[int], tokens: Optional[List[int]],
                positions: List[int]) -> PendingDecode:
@@ -512,6 +619,11 @@ class DecodeEngine:
                 "compiles_total": sum(compiles.values()),
                 "decode_steps": self.decode_steps,
                 "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
+                # prefill programs enqueued, positions they computed and
+                # the prompts' own tokens (positions / tokens: the padding)
+                "prefill_chunks": self.prefill_chunks,
+                "prefill_positions": self.prefill_positions,
+                "prefill_tokens": self.prefill_tokens,
                 "cache_bytes": self.cache_bytes(),
                 "cache_bytes_by_kind": self.cache_bytes_by_kind(),
                 "cache_donated": (self._donated.get("prefill", False)

@@ -346,26 +346,68 @@ def test_serving_through_hvd_serve(served):
         hvd.shutdown()
 
 
+def _toy_transformer(max_seq):
+    from horovod_tpu.models.transformer import Transformer
+
+    model = Transformer(vocab_size=61, d_model=32, num_layers=2,
+                        num_heads=2, d_ff=64, max_seq=max_seq, causal=True,
+                        dtype=jnp.float32)
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 8), jnp.int32),
+                             train=False)["params"]
+
+
 @pytest.mark.parametrize("prompt_len", [5, 16, 23, 32, 57])
 def test_gpt2_toy_serving_is_unchanged_by_the_one_row_head(prompt_len):
     """The old trunk's prefill now applies its head to the last prompt
     row alone (no (bucket, vocab) logits): the first token and the
     largest logit are those of the uncached forward's row
     ``prompt_len - 1``, wherever the prompt ends in its bucket."""
-    from horovod_tpu.models.transformer import Transformer
-
-    model = Transformer(vocab_size=61, d_model=32, num_layers=2,
-                        num_heads=2, d_ff=64, max_seq=64, causal=True,
-                        dtype=jnp.float32)
+    model, params = _toy_transformer(max_seq=64)
     toks = np.random.default_rng(prompt_len).integers(1, 61, prompt_len)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
-                        train=False)["params"]
     want = np.asarray(model.apply({"params": params},
                                   jnp.asarray(toks)[None], train=False))[0]
     engine = DecodeEngine(model, params, num_slots=2)
     first, max_abs = engine.prefill(1, toks.tolist())
     assert first == want[-1].argmax()
     assert abs(max_abs - np.abs(want[-1]).max()) < 1e-5
+
+
+@pytest.mark.parametrize("which", ["transformer", "sala", "xing"])
+def test_a_model_that_cannot_resume_keeps_its_bucket_programs(monkeypatch,
+                                                              which):
+    """Only a model that says its prefill resumes from its cache
+    (``resumable_prefill``: every mixer a power retention) has its
+    prompts run in pieces. The dense trunk lacks the property, a model
+    with block-sparse, lightning or latent layers answers false: each
+    keeps one ``prefill_<bucket>`` program a bucket, one program a
+    prompt, however small the piece would be."""
+    from horovod_tpu.serve import kv_cache
+
+    monkeypatch.setattr(kv_cache, "PREFILL_CHUNK", 32)
+    if which == "transformer":
+        model, params = _toy_transformer(max_seq=256)
+        assert not hasattr(model, "resumable_prefill")
+    else:
+        if which == "sala":
+            cfg, params = weights(ALL)
+            model = build_model(cfg)
+        else:
+            _, params, model = xing()
+        assert model.resumable_prefill is False
+    engine = DecodeEngine(model, params, num_slots=2)
+    began = time.time()
+    for slot, n in enumerate((41, 100)):
+        engine.prefill(slot, (tokens(n, seed=n) % 61).tolist()).collect()
+    stats = engine.stats()
+    assert stats["compiles"] == {"prefill_64": 1, "prefill_128": 1}
+    assert stats["prefill_chunks"] == 2
+    assert stats["prefill_positions"] == 64 + 128
+    assert stats["prefill_tokens"] == 141
+    assert [(s["prompt_len"], s["chunks"], s["bucket"])
+            for s in tracing.spans()
+            if s["name"] == "engine.prefill" and s["t"] >= began] == [
+                (41, 1, 64), (100, 1, 128)]
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,7 @@ Tolerances, on logits whose standard deviation is about 0.23 here:
 """
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +37,10 @@ import pytest
 from benchmark import reference_brumby as ref
 from benchmark import weights_brumby
 from benchmark.runners.serve_brumby import build_model
+from horovod_tpu import tracing
 from horovod_tpu.models import hybrid
-from horovod_tpu.serve.kv_cache import DecodeEngine, prompt_bucket
+from horovod_tpu.serve import kv_cache
+from horovod_tpu.serve.kv_cache import DecodeEngine
 
 F32_TOL, BF16_TOL = 5e-5, 6e-2
 CFG = dict(vocab_size=512, d_model=128, d_ff=256, num_heads=4,
@@ -252,6 +255,48 @@ def test_a_padded_prefill_leaves_the_unpadded_ones_state():
     assert np.abs(np.asarray(unmasked[1]) - np.asarray(short[1])).max() > 1e-2
 
 
+@pytest.mark.parametrize("kind", ["near_0", "random"])
+@pytest.mark.parametrize("short", [0, 9], ids=["whole", "padded"])
+@pytest.mark.parametrize("pieces", [2, 3])
+def test_a_sequence_in_pieces_is_the_whole_sequence(pieces, short, kind):
+    """``initial``: a sequence cut at multiples of the chunk, each piece
+    continuing from the state and the normaliser the piece before it
+    returned (in the cache's layout), makes the whole sequence's products
+    in the whole sequence's order: outputs, state and normaliser are the
+    same numbers, with the true length inside the last piece too."""
+    rng = np.random.default_rng(4)
+    length, chunk, dtype = 96, 16, jnp.float32
+    q = jnp.asarray(rng.normal(size=(2, length, HEADS, D)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(2, length, GROUPS, D)), dtype)
+            for _ in range(2))
+    g = jnp.asarray(gates(kind, rng, (2, length, GROUPS)), jnp.float32)
+    lengths = jnp.asarray([length, length - short], jnp.int32)
+    whole = hybrid.retention_chunked(q, k, v, g, lengths, chunk, 1e-6, dtype)
+    size = length // pieces
+    carried, outs = None, []
+    for at in range(0, length, size):
+        cut = slice(at, at + size)
+        out, *carried = hybrid.retention_chunked(
+            q[:, cut], k[:, cut], v[:, cut], g[:, cut],
+            jnp.clip(lengths - at, 0, size), chunk, 1e-6, dtype,
+            initial=carried)
+        outs.append(np.asarray(out, np.float32))
+    assert carried[0].shape == (2, GROUPS, TURNS, D, D)
+    got = np.concatenate(outs, axis=1)
+    for b, n in enumerate(np.asarray(lengths)):
+        assert np.array_equal(got[b, :n],
+                              np.asarray(whole[0], np.float32)[b, :n])
+    for mine, want in zip(carried, whole[1:]):
+        assert np.array_equal(np.asarray(mine), np.asarray(want))
+    # and a piece that starts from nothing is not the same: the carry is
+    # what holds the tokens before it (its outputs' first row sees them
+    # whatever the gates have let go of since)
+    alone = hybrid.retention_chunked(
+        q[:, -size:], k[:, -size:], v[:, -size:], g[:, -size:], None, chunk,
+        1e-6, dtype)
+    assert np.abs(np.asarray(alone[0])[:, 0] - outs[-1][:, 0]).max() > 1e-2
+
+
 # --------------------------------------------------------------- the model
 
 @pytest.mark.parametrize("length", [100, 301])
@@ -329,18 +374,41 @@ def step_logits(engine, step_tokens, positions):
     return np.asarray(logits[:, 0])
 
 
-# 203 is no multiple of the chunk (256 at this width: one chunk) and its
-# bucket is 256; 300's is 512, two chunks, the second mostly padding
-@pytest.mark.parametrize("prompt_len", [203, 61, 300])
-def test_prefill_then_decode_is_the_references_one_forward(served,
+# the engine cuts a prompt into pieces of PREFILL_CHUNK (set to the toy
+# mixer's own chunk, 256) and pads the last one: 203 and 61 are one
+# padded piece, 300 two, the second mostly padding; then the lengths
+# round a piece's edge, whole pieces alone, and three pieces and 17
+CHUNK = 256
+
+
+@pytest.fixture
+def pieces(monkeypatch):
+    monkeypatch.setattr(kv_cache, "PREFILL_CHUNK", CHUNK)
+    return CHUNK
+
+
+def prefill_spans(began):
+    return [s for s in tracing.spans()
+            if s["name"] == "engine.prefill" and s["t"] >= began]
+
+
+@pytest.mark.parametrize("prompt_len", [
+    203, 61, 300, CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK, 3 * CHUNK + 17])
+def test_prefill_then_decode_is_the_references_one_forward(served, pieces,
                                                            prompt_len):
     params, model = served
     total = prompt_len + 40
     toks = tokens(total, seed=prompt_len)
     want = reference(toks)
     engine = DecodeEngine(model, params, num_slots=3)
-    assert prompt_bucket(prompt_len, model.max_seq) > prompt_len
+    assert model.resumable_prefill
+    chunks = -(-prompt_len // pieces)
+    began = time.time()
     first, max_abs = engine.prefill(1, toks[:prompt_len].tolist())
+    # the last piece is padded (or, for whole pieces, exactly full)
+    span, = prefill_spans(began)
+    assert span["chunks"] == chunks and span["bucket"] == chunks * pieces
+    assert 0 <= span["bucket"] - prompt_len < pieces
     assert first == want[prompt_len - 1].argmax()
     assert abs(max_abs - np.abs(want[prompt_len - 1]).max()) < F32_TOL
     for t in range(prompt_len, total):       # teacher forced
@@ -349,6 +417,105 @@ def test_prefill_then_decode_is_the_references_one_forward(served,
         step[1], at[1] = toks[t], t
         got = step_logits(engine, step, at)[1]
         assert np.abs(got - want[t]).max() < F32_TOL, t
+    # two programs of one shape, whatever the prompt
+    assert sorted(engine.stats()["compiles"]) == (
+        ["prefill_chunk", "prefill_last"] if chunks > 1
+        else ["prefill_last"])
+
+
+def greedy(engine, slot, prompt, steps):
+    """The tokens the engine serves ``prompt`` in ``slot``: the prefill's
+    first and ``steps`` decode steps after it, each fed from the feed."""
+    first, _ = engine.prefill(slot, prompt)
+    out = [first]
+    for t in range(steps):
+        ids, _ = engine.decode([slot], None, [len(prompt) + t])
+        out.append(ids[0])
+    return out
+
+
+@pytest.mark.parametrize("second_len", [150, CHUNK + 40],
+                         ids=["one_piece", "two_pieces"])
+def test_a_slots_second_request_starts_from_an_empty_state(served, pieces,
+                                                           second_len):
+    """A prompt's first piece continues from zeros and not from what the
+    slot's last request left (a prompt in pieces reads the slot's row,
+    where a prompt in one program made a fresh one): the second request
+    of a slot is served the tokens a fresh engine serves it."""
+    params, model = served
+    engine = DecodeEngine(model, params, num_slots=2)
+    greedy(engine, 1, tokens(2 * CHUNK + 30, seed=50).tolist(), 12)
+    # what the first request left there is nothing like zeros
+    left = np.asarray(jax.tree.leaves(engine._cache)[0][1])
+    assert np.abs(left).max() > 1e-3
+    second = tokens(second_len, seed=51).tolist()
+    fresh = DecodeEngine(model, params, num_slots=2)
+    assert greedy(engine, 1, second, 24) == greedy(fresh, 1, second, 24)
+
+
+def test_the_prefill_counters_and_the_spans_chunks(served, pieces):
+    """``stats()``: programs enqueued, positions computed (the padding
+    with them) and the prompts' own tokens; the ``engine.prefill`` span
+    stays one a prompt and carries ``chunks``, ``bucket`` the positions
+    computed."""
+    params, model = served
+    engine = DecodeEngine(model, params, num_slots=2)
+    began = time.time()
+    lengths = [41, CHUNK, 2 * CHUNK + 5]
+    for slot, n in zip((0, 1, 0), lengths):
+        engine.prefill(slot, tokens(n, seed=n).tolist()).collect()
+    stats = engine.stats()
+    assert stats["prefill_chunks"] == 1 + 1 + 3
+    assert stats["prefill_positions"] == 5 * CHUNK
+    assert stats["prefill_tokens"] == sum(lengths)
+    assert stats["compiles"] == {"prefill_last": 1, "prefill_chunk": 1}
+    assert stats["cache_donated"] is False      # no decode step yet
+    assert engine._donated["prefill"] is True
+    assert [(s["prompt_len"], s["chunks"], s["bucket"])
+            for s in prefill_spans(began)] == [
+                (41, 1, CHUNK), (CHUNK, 1, CHUNK),
+                (2 * CHUNK + 5, 3, 3 * CHUNK)]
+
+
+def test_a_counter_rides_through_the_pieces(pieces):
+    """A model that resumes may also count (an expert layer's
+    ``expert_counts`` is no slot's row): every piece adds its own true
+    tokens' pairs to the counter as it stands, the first piece's zeroing
+    does not touch it, and the padding of the last is not counted."""
+    model = hybrid.HybridDecoder(
+        vocab_size=64, d_model=32, d_ff=64, num_heads=2, num_kv_heads=1,
+        head_dim=16, mixers=(hybrid.POWER_RETENTION,) * 2,
+        mlps=(hybrid.DENSE_MLP, hybrid.EXPERTS_MLP),
+        experts=dict(num_experts=4, top_k=2, d_ff=32), scale_depth=None,
+        max_seq=1024, dtype=jnp.float32)
+    assert model.resumable_prefill and model.counts_active_rows
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = DecodeEngine(model, params, num_slots=2)
+    total = 0
+    for slot, n in ((1, 2 * CHUNK + 40), (1, 90), (0, CHUNK)):
+        prompt = tokens(n, seed=n) % 64
+        want = np.asarray(model.apply({"params": params},
+                                      jnp.asarray(prompt)[None]))[0, -1]
+        first, max_abs = engine.prefill(slot, prompt.tolist())
+        assert first == want.argmax()
+        assert abs(max_abs - np.abs(want).max()) < F32_TOL
+        total += n
+        counts = engine.expert_counts()
+        assert counts.shape == (1, 3, 4)
+        assert counts[0, 0].sum() == 2 * total and not counts[0, 1:].any()
+
+
+def test_a_piece_is_no_longer_than_the_model_allows():
+    """``max_seq`` under PREFILL_CHUNK (the constant as it stands, 1,024):
+    the pieces are ``max_seq`` long, and the model takes them."""
+    cfg = dict(CFG, max_seq=128)
+    model = build_model(cfg)
+    engine = DecodeEngine(model, weights(), num_slots=1)
+    toks = tokens(100, seed=60)
+    first, _ = engine.prefill(0, toks[:90].tolist())
+    assert first == reference(toks[:90])[-1].argmax()
+    assert engine.stats()["prefill_positions"] == 128
 
 
 def test_the_cache_holds_states_and_nothing_else(served):

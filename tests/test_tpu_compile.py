@@ -180,13 +180,17 @@ def test_flat_adamw_shard_kernel(chip, n):
 
 def _feed_is_aliased(text, params, cache):
     """The compiled module's header aliases the token feed's parameter
-    (the first after the parameters' and the cache's leaves) to the
-    result after the cache's leaves."""
+    (the first after the parameters' and the cache's leaves; earlier
+    where ``jit`` dropped parameters that nothing reads, as the head's
+    from a piece of a prompt that has none) to the result after the
+    cache's leaves."""
     n_params = len(jax.tree.leaves(params))
     n_cache = len(jax.tree.leaves(cache))
     header = text.split("\n", 1)[0]
     assert "input_output_alias" in header
-    return f"{{{n_cache}}}: ({n_params + n_cache}, {{}}, may-alias)" in header
+    found = re.search(rf"\{{{n_cache}\}}: \((\d+), \{{\}}, may-alias\)", header)
+    return bool(found) and int(found[1]) <= n_params + n_cache and bool(
+        re.search(rf"= s32\[\d+\]\S* parameter\({found[1]}\)", text))
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -330,23 +334,26 @@ def test_hybrid_engine_fits_and_updates_its_cache_in_place(chip, monkeypatch,
     assert text.count("tpu_custom_call") == (3 if program == "decode" else 0)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_16384"])
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk",
+                                     "prefill_last"])
 def test_retention_engine_fits_and_rewrites_its_cache_in_place(
         chip, monkeypatch, capsys, program):
     """Brumby-14B-Base as the benchmark runs it (benchmark/configs/
     brumby-14b.json: four power-retention layers at full width, 32 slots,
-    bfloat16 weights): the 32-row decode step and the prefill of the
-    largest bucket the cell reaches compile for one v5e chip - Mosaic
-    takes both kernels of ops/pallas/power_retention at these shapes -
-    arguments plus temporaries stay under its 16 GB (printed: run with
-    ``-s``), the result aliases every leaf of the donated cache - which
-    holds states and normalisers and no leaf with a position axis - and
-    the token feed, and nothing copies a state or a normaliser: the
-    decode step's kernel rewrites all 4.4 GB of it where it lies."""
+    bfloat16 weights): the 32-row decode step and the two programs of
+    a prompt run in 1,024-token pieces (every piece but the last, and
+    the last with the head) compile for one v5e chip - Mosaic takes both
+    kernels of ops/pallas/power_retention at these shapes - arguments
+    plus temporaries stay under its 16 GB (printed: run with ``-s``),
+    the result aliases every leaf of the donated cache - which holds
+    states and normalisers and no leaf with a position axis - and the
+    token feed, and nothing copies a state or a normaliser: the decode
+    step's kernel rewrites all 4.4 GB of it where it lies, and a piece
+    reads its slot's row of each leaf and writes it back in place."""
     import json
 
     from benchmark.runners.serve_brumby import build_model
-    from horovod_tpu.serve.kv_cache import DecodeEngine
+    from horovod_tpu.serve.kv_cache import PREFILL_CHUNK, DecodeEngine
 
     def on_chip(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
@@ -356,7 +363,7 @@ def test_retention_engine_fits_and_rewrites_its_cache_in_place(
     with open(os.path.join(root, "benchmark", "configs",
                            "brumby-14b.json")) as f:
         cfg = json.load(f)["as_run"]
-    layers, slots, bucket, turns = 4, 32, 16384, 65
+    layers, slots, turns = 4, 32, 65
     model = build_model(cfg)
     params = on_chip(jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
@@ -378,8 +385,11 @@ def test_retention_engine_fits_and_rewrites_its_cache_in_place(
         lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
                                        i32(slots))
     else:
-        lowered = eng._prefill_fn(bucket).lower(
-            params, eng._cache, i32(slots), i32(1, bucket), i32(), i32())
+        # (tokens, offset, slot), and the true length before the slot
+        # for the last piece
+        scalars = (i32(),) * (2 if program == "prefill_chunk" else 3)
+        lowered = eng._piece_fn(program).lower(
+            params, eng._cache, i32(slots), i32(1, PREFILL_CHUNK), *scalars)
     compiled = lowered.compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
     with capsys.disabled():
@@ -400,12 +410,21 @@ def test_retention_engine_fits_and_rewrites_its_cache_in_place(
     # layer): it is aliased like the state (the sizes above), and the
     # compiler may stage it through VMEM on its way into the kernel
     assert not re.findall(rf"= {state}\S* copy(-start)?\(", text)
-    # a layer is one kernel: the step's pass over the state, or the
-    # prefill's read of the state between chunks (inside its scan)
+    # a layer is one kernel: the step's pass over the state, or a
+    # piece's read of the state between chunks (inside its scan). A
+    # piece without the head returns the states alone, so nothing reads
+    # what its last layer's mixer puts out: the compiler drops that
+    # layer's read of the state with its MLP, and the head's and those
+    # layers' weights are no argument of the program
     kernel = "retention_step" if program == "decode" else "retention_read"
-    assert text.count("tpu_custom_call") == layers
+    kernels = layers - 1 if program == "prefill_chunk" else layers
+    assert text.count("tpu_custom_call") == kernels
     assert len(re.findall(rf"%{kernel}[.\d]* = [^\n]*? custom-call\(",
-                          text)) == layers
+                          text)) == kernels
+    param_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(params))
+    assert (memory.argument_size_in_bytes - eng.cache_bytes()
+            < (0.63 if program == "prefill_chunk" else 1.01) * param_bytes)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_8192"])
