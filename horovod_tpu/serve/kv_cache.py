@@ -88,8 +88,10 @@ Every call therefore has two halves. :meth:`DecodeEngine.prefill` and
 :meth:`DecodeEngine.decode` launch the program and return a
 :class:`Pending` result whose arrays are still on the device (their copy
 to the host is started at once); its ``collect()`` blocks until they are
-here. The serving loop enqueues step k+1 before it collects step k
-(serve/replica.py). A pending result also unpacks like the tuple it
+here, and its ``ready()`` says without blocking whether the device has
+finished them (the ``starved`` flag of the loop's ``serve.step`` and
+the ``ready`` of the ``wait`` spans). The serving loop enqueues step k+1
+before it collects step k (serve/replica.py). A pending result also unpacks like the tuple it
 stands for, collecting first, so ``token, max_abs = engine.prefill(...)``
 and ``ids, max_abs = engine.decode(...)`` are the blocking calls they
 always were, for tests and tools.
@@ -187,6 +189,12 @@ class Pending:
     on_host = False
     _result = None
 
+    def ready(self) -> bool:
+        """Has the program finished on the device (nothing blocks)? What
+        ``serve.step``'s ``starved`` and the ``wait`` spans' ``ready``
+        are read from."""
+        return self._result is not None or self._max_abs.is_ready()
+
     def collect(self) -> tuple:
         if self._result is None:
             self._result = self._read()
@@ -204,7 +212,10 @@ class PendingPrefill(Pending):
         self._t0, self._attrs = t0, attrs
 
     def _read(self) -> Tuple[int, float]:
-        with tracing.span("engine.prefill.wait"):   # blocked on the device
+        # ``ready``: the value was there when the wait began, so the host
+        # was the later of the two
+        with tracing.span("engine.prefill.wait",   # blocked on the device
+                          ready=int(self.ready())):
             out = int(self._token), float(self._max_abs)
         # the start of the dispatch to the first token on the host
         tracing.record("engine.prefill", self._t0, time.time() - self._t0,
@@ -223,10 +234,12 @@ class PendingDecode(Pending):
 
     def _read(self) -> Tuple[List[int], List[float]]:
         # ``ahead``: a later decode step was already enqueued when the
-        # wait began, so the device has work while the host reads
+        # wait began, so the device has work while the host reads;
+        # ``ready``: this step's ids were there already (the host is late)
         engine = self._engine
         ahead = int(engine.decodes_enqueued > self._number)
-        with tracing.span("engine.decode.wait", ahead=ahead):
+        with tracing.span("engine.decode.wait", ahead=ahead,
+                          ready=int(self.ready())):
             ids = np.asarray(self._ids)          # blocked on the device
             max_abs = np.asarray(self._max_abs)
         # the start of the prep to the ids on the host
@@ -299,6 +312,9 @@ class DecodeEngine:
         # while stats() copies the counters out of it: a leaf that is
         # being read is not donated under the reader
         self._cache_lock = witness.make_lock("DecodeEngine._cache_lock")
+        # seconds the replica thread waited for it since a dispatch span
+        # last set this to zero (the span's ``lock_ms``)
+        self._lock_wait_s = 0.0
         self._compiles: Dict[str, int] = {}      # guarded-by: _lock
         # decode steps enqueued; collected; collected with their successor
         # already enqueued (Replica.stats()["lookahead_share"])
@@ -386,7 +402,9 @@ class DecodeEngine:
         anything."""
         old = None if kind in self._donated \
             else jax.tree.leaves((self._cache, self._feed))
+        t0 = time.perf_counter()
         with self._cache_lock:
+            self._lock_wait_s += time.perf_counter() - t0
             self._cache, self._feed, *rest = fn(
                 self._params, self._cache, self._feed, *args)
         if old is not None:
@@ -503,7 +521,8 @@ class DecodeEngine:
         else:
             chunks, bucket = 1, prompt_bucket(len(prompt), self.max_seq)
         t0 = time.time()
-        with tracing.span("engine.prefill.dispatch"):
+        with tracing.span("engine.prefill.dispatch") as dispatch:
+            self._lock_wait_s = 0.0
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(prompt)] = prompt
             # numpy scalars ride along with the call; a jnp scalar would
@@ -523,6 +542,7 @@ class DecodeEngine:
                 token, max_abs = self._run_donating(
                     "prefill", self._prefill_fn(bucket), padded,
                     np.int32(len(prompt)), np.int32(slot))
+            dispatch.set(lock_ms=round(self._lock_wait_s * 1e3, 4))
         self.prefill_chunks += chunks
         self.prefill_positions += bucket
         self.prefill_tokens += len(prompt)
@@ -569,7 +589,6 @@ class DecodeEngine:
                 self.kv_tiles_read += read
                 self.kv_tiles_held += held
                 attrs["kv_read_share"] = round(read / held, 4)
-                attrs["write_fused"] = int(self._write_fused)
             elif self._reads_live_latents:
                 read, held, attended = latent_attention.live_tiles(
                     step_pos, self.max_seq)
@@ -577,9 +596,11 @@ class DecodeEngine:
                 self.kv_tiles_held += held
                 self.positions_read += attended
                 attrs["kv_read_share"] = round(read / held, 4)
-        with tracing.span("engine.decode.dispatch"):
+        with tracing.span("engine.decode.dispatch") as dispatch:
+            self._lock_wait_s = 0.0
             ids, max_abs = self._run_donating("decode", self._decode_fn,
                                               step_pos)
+            dispatch.set(lock_ms=round(self._lock_wait_s * 1e3, 4))
         self.decodes_enqueued += 1
         return PendingDecode(self, list(slots), ids, max_abs, t0,
                              self.decodes_enqueued, attrs)
@@ -600,53 +621,66 @@ class DecodeEngine:
         is enqueued (no program: nothing compiles), behind the step in
         flight; this thread then waits for it without holding the
         engine's next dispatch."""
+        return self._expert_counts()[0]
+
+    def _expert_counts(self) -> Tuple[Optional[np.ndarray], float]:
+        """:meth:`expert_counts`, and the seconds this thread waited for
+        the cache's lock (a dispatch of the replica's thread held it)."""
         if not self._counts:
-            return None
+            return None, 0.0
+        t0 = time.perf_counter()
         with self._cache_lock:
+            waited = time.perf_counter() - t0
             found = [(jax.tree_util.keystr(path),
                       jax.device_put(x, may_alias=False)) for path, x
                      in jax.tree_util.tree_leaves_with_path(self._cache)
                      if leaf_kind(path) == "counter"]
         by_layer = sorted(found, key=lambda kv: [
             int(n) for n in re.findall(r"\d+", kv[0])])
-        return np.stack([np.asarray(x) for _, x in by_layer])
+        return np.stack([np.asarray(x) for _, x in by_layer]), waited
 
     def stats(self) -> dict:
-        with self._lock:
-            compiles = dict(self._compiles)
-        counts = self.expert_counts()
-        return {"compiles": compiles,
-                "compiles_total": sum(compiles.values()),
-                "decode_steps": self.decode_steps,
-                "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
-                # prefill programs enqueued, positions they computed and
-                # the prompts' own tokens (positions / tokens: the padding)
-                "prefill_chunks": self.prefill_chunks,
-                "prefill_positions": self.prefill_positions,
-                "prefill_tokens": self.prefill_tokens,
-                "cache_bytes": self.cache_bytes(),
-                "cache_bytes_by_kind": self.cache_bytes_by_kind(),
-                "cache_donated": (self._donated.get("prefill", False)
-                                  and self._donated.get("decode", False)),
-                # lane tiles of a key/value leaf the decode steps read
-                # over the tiles of all rows; None where the decode
-                # program reads whole rows (no decode-attention kernel
-                # in it), holds no keys or values at all, or has not run
-                "decode_kv_read_share": (
-                    round(self.kv_tiles_read / self.kv_tiles_held, 4)
-                    if self.kv_tiles_held else None),
-                # the attention kernel writes the step's new key and
-                # value columns itself (the decode program holds no
-                # kv_cache_write); None where it holds no such kernel
-                "decode_write_fused": self._write_fused,
-                # positions the latent kernel's steps attended (a row
-                # that is not active: one), None without that kernel
-                "decode_positions_read": (self.positions_read
-                                          if self._reads_live_latents
-                                          else None),
-                # (layers, 3, experts) as nested lists: pairs, decode
-                # steps that hit the expert, decode steps, each modulo
-                # 2**32 (None: the model has no expert layer)
-                "expert_counts": (None if counts is None
-                                  else counts.tolist()),
-                "slots": self.num_slots}
+        """The engine's counters, as one ``engine.stats`` span on the
+        caller's thread: a reader of the ring sees what ran beside the
+        replica's thread, and ``lock_ms`` is this call's own wait for
+        the cache's lock."""
+        with tracing.span("engine.stats") as span:
+            counts, waited = self._expert_counts()
+            span.set(lock_ms=round(waited * 1e3, 4))
+            with self._lock:
+                compiles = dict(self._compiles)
+            return {"compiles": compiles,
+                    "compiles_total": sum(compiles.values()),
+                    "decode_steps": self.decode_steps,
+                    "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
+                    # prefill programs enqueued, positions they computed and
+                    # the prompts' own tokens (positions / tokens: the padding)
+                    "prefill_chunks": self.prefill_chunks,
+                    "prefill_positions": self.prefill_positions,
+                    "prefill_tokens": self.prefill_tokens,
+                    "cache_bytes": self.cache_bytes(),
+                    "cache_bytes_by_kind": self.cache_bytes_by_kind(),
+                    "cache_donated": (self._donated.get("prefill", False)
+                                      and self._donated.get("decode", False)),
+                    # lane tiles of a key/value leaf the decode steps read
+                    # over the tiles of all rows; None where the decode
+                    # program reads whole rows (no decode-attention kernel
+                    # in it), holds no keys or values at all, or has not run
+                    "decode_kv_read_share": (
+                        round(self.kv_tiles_read / self.kv_tiles_held, 4)
+                        if self.kv_tiles_held else None),
+                    # the attention kernel writes the step's new key and
+                    # value columns itself (the decode program holds no
+                    # kv_cache_write); None where it holds no such kernel
+                    "decode_write_fused": self._write_fused,
+                    # positions the latent kernel's steps attended (a row
+                    # that is not active: one), None without that kernel
+                    "decode_positions_read": (self.positions_read
+                                              if self._reads_live_latents
+                                              else None),
+                    # (layers, 3, experts) as nested lists: pairs, decode
+                    # steps that hit the expert, decode steps, each modulo
+                    # 2**32 (None: the model has no expert layer)
+                    "expert_counts": (None if counts is None
+                                      else counts.tolist()),
+                    "slots": self.num_slots}

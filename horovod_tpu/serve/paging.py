@@ -646,7 +646,8 @@ class PagedDecodeEngine:
                 self._params, self._cache, jnp.asarray(padded),
                 jnp.int32(hit_tokens), jnp.int32(len(suffix) - 1),
                 jnp.asarray(row))
-        with tracing.span("engine.prefill.wait"):   # blocked on the device
+        with tracing.span("engine.prefill.wait",   # blocked on the device
+                          ready=int(max_abs.is_ready())):
             token, max_abs = int(token), float(max_abs)
         self._set_table(slot, taken, len(prompt))
         if hit_pages:
@@ -700,7 +701,8 @@ class PagedDecodeEngine:
             self._cache, ids, max_abs = self._decode_fn(
                 self._params, self._cache, jnp.asarray(step_tokens),
                 jnp.asarray(step_pos), jnp.asarray(step_table))
-        with tracing.span("engine.decode.wait"):   # blocked on the device
+        with tracing.span("engine.decode.wait",   # blocked on the device
+                          ready=int(max_abs.is_ready())):
             ids = np.asarray(ids)
             max_abs = np.asarray(max_abs)
         ms = (time.monotonic() - start) * 1000.0
@@ -753,16 +755,19 @@ class PagedDecodeEngine:
         return round(self.reused_tokens / total, 4) if total else 0.0
 
     def stats(self) -> dict:
-        with self._lock:
-            compiles = dict(self._compiles)
-        return {"compiles": compiles,
-                "compiles_total": sum(compiles.values()),
-                "decode_steps": self.decode_steps,
-                "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
-                "cache_bytes": self.cache_bytes(),
-                # the paged decode step gathers every mapped page into a
-                # row view and attends over all of it
-                "decode_kv_read_share": None,
-                "decode_write_fused": None,
-                "slots": self.num_slots,
-                "pages": self.page_stats()}
+        # on the caller's thread, as the dense engine's (it takes no
+        # lock the replica's thread wants: no ``lock_ms``)
+        with tracing.span("engine.stats"):
+            with self._lock:
+                compiles = dict(self._compiles)
+            return {"compiles": compiles,
+                    "compiles_total": sum(compiles.values()),
+                    "decode_steps": self.decode_steps,
+                    "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
+                    "cache_bytes": self.cache_bytes(),
+                    # the paged decode step gathers every mapped page into
+                    # a row view and attends over all of it
+                    "decode_kv_read_share": None,
+                    "decode_write_fused": None,
+                    "slots": self.num_slots,
+                    "pages": self.page_stats()}

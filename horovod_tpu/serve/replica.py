@@ -29,8 +29,10 @@ passed the guard. Whatever tears the loop down (quarantine, ``stop()``,
 unread: greedy decoding regenerates it elsewhere, and the engine's cache
 is always the result of the last program enqueued.
 
-The loop is written against enqueue/collect and looks at one thing, a
-result's ``on_host``. The dense engine's ``prefill`` / ``decode`` return
+The loop is written against enqueue/collect and asks a result two
+things: ``on_host`` (collect it at once?) and, round each enqueue for
+the ``starved`` flag and the ``dry_enqueues`` count of its
+``serve.step``, ``ready()`` (has the device finished it?). The dense engine's ``prefill`` / ``decode`` return
 results that are still on the device, so the loop runs ahead; an engine
 whose calls block (the paged engine, which re-plans rows between
 dispatch and result: serve/paging.py) hands back plain tuples that are
@@ -225,6 +227,9 @@ class _Collected:
     def __init__(self, result):
         self._result = result
 
+    def ready(self) -> bool:
+        return True
+
     def collect(self):
         return self._result
 
@@ -276,6 +281,13 @@ class Replica:
         # of the open serve.step: requests admitted and pulled in it, and
         # the passes before it that pulled, admitted and decoded nothing
         self._admitted = self._pulled = self._idle_passes = 0
+        # had the device run dry when this pass came to its first enqueue
+        # (None: it has enqueued nothing), how many of its enqueues
+        # returned to find the program before them finished, and the
+        # passes whose first enqueue found it dry
+        self._starved: Optional[int] = None
+        self._dry_enqueues = 0
+        self.starved_steps = 0
         # enqueued, not collected: the decode step of the pass before,
         # this pass's prefills (with their request and dispatch time),
         # and the requests retired by count whose values are among them
@@ -471,9 +483,16 @@ class Replica:
         found no rows and slept is recorded with ``decoded=0``; of a run
         of passes that pulled, admitted and decoded nothing only the
         first is (an idle replica spins every 2 ms and would otherwise
-        wipe the ring in seconds)."""
+        wipe the ring in seconds). ``starved``: at the pass's first
+        enqueue everything enqueued before had finished on the device,
+        which then had nothing to run until this pass's program arrived
+        (a blocking engine's passes all read 1); ``dry_enqueues``: how
+        many of the pass's enqueues returned to find the program before
+        them already finished (the device ran dry while the host was
+        still dispatching the next: a gap inside the pass)."""
         with tracing.span("serve.step") as step:
             self._admitted = self._pulled = 0
+            self._starved, self._dry_enqueues = None, 0
             decoded = self._step()
             if decoded or self._admitted or self._pulled:
                 self._idle_passes = 0
@@ -485,6 +504,9 @@ class Replica:
                      occupancy=decoded or self.batcher.occupancy(),
                      waiting=self.batcher.waiting(),
                      admitted=self._admitted)
+            if self._starved is not None:
+                step.set(starved=self._starved,
+                         dry_enqueues=self._dry_enqueues)
 
     def _pull(self, now: float) -> None:
         free = self.engine.num_slots - self.batcher.occupancy()
@@ -509,14 +531,36 @@ class Replica:
             if not reqs and self._idle_passes:
                 pulled.discard()
 
+    def _before_enqueue(self):
+        """The newest program the loop has in flight (this pass's last
+        prefill, else the decode step of the pass before, if not
+        collected yet; ``None``: nothing). Before the pass's first
+        enqueue it decides ``starved``: had that program finished?"""
+        newest = self._first_tokens[-1][1] if self._first_tokens \
+            else self._ahead and self._ahead.pending
+        if self._starved is None:
+            self._starved = int(newest is None or newest.ready())
+            self.starved_steps += self._starved
+        return newest
+
+    def _after_enqueue(self, before) -> None:
+        """The enqueue has returned: if the program ``before`` it had
+        finished by now, the device ran dry before this one reached it
+        (a dispatch takes the host a millisecond or more, and the gap
+        opens while it is under way: a look before it cannot see it)."""
+        self._dry_enqueues += int(before is None or before.ready())
+
     def _enqueue_prefill(self, active: ActiveRequest):
         """The slot's prefill as something to ``collect()``; ``None``
         when the page pool could not take it and the admission bounced
         back to the queue."""
+        before = self._before_enqueue()
         while True:
             try:
-                return _pending(self.engine.prefill(active.slot,
-                                                    active.request.prompt))
+                pending = _pending(self.engine.prefill(
+                    active.slot, active.request.prompt))
+                self._after_enqueue(before)
+                return pending
             except PagePoolExhausted:
                 # prefill rolled its partial allocations back; preempt
                 # the newest OTHER request and retry. With nothing left
@@ -532,10 +576,13 @@ class Replica:
         # a row's last token is on the host unless the program that makes
         # it is still in flight, and the engine that left it in flight
         # has it in its feed
+        before = self._before_enqueue()
         in_flight = self._ahead is not None or bool(self._first_tokens)
         tokens = None if in_flight else [a.generated[-1] for a in rows]
-        return _pending(self.engine.decode(
+        pending = _pending(self.engine.decode(
             [a.slot for a in rows], tokens, [a.position for a in rows]))
+        self._after_enqueue(before)
+        return pending
 
     def _admit(self, now: float) -> bool:
         """Enqueue the prefills of what the batcher admits. False when a
@@ -750,6 +797,10 @@ class Replica:
                # loop, 0 where the engine's calls block
                "lookahead_share": round(
                    getattr(self.engine, "decodes_ahead", 0) / steps, 3),
+               # passes that came to enqueue with nothing left running
+               # on the device (serve.step's ``starved``): a replica
+               # where this grows with decode_steps is host-bound
+               "starved_steps": self.starved_steps,
                # memory plane: resident KV bytes + the slot-occupancy-
                # weighted share of the cache that did useful work
                "kv_cache_bytes": self.engine.cache_bytes(),

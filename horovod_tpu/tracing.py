@@ -25,13 +25,21 @@ This module is the Dapper-style answer:
   engine (``engine.prefill``, ``engine.decode`` and their ``dispatch`` /
   ``wait`` parts) and the input feed (``input.wait``, ``input.put``).
   A span goes to the same ring with its ``parent`` (the enclosing open
-  span of its thread) and, under the same name, into whatever
-  ``jax.profiler`` trace is running, on the clock the device events are
-  on.
+  span of its thread) and its ``thread`` (the recording thread's name)
+  and, under the same name, into whatever ``jax.profiler`` trace is
+  running, on the clock the device events are on.
   Spans serialize into the profiler dump (``request_spans``) and merge
   into the Perfetto trace as per-request lanes with flow arrows joining
   one ``trace_id`` across ranks on the ``/_time``-corrected clock
   (profiler.merge_profile_dir).
+* **Host stalls.** While tracing is on, one ``gc.callbacks`` entry
+  (installed by :func:`configure`, removed at ``hvd.shutdown()``) turns
+  every pass of the garbage collector into a ``TraceAnnotation`` and
+  running totals by generation
+  (:func:`gc_totals`, in the ``GET /slo`` document), and a pass of
+  :data:`GC_SPAN_FLOOR_S` or more into a ``host.gc`` span on the thread
+  that ran it: whatever the collector held the interpreter for has a
+  name, a thread and a place on the profiler's clock.
 * **SLOs.** Declared objectives — ``HOROVOD_SLO_TTFT_MS``,
   ``HOROVOD_SLO_LATENCY_MS``, ``HOROVOD_SLO_AVAILABILITY`` — tracked as
   rolling good/bad windows with error-budget and burn-rate gauges
@@ -52,6 +60,7 @@ fast-burn page threshold). docs/tracing.md is the full model.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import os
 import threading
@@ -73,6 +82,13 @@ SCHEMA = "horovod-tracing-v1"
 OBJECTIVES = ("ttft", "latency", "availability")
 # slowest-request exemplars kept (each carries its span summary)
 _EXEMPLARS_MAX = 8
+# a garbage-collector pass this long or longer is a ``host.gc`` span; a
+# shorter one only adds to gc_totals() (a young-generation pass every few
+# hundred allocations would flood the ring)
+GC_SPAN_FLOOR_S = 0.0005
+# spans that say whether a jax.profiler session was recording when they
+# began (``profiled=1``): a reader keeps a traced slice's steps apart
+_MARKS_PROFILED = frozenset(("serve.step", "input.wait"))
 
 _SPANS_TOTAL = _metrics().counter(
     "horovod_trace_spans_total",
@@ -133,11 +149,12 @@ class Tracer:
         package-wide trace clock domain, correctable by the rendezvous
         ``/_time`` offset at merge time); ``dur`` is seconds. ``parent``
         is the :func:`span` open on this thread, if any, as its
-        ``(name, sid)``."""
+        ``(name, sid)``; ``thread`` is this thread's name."""
         if not self.enabled:
             return
         span = {"trace_id": trace_id, "name": name, "t": t0,
-                "dur": dur, "rank": self.rank}
+                "dur": dur, "rank": self.rank,
+                "thread": threading.current_thread().name}
         stack = getattr(_open, "stack", None)
         if stack:
             span["parent"] = stack[-1]
@@ -201,7 +218,10 @@ class _Span:
         if stack is None:
             stack = _open.stack = []
         self.key = (self.name, next(_numbers))
-        self._annotated = (_annotation or _annotation_class())(
+        cls = _annotation or _annotation_class()
+        if self.name in _MARKS_PROFILED and _profiling(cls):
+            self.attrs["profiled"] = 1
+        self._annotated = cls(
             self.name, **{k: v for k, v in self.attrs.items()
                           if isinstance(v, (int, float, str))})
         self._annotated.__enter__()
@@ -239,6 +259,64 @@ class _NoSpan:
 
 
 _NO_SPAN = _NoSpan()
+
+
+def _profiling(cls) -> bool:
+    """Is a ``jax.profiler`` session recording (``cls`` is what
+    :func:`_annotation_class` gave)?"""
+    is_enabled = getattr(cls, "is_enabled", None)
+    return bool(is_enabled and is_enabled())
+
+
+class _GcWatch:
+    """The one ``gc.callbacks`` entry (module docstring, "Host stalls").
+    The collector never runs inside itself, in this thread or another,
+    so one pass is open at a time and its start needs no lock."""
+
+    def __init__(self) -> None:
+        # generation -> [passes, seconds, the longest pass's seconds]
+        self.totals: Dict[int, List[float]] = {}
+        self._t0 = None
+        self._annotated = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._annotated = (_annotation or _annotation_class())(
+                "host.gc", generation=info["generation"])
+            self._annotated.__enter__()
+            self._t0 = time.time()
+        elif self._t0 is not None:
+            t0, self._t0 = self._t0, None
+            dur = time.time() - t0
+            self._annotated.__exit__(None, None, None)
+            total = self.totals.setdefault(info["generation"], [0, 0.0, 0.0])
+            total[0] += 1
+            total[1] += dur
+            total[2] = max(total[2], dur)
+            if dur >= GC_SPAN_FLOOR_S:
+                _tracer.record("host.gc", t0, dur,
+                               generation=info["generation"],
+                               collected=info["collected"])
+
+    def watch(self, on: bool) -> None:
+        """In ``gc.callbacks`` once while ``on``, not at all otherwise."""
+        if on and self not in gc.callbacks:
+            gc.callbacks.append(self)
+        elif not on and self in gc.callbacks:
+            gc.callbacks.remove(self)
+            self._t0 = None
+
+
+_gc_watch = _GcWatch()
+
+
+def gc_totals() -> Dict[int, dict]:
+    """Every garbage-collector pass since tracing came on, by generation:
+    ``{generation: {"count", "seconds", "longest_s"}}``. Unlike the ring
+    it forgets nothing; empty where tracing never was on."""
+    return {gen: {"count": int(n), "seconds": seconds, "longest_s": longest}
+            for gen, (n, seconds, longest)
+            in sorted(_gc_watch.totals.items())}
 
 
 class SLOTracker:
@@ -413,6 +491,7 @@ class SLOTracker:
                 "slow_request_exemplars": list(self._exemplars),
             }
         doc["spans_recorded"] = _tracer.spans_recorded()
+        doc["gc"] = {str(gen): total for gen, total in gc_totals().items()}
         doc["rank"] = _tracer.rank
         return doc
 
@@ -450,8 +529,9 @@ def record(name: str, t0: float, dur: float, trace_id: str = "",
 def span(name: str, trace_id: str = "", **attrs):
     """Context manager around one layer-boundary interval: stamps start
     and end on the epoch clock and records ``name``, ``t``, ``dur``,
-    ``rank``, ``trace_id``, ``attrs``, its running number ``sid`` and its
-    ``parent`` (the enclosing open span of this thread) into the ring;
+    ``rank``, ``thread``, ``trace_id``, ``attrs``, its running number
+    ``sid`` and its ``parent`` (the enclosing open span of this thread)
+    into the ring;
     the same interval is a ``jax.profiler.TraceAnnotation`` of the same
     name, so it lands in the host plane of any running profiler session.
     An exception inside still closes and records it."""
@@ -465,11 +545,12 @@ def spans() -> List[dict]:
 
 
 def configure(rank: Optional[int] = None) -> None:
-    """Adopt the rank, re-read knobs, register the flight-recorder state
-    provider and mark the process initialized (called from
-    ``hvd.init()``)."""
+    """Adopt the rank, re-read knobs, watch the garbage collector while
+    tracing is on, register the flight-recorder state provider and mark
+    the process initialized (called from ``hvd.init()``)."""
     global _init_ready
     _tracer.configure(rank=rank)
+    _gc_watch.watch(_tracer.enabled)
     _slo.configure()
     _init_ready = True
     from horovod_tpu import flight_recorder
@@ -478,8 +559,12 @@ def configure(rank: Optional[int] = None) -> None:
 
 
 def mark_initialized(ready: bool = True) -> None:
+    """``hvd.shutdown()`` marks the process not ready: the collector's
+    watch goes with it (what runs after the program is not its stall)."""
     global _init_ready
     _init_ready = ready
+    if not ready:
+        _gc_watch.watch(False)
 
 
 def note_serve_started() -> None:

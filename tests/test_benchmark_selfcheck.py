@@ -20,7 +20,9 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCHMARK_TESTS = os.path.join(REPO, "benchmark", "tests")
-TIMEOUT_S = 600
+# alone on an idle machine they take 370-430 s (92 cases, PR 37); beside
+# five other workers of this suite they have passed 600 s here
+TIMEOUT_S = 900
 
 
 def _test_functions():

@@ -1036,6 +1036,7 @@ class _Poisoned:
 
     def __init__(self, pending):
         self._pending = pending
+        self.ready = pending.ready
 
     def collect(self):
         ids, max_abs = self._pending.collect()
@@ -1065,6 +1066,7 @@ def test_guard_one_step_late_delivers_nothing_of_the_bad_step(tiny_lm):
 
         def __init__(self, pending, number):
             self._pending, self._number = pending, number
+            self.ready = pending.ready
 
         def collect(self):
             collected.append(self._number)

@@ -535,11 +535,11 @@ def tiny_lm():
 ], ids=["kernel", "masked"])
 def test_engine_decode_span_carries_kv_read_share(ring, max_seq, shares):
     """``engine.decode`` says what share of the rows' lane tiles the
-    step's attention read, and that the same kernel wrote the step's new
-    columns (``write_fused``), where the decode program holds the
-    decode-attention kernel; it carries neither attribute where the
-    program reads whole rows and writes through ``kv_cache_write``. The
-    engine's ``stats()`` say the same of the program as a whole."""
+    step's attention read, where the decode program holds the
+    decode-attention kernel; it carries no such attribute where the
+    program reads whole rows and writes through ``kv_cache_write``. That
+    the same kernel wrote the step's new columns is a constant of the
+    engine: its ``stats()`` say it, the span does not."""
     import jax
     import jax.numpy as jnp
 
@@ -560,8 +560,7 @@ def test_engine_decode_span_carries_kv_read_share(ring, max_seq, shares):
            if s["name"] == "engine.decode"]
     assert got == shares
     kernel = shares[0] is not None
-    assert [s.get("write_fused") for s in ring.spans()
-            if s["name"] == "engine.decode"] == [1 if kernel else None] * 3
+    assert not any("write_fused" in s for s in ring.spans())
     assert engine.stats()["decode_write_fused"] is (True if kernel else None)
     assert [s["rows"] for s in ring.spans()
             if s["name"] == "engine.decode"] == [1, 2, 2]
@@ -680,6 +679,283 @@ def test_replica_loop_and_engine_spans(ring, tiny_lm, paged):
         assert s["blocks"] == {3: 1, 4: 2}[s["tokens"]]
     assert {s["parent"][0] for s in by["request.queue_wait"]} == \
         {"serve.admit"}
+
+
+# ------------------------------------------------- the host's stalls
+
+def test_spans_carry_the_thread_that_recorded_them(ring):
+    import threading
+
+    def other():
+        with tracing.span("worker.span"):
+            tracing.record("worker.record", time.time(), 0.0)
+
+    t = threading.Thread(target=other, name="worker-7")
+    t.start()
+    t.join(5.0)
+    with tracing.span("main.span"):
+        pass
+    by = _by_name(ring.spans())
+    assert by["worker.span"][0]["thread"] == "worker-7"
+    assert by["worker.record"][0]["thread"] == "worker-7"
+    assert by["main.span"][0]["thread"] == threading.current_thread().name
+
+
+@pytest.fixture
+def gc_watch(ring, monkeypatch):
+    """A fresh collector watch behind ``tracing.configure()``; whatever
+    the test installs is out of ``gc.callbacks`` again afterwards."""
+    import gc
+
+    watch = tracing._GcWatch()
+    monkeypatch.setattr(tracing, "_gc_watch", watch)
+    monkeypatch.setattr(tracing, "_init_ready", tracing._init_ready)
+    yield watch
+    watch.watch(False)
+    assert watch not in gc.callbacks
+
+
+@pytest.mark.parametrize("generation", [0, 2])
+def test_gc_pass_is_a_span_with_its_generation(gc_watch, ring, monkeypatch,
+                                               generation):
+    import gc
+
+    monkeypatch.setattr(tracing, "GC_SPAN_FLOOR_S", 0.0)
+    tracing.configure()
+    with tracing.span("outer") as outer:
+        gc.collect(generation)
+    mine = [s for s in ring.spans() if s["name"] == "host.gc"
+            and s["generation"] == generation]
+    assert mine, ring.spans()
+    last = mine[-1]
+    assert last["thread"] == "MainThread" and last["collected"] >= 0
+    assert last["parent"] == outer.key and last["dur"] >= 0.0
+    total = tracing.gc_totals()[generation]
+    assert total["count"] >= 1 and total["longest_s"] <= total["seconds"]
+    # the totals ride the GET /slo document beside the spans recorded
+    doc = tracing.slo_state()
+    assert doc["gc"][str(generation)]["count"] == \
+        tracing.gc_totals()[generation]["count"]
+    assert doc["spans_recorded"] >= 1
+    json.dumps(doc)
+
+
+def test_gc_pass_under_the_floor_counts_and_stays_out_of_the_ring(
+        gc_watch, ring, monkeypatch):
+    import gc
+
+    monkeypatch.setattr(tracing, "GC_SPAN_FLOOR_S", 3600.0)
+    tracing.configure()
+    gc.collect(0)
+    gc.collect(0)
+    assert tracing.gc_totals()[0]["count"] >= 2
+    assert not [s for s in ring.spans() if s["name"] == "host.gc"]
+
+
+def test_trace_off_hooks_nothing_and_keeps_no_totals(gc_watch, monkeypatch):
+    import gc
+
+    monkeypatch.setenv(HOROVOD_TRACE, "0")
+    monkeypatch.setattr(tracing, "_tracer", tracing.Tracer())
+    tracing.configure()
+    assert gc_watch not in gc.callbacks
+    gc.collect()
+    assert tracing.gc_totals() == {} and tracing.spans() == []
+    assert tracing.span("serve.step") is tracing.span("host.gc")
+
+
+def test_configure_twice_installs_one_callback_and_off_removes_it(
+        gc_watch, monkeypatch):
+    import gc
+
+    tracing.configure()
+    tracing.configure()
+    assert gc.callbacks.count(gc_watch) == 1
+    tracing.mark_initialized(False)         # hvd.shutdown()
+    assert gc_watch not in gc.callbacks
+    tracing.configure()
+    assert gc.callbacks.count(gc_watch) == 1
+    monkeypatch.setenv(HOROVOD_TRACE, "0")
+    tracing.configure()
+    assert gc_watch not in gc.callbacks
+
+
+def test_steps_and_input_waits_say_whether_a_profiler_was_recording(
+        ring, tmp_path):
+    import jax
+
+    def spans_now():
+        with tracing.span("serve.step"):
+            with tracing.span("engine.decode"):
+                pass
+        with tracing.span("input.wait", depth=1):
+            pass
+
+    spans_now()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        spans_now()
+    finally:
+        jax.profiler.stop_trace()
+    spans_now()
+    by = _by_name(ring.spans())
+    assert [s.get("profiled") for s in by["serve.step"]] == [None, 1, None]
+    assert [s.get("profiled") for s in by["input.wait"]] == [None, 1, None]
+    assert not any("profiled" in s for s in by["engine.decode"])
+
+
+class _OnDevice:
+    """A device array's stand-in whose ``is_ready()`` is as told: the
+    values are the real call's, already on the host."""
+
+    def __init__(self, values, ready):
+        import numpy as np
+
+        self._values, self._ready = np.asarray(values), ready
+
+    def is_ready(self):
+        return self._ready
+
+    def __array__(self, dtype=None, copy=None):
+        return self._values
+
+    def __int__(self):
+        return int(self._values)
+
+    def __float__(self):
+        return float(self._values)
+
+    def copy_to_host_async(self):
+        pass
+
+
+@pytest.mark.parametrize("ready", [0, 1], ids=["running", "finished"])
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_starved_and_ready_follow_the_pending_results(ring, tiny_lm, paged,
+                                                      ready):
+    """``serve.step``'s ``starved`` is what the newest pending result
+    said at the pass's first enqueue, and a ``wait`` span's ``ready``
+    what its own said when the wait began. The dense loop holds one
+    decode step across passes, so its flag follows the device; the paged
+    engine's calls block, nothing is ever pending, and every pass that
+    enqueues reads 1."""
+    from test_serve import _replica
+
+    model, params = tiny_lm
+    if paged:
+        from horovod_tpu.serve.paging import PagedDecodeEngine
+
+        engine = PagedDecodeEngine(model, params, num_slots=2,
+                                   page_tokens=16, pool_pages=12)
+        decode_fn = engine._decode_fn
+
+        def told(*args):
+            cache, ids, max_abs = decode_fn(*args)
+            return cache, _OnDevice(ids, ready), _OnDevice(max_abs, ready)
+
+        engine._decode_fn = told
+    else:
+        from horovod_tpu.serve.kv_cache import DecodeEngine
+
+        engine = DecodeEngine(model, params, num_slots=2)
+        run = engine._run_donating
+        engine._run_donating = lambda *args: [
+            _OnDevice(x, ready) for x in run(*args)]
+    q = RequestQueue()
+    uids = [q.submit([1, 2, 3], max_new_tokens=4),
+            q.submit([4, 5], max_new_tokens=4)]
+    rep = _replica(engine, q)
+    for _ in range(6):
+        rep._iterate()
+    assert all(q.result(u, timeout=1.0).finish == "length" for u in uids)
+    by = _by_name(ring.spans())
+    enqueued = [s for s in by["serve.step"] if s["decoded"]]
+    assert len(enqueued) == 3
+    # the first pass finds nothing in flight: the device is dry
+    assert [s["starved"] for s in enqueued] == \
+        ([1, 1, 1] if paged or ready else [1, 0, 0])
+    # the first pass enqueues two prefills and a decode step, the others
+    # a decode step: each returns to find what went before it done, or
+    # not (before the very first there was nothing: dry)
+    assert [s["dry_enqueues"] for s in enqueued] == \
+        ([3, 1, 1] if paged or ready else [1, 0, 0])
+    assert all("starved" not in s and "dry_enqueues" not in s
+               for s in by["serve.step"] if not s["decoded"])
+    assert [w["ready"] for w in by["engine.decode.wait"]] == [ready] * 3
+    if paged:
+        assert {w["ready"] for w in by["engine.prefill.wait"]} <= {0, 1}
+    else:
+        assert [w["ready"] for w in by["engine.prefill.wait"]] == [ready] * 2
+    stats = rep.stats()
+    assert stats["starved_steps"] == sum(s["starved"] for s in enqueued)
+    assert "lookahead_share" in stats
+
+
+def test_starved_steps_counts_with_tracing_off(monkeypatch, tiny_lm):
+    """The flag is a span's; the count is the replica's own."""
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+    from test_serve import _replica
+
+    monkeypatch.setenv(HOROVOD_TRACE, "0")
+    monkeypatch.setattr(tracing, "_tracer", tracing.Tracer())
+    model, params = tiny_lm
+    q = RequestQueue()
+    q.submit([1, 2, 3], max_new_tokens=3)
+    rep = _replica(DecodeEngine(model, params, num_slots=2), q)
+    for _ in range(4):
+        rep._iterate()
+    assert rep.completed == 1 and tracing.spans() == []
+    assert 1 <= rep.stats()["starved_steps"] <= rep.decode_iterations
+
+
+def test_lock_waits_show_on_the_dispatch_and_on_engine_stats(ring):
+    """A thread that holds the cache's lock keeps both a dispatch of the
+    replica's thread and a ``stats()`` of the caller's waiting: each
+    span says for how long, and ``engine.stats`` is a span of the thread
+    that called it."""
+    import threading
+
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+    from test_hybrid_model import xing
+
+    cfg, params, model = xing()
+    engine = DecodeEngine(model, params, num_slots=2)
+    engine.prefill(0, [3, 1, 4, 1, 5]).collect()
+    engine.stats()                                   # nobody holds it
+    calm = [s for s in ring.spans() if s["name"] == "engine.stats"][-1]
+    assert calm["lock_ms"] < 40.0 and calm["thread"] == "MainThread"
+
+    def holding(call):
+        held = threading.Event()
+
+        def hold():
+            with engine._cache_lock:
+                held.set()
+                time.sleep(0.06)
+
+        t = threading.Thread(target=hold)
+        t.start()
+        assert held.wait(5.0)
+        out = call()
+        t.join(5.0)
+        assert not t.is_alive()
+        return out
+
+    holding(lambda: engine.decode([0], [7], [5])).collect()
+    reader = threading.Thread(target=lambda: holding(engine.stats),
+                              name="stats-reader")
+    reader.start()
+    reader.join(10.0)
+    assert not reader.is_alive()
+    by = _by_name(ring.spans())
+    assert by["engine.decode.dispatch"][-1]["lock_ms"] >= 40.0
+    assert by["engine.prefill.dispatch"][-1]["lock_ms"] < 40.0
+    stats = by["engine.stats"][-1]
+    assert stats["lock_ms"] >= 40.0 and stats["thread"] == "stats-reader"
+    assert stats["dur"] * 1e3 >= stats["lock_ms"]
+    assert "parent" not in stats
 
 
 def test_prefetch_records_one_wait_and_one_put_per_batch(ring):
