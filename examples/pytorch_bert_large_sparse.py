@@ -14,7 +14,8 @@ the chip rate of BERT-Large see the ``bertl-train-c1`` cell of
 semantics* end-to-end under the launcher:
 
     tpurun -np 2 python examples/pytorch_bert_large_sparse.py \
-        --layers 2 --seq 32 --batch 4 --steps 2   # CI-sized
+        --layers 2 --d-model 128 --heads 4 --seq 32 --batch 4 \
+        --steps 2                                 # CI-sized
     tpurun -np 8 python examples/pytorch_bert_large_sparse.py  # full
 
 Prints per-rank tokens/s and verifies all ranks hold identical weights
@@ -34,8 +35,8 @@ VOCAB = 30522
 
 class BertLarge(torch.nn.Module):
     """BERT-Large-shaped encoder MLM (d=1024, 16 heads, ff 4096; layer
-    count configurable for CI). The token embedding is sparse=True so
-    its gradient takes the allgather/sparse path."""
+    count and width configurable for CI). The token embedding is
+    sparse=True so its gradient takes the allgather/sparse path."""
 
     def __init__(self, layers=24, d_model=1024, heads=16, seq=512):
         super().__init__()
@@ -55,6 +56,8 @@ class BertLarge(torch.nn.Module):
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--layers", type=int, default=24)
+    parser.add_argument("--d-model", type=int, default=1024)
+    parser.add_argument("--heads", type=int, default=16)
     parser.add_argument("--seq", type=int, default=512)
     parser.add_argument("--batch", type=int, default=8)
     parser.add_argument("--steps", type=int, default=3)
@@ -62,7 +65,8 @@ def main():
 
     hvd.init()
     torch.manual_seed(1234 + hvd.rank())  # different init; broadcast fixes
-    model = BertLarge(layers=args.layers, seq=args.seq)
+    model = BertLarge(layers=args.layers, d_model=args.d_model,
+                      heads=args.heads, seq=args.seq)
 
     # sparse-compatible optimizer (momentum densifies); the wrapper
     # exchanges the embedding grad by allgather, everything else by

@@ -298,6 +298,17 @@ class _PendingOp:
             # though this cycle completes "normally" (entries failed by
             # status): record it so the runtime raises typed errors
             self.executor.failure = exc
+            # This rank has left a ring collective half way. A peer whose
+            # own link to the dead worker showed no error (its sends were
+            # all in the socket's buffer before the death) is still
+            # blocked in that collective on a socket of OURS, with no
+            # deadline, while our cycle goes on to a control round that
+            # it will never answer: a deadlock, not a delay. Shut our
+            # links so that it fails as we did, and the next control
+            # round fails on both sides and ends the cycle for the
+            # elastic re-form.
+            if self.executor.net is not None:
+                self.executor.net.abort()
         self.fail(types.Status.UnknownError(str(exc)))
 
     def complete(self) -> None:

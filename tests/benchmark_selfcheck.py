@@ -1,0 +1,188 @@
+"""The benchmark's own tests (``benchmark/tests``), as tier-1 cases: what
+the ``tests/test_benchmark_selfcheck*.py`` files share (not collected).
+
+They guard the judge: the plain references against the program at toy
+size, ``correct`` false for a step that returns its state unchanged or
+an altered served token, the span and trace readers. They cannot simply
+be collected with ``tests/`` (``benchmark/tests/test_serve.py`` shares a
+basename with ``tests/test_serve.py``, and they want a process without
+this suite's eight virtual devices), so each file names some of their
+modules (or some functions of one), one module-scoped fixture runs those
+once in a subprocess under the file's own limit, and each of their test
+functions is one case: a failure names the benchmark test that broke,
+and a slow module fails its own cases alone. A file is what tier-1's
+``--dist loadfile`` schedules, so the subprocesses run on different
+workers; the first case of a file waits for its subprocess and carries
+its seconds.
+"""
+
+import ast
+import os
+import re
+import subprocess
+import sys
+import warnings
+import xml.etree.ElementTree as ElementTree
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCHMARK_TESTS = os.path.join(REPO, "benchmark", "tests")
+
+
+# ``benchmark/tests/test_serve_xing.py`` alone takes 290 s on an idle
+# machine, four of its functions 50-90 s each (a rehearsal of the routed
+# experts apiece): three run apart from the rest, each in its own file
+XING_UNTRACED = ("test_an_untraced_rehearsal_reads_the_end_to_end_metrics",
+                 "test_the_float8_control_fails_where_the_program_passes")
+XING_SCALING = ("test_a_dropped_scaling_factor_is_not_correct",)
+XING_FAULTS = ("test_the_faults_are_planted_in_the_reference_and_leave_it_"
+               "plain",)
+
+
+def test_functions(modules, only=(), without=()):
+    """``(module, function)`` for every test function of the named
+    ``benchmark/tests/<module>.py`` (those in ``only`` alone, if given;
+    none of ``without``), read from the source: nothing is imported or
+    run while this suite is collected."""
+    found = []
+    for name in modules:
+        fname = name + ".py"
+        with open(os.path.join(BENCHMARK_TESTS, fname)) as f:
+            tree = ast.parse(f.read(), fname)
+        module = "benchmark.tests." + name
+        for node in tree.body:
+            if isinstance(node, ast.ClassDef) and node.name.startswith("Test"):
+                found += [(f"{module}.{node.name}", sub.name)
+                          for sub in node.body
+                          if isinstance(sub, ast.FunctionDef)
+                          and sub.name.startswith("test")]
+            elif (isinstance(node, ast.FunctionDef)
+                  and node.name.startswith("test")):
+                found.append((module, node.name))
+    unknown = (set(only) | set(without)) - {f for _, f in found}
+    assert not unknown, f"no such test function in {modules}: {unknown}"
+    return [(c, f) for c, f in found
+            if f not in without and (not only or f in only)]
+
+
+def _pytest(chosen, timeout_s, xml):
+    """One pytest subprocess over ``chosen`` the way ``benchmark/tests``'
+    conftest says to run them by hand; ``{(classname, case name): what
+    went wrong or None}`` and the end of the run's output."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)  # tests/conftest.py's eight virtual devices
+    try:
+        done = subprocess.run(
+            [sys.executable, "-m", "pytest", *chosen, "-q",
+             "-p", "no:cacheprovider", "-p", "no:xdist",
+             f"--junitxml={xml}"],
+            cwd=REPO, env=env, capture_output=True, text=True,
+            timeout=timeout_s)
+        tail = (done.stdout + done.stderr)[-4000:]
+    except subprocess.TimeoutExpired as exc:
+        return {}, f"benchmark/tests did not finish in {timeout_s} s: {exc}"
+    if not xml.exists():
+        return {}, tail
+    cases = {}
+    for case in ElementTree.parse(xml).getroot().iter("testcase"):
+        bad = [child for child in case
+               if child.tag in ("failure", "error", "skipped")]
+        cases[(case.get("classname"), case.get("name"))] = (
+            f"{bad[0].tag}: {bad[0].get('message')}\n{bad[0].text}"
+            if bad else None)
+    return cases, tail
+
+
+def _node_id(classname, name):
+    """``benchmark.tests.test_x[.TestY]``, ``test_z[p]`` as pytest takes
+    it on its command line."""
+    module, *classes = classname.split(".")[2:]
+    return "::".join([os.path.join(BENCHMARK_TESTS, module + ".py"),
+                      *classes, name])
+
+
+# The one assertion under ``benchmark/`` that answers to the machine's
+# load and not to the code: a sound rehearsal serves for three seconds of
+# the wall clock and expects more than 8 (GPT-2: 20) requests attempted,
+# which beside five busy workers it has missed by one (``assert 8 > 8``,
+# one whole run in five of PR 38). Nothing under ``benchmark/`` is this
+# suite's to change (ROADMAP D13: a count of requests in place of the
+# seconds, for a ``benchmark`` PR).
+REHEARSALS = {
+    ("benchmark.tests.test_serve", "test_a_sound_rehearsal_is_correct"),
+    ("benchmark.tests.test_serve_sala", "test_a_sound_rehearsal_is_correct"),
+    ("benchmark.tests.test_serve_brumby",
+     "test_a_sound_rehearsal_is_correct"),
+    ("benchmark.tests.test_serve_xing",
+     "test_a_sound_traced_rehearsal_is_correct_and_reads_its_metrics"),
+}
+# the line pytest marks as the one that failed, in the test's own body
+TOO_FEW_ATTEMPTED = re.compile(
+    r'^>\s+assert result\["attempted"\] > \d+\s*$', re.MULTILINE)
+
+
+def starved(case, why):
+    """Whether this failure is a sound rehearsal's too-few-requests
+    assertion and nothing else: a wrong token, ``correct`` false or a
+    reference that disagrees, in these functions too, is not."""
+    return (case in REHEARSALS and why.startswith("failure:")
+            and TOO_FEW_ATTEMPTED.search(why) is not None)
+
+
+def _run(functions, timeout_s, tmp_path_factory):
+    """One run of the chosen test functions, each named to pytest by its
+    own id (``--deselect`` takes ids relative to the root directory and
+    says nothing of one that matches no test). Every failure fails, but
+    for a ``starved`` rehearsal, which runs once more: what fails twice
+    fails; what passed only the second time is in the report's third
+    part, and its case warns."""
+    tmp = tmp_path_factory.mktemp("benchmark_selfcheck")
+    cases, tail = _pytest([_node_id(*function) for function in functions],
+                          timeout_s, tmp / "report.xml")
+    hungry = {case: why for case, why in cases.items()
+              if why is not None and starved(case, why)}
+    again = {}
+    if hungry:
+        again, _ = _pytest([_node_id(*case) for case in hungry], timeout_s,
+                           tmp / "again.xml")
+    second_time = {case: hungry[case] for case, why in again.items()
+                   if why is None and case in hungry}
+    cases.update(dict.fromkeys(second_time))
+    return cases, tail, second_time
+
+
+def _check(report, classname, function):
+    cases, tail, second_time = report
+    mine = {name: wrong for (cls, name), wrong in cases.items()
+            if cls == classname
+            and (name == function or name.startswith(function + "["))}
+    assert mine, (f"no case of {classname}::{function} in the report of "
+                  f"benchmark/tests:\n{tail}")
+    wrong = {name: why for name, why in mine.items() if why is not None}
+    assert not wrong, "\n\n".join(f"{name}: {why}"
+                                  for name, why in wrong.items())
+    for (cls, name), why in second_time.items():
+        if cls == classname and name in mine:
+            warnings.warn(f"{classname}::{name} passed only on a second "
+                          f"run; the first said:\n{why}")
+
+
+def cases(modules, timeout_s, **which):
+    """The module-scoped ``report`` fixture and the parametrised test for
+    a file: ``report, test_benchmark_test_passes = cases(...)``.
+    ``timeout_s`` is the file's own limit for its subprocess; ``only`` /
+    ``without`` choose among the modules' functions."""
+    functions = test_functions(modules, **which)
+
+    @pytest.fixture(scope="module")
+    def report(tmp_path_factory):
+        return _run(functions, timeout_s, tmp_path_factory)
+
+    @pytest.mark.parametrize(
+        "classname,function", functions,
+        ids=[f"{c.rsplit('.', 1)[-1]}::{f}" for c, f in functions])
+    def test_benchmark_test_passes(report, classname, function):
+        _check(report, classname, function)
+
+    return report, test_benchmark_test_passes

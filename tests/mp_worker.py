@@ -110,6 +110,11 @@ def main():
         hvd.alltoall(big, name="bytes/a2a")
         sent_a2a = net.data_bytes_sent() - before
         assert sent_a2a == optimal, (sent_a2a, optimal)
+        # no rank leaves before every rank has read its counters: the
+        # first to finish shuts the world down, the others' loops close
+        # their communicators, and a closed one counts 0 (beside five
+        # busy workers a late rank read -131412 here, one run in twelve)
+        hvd.allreduce(np.zeros((1,), np.float32), name="bytes/read")
         # cache populated
         from horovod_tpu.core import state
         rt = state.global_state().runtime

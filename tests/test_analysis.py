@@ -587,7 +587,13 @@ hvd.shutdown()
 print("WITNESS_OK")
 """ % (REPO, REPO, REPO))
     env = dict(os.environ)
-    env.update({"HOROVOD_DEBUG_LOCKS": "1", "JAX_PLATFORMS": "cpu"})
+    # this test is about the ORDER of locks. hvd.init() holds
+    # GlobalState.lock while it imports and builds the runtime, which
+    # beside five busy workers has taken 9.5 s: a hold read off the wall
+    # clock is not a violation of order (test_witness_hold_warning
+    # plants a real one)
+    env.update({"HOROVOD_DEBUG_LOCKS": "1", "JAX_PLATFORMS": "cpu",
+                "HOROVOD_LOCK_HOLD_WARN_SECONDS": "600"})
     out = subprocess.run([sys.executable, str(script)], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stdout + out.stderr

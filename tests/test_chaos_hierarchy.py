@@ -13,13 +13,13 @@ where hierarchy re-enables — runs in tools/chaos_matrix.py
 import json
 import os
 import socket
-import subprocess
 import sys
 
 import pytest
 
 from horovod_tpu.run.rendezvous import RendezvousServer
 from horovod_tpu.runtime.native import native_built
+from mp_launch import collect, start
 
 pytestmark = [
     pytest.mark.skipif(not native_built(),
@@ -42,7 +42,7 @@ def test_rank_killed_mid_cross_exchange_reforms_and_finishes(tmp_path):
     server = RendezvousServer(host="127.0.0.1")
     http_port = server.start()
     socket_port = _free_port()
-    procs = []
+    procs, logs = [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -68,13 +68,10 @@ def test_rank_killed_mid_cross_exchange_reforms_and_finishes(tmp_path):
                 "CHAOS_TOTAL_STEPS": str(TOTAL),
                 "JAX_PLATFORMS": "cpu",
             })
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
+            start(procs, logs, [sys.executable, WORKER], env)
         results = {}
-        for rank, proc in enumerate(procs):
-            out, _ = proc.communicate(timeout=180)
+        outs = collect(procs, logs, 180)
+        for rank, (proc, out) in enumerate(zip(procs, outs)):
             want = 17 if rank == 3 else 0
             assert proc.returncode == want, \
                 f"rank {rank} exited {proc.returncode}:\n{out[-2000:]}"

@@ -18,13 +18,13 @@ kv_outage bridging, chaos grammar) are tier-1 via tests/test_resilience.py.
 import json
 import os
 import socket
-import subprocess
 import sys
 
 import pytest
 
 from horovod_tpu.run.rendezvous import RendezvousServer
 from horovod_tpu.runtime.native import native_built
+from mp_launch import collect, start
 
 pytestmark = [
     pytest.mark.slow,
@@ -48,7 +48,7 @@ def test_flaky_negotiate_completes_with_retries(tmp_path):
     server = RendezvousServer(host="127.0.0.1")
     http_port = server.start()
     socket_port = _free_port()
-    procs = []
+    procs, logs = [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -70,13 +70,10 @@ def test_flaky_negotiate_completes_with_retries(tmp_path):
                 "CHAOS_TOTAL_STEPS": str(TOTAL),
                 "JAX_PLATFORMS": "cpu",
             })
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
+            start(procs, logs, [sys.executable, WORKER], env)
         results = {}
-        for rank, proc in enumerate(procs):
-            out, _ = proc.communicate(timeout=120)
+        outs = collect(procs, logs, 120)
+        for rank, (proc, out) in enumerate(zip(procs, outs)):
             assert proc.returncode == 0, \
                 f"rank {rank} exited {proc.returncode}:\n{out[-2000:]}"
             for line in out.splitlines():

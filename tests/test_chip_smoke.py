@@ -10,6 +10,8 @@ import sys
 
 import pytest
 
+from mp_launch import collect, start
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
 
@@ -97,18 +99,17 @@ def test_mesh_follows_launcher_ranks_when_jax_numbers_processes_otherwise():
     http_port = rendezvous.start()
     socket_port, coordinator_port = (launcher._free_port(),
                                      launcher._free_port())
-    procs = []
+    procs, logs = [], []
     try:
         for slot in slots:
             env = launcher.build_worker_env(
                 slot, _env(), "127.0.0.1", socket_port, http_port,
                 coordinator_port, num_processes=2)
             env["HOROVOD_PROCESS_ID"] = str(1 - slot.rank)
-            procs.append(subprocess.Popen(
-                [sys.executable, SMOKE, "--rehearse", "--phase", "worker"],
-                env=env, cwd=REPO, stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT, text=True))
-        outs = [p.communicate(timeout=240)[0] for p in procs]
+            start(procs, logs,
+                  [sys.executable, SMOKE, "--rehearse", "--phase", "worker"],
+                  env, cwd=REPO)
+        outs = collect(procs, logs, 240)
     finally:
         for p in procs:
             p.kill()

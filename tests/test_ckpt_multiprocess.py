@@ -23,6 +23,7 @@ import pytest
 from horovod_tpu.ckpt import io as ckpt_io
 from horovod_tpu.ckpt import manifest as mf
 from horovod_tpu.ckpt import restore as rst
+from mp_launch import collect, start
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -50,7 +51,7 @@ print("WORKER_DONE", rank, flush=True)
 def test_kill_while_staging_preserves_previous_cut(tmp_path):
     d = str(tmp_path / "ckpts")
     os.makedirs(d)
-    procs = []
+    procs, logs = [], []
     for rank in range(2):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
@@ -61,12 +62,8 @@ def test_kill_while_staging_preserves_previous_cut(tmp_path):
             # staged at step 2, killed before anything is published
             "HOROVOD_CKPT_FAULT": "kill:rank=1:phase=stage:step=2:code=21",
         })
-        procs.append(subprocess.Popen(
-            [sys.executable, "-c", _WORKER, d], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-    outs = {}
-    for rank, proc in enumerate(procs):
-        outs[rank], _ = proc.communicate(timeout=120)
+        start(procs, logs, [sys.executable, "-c", _WORKER, d], env)
+    outs = collect(procs, logs, 120)
     assert procs[1].returncode == 21, outs[1][-2000:]
     # the survivor abandons step 2 and exits cleanly
     assert procs[0].returncode == 0, outs[0][-2000:]

@@ -427,6 +427,47 @@ class TestLeaseLifecycle:
         assert ex.fusion_buffers.live_bytes() == 0
         assert ex.fusion_buffers.leases_outstanding() == 0
 
+    @pytest.mark.parametrize("with_net", [True, False])
+    def test_a_lost_peer_aborts_the_ring_and_a_numerical_verdict_does_not(
+            self, hvd, with_net):
+        """A rank that records a data-plane ``WorkersDownError`` shuts its
+        links (``NetComm.abort``), once, so that a peer still blocked in
+        the same ring collective fails too and does not wait for ever; a
+        ``NumericalError`` touches neither the links nor ``failure``: the
+        runtime survives the rollback. No host ring (``net`` None, every
+        cell) is tolerated."""
+        from horovod_tpu import exceptions
+        from horovod_tpu.core import state
+        from horovod_tpu.runtime import executor as ex_mod
+
+        class _Net:
+            aborts = 0
+
+            def abort(self):
+                self.aborts += 1
+
+        def failed_by(ex, exc):
+            entry = types.TensorTableEntry(name="ring/tok",
+                                           tensor=np.ones((4,), "float32"))
+            tok = ex_mod._PendingOp(ex, types.ALLREDUCE, [entry], None)
+            tok.fail_exc(exc)
+            assert tok.done
+
+        ex = ex_mod.Executor(state.global_state().mesh)
+        ex.net = net = _Net() if with_net else None
+        bad = exceptions.NumericalError("non-finite bucket")
+        failed_by(ex, bad)
+        assert ex.integrity_failure is bad and ex.failure is None
+        assert not with_net or net.aborts == 0
+        lost = exceptions.WorkerLostError("peer closed", ranks=[1])
+        failed_by(ex, lost)
+        assert ex.failure is lost
+        assert not with_net or net.aborts == 1
+        # the first loss stands, and the links are shut once
+        failed_by(ex, exceptions.WorkerLostError("again", ranks=[1]))
+        assert ex.failure is lost
+        assert not with_net or net.aborts == 1
+
 
 class TestKnobParsing:
     def test_defaults(self, monkeypatch):

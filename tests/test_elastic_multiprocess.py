@@ -10,13 +10,13 @@ finish all 8 steps with the training invariant (w == step) intact.
 
 import os
 import socket
-import subprocess
 import sys
 
 import pytest
 
 from horovod_tpu.run.rendezvous import RendezvousServer
 from horovod_tpu.runtime.native import native_built
+from mp_launch import collect, start
 
 WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "elastic_worker.py")
@@ -40,7 +40,7 @@ def _launch_elastic(world: int, extra_env=None, timeout=240,
     rendezvous = RendezvousServer(host="127.0.0.1")
     http_port = rendezvous.start()
     socket_port = _free_port()
-    procs = []
+    procs, logs = [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -60,14 +60,8 @@ def _launch_elastic(world: int, extra_env=None, timeout=240,
                 "JAX_PLATFORMS": "cpu",
             })
             env.update(extra_env or {})
-            procs.append(subprocess.Popen(
-                [sys.executable, worker],
-                env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
-        outs = []
-        for p in procs:
-            out, _ = p.communicate(timeout=timeout)
-            outs.append(out)
+            start(procs, logs, [sys.executable, worker], env)
+        outs = collect(procs, logs, timeout)
     finally:
         for p in procs:
             if p.poll() is None:

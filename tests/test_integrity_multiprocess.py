@@ -27,6 +27,7 @@ import pytest
 
 from horovod_tpu.run.rendezvous import RendezvousServer
 from horovod_tpu.runtime.native import native_built
+from mp_launch import collect, start
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tools", "chaos_worker.py")
@@ -45,7 +46,7 @@ def _launch(world, extra_env, timeout=240):
     rendezvous = RendezvousServer(host="127.0.0.1")
     http_port = rendezvous.start()
     socket_port = _free_port()
-    procs = []
+    procs, logs = [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -63,14 +64,8 @@ def _launch(world, extra_env, timeout=240):
                 "JAX_PLATFORMS": "cpu",
             })
             env.update(extra_env)
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER],
-                env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
-        outs = []
-        for p in procs:
-            out, _ = p.communicate(timeout=timeout)
-            outs.append(out)
+            start(procs, logs, [sys.executable, WORKER], env)
+        outs = collect(procs, logs, timeout)
     finally:
         for p in procs:
             if p.poll() is None:

@@ -29,6 +29,7 @@ from horovod_tpu.serve.batcher import ContinuousBatcher
 from horovod_tpu.serve.paging import (PagePool, PagePoolExhausted,
                                       PrefixCache, auto_pool_pages)
 from horovod_tpu.serve.queue import Request
+from toy_models import toy_transformer, uncached_greedy as _uncached_greedy
 
 
 def _req(uid, prompt, max_new=8):
@@ -222,31 +223,7 @@ class TestPagedAdmission:
 
 @pytest.fixture(scope="module")
 def tiny_lm():
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.models.transformer import Transformer
-
-    model = Transformer(vocab_size=61, d_model=32, num_layers=2,
-                        num_heads=2, d_ff=64, max_seq=48, causal=True,
-                        dtype=jnp.float32)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32),
-                        train=False)["params"]
-    return model, params
-
-
-def _uncached_greedy(model, params, prompt, n):
-    import jax.numpy as jnp
-
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = model.apply({"params": params},
-                             jnp.asarray([toks], jnp.int32), train=False)
-        out.append(int(jnp.argmax(logits[0, len(toks) - 1])))
-        toks.append(out[-1])
-    return out
+    return toy_transformer(max_seq=48)
 
 
 def _engine(model, params, slots=3, **kw):

@@ -26,9 +26,6 @@ Tolerances, on logits whose standard deviation is about 0.23 here:
   the error times the normaliser, that is the numerator.
 """
 
-import functools
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,38 +34,14 @@ import pytest
 from benchmark import reference_brumby as ref
 from benchmark import weights_brumby
 from benchmark.runners.serve_brumby import build_model
-from horovod_tpu import tracing
 from horovod_tpu.models import hybrid
-from horovod_tpu.serve import kv_cache
-from horovod_tpu.serve.kv_cache import DecodeEngine
+from toy_models import (BRUMBY_CFG as CFG, brumby_reference as reference,
+                        brumby_weights as weights, tokens)
 
 F32_TOL, BF16_TOL = 5e-5, 6e-2
-CFG = dict(vocab_size=512, d_model=128, d_ff=256, num_heads=4,
-           num_kv_heads=2, head_dim=32, num_layers=2, layer_indices=[0, 1],
-           published_depth=40, rope_theta=1000000, rms_norm_eps=1e-6,
-           retention_eps=1e-6, dim_model_base=None, max_seq=1024,
-           dtype="float32",
-           param_dtype="bfloat16")
-SEED = 7
 HEADS, GROUPS, D = 4, 2, 32
 WIDTH = D * (D + 1) // 2
 TURNS = D // 2 + 1          # rows of distances: the cache pads to these
-
-_forward = jax.jit(ref.forward, static_argnums=(2, 3))
-
-
-@functools.lru_cache(maxsize=None)
-def weights():
-    return weights_brumby.make_params(CFG, SEED)
-
-
-def tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(1, CFG["vocab_size"], n)
-
-
-def reference(toks, precision="f32"):
-    return np.asarray(_forward(weights(), jnp.asarray(toks, jnp.int32),
-                               ref.frozen(CFG), precision))
 
 
 @pytest.fixture(scope="module")
@@ -352,312 +325,3 @@ def test_neutral_scalings_leave_the_trunk_alone():
     a = np.asarray(plain.apply({"params": params}, toks))
     b = np.asarray(scaled.apply({"params": params}, toks))
     assert np.abs(a - b).max() > 1e-2
-
-
-# -------------------------------------------------------------- the engine
-
-@functools.lru_cache(maxsize=None)
-def _step(model):
-    return jax.jit(lambda p, c, t, q: model.apply(
-        {"params": p, "cache": c}, t, positions=q, train=False,
-        mutable=["cache"]))
-
-
-def step_logits(engine, step_tokens, positions):
-    """One decode step over all of the engine's rows, as ``_decode_impl``
-    runs it, returning the logits it would take the argmax of."""
-    logits, mutated = _step(engine._model)(
-        engine._params, engine._cache,
-        jnp.asarray(step_tokens, jnp.int32)[:, None],
-        jnp.asarray(positions, jnp.int32))
-    engine._cache = mutated["cache"]
-    return np.asarray(logits[:, 0])
-
-
-# the engine cuts a prompt into pieces of PREFILL_CHUNK (set to the toy
-# mixer's own chunk, 256) and pads the last one: 203 and 61 are one
-# padded piece, 300 two, the second mostly padding; then the lengths
-# round a piece's edge, whole pieces alone, and three pieces and 17
-CHUNK = 256
-
-
-@pytest.fixture
-def pieces(monkeypatch):
-    monkeypatch.setattr(kv_cache, "PREFILL_CHUNK", CHUNK)
-    return CHUNK
-
-
-def prefill_spans(began):
-    return [s for s in tracing.spans()
-            if s["name"] == "engine.prefill" and s["t"] >= began]
-
-
-@pytest.mark.parametrize("prompt_len", [
-    203, 61, 300, CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK, 3 * CHUNK + 17])
-def test_prefill_then_decode_is_the_references_one_forward(served, pieces,
-                                                           prompt_len):
-    params, model = served
-    total = prompt_len + 40
-    toks = tokens(total, seed=prompt_len)
-    want = reference(toks)
-    engine = DecodeEngine(model, params, num_slots=3)
-    assert model.resumable_prefill
-    chunks = -(-prompt_len // pieces)
-    began = time.time()
-    first, max_abs = engine.prefill(1, toks[:prompt_len].tolist())
-    # the last piece is padded (or, for whole pieces, exactly full)
-    span, = prefill_spans(began)
-    assert span["chunks"] == chunks and span["bucket"] == chunks * pieces
-    assert 0 <= span["bucket"] - prompt_len < pieces
-    assert first == want[prompt_len - 1].argmax()
-    assert abs(max_abs - np.abs(want[prompt_len - 1]).max()) < F32_TOL
-    for t in range(prompt_len, total):       # teacher forced
-        step = np.zeros(3, np.int64)
-        at = np.zeros(3, np.int64)
-        step[1], at[1] = toks[t], t
-        got = step_logits(engine, step, at)[1]
-        assert np.abs(got - want[t]).max() < F32_TOL, t
-    # two programs of one shape, whatever the prompt
-    assert sorted(engine.stats()["compiles"]) == (
-        ["prefill_chunk", "prefill_last"] if chunks > 1
-        else ["prefill_last"])
-
-
-def greedy(engine, slot, prompt, steps):
-    """The tokens the engine serves ``prompt`` in ``slot``: the prefill's
-    first and ``steps`` decode steps after it, each fed from the feed."""
-    first, _ = engine.prefill(slot, prompt)
-    out = [first]
-    for t in range(steps):
-        ids, _ = engine.decode([slot], None, [len(prompt) + t])
-        out.append(ids[0])
-    return out
-
-
-@pytest.mark.parametrize("second_len", [150, CHUNK + 40],
-                         ids=["one_piece", "two_pieces"])
-def test_a_slots_second_request_starts_from_an_empty_state(served, pieces,
-                                                           second_len):
-    """A prompt's first piece continues from zeros and not from what the
-    slot's last request left (a prompt in pieces reads the slot's row,
-    where a prompt in one program made a fresh one): the second request
-    of a slot is served the tokens a fresh engine serves it."""
-    params, model = served
-    engine = DecodeEngine(model, params, num_slots=2)
-    greedy(engine, 1, tokens(2 * CHUNK + 30, seed=50).tolist(), 12)
-    # what the first request left there is nothing like zeros
-    left = np.asarray(jax.tree.leaves(engine._cache)[0][1])
-    assert np.abs(left).max() > 1e-3
-    second = tokens(second_len, seed=51).tolist()
-    fresh = DecodeEngine(model, params, num_slots=2)
-    assert greedy(engine, 1, second, 24) == greedy(fresh, 1, second, 24)
-
-
-def test_the_prefill_counters_and_the_spans_chunks(served, pieces):
-    """``stats()``: programs enqueued, positions computed (the padding
-    with them) and the prompts' own tokens; the ``engine.prefill`` span
-    stays one a prompt and carries ``chunks``, ``bucket`` the positions
-    computed."""
-    params, model = served
-    engine = DecodeEngine(model, params, num_slots=2)
-    began = time.time()
-    lengths = [41, CHUNK, 2 * CHUNK + 5]
-    for slot, n in zip((0, 1, 0), lengths):
-        engine.prefill(slot, tokens(n, seed=n).tolist()).collect()
-    stats = engine.stats()
-    assert stats["prefill_chunks"] == 1 + 1 + 3
-    assert stats["prefill_positions"] == 5 * CHUNK
-    assert stats["prefill_tokens"] == sum(lengths)
-    assert stats["compiles"] == {"prefill_last": 1, "prefill_chunk": 1}
-    assert stats["cache_donated"] is False      # no decode step yet
-    assert engine._donated["prefill"] is True
-    assert [(s["prompt_len"], s["chunks"], s["bucket"])
-            for s in prefill_spans(began)] == [
-                (41, 1, CHUNK), (CHUNK, 1, CHUNK),
-                (2 * CHUNK + 5, 3, 3 * CHUNK)]
-
-
-def test_a_counter_rides_through_the_pieces(pieces):
-    """A model that resumes may also count (an expert layer's
-    ``expert_counts`` is no slot's row): every piece adds its own true
-    tokens' pairs to the counter as it stands, the first piece's zeroing
-    does not touch it, and the padding of the last is not counted."""
-    model = hybrid.HybridDecoder(
-        vocab_size=64, d_model=32, d_ff=64, num_heads=2, num_kv_heads=1,
-        head_dim=16, mixers=(hybrid.POWER_RETENTION,) * 2,
-        mlps=(hybrid.DENSE_MLP, hybrid.EXPERTS_MLP),
-        experts=dict(num_experts=4, top_k=2, d_ff=32), scale_depth=None,
-        max_seq=1024, dtype=jnp.float32)
-    assert model.resumable_prefill and model.counts_active_rows
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-    engine = DecodeEngine(model, params, num_slots=2)
-    total = 0
-    for slot, n in ((1, 2 * CHUNK + 40), (1, 90), (0, CHUNK)):
-        prompt = tokens(n, seed=n) % 64
-        want = np.asarray(model.apply({"params": params},
-                                      jnp.asarray(prompt)[None]))[0, -1]
-        first, max_abs = engine.prefill(slot, prompt.tolist())
-        assert first == want.argmax()
-        assert abs(max_abs - np.abs(want).max()) < F32_TOL
-        total += n
-        counts = engine.expert_counts()
-        assert counts.shape == (1, 3, 4)
-        assert counts[0, 0].sum() == 2 * total and not counts[0, 1:].any()
-
-
-def test_a_piece_is_no_longer_than_the_model_allows():
-    """``max_seq`` under PREFILL_CHUNK (the constant as it stands, 1,024):
-    the pieces are ``max_seq`` long, and the model takes them."""
-    cfg = dict(CFG, max_seq=128)
-    model = build_model(cfg)
-    engine = DecodeEngine(model, weights(), num_slots=1)
-    toks = tokens(100, seed=60)
-    first, _ = engine.prefill(0, toks[:90].tolist())
-    assert first == reference(toks[:90])[-1].argmax()
-    assert engine.stats()["prefill_positions"] == 128
-
-
-def test_the_cache_holds_states_and_nothing_else(served):
-    """No leaf with a position axis: every byte is ``state``, the
-    key/value read share is ``None`` (nothing to divide by, and no
-    warning), and the donation is taken."""
-    import warnings
-
-    params, model = served
-    engine = DecodeEngine(model, params, num_slots=2)
-    by_kind = engine.cache_bytes_by_kind()
-    assert by_kind == {"kv": 0, "compressed": 0,
-                       "state": 2 * 2 * GROUPS * TURNS * D * (D + 1) * 4}
-    assert engine.cache_bytes() == by_kind["state"]
-    leaves = jax.tree_util.tree_leaves_with_path(engine._cache)
-    assert sorted(x.shape for _, x in leaves) == sorted(
-        [(2, GROUPS, TURNS, D, D), (2, GROUPS, TURNS, D)] * 2)
-    assert not engine._reads_live_tiles and engine._dense_len is None
-    first, _ = engine.prefill(0, tokens(41).tolist())
-    engine.decode([0], [first], [41]).collect()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        stats = engine.stats()
-    assert stats["decode_kv_read_share"] is None
-    assert stats["cache_bytes_by_kind"] == by_kind
-    assert stats["cache_donated"] is True
-
-
-def test_the_state_after_the_padding_would_be_seen(served):
-    """The broken path the true length guards against: a prefill that
-    hands the model the bucket in place of the prompt's length leaves the
-    state after the padding, and the next logits are off by far more
-    than any tolerance here."""
-    params, model = served
-    toks = tokens(204, seed=30)
-    want = reference(toks)
-    engine = DecodeEngine(model, params, num_slots=1)
-    padded = np.zeros((1, 256), np.int32)
-    padded[0, :203] = toks[:203]
-    _, mutated = engine._model.apply(
-        {"params": params}, jnp.asarray(padded),
-        positions=jnp.zeros((1,), jnp.int32),
-        lengths=jnp.asarray([256], jnp.int32), train=False,
-        mutable=["cache"])
-    engine._cache = mutated["cache"]
-    got = step_logits(engine, [toks[203]], [203])[0]
-    assert np.abs(got - want[203]).max() > 100 * F32_TOL
-
-
-def test_a_slot_is_reused_after_a_longer_occupant(served):
-    params, model = served
-    engine = DecodeEngine(model, params, num_slots=2)
-    long = tokens(330, seed=20)
-    engine.prefill(0, long[:300].tolist())
-    for t in range(300, 330):
-        engine.decode([0], [int(long[t])], [t])
-    short = tokens(170, seed=21)
-    want = reference(short)
-    first, _ = engine.prefill(0, short[:150].tolist())
-    assert first == want[149].argmax()
-    for t in range(150, 170):
-        got = step_logits(engine, [short[t], 0], [t, 0])[0]
-        assert np.abs(got - want[t]).max() < F32_TOL, t
-
-
-def test_an_idle_slots_state_stays_finite(served):
-    """The decode program runs every slot every step: a row that is not
-    active runs token 0 at position 0 and rewrites its state. That state
-    is a geometric series in the token's own gates (the slowest keeps
-    0.999 a step), so it nears a finite fixed point: after three
-    thousand steps it is finite and has all but stopped growing, and the
-    active row beside it is untouched by it."""
-    params, model = served
-    engine = DecodeEngine(model, params, num_slots=2)
-    toks = tokens(60, seed=40)
-    want = reference(toks)
-    first, _ = engine.prefill(0, toks[:20].tolist())
-    assert first == want[19].argmax()
-
-    @jax.jit
-    def idle(cache, n):
-        def one(_, cache):
-            _, mutated = engine._model.apply(
-                {"params": params, "cache": cache},
-                jnp.zeros((2, 1), jnp.int32),
-                positions=jnp.zeros((2,), jnp.int32), train=False,
-                mutable=["cache"])
-            # only row 1 idles: row 0 keeps the prompt's state
-            return jax.tree.map(lambda old, new: old.at[1].set(new[1]),
-                                cache, mutated["cache"])
-        return jax.lax.fori_loop(0, n, one, cache)
-
-    before = idle(engine._cache, 2000)
-    engine._cache = idle(before, 1000)
-    for (_, then), (_, now) in zip(
-            jax.tree_util.tree_leaves_with_path(before),
-            jax.tree_util.tree_leaves_with_path(engine._cache)):
-        now, then = np.asarray(now[1]), np.asarray(then[1])
-        assert np.isfinite(now).all()
-        assert np.abs(now).max() <= 1.5 * np.abs(then).max()
-    for t in range(20, 30):
-        got = step_logits(engine, [toks[t], 0], [t, 0])[0]
-        assert np.abs(got - want[t]).max() < F32_TOL, t
-
-
-def test_the_paged_engine_refuses_a_model_without_pages(served):
-    from horovod_tpu.serve.paging import PagedDecodeEngine
-
-    params, model = served
-    with pytest.raises(ValueError, match="has no paged cache .* recurrent "
-                                         "state"):
-        PagedDecodeEngine(model, params, num_slots=2)
-
-
-def test_serving_through_hvd_serve(served):
-    """The model behind the public entry point: ``hvd.serve()`` ->
-    ``Replica`` -> ``ContinuousBatcher`` -> ``DecodeEngine``, the same
-    path as every dense model; more requests than slots, so that slots
-    are reused."""
-    import horovod_tpu as hvd
-
-    params, model = served
-    hvd.init()
-    try:
-        handle = hvd.serve(model, params, slots=2, max_new_tokens=8,
-                           max_batch_tokens=2048)
-        try:
-            prompts = [tokens(n, seed=n).tolist() for n in (150, 37, 260)]
-            uids = [handle.submit(p, max_new_tokens=8) for p in prompts]
-            for prompt, uid in zip(prompts, uids):
-                done = handle.result(uid, timeout=300.0)
-                full = np.asarray(prompt + list(done.tokens))
-                want = reference(full)
-                rows = want[len(prompt) - 1:len(full) - 1]
-                assert list(done.tokens) == rows.argmax(-1).tolist()
-            engine = handle.stats()["replicas"][0]["engine"]
-            assert engine["cache_bytes_by_kind"]["state"] \
-                == engine["cache_bytes"] > 0
-            assert engine["decode_kv_read_share"] is None
-            with pytest.raises(ValueError, match="no paged cache"):
-                hvd.serve(model, params, slots=2, paged=True)
-        finally:
-            handle.close()
-    finally:
-        hvd.shutdown()

@@ -14,7 +14,6 @@ per-rank clock correction applied.
 import json
 import os
 import socket
-import subprocess
 import sys
 import time
 import urllib.request
@@ -23,6 +22,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.runtime import message as msg, types
+from mp_launch import collect, start
 
 WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "profiler_worker.py")
@@ -383,7 +383,7 @@ def test_two_rank_profile_merge(tmp_path):
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         socket_port = s.getsockname()[1]
-    world, procs, outs = 2, [], []
+    world, procs, logs, outs = 2, [], [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -402,13 +402,8 @@ def test_two_rank_profile_merge(tmp_path):
                     profile_dir / f"timeline-rank-{rank}.json"),
                 "JAX_PLATFORMS": "cpu",
             })
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
-        for p in procs:
-            out, _ = p.communicate(timeout=180)
-            outs.append(out)
+            start(procs, logs, [sys.executable, WORKER], env)
+        outs = collect(procs, logs, 180)
     finally:
         for p in procs:
             if p.poll() is None:

@@ -30,6 +30,7 @@ from horovod_tpu.serve.batcher import ContinuousBatcher
 from horovod_tpu.serve.queue import (KVQueueFrontend, KVQueueReplica,
                                      QueueFull, Completion, Request,
                                      RequestQueue)
+from toy_models import toy_transformer, uncached_greedy as _uncached_greedy
 
 
 def _req(uid, prompt_len=8, max_new=4):
@@ -230,32 +231,7 @@ def test_prompt_bucket_policy():
 
 @pytest.fixture(scope="module")
 def tiny_lm():
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.models.transformer import Transformer
-
-    model = Transformer(vocab_size=61, d_model=32, num_layers=2,
-                        num_heads=2, d_ff=64, max_seq=48, causal=True,
-                        dtype=jnp.float32)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32),
-                        train=False)["params"]
-    return model, params
-
-
-def _uncached_greedy(model, params, prompt, n):
-    """Reference: full (cache-free) forward per token, greedy argmax."""
-    import jax.numpy as jnp
-
-    toks = list(prompt)
-    out = []
-    for _ in range(n):
-        logits = model.apply({"params": params},
-                             jnp.asarray([toks], jnp.int32), train=False)
-        out.append(int(jnp.argmax(logits[0, len(toks) - 1])))
-        toks.append(out[-1])
-    return out
+    return toy_transformer(max_seq=48)
 
 
 def test_prefill_decode_parity_and_isolation(tiny_lm):
@@ -934,10 +910,10 @@ def _blocking_greedy(eng, slot, prompt, n):
 def _toy(kind, request):
     if kind == "transformer":
         return request.getfixturevalue("tiny_lm")
-    from test_hybrid_model import ALL, build_model, weights
+    from toy_models import family
 
-    cfg, params = weights(ALL)
-    return build_model(cfg), params
+    sala = family("sala")
+    return sala.model, sala.params
 
 
 @pytest.mark.parametrize("kind", ["transformer", "hybrid"])

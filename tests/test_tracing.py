@@ -26,7 +26,6 @@ merged Perfetto trace) is at the bottom.
 
 import json
 import os
-import subprocess
 import sys
 import time
 import urllib.error
@@ -41,6 +40,7 @@ from horovod_tpu.utils.env import (DEFAULT_TRACE_CAPACITY,
                                    HOROVOD_SLO_LATENCY_MS,
                                    HOROVOD_SLO_TTFT_MS, HOROVOD_SLO_WINDOW,
                                    HOROVOD_TRACE, parse_trace)
+from mp_launch import collect, start
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -918,7 +918,7 @@ def test_lock_waits_show_on_the_dispatch_and_on_engine_stats(ring):
     import threading
 
     from horovod_tpu.serve.kv_cache import DecodeEngine
-    from test_hybrid_model import xing
+    from toy_models import xing
 
     cfg, params, model = xing()
     engine = DecodeEngine(model, params, num_slots=2)
@@ -1032,7 +1032,7 @@ def test_one_trace_id_spans_both_ranks_in_merged_trace(tmp_path,
 
     server = RendezvousServer(host="127.0.0.1")
     port = server.start()
-    proc = None
+    procs, logs = [], []
     try:
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
@@ -1044,12 +1044,13 @@ def test_one_trace_id_spans_both_ranks_in_merged_trace(tmp_path,
             "HOROVOD_SERVE_ADMISSION_MS": "1",
             "JAX_PLATFORMS": "cpu",
         })
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "horovod_tpu.serve", "--vocab", "64",
-             "--d-model", "16", "--layers", "1", "--heads", "1",
-             "--d-ff", "32", "--max-seq", "32"],
-            env=env, cwd=REPO, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True)
+        # the replica's output goes to a file: nobody reads it while
+        # it serves, and a pipe would fill (mp_launch.start)
+        start(procs, logs,
+              [sys.executable, "-m", "horovod_tpu.serve", "--vocab", "64",
+               "--d-model", "16", "--layers", "1", "--heads", "1",
+               "--d-ff", "32", "--max-seq", "32"], env, cwd=REPO)
+        proc, = procs
 
         front = KVQueueFrontend(
             KVStoreClient("127.0.0.1", port, scope="serve", timeout=10.0))
@@ -1066,7 +1067,7 @@ def test_one_trace_id_spans_both_ranks_in_merged_trace(tmp_path,
         assert front.pending() == 0, "traced request never completed"
         assert front._done["traced-1"].trace_id == trace_id
         front.stop_fleet()
-        out, _ = proc.communicate(timeout=60)
+        out, = collect(procs, logs, 60)
         assert proc.returncode == 0, out[-2000:]
 
         # the worker's finalize dumped profile-rank-1.json; dump the
@@ -1089,6 +1090,7 @@ def test_one_trace_id_spans_both_ranks_in_merged_trace(tmp_path,
         assert [f for f in flows if f["ph"] == "s"] and \
             [f for f in flows if f["ph"] == "f"]
     finally:
-        if proc is not None and proc.poll() is None:
-            proc.kill()
+        for proc in procs:
+            if proc.poll() is None:
+                proc.kill()
         server.stop()

@@ -12,14 +12,13 @@ with zero leaked fusion-buffer leases.
 """
 
 import os
-import socket
-import subprocess
 import sys
 
 import pytest
 
 from horovod_tpu.run.rendezvous import RendezvousServer
 from horovod_tpu.runtime.native import native_built
+from mp_launch import collect, free_port, start
 
 WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "zero2_elastic_worker.py")
@@ -28,17 +27,11 @@ pytestmark = pytest.mark.skipif(
     not native_built(), reason="native transport not built")
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
-def _launch(world, extra_env=None, timeout=240):
+def _launch(world, extra_env=None, timeout=90):
     rendezvous = RendezvousServer(host="127.0.0.1")
     http_port = rendezvous.start()
-    socket_port = _free_port()
-    procs = []
+    socket_port = free_port()
+    procs, logs = [], []
     try:
         for rank in range(world):
             env = dict(os.environ)
@@ -56,14 +49,11 @@ def _launch(world, extra_env=None, timeout=240):
                 "JAX_PLATFORMS": "cpu",
             })
             env.update(extra_env or {})
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER],
-                env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
-        outs = []
-        for p in procs:
-            out, _ = p.communicate(timeout=timeout)
-            outs.append(out)
+            start(procs, logs, [sys.executable, WORKER], env)
+        # a hang fails within the wait with every rank's output and
+        # stacks (mp_launch.collect): that is how PR 38 found the
+        # survivors' deadlock that this test used to meet under load
+        outs = collect(procs, logs, timeout)
     finally:
         for p in procs:
             if p.poll() is None:

@@ -1,0 +1,210 @@
+"""The model families behind the dense serving engine, at toy sizes with
+seeded weights, each beside its plain reference under ``benchmark/``
+(not collected: what ``tests/test_hybrid_model.py``,
+``tests/test_latent_experts.py``, ``tests/test_power_retention.py`` and
+``tests/test_engine_contract.py`` share).
+
+* ``sala``: MiniCPM-SALA, block-sparse attention beside lightning linear
+  attention, against ``benchmark/reference_sala.py``; logits' standard
+  deviation about 0.06, float32 against float32 measured 1e-7 to 3e-6.
+* ``brumby``: Brumby-14B-Base, power retention, a cache of states alone,
+  against ``benchmark/reference_brumby.py``; about 0.23, measured 2e-6
+  to 1e-5 (``tests/test_power_retention.py`` says why it is the widest).
+* ``xing``: Xing4.0-29B-A4B, latent attention, routed experts and
+  hyper-connected streams, against ``benchmark/reference_xing.py``
+  (``benchmark/configs/xing4-29b-a4b.json``'s toy sizes, three of its
+  six layers: one dense, two with experts); about 0.23.
+
+A new family adds its entry to :func:`family` and so joins every case of
+``tests/test_engine_contract.py``; it does not copy them.
+"""
+
+import collections
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmark import reference_brumby, reference_sala, reference_xing
+from benchmark import weights_brumby, weights_sala, weights_xing
+from benchmark.runners import serve_brumby, serve_sala, serve_xing
+from horovod_tpu import tracing
+from horovod_tpu.models.transformer import Transformer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEED = 7
+VOCAB = 512
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(1, VOCAB, n)
+
+
+# ----------------------------------------------------------------- SALA
+
+SPARSE = dict(kernel=8, stride=4, block_size=16, topk=6, init_blocks=1,
+              window_size=32, dense_len=128)
+SALA_CFG = dict(vocab_size=VOCAB, d_model=128, d_ff=256, num_heads=4,
+                num_kv_heads=2, head_dim=32,
+                mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                             "lightning-attn"],
+                layer_indices=[0, 1, 2, 3], published_depth=32, scale_emb=12,
+                scale_depth=1.4, dim_model_base=32, rope_theta=10000,
+                rms_norm_eps=1e-6, sparse=SPARSE, max_seq=1024,
+                dtype="float32", param_dtype="bfloat16")
+SALA_ALL = tuple(SALA_CFG["mixer_types"])
+
+_sala_forward = jax.jit(reference_sala.forward, static_argnums=(2, 3))
+
+
+@functools.lru_cache(maxsize=None)
+def sala_weights(kinds):
+    cfg = dict(SALA_CFG, mixer_types=list(kinds),
+               layer_indices=list(range(len(kinds))))
+    return cfg, weights_sala.make_params(cfg, SEED)
+
+
+def sala_reference(cfg, params, toks, precision="f32"):
+    return np.asarray(_sala_forward(params, jnp.asarray(toks, jnp.int32),
+                                    reference_sala.frozen(cfg), precision))
+
+
+# --------------------------------------------------------------- Brumby
+
+BRUMBY_CFG = dict(vocab_size=VOCAB, d_model=128, d_ff=256, num_heads=4,
+                  num_kv_heads=2, head_dim=32, num_layers=2,
+                  layer_indices=[0, 1], published_depth=40,
+                  rope_theta=1000000, rms_norm_eps=1e-6, retention_eps=1e-6,
+                  dim_model_base=None, max_seq=1024, dtype="float32",
+                  param_dtype="bfloat16")
+
+_brumby_forward = jax.jit(reference_brumby.forward, static_argnums=(2, 3))
+
+
+@functools.lru_cache(maxsize=None)
+def brumby_weights():
+    return weights_brumby.make_params(BRUMBY_CFG, SEED)
+
+
+def brumby_reference(toks, precision="f32"):
+    return np.asarray(_brumby_forward(
+        brumby_weights(), jnp.asarray(toks, jnp.int32),
+        reference_brumby.frozen(BRUMBY_CFG), precision))
+
+
+# ----------------------------------------------------------------- Xing
+
+def xing_cfg(dtype="float32", **changes):
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "xing4-29b-a4b.json")) as f:
+        published = json.load(f)
+    cfg = dict(published["as_run"], **published["rehearse"])
+    cfg.update(num_layers=3, layer_indices=[0, 2, 3], max_seq=512,
+               dtype=dtype, param_dtype=dtype)
+    cfg.update(changes)
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def xing(dtype="float32"):
+    cfg = xing_cfg(dtype)
+    return cfg, weights_xing.make_params(cfg, SEED), \
+        serve_xing.build_model(cfg)
+
+
+_xing_forward = jax.jit(reference_xing.forward, static_argnums=(2, 3))
+
+
+def xing_reference(cfg, params, toks, precision="f32"):
+    return np.asarray(_xing_forward(params, jnp.asarray(toks, jnp.int32),
+                                    reference_xing.frozen(cfg), precision))
+
+
+# ------------------------------------------------- what the engine serves
+
+# ``reference(toks, precision="f32")``: the plain reference's logits for
+# one sequence; ``tol``: float32 against float32 on the CPU; ``no_pages``:
+# what the paged engine says of a cache it cannot page (None: untested)
+Family = collections.namedtuple(
+    "Family", "name cfg params model reference tol no_pages")
+FAMILIES = ("sala", "brumby", "xing")
+
+
+@functools.lru_cache(maxsize=None)
+def family(name):
+    if name == "sala":
+        cfg, params = sala_weights(SALA_ALL)
+        return Family(name, cfg, params, serve_sala.build_model(cfg),
+                      functools.partial(sala_reference, cfg, params), 2e-5,
+                      "key/value models only")
+    if name == "brumby":
+        return Family(name, BRUMBY_CFG, brumby_weights(),
+                      serve_brumby.build_model(BRUMBY_CFG),
+                      brumby_reference, 5e-5,
+                      "has no paged cache .* recurrent state")
+    if name == "xing":
+        cfg, params, model = xing()
+        return Family(name, cfg, params, model,
+                      functools.partial(xing_reference, cfg, params), 2e-5,
+                      None)
+    raise KeyError(name)
+
+
+def prefill_spans(began):
+    """The ``engine.prefill`` spans written since ``began``."""
+    return [s for s in tracing.spans()
+            if s["name"] == "engine.prefill" and s["t"] >= began]
+
+
+def step_logits(engine, step_tokens, positions):
+    """One teacher-forced decode step over all of the engine's rows, as
+    ``_decode_impl`` runs it, returning the logits it would take the
+    argmax of. A row at position -1 is not active: it runs token 0 at
+    position 0, and a model that counts is told so. One program an
+    engine, not one a call: a teacher-forced case takes forty steps."""
+    if not hasattr(engine, "step_for_tests"):
+        model = engine._model
+        counts = bool(getattr(model, "counts_active_rows", False))
+        engine.step_for_tests = jax.jit(lambda p, c, t, q: model.apply(
+            {"params": p, "cache": c}, jnp.where(q >= 0, t, 0)[:, None],
+            positions=jnp.maximum(q, 0), train=False, mutable=["cache"],
+            **({"active": q >= 0} if counts else {})))
+    logits, mutated = engine.step_for_tests(
+        engine._params, engine._cache, jnp.asarray(step_tokens, jnp.int32),
+        jnp.asarray(positions, jnp.int32))
+    engine._cache = mutated["cache"]
+    return np.asarray(logits[:, 0])
+
+
+def toy_transformer(max_seq):
+    model = Transformer(vocab_size=61, d_model=32, num_layers=2,
+                        num_heads=2, d_ff=64, max_seq=max_seq, causal=True,
+                        dtype=jnp.float32)
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 8), jnp.int32),
+                             train=False)["params"]
+
+
+@functools.lru_cache(maxsize=None)
+def _forward(model):
+    return jax.jit(lambda params, toks: model.apply(
+        {"params": params}, toks, train=False))
+
+
+def uncached_greedy(model, params, prompt, n):
+    """Reference: a full (cache-free) forward per token, greedy argmax.
+    Every length runs the one program of ``max_seq`` positions: row
+    ``len - 1`` of a causal model does not see the padding after it."""
+    forward = _forward(model)
+    toks = list(prompt)
+    padded = np.zeros((1, model.max_seq), np.int32)
+    out = []
+    for _ in range(n):
+        padded[0, :len(toks)] = toks
+        logits = forward(params, padded)
+        out.append(int(jnp.argmax(logits[0, len(toks) - 1])))
+        toks.append(out[-1])
+    return out

@@ -31,6 +31,7 @@ import os
 import socket
 import subprocess
 import sys
+import tempfile
 import time
 
 # these host-side processes stay on the CPU (and off the chip, which
@@ -146,7 +147,7 @@ def worker() -> None:
 
 def launch(world: int, extra_env: dict, timeout: float = 420.0):
     port = _free_port()
-    procs = []
+    procs, logs = [], []
     for rank in range(world):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
@@ -159,21 +160,26 @@ def launch(world: int, extra_env: dict, timeout: float = 420.0):
             "JAX_PLATFORMS": "cpu",
         })
         env.update(extra_env)
+        # a file, not a pipe: the ranks wait on each other, and one
+        # that nobody reads yet would block on a full pipe (64 KB)
+        logs.append(tempfile.TemporaryFile("w+"))
         procs.append(subprocess.Popen(
             [sys.executable, os.path.abspath(__file__), "--worker"],
-            env=env, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True))
+            env=env, stdout=logs[-1], stderr=subprocess.STDOUT))
     outs = []
     try:
-        for p in procs:
-            out, _ = p.communicate(timeout=timeout)
-            outs.append(out)
+        for p, log in zip(procs, logs):
+            p.wait(timeout=timeout)
+            log.seek(0)
+            outs.append(log.read())
             if p.returncode != 0:
-                raise RuntimeError(f"worker failed rc={p.returncode}:\n{out}")
+                raise RuntimeError(
+                    f"worker failed rc={p.returncode}:\n{outs[-1]}")
     finally:
-        for p in procs:
+        for p, log in zip(procs, logs):
             if p.poll() is None:
                 p.kill()
+            log.close()
     for out in outs:
         for line in out.splitlines():
             if line.startswith("RESULTS "):

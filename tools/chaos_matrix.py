@@ -398,7 +398,7 @@ def run_scenario(name, spec):
     server = RendezvousServer(host="127.0.0.1")
     http_port = server.start()
     socket_port = _free_port()
-    procs = []
+    procs, logs = [], []
     outs = [""] * world
     failures = []
     try:
@@ -421,10 +421,12 @@ def run_scenario(name, spec):
             if ckpt_dir:
                 env["HOROVOD_CKPT_DIR"] = ckpt_dir
             env.update(spec.get("env", {}))
+            # a file, not a pipe: nobody reads a rank while the ranks
+            # wait on each other, and a full pipe (64 KB) would block it
+            logs.append(tempfile.TemporaryFile("w+"))
             procs.append(subprocess.Popen(
                 [sys.executable, worker], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
+                stdout=logs[-1], stderr=subprocess.STDOUT))
         # wait for every rank that is expected to terminate on its own;
         # a permanently-partitioned rank blocks forever by design and is
         # reaped after the survivors finish
@@ -442,8 +444,9 @@ def run_scenario(name, spec):
                 p.kill()
                 if i not in hung and i in waiting:
                     pass  # already reported as a timeout above
-            out, _ = p.communicate(timeout=30)
-            outs[i] = out or ""
+            p.wait(timeout=30)
+            logs[i].seek(0)
+            outs[i] = logs[i].read()
 
         results = {}
         for i, out in enumerate(outs):
@@ -583,6 +586,8 @@ def run_scenario(name, spec):
         for p in procs:
             if p.poll() is None:
                 p.kill()
+        for log in logs:
+            log.close()
         server.stop()
         shutil.rmtree(flight_dir, ignore_errors=True)
         if ckpt_dir:
