@@ -1,23 +1,28 @@
 """Decoders whose layers differ in kind (flax): block-sparse softmax
 attention, linear ("lightning") attention with a fixed decay, power
-retention (gated, normalised linear attention of degree 2) and latent
+retention (gated, normalised linear attention of degree 2), latent
 attention (MLA: one low-rank latent and one rotary key a token, shared by
-the heads), on a modern trunk - RMSNorm, a SiLU-gated MLP or a top-k
-router over such MLPs ("experts") beside a shared one, rotary positions
-(plain or YaRN), grouped key/value heads, per-head QK-norm, sigmoid
-output gates, one residual stream or several hyper-connected ones, an
-untied head and (optional) muP scalings.
+the heads) and grouped-query softmax attention over every causal key
+("full") or over the last ``window`` keys ("window"), on a modern trunk -
+RMSNorm on a sublayer's input or on its output, a SiLU-gated MLP or a
+top-k router over such MLPs ("experts", all of them or this chip's
+share) beside a shared one, rotary positions (plain or YaRN), grouped
+key/value heads, per-head QK-norm, sigmoid output gates, one residual
+stream or several hyper-connected ones, an untied head and (optional)
+muP scalings.
 
 :class:`HybridDecoder` reads its layer kinds from ``mixers`` and is what
 ``hvd.serve()`` runs for MiniCPM-SALA (``benchmark/configs/
 minicpm-sala.json``; the plain reference is
 ``benchmark/reference_sala.py``), for Brumby-14B-Base
 (``benchmark/configs/brumby-14b.json``, ``benchmark/
-reference_brumby.py``) and for Xing4.0-29B-A4B (``benchmark/configs/
-xing4-29b-a4b.json``, ``benchmark/reference_xing.py``). The blocks
-(:class:`RMSNorm`, :class:`GatedMlp`, :func:`rope`,
-:class:`BlockSparseAttention`, :class:`LightningAttention`,
-:class:`PowerRetention`, :class:`LatentAttention`,
+reference_brumby.py``), for Xing4.0-29B-A4B (``benchmark/configs/
+xing4-29b-a4b.json``, ``benchmark/reference_xing.py``) and for
+K-EXAONE-236B-A23B (``benchmark/configs/k-exaone-236b-a23b.json``,
+``benchmark/reference_kexaone.py``). The blocks (:class:`RMSNorm`,
+:class:`GatedMlp`, :func:`rope`, :class:`BlockSparseAttention`,
+:class:`LightningAttention`, :class:`PowerRetention`,
+:class:`LatentAttention`, :class:`GroupedQueryAttention`,
 :class:`RoutedExperts`, :class:`HyperConnection`) are not tied to those
 models.
 
@@ -26,8 +31,15 @@ have the slot as axis 0; which kinds it holds depends on the mixers (a
 model of power-retention layers alone has no leaf with a position axis):
 
 * ``cached_key`` / ``cached_value`` ``(slots, kv_heads, head_dim,
-  max_seq)`` - a sparse layer's keys and values, positions last, the
-  layout ``ops/pallas/kv_cache_write`` writes one token into;
+  max_seq)`` - a sparse or a full layer's keys and values, positions
+  last, the layout ``ops/pallas/kv_cache_write`` writes one token into;
+* ``ring_key`` / ``ring_value`` ``(slots, kv_heads, head_dim, ring)`` - a
+  window layer's keys and values, a ring of the smallest whole number of
+  lane tiles that holds the window, position ``p`` in column ``p mod
+  ring``: a prefill leaves the prompt's last positions there, a decode
+  step writes over the oldest column, and the step's mask sees a column
+  only where the position it holds is this request's and inside the
+  window;
 * ``compressed_key`` ``(slots, kv_heads, head_dim, windows)`` - the
   means of the key windows that block selection scores;
 * ``state`` ``(slots, heads, head_dim, head_dim)`` float32 - a lightning
@@ -59,7 +71,13 @@ the state it leaves is the state after ``lengths`` tokens, and with
 The sparse layer computes masked dense attention in blocks of queries
 (each query's selected key blocks are a mask over all causal keys) and
 the lightning layer scans chunks, both in XLA; PERF.md says what that
-costs and what a kernel would save. The power-retention layer scans
+costs and what a kernel would save. A window layer's prompt is banded
+blocks in XLA (each block of ``window`` queries against its own keys and
+the block's before it: work in ``seq x 2 window``, not ``seq^2``), a
+full layer's the flash kernel; a full layer's decode step reads its
+rows' live tiles through ``ops/pallas/grouped_decode_attention`` and a
+window layer's its ring, one lane tile a head at a window of 128, in
+XLA. The power-retention layer scans
 chunks too, and reads its state through two kernels
 (``ops/pallas/power_retention``): XLA would write every query's 8,256
 features to memory first.
@@ -74,9 +92,11 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from horovod_tpu.models.transformer import write_cache_rows
-from horovod_tpu.ops.pallas import latent_attention, power_retention
+from horovod_tpu.ops.pallas import (grouped_decode_attention,
+                                    latent_attention, power_retention)
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
 from horovod_tpu.ops.pallas.kv_cache_write import LANES, write_token
 
@@ -87,6 +107,11 @@ HIGHEST = jax.lax.Precision.HIGHEST
 BLOCK_SPARSE, LIGHTNING = "block_sparse", "lightning"
 POWER_RETENTION = "power_retention"
 LATENT = "latent"
+# grouped-query softmax attention over every causal key, and the same over
+# the last ``window`` keys
+FULL, WINDOW = "full", "window"
+# where a sublayer's RMSNorm sits: ``h += F(norm(h))`` or ``h += norm(F(h))``
+NORM_INPUT, NORM_OUTPUT = "input", "output"
 # a layer's MLP: one gated MLP, or a router over experts beside a shared one
 DENSE_MLP, EXPERTS_MLP = "dense", "experts"
 # a masked score: finite, so that a row with nothing to see stays a number
@@ -99,6 +124,9 @@ QUERY_BLOCK = 128
 # the keys up to its own end (static), so that about half of the causal
 # triangle's upper part is never computed
 KEY_EXTENTS = 8
+# query blocks of a window layer's prompt that are scored at once: the
+# float32 scores of one turn are (heads, BAND_BLOCKS, block, 2 block)
+BAND_BLOCKS = 16
 
 
 class RMSNorm(nn.Module):
@@ -954,16 +982,222 @@ class LatentAttention(nn.Module):
             o.reshape(batch, seq, heads * self.v_dim))
 
 
+# ------------------------------------------------- grouped-query attention
+
+def ring_len(window):
+    """Positions a window layer's ring holds: the smallest whole number
+    of lane tiles that holds the window."""
+    return -(-window // LANES) * LANES
+
+
+def ring_positions(last, ring):
+    """The position each column of a ring holds once position ``last``
+    is written: column ``j`` holds the newest ``p <= last`` with ``p mod
+    ring == j`` (negative: nothing yet). ``last``: (batch,); returns
+    (batch, ring) int32."""
+    column = jnp.arange(ring, dtype=jnp.int32)
+    return last[:, None] - (last[:, None] - column) % ring
+
+
+def window_prompt_attention(q, k, v, window, scale, dtype):
+    """Causal attention of a whole prompt from position 0 in which query
+    ``t`` sees keys ``max(0, t - window + 1) .. t``, as banded blocks:
+    the queries in blocks of ``window``, each block against its own keys
+    and the block's before it, so the work is ``seq x 2 window`` whatever
+    the length (masked full attention does ``seq^2 / 2``). ``q``: (batch,
+    seq, heads, d); ``k``/``v``: (batch, seq, kv_heads, d). Returns
+    (batch, seq, heads, d) in ``dtype``."""
+    batch, seq, heads, d = q.shape
+    groups = k.shape[2]
+    per = heads // groups
+    block = window
+    turn = block * (BAND_BLOCKS if seq > block * BAND_BLOCKS else 1)
+    pad = -seq % turn
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+    n = (seq + pad) // block
+
+    def banded(t):      # each block's keys after the block's before it
+        t = t.reshape(batch, n, block, groups, d)
+        before = jnp.pad(t[:, :-1], ((0, 0), (1, 0)) + ((0, 0),) * 3)
+        return jnp.concatenate([before, t], axis=2)
+
+    at = jnp.arange(block, dtype=jnp.int32)
+    apart = at[:, None] + block - jnp.arange(2 * block, dtype=jnp.int32)
+    near = (apart >= 0) & (apart < window)                  # (t, s)
+    own = jnp.arange(2 * block) >= block     # the block's own keys
+
+    def one(xs):        # BAND_BLOCKS blocks (or all of a short prompt)
+        q_b, k_b, v_b, number = xs
+        # block 0's "block before" is padding, not keys
+        seen = near & (own | (number > 0)[:, None, None])   # (n, t, s)
+        s = jnp.einsum("bntgrd,bnsgd->bngrts", q_b, k_b,
+                       preferred_element_type=F32) * scale
+        s = jnp.where(seen[None, :, None, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(dtype)
+        return jnp.einsum("bngrts,bnsgd->bntgrd", p, v_b)
+
+    turns = n * block // turn
+    each = n // turns
+    cut = lambda t: jnp.moveaxis(
+        t.reshape((batch, turns, each) + t.shape[2:]), 1, 0)
+    out = jax.lax.map(one, (
+        cut(q.reshape(batch, n, block, groups, per, d)), cut(banded(k)),
+        cut(banded(v)), jnp.arange(n, dtype=jnp.int32).reshape(turns, each)))
+    out = jnp.moveaxis(out, 0, 1).reshape(batch, seq + pad, heads, d)
+    return out[:, :seq]
+
+
+def full_prompt_attention(q, k, v, scale):
+    """Causal attention of a whole prompt from position 0 through the
+    flash kernel, which has one key/value head a query head: each group's
+    keys and values are repeated to its queries. ``q``: (batch, seq,
+    heads, d); ``k``/``v``: (batch, seq, kv_heads, d). Returns (batch,
+    seq, heads, d)."""
+    per = q.shape[2] // k.shape[2]
+    heads_first = lambda t: t.transpose(0, 2, 1, 3)
+    wide = lambda t: jnp.repeat(heads_first(t), per, axis=1)
+    o = flash_attention(heads_first(q), wide(k), wide(v), causal=True,
+                        sm_scale=scale)
+    return o.transpose(0, 2, 1, 3)
+
+
+def ring_step_attention(q, keys, values, positions, window, scale, dtype):
+    """One decode step of window attention against the ring, the new
+    token's columns already in it: column ``j`` holds position
+    :func:`ring_positions` and is seen where that is a position of this
+    request (not negative: an earlier occupant's column is never read)
+    inside the window. ``q``: (batch, heads, d); ``keys``/``values``:
+    (batch, kv_heads, d, ring); ``positions``: (batch,). The whole ring
+    is one lane tile a head at a window of 128: XLA's masked products
+    read what a kernel would."""
+    batch, heads, d = q.shape
+    groups, ring = keys.shape[1], keys.shape[-1]
+    held = ring_positions(positions, ring)
+    seen = (held >= 0) & (positions[:, None] - held < window)
+    s = jnp.einsum("bgrd,bgdj->bgrj",
+                   q.reshape(batch, groups, heads // groups, d), keys,
+                   preferred_element_type=F32) * scale
+    s = jnp.where(seen[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(dtype)
+    return jnp.einsum("bgrj,bgdj->bgrd", p, values).reshape(batch, heads, d)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Grouped-query softmax attention with per-head QK-norm and no
+    gate: over every causal key (``window`` ``None``; no positional
+    encoding unless ``rotary``) or over the last ``window`` keys, the
+    query's own among them (rotary positions over the whole head width
+    where ``rotary``).
+
+    With ``decode=True`` a full layer keeps ``cached_key`` /
+    ``cached_value`` ``(batch, kv_heads, head_dim, max_cache_len)`` and a
+    decode step reads the live tiles of its rows
+    (``ops/pallas/grouped_decode_attention``); a window layer keeps
+    ``ring_key`` / ``ring_value`` ``(batch, kv_heads, head_dim, ring)``,
+    position ``p`` in column ``p mod ring`` (:func:`ring_len`): a prefill
+    leaves the prompt's last ``ring`` positions there (``lengths``: the
+    true length) and a decode step writes over the oldest column."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int] = None
+    rotary: bool = False
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    decode: bool = False
+    max_cache_len: int = 0
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = F32
+
+    @nn.compact
+    def __call__(self, x, positions, lengths=None):
+        batch, seq, d_model = x.shape
+        heads, groups, d = self.num_heads, self.num_kv_heads, self.head_dim
+        window = self.window
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        norm = partial(RMSNorm, eps=self.eps, dtype=self.dtype,
+                       param_dtype=self.param_dtype)
+        q = norm(name="q_norm")(
+            dense(heads * d, name="query")(x).reshape(batch, seq, heads, d))
+        k = norm(name="k_norm")(
+            dense(groups * d, name="key")(x).reshape(batch, seq, groups, d))
+        v = dense(groups * d, name="value")(x).reshape(batch, seq, groups, d)
+        if self.rotary:
+            at = positions[:, None] \
+                + jnp.arange(seq, dtype=jnp.int32)[None, :]
+            q = rope(q, at, self.rope_theta).astype(self.dtype)
+            k = rope(k, at, self.rope_theta).astype(self.dtype)
+        scale = d ** -0.5
+        if self.decode:
+            keys, values = (self.variable(
+                "cache", name, jnp.zeros,
+                (batch, groups, d,
+                 ring_len(window) if window else self.max_cache_len),
+                self.dtype) for name in (
+                    ("ring_key", "ring_value") if window
+                    else ("cached_key", "cached_value")))
+        with jax.named_scope("window_attention" if window
+                             else "full_attention"):
+            if self.decode and seq == 1:
+                column = positions % keys.value.shape[-1] if window \
+                    else positions
+                keys.value = write_token(keys.value, k[:, 0], column)
+                values.value = write_token(values.value, v[:, 0], column)
+                if window:
+                    o = ring_step_attention(
+                        q[:, 0], keys.value, values.value, positions, window,
+                        scale, self.dtype)
+                else:
+                    o = grouped_decode_attention.grouped_decode_attention(
+                        q[:, 0].reshape(batch, groups, heads // groups, d),
+                        keys.value, values.value, positions, scale)
+            elif window:
+                o = window_prompt_attention(q, k, v, window, scale,
+                                            self.dtype)
+                if self.decode:
+                    # column j takes the newest position of the prompt
+                    # that falls on it; a column no position has reached
+                    # keeps zeros, and the step's mask never reads it
+                    last = (jnp.full((batch,), seq, jnp.int32)
+                            if lengths is None else lengths) - 1
+                    held = ring_positions(last, keys.value.shape[-1])
+                    pick = lambda t: jnp.where(
+                        (held >= 0)[:, None, None], jnp.take_along_axis(
+                            t.transpose(0, 2, 3, 1),
+                            jnp.clip(held, 0, seq - 1)[:, None, None],
+                            axis=-1), 0).astype(self.dtype)
+                    keys.value, values.value = pick(k), pick(v)
+            else:
+                o = full_prompt_attention(q, k, v, scale)
+                if self.decode:
+                    keys.value = write_cache_rows(keys.value, k, positions)
+                    values.value = write_cache_rows(values.value, v,
+                                                    positions)
+        return dense(d_model, name="out")(o.reshape(batch, seq, heads * d))
+
+
 # ----------------------------------------------------------- routed experts
 
-# at or under this many (token, expert) pairs a held expert, every held
-# expert multiplies every row under a 0/1 weight (experts_masked); above
-# it the pairs are sorted and grouped (experts_grouped). The one point
+# at or under this many (token, expert) pairs a held expert (of the pairs
+# expected here: a layer that holds a share of the experts gets that share
+# of a step's pairs), every held expert multiplies every row under a 0/1
+# weight (experts_masked); above it the pairs are sorted and grouped
+# (experts_grouped, experts_grouped_held). The one point
 # measured on the chip is 64 rows x top-4 over 64 experts, 4 pairs an
 # expert, where the masked product runs within 8-16% of the time of
-# reading the experts (PERF.md, PR 34); nothing smaller can gain from
-# grouping, and nothing larger has been measured in the masked form.
+# reading the experts (PERF.md, PR 34), and 32 rows x top-8 over 8 of 128
+# experts, 2 pairs an expert of the 16 expected here, where it runs at
+# the time of reading them (0.84 ms a layer for 681 MB; PERF.md, PR 39);
+# nothing smaller can gain from grouping, and nothing larger has been
+# measured in the masked form.
 MASKED_PAIRS = 4
+# tokens of a prompt that a layer holding a share of the experts groups at
+# once (experts_grouped_held)
+HELD_TOKENS = 4096
 
 def route(x, router, bias, top_k, scaling):
     """DeepSeek-V3's ``noaux_tc`` router without group limits: scores
@@ -1024,6 +1258,63 @@ def experts_grouped(x, chosen, weights, gate, up, down):
     return sum(jnp.where(chosen[:, k, None] < experts,
                          y[back[:, k]] * weights[:, k, None], 0.0)
                for k in range(top_k))
+
+
+def experts_grouped_held(x, chosen, weights, gate, up, down, share):
+    """:func:`experts_grouped` for a layer that holds a ``share`` (under
+    1) of the experts, where most pairs are not here: only the pairs that
+    are here are gathered and multiplied. The sorted pairs are taken
+    ``room`` at a time - twice the pairs an even router would send here,
+    in whole lane tiles - in a loop that runs as often as the pairs here
+    need: once, unless the router is very uneven. No pair is dropped;
+    the rows gathered, the hidden rows and the float32 products are
+    ``room`` long and not ``tokens x top_k``. A long prompt goes
+    ``HELD_TOKENS`` tokens at a time, so that none of that grows with its
+    length (each turn reads the held experts again: a millisecond).
+    Returns (tokens, d) float32."""
+    tokens, top_k = chosen.shape
+    experts = gate.shape[0]
+    if tokens > HELD_TOKENS and tokens % HELD_TOKENS == 0:
+        cut = lambda t: t.reshape((-1, HELD_TOKENS) + t.shape[1:])
+        return jax.lax.map(
+            lambda xs: experts_grouped_held(*xs, gate, up, down, share),
+            (cut(x), cut(chosen), cut(weights))).reshape(tokens, -1)
+    pairs = tokens * top_k
+    room = min(pairs, -(-math.ceil(2 * pairs * share) // LANES) * LANES)
+    pair_expert = chosen.reshape(-1)
+    order = jnp.argsort(pair_expert, stable=True)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(pairs, dtype=order.dtype)).reshape(chosen.shape)
+    here = jnp.sum(pair_expert < experts, dtype=jnp.int32)
+    order = jnp.pad(order, (0, -pairs % room))
+
+    def some(i, out):
+        start = i * room
+        taken = jax.lax.dynamic_slice(order, (start,), (room,))
+        expert = jnp.where(start + jnp.arange(room) < here,
+                           pair_expert[taken], experts)
+        sizes = jnp.sum(expert[:, None] == jnp.arange(experts)[None, :],
+                        axis=0, dtype=jnp.int32)
+        rows = x[taken // top_k]
+        h = nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
+            * jax.lax.ragged_dot(rows, up, sizes)
+        y = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=F32)
+        # a token's k-th pair at a time, as experts_grouped gathers them,
+        # but one after the other: together the gathered rows of a long
+        # prompt are gigabytes. A pair that is not here, or not in this
+        # turn, adds nothing
+        def kth(k, out):
+            at = jnp.take(back, k, axis=1) - start
+            mine = (jnp.take(chosen, k, axis=1) < experts) \
+                & (at >= 0) & (at < room)
+            return out + jnp.where(
+                mine[:, None], y[jnp.clip(at, 0, room - 1)]
+                * jnp.take(weights, k, axis=1)[:, None], 0.0)
+
+        return jax.lax.fori_loop(0, top_k, kth, out)
+
+    return jax.lax.fori_loop(0, -(-here // room), some,
+                             jnp.zeros((tokens, x.shape[-1]), F32))
 
 
 class RoutedExperts(nn.Module):
@@ -1093,14 +1384,21 @@ class RoutedExperts(nn.Module):
             counts.value = counts.value + jnp.stack(
                 [pairs, jnp.minimum(pairs, 1) * step, step])
         flat = x.reshape(batch * seq, d)
-        if batch * seq * self.top_k <= MASKED_PAIRS * count:
+        # the pairs expected here are ``count / num_experts`` of the
+        # step's: MASKED_PAIRS an expert of them, over all the experts
+        if batch * seq * self.top_k <= MASKED_PAIRS * self.num_experts:
             y = experts_masked(
                 flat, jnp.sum(hit * weights[..., None], axis=2).reshape(
                     batch * seq, count), gate, up, down)
-        else:
+        elif count == self.num_experts:
             y = experts_grouped(flat, here.reshape(-1, self.top_k),
                                 weights.reshape(-1, self.top_k), gate, up,
                                 down)
+        else:
+            y = experts_grouped_held(
+                flat, here.reshape(-1, self.top_k),
+                weights.reshape(-1, self.top_k), gate, up, down,
+                count / self.num_experts)
         y = y.reshape(batch, seq, d)
         if self.shared:
             y = y + GatedMlp(self.shared * self.d_ff, dtype=self.dtype,
@@ -1206,6 +1504,10 @@ class HybridLayer(nn.Module):
     MLP (``"dense"``: a :class:`GatedMlp` of ``d_ff``; ``"experts"``:
     :class:`RoutedExperts` with the fields ``mlp_args``).
 
+    With ``norms="output"`` the norm sits on each sublayer's output and
+    none on its input: ``h += a norm(Mixer(h)); h += a norm(Mlp(h))``
+    (EXAONE 4.0's placement; one residual stream only).
+
     With ``streams`` > 1 the residual is ``streams`` streams a token
     (``h``: (batch, streams, seq, C)) and each sublayer sits inside a
     :class:`HyperConnection` (fields ``hyper_args``): ``X' = H_res X +
@@ -1215,6 +1517,7 @@ class HybridLayer(nn.Module):
     mixer_args: Any
     d_ff: int
     residual_scale: float = 1.0
+    norms: str = NORM_INPUT
     mlp: str = DENSE_MLP
     mlp_args: Any = None
     streams: int = 1
@@ -1230,7 +1533,9 @@ class HybridLayer(nn.Module):
         mixer = {LIGHTNING: LightningAttention,
                  BLOCK_SPARSE: BlockSparseAttention,
                  POWER_RETENTION: PowerRetention,
-                 LATENT: LatentAttention}[self.kind](
+                 LATENT: LatentAttention,
+                 FULL: GroupedQueryAttention,
+                 WINDOW: GroupedQueryAttention}[self.kind](
                      name="mixer", **dict(self.mixer_args), **common)
         norm = partial(RMSNorm, **common)
         if self.mlp == EXPERTS_MLP:
@@ -1243,6 +1548,14 @@ class HybridLayer(nn.Module):
             mlp = lambda u: GatedMlp(
                 self.d_ff, dtype=self.dtype, param_dtype=self.param_dtype,
                 name="mlp")(u)
+        if self.norms == NORM_OUTPUT:
+            if self.streams != 1:
+                raise ValueError("norms on the sublayers' outputs go with "
+                                 "one residual stream")
+            a = jnp.asarray(self.residual_scale, self.dtype)
+            h = h + a * norm(name="mixer_norm")(mixer(h, positions, lengths))
+            with jax.named_scope(scope):
+                return h + a * norm(name="mlp_norm")(mlp(h))
         if self.streams == 1:
             a = jnp.asarray(self.residual_scale, self.dtype)
             h = h + a * mixer(norm(name="input_norm")(h), positions, lengths)
@@ -1272,11 +1585,19 @@ class HybridLayer(nn.Module):
 class HybridDecoder(nn.Module):
     """Embedding, ``len(mixers)`` layers of the kinds ``mixers`` names
     (``"block_sparse"`` / ``"lightning"`` / ``"power_retention"`` /
-    ``"latent"``), final RMSNorm, untied head.
+    ``"latent"`` / ``"full"`` / ``"window"``), final RMSNorm, untied head.
 
     ``mlps`` names each layer's MLP (``"dense"``, the default, or
     ``"experts"``: :class:`RoutedExperts` with the fields ``experts``);
     ``latent`` holds :class:`LatentAttention`'s ranks and widths;
+    ``window`` is the keys a ``"window"`` layer sees
+    (:class:`GroupedQueryAttention`: rotary positions there and none on a
+    ``"full"`` layer); ``norms`` says where a sublayer's norm sits
+    (:class:`HybridLayer`); ``layer_barriers`` puts an optimisation
+    barrier after every layer of a prompt, so that XLA does not run one
+    layer's work under another's: without it the temporaries of four
+    layers of a 16,384-token prompt at a width of 6,144 live at once
+    (4.2 GB where the barriers leave 2.5; a decode step has none);
     ``streams`` > 1 repeats the embedding into that many residual
     streams, puts a :class:`HyperConnection` (fields ``hyper``) round
     every sublayer and sums the streams before the final norm.
@@ -1299,6 +1620,9 @@ class HybridDecoder(nn.Module):
     mixers: Tuple[str, ...]
     sparse: Any = None                  # a mapping; see BlockSparseAttention
     latent: Any = None                  # a mapping; see LatentAttention
+    window: Optional[int] = None        # keys a "window" layer sees
+    norms: str = NORM_INPUT
+    layer_barriers: bool = False
     mlps: Optional[Tuple[str, ...]] = None
     experts: Any = None                 # a mapping; see RoutedExperts
     streams: int = 1
@@ -1363,7 +1687,28 @@ class HybridDecoder(nn.Module):
                         rope_theta=self.rope_theta,
                         max_cache_len=self.max_seq, decode=self.decode,
                         **dict(self.latent))
+        if kind in (FULL, WINDOW):
+            return dict(num_heads=self.num_heads,
+                        num_kv_heads=self.num_kv_heads,
+                        head_dim=self.head_dim,
+                        window=self.window if kind == WINDOW else None,
+                        rotary=kind == WINDOW, rope_theta=self.rope_theta,
+                        max_cache_len=self.max_seq, decode=self.decode)
         raise ValueError(f"unknown mixer {kind!r}")
+
+    def decode_positions_by_kind(self, positions):
+        """Positions a decode step at ``positions`` (numpy, (rows,), a row
+        that is not active at 0) attends, all rows and all layers of a
+        kind together, by the kind of cache leaf they are read from:
+        ``kv`` (a full layer: every position up to the row's own) and
+        ``ring`` (a window layer: the window's at most). ``None`` for a
+        model with neither kind of layer."""
+        full, ring = self.mixers.count(FULL), self.mixers.count(WINDOW)
+        if not full and not ring:
+            return None
+        seen = np.asarray(positions, np.int64) + 1
+        return {"kv": full * int(seen.sum()),
+                "ring": ring * int(np.minimum(seen, self.window or 0).sum())}
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
@@ -1403,13 +1748,15 @@ class HybridDecoder(nn.Module):
                 d_ff=self.d_ff,
                 residual_scale=(1.0 if self.scale_depth is None
                                 else self.scale_depth / math.sqrt(depth)),
-                mlp=mlps[i],
+                norms=self.norms, mlp=mlps[i],
                 mlp_args=(dict(self.experts, decode=self.decode)
                           if mlps[i] == EXPERTS_MLP else None),
                 streams=self.streams, hyper_args=self.hyper,
                 eps=self.eps, dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name=f"layer_{i}")(h, positions, lengths, active)
+            if self.layer_barriers and seq > 1:
+                h = jax.lax.optimization_barrier(h)
         if self.streams > 1:
             h = h.astype(F32).sum(axis=1).astype(self.dtype)
         if lengths is not None:

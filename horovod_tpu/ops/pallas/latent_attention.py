@@ -43,12 +43,11 @@ from horovod_tpu.ops.pallas.kv_cache_write import LANES
 TILE = 1024
 
 
-def tile_of(cache_len: int) -> int:
-    """The positions a grid step takes: ``TILE``, or the largest halving
+def tile_of(cache_len: int, tile: int = TILE) -> int:
+    """The positions a grid step takes: ``tile``, or the largest halving
     of it (down to a lane tile) that divides ``cache_len``, or all of a
     cache whose length is no whole number of lane tiles (a block is a
     multiple of the lane tile or the whole dimension)."""
-    tile = TILE
     while tile >= LANES:
         if cache_len % tile == 0:
             return tile

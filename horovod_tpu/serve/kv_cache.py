@@ -54,7 +54,13 @@ states alone - a model of power-retention layers has no leaf with a
 position axis at all, and its decode step rewrites its whole cache
 (:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). A latent-attention
 layer keeps one latent and one rotary key a position and nothing a head
-(kind ``latent``). Nothing here asks a leaf for more than the slot axis,
+(kind ``latent``). A window layer keeps its keys and values in a ring of
+window-many positions (whole lane tiles of them), position ``p`` in
+column ``p mod ring``, and not in ``max_seq`` (kind ``ring``): a prefill
+leaves the prompt's last positions there and a decode step writes over
+the oldest column, both inside the model, so that a slot's row of a ring
+leaf is written and replaced like any other row. Nothing here asks a
+leaf for more than the slot axis,
 with one exception: a leaf of kind ``counter`` (an expert layer's
 ``expert_counts``) is a running count and no slot's row, so a prefill
 adds its fresh counts to it where it overwrites a row of every other
@@ -111,7 +117,9 @@ import numpy as np
 from horovod_tpu import tracing
 from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
-from horovod_tpu.ops.pallas import decode_attention, latent_attention
+from horovod_tpu.ops.pallas import (decode_attention,
+                                    grouped_decode_attention,
+                                    latent_attention)
 from horovod_tpu.ops.pallas._backend import kernels_in
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
 
@@ -164,17 +172,19 @@ def prompt_bucket(prompt_len: int, max_seq: int,
 # running sum of features) that does not grow. A model need not have
 # every kind: one of recurrent layers alone holds states and nothing else.
 # A latent-attention layer's two leaves (one latent and one rotary key a
-# position, nothing a head) are ``latent``; an expert layer's running
-# counts are ``counter``: no slot's row (module docstring)
+# position, nothing a head) are ``latent``; a window layer's keys and
+# values, a ring of window-many positions, are ``ring``; an expert layer's
+# running counts are ``counter``: no slot's row (module docstring)
 CACHE_KINDS = {"cached_key": "kv", "cached_value": "kv",
                "compressed_key": "compressed", "state": "state",
                "state_norm": "state", "latent": "latent",
-               "rope_key": "latent", "expert_counts": "counter"}
+               "rope_key": "latent", "ring_key": "ring",
+               "ring_value": "ring", "expert_counts": "counter"}
 
 
 def leaf_kind(path) -> str:
-    """``kv``, ``compressed``, ``state``, ``latent`` or ``counter`` for a
-    cache leaf's tree path (``other`` for a name :data:`CACHE_KINDS` does
+    """``kv``, ``compressed``, ``state``, ``latent``, ``ring`` or
+    ``counter`` for a cache leaf's tree path (``other`` for a name :data:`CACHE_KINDS` does
     not know)."""
     name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
     return CACHE_KINDS.get(str(name), "other")
@@ -290,6 +300,16 @@ class DecodeEngine:
         # roofline of that kernel counts bytes by
         self._reads_live_latents = False
         self.positions_read = 0
+        # the same for key/value leaves read through ops/pallas/
+        # grouped_decode_attention; and, for a model whose layers keep
+        # different kinds of cache (``decode_positions_by_kind``), the
+        # positions its steps attended by the kind of leaf they were
+        # read from (stats()["decode_positions_by_kind"])
+        self._reads_live_groups = False
+        by_kind = getattr(model, "decode_positions_by_kind", None)
+        self._positions_by_kind = by_kind \
+            if by_kind and by_kind(np.zeros((1,), np.int64)) else None
+        self.positions_by_kind: Dict[str, int] = {}
         self._cache = self._allocate_cache()
         # the next token of every row, on the device (module docstring)
         self._feed = jnp.zeros((self.num_slots,), jnp.int32)
@@ -343,6 +363,7 @@ class DecodeEngine:
         self._write_fused = ("kv_cache_write" not in kernels
                              if self._reads_live_tiles else None)
         self._reads_live_latents = "latent_decode_attention" in kernels
+        self._reads_live_groups = "grouped_decode_attention" in kernels
         return shapes["cache"]
 
     def _allocate_cache(self):
@@ -581,11 +602,12 @@ class DecodeEngine:
                     f"decode: slot {slot} position {step_pos[slot]} >= "
                     f"max_seq {self.max_seq} (admission cap violated)")
             attrs = {}
-            if self._reads_live_tiles:
+            if self._reads_live_tiles or self._reads_live_groups:
                 # what the kernel will fetch: a row that is not active
                 # runs at position 0 and costs one tile
-                read, held = decode_attention.live_tiles(step_pos,
-                                                         self.max_seq)
+                kernel = decode_attention if self._reads_live_tiles \
+                    else grouped_decode_attention
+                read, held = kernel.live_tiles(step_pos, self.max_seq)
                 self.kv_tiles_read += read
                 self.kv_tiles_held += held
                 attrs["kv_read_share"] = round(read / held, 4)
@@ -596,6 +618,12 @@ class DecodeEngine:
                 self.kv_tiles_held += held
                 self.positions_read += attended
                 attrs["kv_read_share"] = round(read / held, 4)
+            by_kind = self._positions_by_kind(np.maximum(step_pos, 0)) \
+                if self._positions_by_kind else {}
+            for kind, attended in by_kind.items():
+                self.positions_by_kind[kind] = \
+                    self.positions_by_kind.get(kind, 0) + attended
+                attrs[f"{kind}_positions_read"] = attended
         with tracing.span("engine.decode.dispatch") as dispatch:
             self._lock_wait_s = 0.0
             ids, max_abs = self._run_donating("decode", self._decode_fn,
@@ -678,6 +706,13 @@ class DecodeEngine:
                     "decode_positions_read": (self.positions_read
                                               if self._reads_live_latents
                                               else None),
+                    # positions the decode steps attended, all layers of
+                    # a kind together, by the kind of leaf they were read
+                    # from (``kv``, ``ring``); None for a model whose
+                    # layers all keep one kind
+                    "decode_positions_by_kind": (
+                        dict(self.positions_by_kind)
+                        if self._positions_by_kind else None),
                     # (layers, 3, experts) as nested lists: pairs, decode
                     # steps that hit the expert, decode steps, each modulo
                     # 2**32 (None: the model has no expert layer)

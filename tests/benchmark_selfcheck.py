@@ -38,6 +38,11 @@ XING_UNTRACED = ("test_an_untraced_rehearsal_reads_the_end_to_end_metrics",
 XING_SCALING = ("test_a_dropped_scaling_factor_is_not_correct",)
 XING_FAULTS = ("test_the_faults_are_planted_in_the_reference_and_leave_it_"
                "plain",)
+# ``benchmark/tests/test_serve_kexaone.py``: two rehearsals of about a
+# minute each; the untraced one and the controls run apart from the rest
+KEXAONE_UNTRACED = ("test_an_untraced_rehearsal_reads_the_end_to_end_metrics",
+                    "test_the_float8_control_and_the_planted_faults_fail_a_"
+                    "limit")
 
 
 def test_functions(modules, only=(), without=()):
@@ -115,6 +120,8 @@ REHEARSALS = {
     ("benchmark.tests.test_serve_brumby",
      "test_a_sound_rehearsal_is_correct"),
     ("benchmark.tests.test_serve_xing",
+     "test_a_sound_traced_rehearsal_is_correct_and_reads_its_metrics"),
+    ("benchmark.tests.test_serve_kexaone",
      "test_a_sound_traced_rehearsal_is_correct_and_reads_its_metrics"),
 }
 # the line pytest marks as the one that failed, in the test's own body

@@ -1,6 +1,6 @@
 """What every model behind the dense serving engine promises, once for
-all of them: the three families of ``tests/toy_models.py`` (MiniCPM-SALA,
-Brumby, Xing: each a ``HybridDecoder`` with a cache of its own kinds,
+all of them: the four families of ``tests/toy_models.py`` (MiniCPM-SALA,
+Brumby, Xing, K-EXAONE: each a ``HybridDecoder`` with a cache of its own kinds,
 against its plain reference's one forward in float32). A new family is
 one more entry of ``toy_models.family`` and of its ``FAMILIES``, not a
 copy of these cases; what only one family has (its mixers, its cache's
@@ -57,7 +57,10 @@ def engines():
     ("sala", 203), ("sala", 61),
     *(("brumby", n) for n in (203, 61, 300, CHUNK - 1, CHUNK, CHUNK + 1,
                               2 * CHUNK, 3 * CHUNK + 17)),
-    ("xing", 203), ("xing", 61)])
+    ("xing", 203), ("xing", 61),
+    # 203 lies past K-EXAONE's ring of 128 (its last 128 positions wrap
+    # into it), 61 under its window of 64
+    ("kexaone", 203), ("kexaone", 61)])
 def test_prefill_then_decode_is_the_references_one_forward(pieces, engines,
                                                            name, prompt_len):
     """A padded prefill (a bucket, or pieces that carry the slot's state),

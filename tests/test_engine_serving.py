@@ -1,5 +1,5 @@
 """Every model family behind the public entry point, ``hvd.serve()``, and
-what the dense engine's prefill promises whatever the model: the three
+what the dense engine's prefill promises whatever the model: the four
 families of ``tests/toy_models.py`` and the toy GPT-2 trunk (the
 engine's own contract, on logits, is ``tests/test_engine_contract.py``:
 a file is what tier-1's ``--dist loadfile`` schedules).
@@ -50,6 +50,22 @@ def test_serving_through_hvd_serve(name):
                 counts = np.asarray(engine["expert_counts"])
                 assert (counts[:, 0].sum(axis=1)
                         == (150 + 37 + 260 + 3 * 7) * fam.cfg["top_k"]).all()
+            if name == "kexaone":
+                # rings on the six window layers, max_seq-long rows on
+                # the two full ones; what the decode steps attended of
+                # each (a request's last token is never fed back)
+                by_kind = engine["cache_bytes_by_kind"]
+                width = fam.cfg["num_kv_heads"] * fam.cfg["head_dim"] * 4
+                assert by_kind["ring"] == 6 * 2 * 2 * 128 * width
+                assert by_kind["kv"] == 2 * 2 * 2 * fam.cfg["max_seq"] * width
+                read = engine["decode_positions_by_kind"]
+                contexts = [n + t for n in (150, 37, 260)
+                            for t in range(1, 8)]
+                inactive = engine["decode_steps"] * 2 - len(contexts)
+                assert read["kv"] == 2 * (sum(contexts) + inactive)
+                assert read["ring"] == 6 * (sum(min(c, 64) for c in contexts)
+                                            + inactive)
+                assert 0 < engine["decode_kv_read_share"] <= 1.0
             if fam.no_pages:
                 with pytest.raises(ValueError, match=fam.no_pages):
                     hvd.serve(fam.model, fam.params, slots=2, paged=True)

@@ -19,11 +19,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark import reference_kexaone as kref
 from benchmark import reference_xing as xref
 from benchmark import weights_xing
 from benchmark.runners import serve_xing
 from horovod_tpu.models import hybrid
-from toy_models import (REPO, SEED, tokens, xing, xing_cfg,
+from toy_models import (REPO, SEED, kexaone, tokens, xing, xing_cfg,
                         xing_reference as xreference)
 
 F32_TOL = 2e-5
@@ -178,37 +179,58 @@ def _held(p, first, count, shared=True):
     return part
 
 
+def _layout(name):
+    """The expert layer of a toy model, whole: its configuration, its
+    parameters, the reference's ``routed`` and the experts a share."""
+    if name == "8-experts-top-4":
+        cfg, params, _ = xing()
+        return cfg, params["layer_1"]["moe"], xref, 4
+    # K-EXAONE's router at its published count: 128 experts, top-8,
+    # scaling 2.5, sixteen shares of 8 (the toy's own widths)
+    cfg, params, _ = kexaone(num_experts=128, experts_count=128, top_k=8)
+    return cfg, params["layer_1"]["moe"], kref, 8
+
+
+@pytest.mark.parametrize("layout", ["8-experts-top-4", "128-experts-top-8"])
 @pytest.mark.parametrize("seq", [50, 2], ids=["grouped", "masked"])
-def test_the_expert_layers_shares_add_up(seq):
-    """Eight toy experts held as (0, 4) + (4, 4) and as (0, 8): the
-    routed parts summed, with the shared expert counted once, are the
-    whole layer of the reference; each share routes over all eight
-    router outputs. Both forms of the product, each reached by its size:
-    100 tokens are 400 pairs, 4 tokens 16 = ``MASKED_PAIRS`` x 4."""
-    cfg, params, _ = xing()
-    p = params["layer_1"]["moe"]
+def test_the_expert_layers_shares_add_up(seq, layout):
+    """The toy experts held in shares (8 as (0, 4) + (4, 4); 128 as
+    sixteen shares of 8) and whole: the routed parts summed, with the
+    shared expert counted once, are the whole layer of the reference;
+    each share routes over all the router's outputs. Both forms of the
+    product, each reached by its size: 100 tokens are 400 (800) pairs,
+    4 tokens 16 (32), at or under ``MASKED_PAIRS`` an expert of the pairs
+    a share expects."""
+    cfg, p, ref, count = _layout(layout)
+    experts = cfg["num_experts"]
+
+    def layer(first, count, shared):
+        return hybrid.RoutedExperts(
+            num_experts=experts, top_k=cfg["top_k"],
+            d_ff=cfg["expert_d_ff"], shared=shared,
+            scaling=cfg["routed_scaling"], first=first, count=count,
+            dtype=jnp.float32)
+
     x = jnp.asarray(np.random.default_rng(9).normal(size=(2, seq, 128)),
                     jnp.float32)
-    for count in (8, 4):
-        assert _is_grouped(_routed_layer(cfg, 0, count, 1),
-                           {"params": _held(p, 0, count)}, x) == (seq == 50)
-    want = np.asarray(xref.routed(xref._matmul("f32"), x.reshape(-1, 128),
-                                  p, xref.frozen(cfg))).reshape(x.shape)
-    whole = _routed_layer(cfg, 0, 8, 1).apply(
-        {"params": _held(p, 0, 8)}, x)
+    for held in (experts, count):
+        assert _is_grouped(layer(0, held, 1),
+                           {"params": _held(p, 0, held)}, x) == (seq == 50)
+    want = np.asarray(ref.routed(ref._matmul("f32"), x.reshape(-1, 128),
+                                 p, ref.frozen(cfg))).reshape(x.shape)
+    whole = layer(0, experts, 1).apply({"params": _held(p, 0, experts)}, x)
     assert np.abs(np.asarray(whole) - want).max() < F32_TOL
-    low = _routed_layer(cfg, 0, 4, 1).apply(
-        {"params": _held(p, 0, 4)}, x)
-    high = _routed_layer(cfg, 4, 4, 0).apply(
-        {"params": _held(p, 4, 4, shared=False)}, x)
-    assert np.abs(np.asarray(low + high) - want).max() < F32_TOL
+    parts = [layer(first, count, int(first == 0)).apply(
+        {"params": _held(p, first, count, shared=first == 0)}, x)
+        for first in range(0, experts, count)]
+    assert np.abs(np.asarray(sum(parts)) - want).max() < F32_TOL
     # the reference given the same share computes the same part
-    share = xref.frozen(dict(cfg, experts_first=4, experts_count=4,
-                             shared_experts=0))
-    part = np.asarray(xref.routed(
-        xref._matmul("f32"), x.reshape(-1, 128),
-        _held(p, 4, 4, shared=False), share)).reshape(x.shape)
-    assert np.abs(np.asarray(high) - part).max() < F32_TOL
+    share = ref.frozen(dict(cfg, experts_first=count, experts_count=count,
+                            shared_experts=0))
+    part = np.asarray(ref.routed(
+        ref._matmul("f32"), x.reshape(-1, 128),
+        _held(p, count, count, shared=False), share)).reshape(x.shape)
+    assert np.abs(np.asarray(parts[1]) - part).max() < F32_TOL
 
 
 @pytest.mark.parametrize("form", ["grouped", "masked"])

@@ -514,6 +514,94 @@ def test_latent_experts_engine_fits_and_updates_its_cache_in_place(
         assert text.count("tpu_custom_call") >= layers + 3 * (layers - 1)
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_16384"])
+def test_window_full_engine_fits_and_updates_its_cache_in_place(
+        chip, monkeypatch, capsys, program):
+    """K-EXAONE-236B-A23B as the benchmark runs it (benchmark/configs/
+    k-exaone-236b-a23b.json: layers 0-7 at full width, six window layers
+    to two full ones, 8 of 128 experts, 32 slots of 16,384 positions,
+    bfloat16 weights): the 32-row decode step and the prefill of the
+    largest bucket compile for one v5e chip, arguments plus temporaries
+    stay under its 16 GB (printed: run with ``-s``), and the result
+    aliases every leaf of the donated cache - ``max_seq``-long keys and
+    values on the two full layers, a ring of 128 positions on the six
+    window layers, the expert layers' counters - and the token feed.
+    The decode step writes its columns through ``kv_cache_write`` (two
+    leaves a layer) and a full layer attends through
+    ``ops/pallas/grouped_decode_attention`` (Mosaic takes it at 8 queries
+    a key/value head and tiles of 512 positions); the prefill's full
+    layers attend through the flash kernel, its window layers in XLA,
+    and its pairs that are here are grouped through ``ragged_dot``."""
+    import json
+
+    from benchmark.runners.serve_kexaone import build_model
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        cfg = json.load(f)["as_run"]
+    full, window, slots, bucket, seq = 2, 6, 32, 16384, 16384
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+                           )["params"]))
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+    assert eng.cache_bytes_by_kind() == {
+        "kv": full * 2 * slots * seq * 8 * 128 * 2,         # 4.29 GB
+        "compressed": 0, "state": 0,
+        "ring": window * 2 * slots * 128 * 8 * 128 * 2,     # 0.10 GB
+        "counter": (full + window - 1) * 3 * 8 * 4}
+    assert eng._reads_live_groups and eng._counts
+    assert not eng._reads_live_tiles and not eng._reads_live_latents
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
+                                       i32(slots))
+    else:
+        lowered = eng._prefill_fn(bucket).lower(
+            params, eng._cache, i32(slots), i32(1, bucket), i32(), i32())
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nkexaone {program}: arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{memory.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{memory.alias_size_in_bytes / 1e9:.3f} GB")
+    # the feed, and seven counters of 96 bytes in a tile each
+    assert 0 < memory.alias_size_in_bytes - eng.cache_bytes() <= 16384
+    assert _feed_is_aliased(text, params, eng._cache)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16e9)
+    row, ring = (rf"bf16\[{slots},8,128,{seq}\]",
+                 rf"bf16\[{slots},8,128,128\]")
+    for leaf, layers in ((row, full), (ring, window)):
+        assert len(re.findall(rf"= {leaf}\S* parameter\(\d+\), sharding",
+                              text)) == 2 * layers, leaf
+        assert not re.findall(rf"= {leaf}\S* copy(-start)?\(", text), leaf
+    if program == "decode":
+        # two column writes a layer and one attention a full layer
+        assert text.count("tpu_custom_call") == 2 * (full + window) + full
+        assert len(re.findall(
+            r"%grouped_decode_attention[.\d]* = [^\n]*? custom-call\(",
+            text)) == full
+    else:
+        # one flash kernel a full layer; three grouped products an expert
+        # layer, inside the loop over the pairs that are here
+        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
+                              text)) >= 3
+        assert text.count("tpu_custom_call") >= full
+
+
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
     """What ``training.make_train_step`` builds on a four-chip host: one
     jit over the global mesh, batch sharded. XLA cannot partition a

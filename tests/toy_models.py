@@ -14,6 +14,12 @@ seeded weights, each beside its plain reference under ``benchmark/``
   hyper-connected streams, against ``benchmark/reference_xing.py``
   (``benchmark/configs/xing4-29b-a4b.json``'s toy sizes, three of its
   six layers: one dense, two with experts); about 0.23.
+* ``kexaone``: K-EXAONE-236B-A23B, window and full grouped-query layers
+  three to one with a ring cache on the window layers, norms on the
+  sublayers' outputs and a share of the routed experts, against
+  ``benchmark/reference_kexaone.py`` (``benchmark/configs/
+  k-exaone-236b-a23b.json``'s toy sizes, all eight layers: a window of
+  64 in a ring of 128); about 1.0.
 
 A new family adds its entry to :func:`family` and so joins every case of
 ``tests/test_engine_contract.py``; it does not copy them.
@@ -28,9 +34,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import reference_brumby, reference_sala, reference_xing
-from benchmark import weights_brumby, weights_sala, weights_xing
-from benchmark.runners import serve_brumby, serve_sala, serve_xing
+from benchmark import (reference_brumby, reference_kexaone, reference_sala,
+                       reference_xing)
+from benchmark import (weights_brumby, weights_kexaone, weights_sala,
+                       weights_xing)
+from benchmark.runners import (serve_brumby, serve_kexaone, serve_sala,
+                               serve_xing)
 from horovod_tpu import tracing
 from horovod_tpu.models.transformer import Transformer
 
@@ -123,6 +132,35 @@ def xing_reference(cfg, params, toks, precision="f32"):
                                     reference_xing.frozen(cfg), precision))
 
 
+# -------------------------------------------------------------- K-EXAONE
+
+def kexaone_cfg(dtype="float32", **changes):
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        published = json.load(f)
+    cfg = dict(published["as_run"], **published["rehearse"])
+    cfg.update(max_seq=512, dtype=dtype, param_dtype=dtype)
+    cfg.update(changes)
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def kexaone(**changes):
+    cfg = kexaone_cfg(**changes)
+    return cfg, weights_kexaone.make_params(cfg, SEED), \
+        serve_kexaone.build_model(cfg)
+
+
+_kexaone_forward = jax.jit(reference_kexaone.forward,
+                           static_argnums=(2, 3, 5))
+
+
+def kexaone_reference(cfg, params, toks, precision="f32", fault=None):
+    return np.asarray(_kexaone_forward(
+        params, jnp.asarray(toks, jnp.int32), reference_kexaone.frozen(cfg),
+        precision, None, fault))
+
+
 # ------------------------------------------------- what the engine serves
 
 # ``reference(toks, precision="f32")``: the plain reference's logits for
@@ -130,7 +168,7 @@ def xing_reference(cfg, params, toks, precision="f32"):
 # what the paged engine says of a cache it cannot page (None: untested)
 Family = collections.namedtuple(
     "Family", "name cfg params model reference tol no_pages")
-FAMILIES = ("sala", "brumby", "xing")
+FAMILIES = ("sala", "brumby", "xing", "kexaone")
 
 
 @functools.lru_cache(maxsize=None)
@@ -150,6 +188,11 @@ def family(name):
         return Family(name, cfg, params, model,
                       functools.partial(xing_reference, cfg, params), 2e-5,
                       None)
+    if name == "kexaone":
+        cfg, params, model = kexaone()
+        return Family(name, cfg, params, model,
+                      functools.partial(kexaone_reference, cfg, params),
+                      5e-5, None)
     raise KeyError(name)
 
 
