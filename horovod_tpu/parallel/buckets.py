@@ -28,9 +28,19 @@ Three lanes, matching the collectives module:
   scalar token through ``lax.optimization_barrier`` at every bucket
   boundary, so XLA cannot sink the collectives to the end of the
   program — the staged-interleave analogue of the eager release.
-* **plain jit (no bound axes)** — identity: gradients of a
-  global-mean loss are already the global average and XLA schedules
-  the collective from the shardings.
+* **plain jit (no bound axes)** — identity, bit for bit: gradients of
+  a global-mean loss are already the global average and the partitioner
+  puts one all-reduce behind each weight-gradient product. Nothing the
+  backward pass of the layers below computes depends on a weight
+  gradient, so a hook on a parameter leaf has nothing to hold its
+  reduction to; on this lane the exchange is released by the step
+  program's own options (``training._exchange_options``: asynchronous
+  collective fusions on a mesh of several TPU chips), and
+  :func:`exchange_schedule` reads from a compiled step what became of
+  it. Pins *inside* the differentiated program (a barrier tying each
+  bucket to the activations' cotangent one bucket later) were built in
+  PR 40 and showed no gain over the options alone on the chip (one
+  reading at 8 of BERT-Large's 24 layers): PERF.md section 6.
 
 ``backward_passes_per_step > 1`` composes on the eager lane: the plan
 owns the accumulation (``every_k``), buckets accumulate locally for
@@ -69,14 +79,16 @@ Knobs: ``HOROVOD_GRAD_BUCKET_BYTES`` (target bucket payload, default
 (``auto``/``off`` — whether single-controller replicated gradients are
 shipped worker-stacked through the runtime so the release is a real
 dispatch, or short-circuited to local math), and
-``HOROVOD_GRAD_BUCKET_RELEASE`` (default-on switch consumed by
-``training.make_train_step``). See docs/performance.md "backward
+``HOROVOD_GRAD_BUCKET_RELEASE`` (off by default, see
+:func:`release_enabled`: ``1`` makes ``training.make_train_step`` build
+a plan when it is given none). See docs/performance.md "backward
 overlap".
 """
 
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from contextlib import contextmanager
@@ -154,6 +166,37 @@ def _wire_mode() -> str:
 def _leaf_nbytes(leaf) -> int:
     return int(np.prod(np.shape(leaf), dtype=np.int64)
                * np.dtype(leaf.dtype).itemsize)
+
+
+# an array type in HLO text: ``bf16[1024,4096]``, ``pred[8]``, ``f8e4m3fn[4]``
+_HLO_ARRAY = re.compile(r"\b(?:pred|[a-z]+(\d+)[a-z0-9]*)\[([0-9,]*)\]")
+_HLO_ALL_REDUCE = re.compile(
+    r" = (.+?) all-reduce(?:-start)?\(.*?channel_id=(\d+)")
+
+
+def exchange_schedule(compiled_text: str) -> dict:
+    """How a compiled step issues its gradient exchange, read from the
+    program's own text (``compiled.as_text()``): ``sync_bytes`` are
+    reduced by ``all-reduce`` instructions that hold the core's stream,
+    ``async_bytes`` inside asynchronous collective fusions (the steps of
+    one chain share a channel and carry its ``chain_id``; an
+    ``all-reduce-start`` counts the same), ``reductions`` is how many
+    there are of both. Bytes are those of the result, as the wire's
+    dtype has them."""
+    seen = {}   # channel -> (asynchronous, bytes)
+    for line in compiled_text.splitlines():
+        m = _HLO_ALL_REDUCE.search(line)
+        if m is None:
+            continue
+        nbytes = sum(
+            max(int(bits or 8) // 8, 1) * int(np.prod(
+                [int(d) for d in dims.split(",") if d], dtype=np.int64))
+            for bits, dims in _HLO_ARRAY.findall(m.group(1)))
+        seen[m.group(2)] = ('chain_id="' in line
+                            or " all-reduce-start(" in line, nbytes)
+    return {"reductions": len(seen),
+            "async_bytes": sum(n for a, n in seen.values() if a),
+            "sync_bytes": sum(n for a, n in seen.values() if not a)}
 
 
 class _Bucket:
@@ -356,8 +399,9 @@ class GradReleasePlan:
         boundary = self._remaining[bucket.index] == 0
         if not axes:
             # plain jit global-batch DP: gradients are already the global
-            # average (XLA inserts the collective from the shardings);
-            # nothing to stage
+            # average (XLA inserts the collective from the shardings) and
+            # a hook on a leaf has nothing to hold them to (module
+            # docstring): the step program's options do this lane's work
             return g
         from jax import lax
 

@@ -1,6 +1,6 @@
 """``benchmark/tests``' judge and readers (``correct``, the GPT-2 serving
-runner, the span, stall and trace readers, the traffic and the counted
-operations) as tier-1 cases; ``tests/benchmark_selfcheck.py`` says how
+runner, the span, stall, trace and exchange readers, the traffic and the
+counted operations) as tier-1 cases; ``tests/benchmark_selfcheck.py`` says how
 and why."""
 
 import benchmark_selfcheck as selfcheck
@@ -9,8 +9,8 @@ import benchmark_selfcheck as selfcheck
 # subprocess takes 2-2.5 times what it takes alone; the limit is the
 # subprocess's own
 report, test_benchmark_test_passes = selfcheck.cases(
-    ("test_correct", "test_serve", "test_spans", "test_stalls", "test_trace",
-     "test_traffic_and_flops"), 450)
+    ("test_correct", "test_exchange", "test_serve", "test_spans",
+     "test_stalls", "test_trace", "test_traffic_and_flops"), 450)
 
 
 PLANTED = '''

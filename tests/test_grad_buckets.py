@@ -328,6 +328,139 @@ class TestTracedLanes:
         np.testing.assert_allclose(np.asarray(g["a"]), 3.0, rtol=1e-6)
 
 
+_SCHEDULED = """HloModule jit_one_step, is_scheduled=true
+
+%fused_computation.1 (param_0.1: bf16[1024,4096]) -> bf16[1024,4096] {
+  %param_0.1 = bf16[1024,4096]{1,0} parameter(0)
+  ROOT %all-reduce.7 = bf16[1024,4096]{1,0} all-reduce(%param_0.1), channel_id=3, metadata={op_name="jit(one_step)/transpose(jvp(T))/layer_1/mlp/wi/dot_general"}, frontend_attributes={chain_id="4"}
+}
+
+%fused_computation.2 (param_0.2: bf16[1024,4096]) -> bf16[1024,4096] {
+  %param_0.2 = bf16[1024,4096]{1,0} parameter(0)
+  ROOT %all-reduce.8 = bf16[1024,4096]{1,0} all-reduce(%param_0.2), channel_id=3, metadata={op_name="jit(one_step)/transpose(jvp(T))/layer_1/mlp/wi/dot_general"}, frontend_attributes={chain_id="4"}
+}
+
+ENTRY %main.1 (p0: bf16[1024,4096]) -> bf16[1024,4096] {
+  %p0 = bf16[1024,4096]{1,0} parameter(0)
+  %async-collective-start = (bf16[1024,4096]{1,0}, u32[]{:S(2)}) fusion(%p0), kind=kCustom, calls=%fused_computation.1
+  %async-collective-done = bf16[1024,4096]{1,0} fusion(%async-collective-start), kind=kCustom, calls=%fused_computation.2
+  %all-reduce.1 = f32[1024]{0} all-reduce(%p0), channel_id=1, metadata={op_name="jit(one_step)/transpose(jvp(T))/layer_1/LayerNorm_0/reduce_sum"}
+  %all-reduce-start.1 = bf16[512,1024]{1,0} all-reduce-start(%p0), channel_id=5
+  %all-reduce-done.1 = bf16[512,1024]{1,0} all-reduce-done(%all-reduce-start.1)
+  ROOT %all-reduce.2 = (bf16[30522,1024]{1,0}, bf16[30522,1024]{1,0}) all-reduce(%p0, %p0), channel_id=2, metadata={op_name="jit(one_step)/transpose(jvp(T))/token_embed.attend/dot_general"}
+}
+"""
+
+
+def _tiny_transformer():
+    from horovod_tpu.models.transformer import Transformer, causal_lm_loss
+
+    model = Transformer(vocab_size=128, d_model=32, num_layers=3,
+                        num_heads=2, d_ff=64, max_seq=16, causal=True,
+                        dtype=jnp.float32)
+    tokens = np.random.RandomState(0).randint(0, 128, (3, 16, 16))
+    return (model, causal_lm_loss, (16, 16), jnp.int32,
+            [(t.astype(np.int32), t.astype(np.int32)) for t in tokens])
+
+
+def _tiny_conv():
+    from horovod_tpu import training
+    from horovod_tpu.models.mnist import MnistConvNet
+
+    rng = np.random.RandomState(0)
+    return (MnistConvNet(), training._default_loss_fn, (16, 28, 28, 1),
+            jnp.float32,
+            [(rng.rand(16, 28, 28, 1).astype(np.float32),
+              rng.randint(0, 10, (16,)).astype(np.int32))
+             for _ in range(3)])
+
+
+class TestPlainJitLane:
+    """``make_train_step`` on the CPU mesh: the plan's plain-``jit`` lane
+    is the identity, the builder adds no TPU option, and the step leaves
+    one ``train.build`` span."""
+
+    @pytest.mark.parametrize("family", [_tiny_transformer, _tiny_conv],
+                             ids=["transformer", "conv"])
+    def test_step_with_plan_is_bit_identical_on_cpu_mesh(self, hvd, family):
+        """Three steps on the eight virtual devices: with a plan the
+        loss, parameters and optimizer state are those of
+        ``grad_release=False``, bit for bit."""
+        import optax
+
+        from horovod_tpu import training
+
+        model, loss_fn, shape, dtype, batches = family()
+
+        def run(grad_release):
+            opt = hvd.DistributedOptimizer(optax.adamw(1e-2))
+            state = training.create_train_state(model, opt, shape,
+                                                input_dtype=dtype)
+            step, rows = training.make_train_step(
+                model, opt, loss_fn=loss_fn, donate=False,
+                grad_release=grad_release)
+            params, stats, opt_state = (state.params, state.batch_stats,
+                                        state.opt_state)
+            losses = []
+            for x, y in batches:
+                loss, params, stats, opt_state = step(
+                    params, stats, opt_state, jax.device_put(x, rows),
+                    jax.device_put(y, rows))
+                losses.append(np.asarray(loss))
+            return losses, jax.device_get((params, opt_state))
+
+        plan = _plan(bucket_bytes=8 * 1024)
+        plain, planned = run(False), run(plan)
+        assert len(plan.buckets()) > 2
+        assert all(np.array_equal(a, b)
+                   for a, b in zip(plain[0], planned[0]))
+        for a, b in zip(jax.tree_util.tree_leaves(plain[1]),
+                        jax.tree_util.tree_leaves(planned[1])):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+    def test_cpu_mesh_step_carries_no_tpu_option(self, hvd, monkeypatch):
+        """The options are for TPU chips alone (XLA:CPU refuses them by
+        name), so on the CPU mesh the builder passes ``jax.jit`` what it
+        always did, and makes no plan of its own."""
+        from horovod_tpu import training
+        from horovod_tpu.models.mnist import MnistConvNet
+
+        monkeypatch.delenv("HOROVOD_GRAD_BUCKET_RELEASE", raising=False)
+        assert training._exchange_options(hvd.mesh()) is None
+        _step, _rows, jit_kwargs = training._build(
+            MnistConvNet(), None, None, None)
+        assert sorted(jit_kwargs) == ["in_shardings", "out_shardings"]
+        assert training._resolve_grad_release(None) is None
+
+    def test_step_leaves_one_train_build_span(self, hvd, ring):
+        import optax
+
+        from horovod_tpu import training
+
+        model, loss_fn, shape, dtype, batches = _tiny_transformer()
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+        state = training.create_train_state(model, opt, shape,
+                                            input_dtype=dtype)
+        step, rows = training.make_train_step(
+            model, opt, loss_fn=loss_fn, donate=False)
+        args = (state.params, state.batch_stats, state.opt_state)
+        for x, y in batches[:2]:
+            _loss, *args = step(*args, jax.device_put(x, rows),
+                                jax.device_put(y, rows))
+        built = [s for s in ring.spans() if s["name"] == "train.build"]
+        assert len(built) == 1
+        assert (built[0]["devices"], built[0]["async_exchange"]) == (
+            8, False)
+
+    def test_exchange_schedule_reads_a_compiled_program(self):
+        """One chain of two steps on one channel, one ``all-reduce-start``,
+        and two synchronous reductions, one of them a tuple."""
+        assert buckets_mod.exchange_schedule(_SCHEDULED) == {
+            "reductions": 4,
+            "async_bytes": 1024 * 4096 * 2 + 512 * 1024 * 2,
+            "sync_bytes": 1024 * 4 + 2 * 30522 * 1024 * 2}
+
+
 class TestIntegration:
     def test_prereduced_scope_skips_exchange(self, hvd):
         grads = {"w": jnp.full((32,), 2.0)}

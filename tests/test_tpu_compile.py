@@ -639,3 +639,102 @@ def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
         assert "all-reduce" in text   # the per-channel scale/bias gradients
     finally:
         hvd.shutdown()
+
+
+@pytest.mark.parametrize(
+    "chips,layers,builder",
+    [(4, 3, "step"), (4, 1, "round"), (4, 2, "bare"), (1, 3, "step")],
+    ids=["four-chips", "four-chips-round", "four-chips-bare", "one-chip"])
+def test_train_step_issues_its_exchange_as_asynchronous_fusions(
+        v5e, monkeypatch, chips, layers, builder):
+    """``training.make_train_step`` over BERT-Large's layer at 3 of its
+    24 layers. On the four described chips the step carries
+    ``training._exchange_options`` and, by the compiled program's own
+    text (``buckets.exchange_schedule``), every matrix of every layer,
+    the MLP's ``wi`` and ``wo`` among them, the position table and both
+    gradients of the tied table are reduced in asynchronous fusions, to
+    the byte; only leaves under ``EXCHANGE_COMBINE_BYTES`` stay
+    synchronous. ``make_train_round``'s scanned body takes the options
+    the same way. Without them (``bare``: what the builder made until
+    PR 40) all of it is synchronous. On one described chip the builder
+    adds nothing: no option, and the program it hands the compiler is
+    that of a bare ``jax.jit`` of the same body (compared as lowered,
+    which costs no compile)."""
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu import training
+    from horovod_tpu.models.transformer import Transformer, masked_lm_loss
+    from horovod_tpu.parallel import buckets
+
+    rows, seq = 16 * chips, 512
+    hvd.shutdown()
+    hvd.init(devices=v5e[:chips])
+    try:
+        everywhere = NamedSharding(hvd.mesh(), P())
+        by_rows = NamedSharding(hvd.mesh(), P(hvd.GLOBAL_AXES))
+        model = Transformer(vocab_size=30522, d_model=1024,
+                            num_layers=layers, num_heads=16, d_ff=4096,
+                            max_seq=seq, causal=False, dtype=BF16)
+        opt = hvd.DistributedOptimizer(optax.adamw(1e-4))
+        loss_fn = lambda logits, labels: masked_lm_loss(
+            logits, labels[0], labels[1])
+        tokens = jax.ShapeDtypeStruct((rows, seq), jnp.int32,
+                                      sharding=by_rows)
+        weights = jax.ShapeDtypeStruct((rows, seq), F32, sharding=by_rows)
+        params = jax.eval_shape(
+            lambda x: model.init(jax.random.PRNGKey(0), x, train=False),
+            tokens)["params"]
+        placed = lambda tree: jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=everywhere), tree)
+        args = (placed(params), {}, placed(jax.eval_shape(opt.init, params)),
+                tokens, (tokens, weights))
+
+        assert (training._exchange_options(hvd.mesh()) is None) == (
+            chips == 1)
+        if builder == "bare":
+            monkeypatch.setattr(training, "_exchange_options",
+                                lambda mesh: None)
+        if builder == "round":
+            step, _ = training.make_train_round(model, opt, loss_fn=loss_fn,
+                                                steps=2)
+        else:
+            step, _ = training.make_train_step(model, opt, loss_fn=loss_fn)
+        if chips == 1:
+            bare = jax.jit(
+                training._make_one_step(model, opt, loss_fn),
+                in_shardings=(everywhere,) * 3 + (by_rows,) * 2,
+                out_shardings=(everywhere,) * 4, donate_argnums=(0, 1, 2))
+            # traced from two call sites: the Mosaic kernels' serialized
+            # bodies carry the call stack and are left out
+            lowered = lambda f: re.sub(r'backend_config = "[^"]*"', "",
+                                       f.lower(*args).as_text())
+            assert lowered(step) == lowered(bare)
+            return
+        got = buckets.exchange_schedule(step.lower(*args).compile().as_text())
+        # bf16 on the wire: four attention matrices and the MLP's two a
+        # layer, the position table, the tied table's two gradients
+        matrices = 2 * (layers * (4 * 1024 * 1024 + 2 * 1024 * 4096)
+                        + seq * 1024 + 2 * 30522 * 1024)
+        if builder == "bare":
+            assert got["async_bytes"] == 0
+            assert got["sync_bytes"] > matrices
+            return
+        assert got["async_bytes"] == matrices
+        assert got["sync_bytes"] < training.EXCHANGE_COMBINE_BYTES
+        assert got["reductions"] > 6 * layers + 3
+    finally:
+        hvd.shutdown()
+
+
+def test_a_refused_exchange_option_names_where_it_comes_from(v5e):
+    """A libtpu that has renamed one of ``_exchange_options``' names
+    refuses it when the builder reads the mesh, with an error that says
+    so, and not at the step's first call."""
+    from horovod_tpu import training
+
+    with pytest.raises(RuntimeError, match="training._exchange_options"):
+        training._check_exchange_options(
+            v5e[0], (("xla_enable_async_all_reduce", True),
+                     ("xla_tpu_no_such_option", True)))
