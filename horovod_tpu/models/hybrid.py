@@ -2,14 +2,17 @@
 attention, linear ("lightning") attention with a fixed decay, power
 retention (gated, normalised linear attention of degree 2), latent
 attention (MLA: one low-rank latent and one rotary key a token, shared by
-the heads) and grouped-query softmax attention over every causal key
-("full") or over the last ``window`` keys ("window"), on a modern trunk -
+the heads), grouped-query softmax attention over every causal key
+("full") or over the last ``window`` keys ("window") and Mamba-2
+state-space layers ("mamba2": a selective scan, the decay a token a
+head from the input), on a modern trunk -
 RMSNorm on a sublayer's input or on its output, a SiLU-gated MLP or a
-top-k router over such MLPs ("experts", all of them or this chip's
-share) beside a shared one, rotary positions (plain or YaRN), grouped
-key/value heads, per-head QK-norm, sigmoid output gates, one residual
-stream or several hyper-connected ones, an untied head and (optional)
-muP scalings.
+top-k router (sigmoid or softmax) over such MLPs ("experts", all of
+them or this chip's share) beside a shared one, rotary positions (plain
+or YaRN) or none, grouped key/value heads, per-head QK-norm or none,
+sigmoid output gates, one residual stream or several hyper-connected
+ones, a head of its own or the embedding's, and (optional) muP scalings
+or fixed multipliers.
 
 :class:`HybridDecoder` reads its layer kinds from ``mixers`` and is what
 ``hvd.serve()`` runs for MiniCPM-SALA (``benchmark/configs/
@@ -17,14 +20,16 @@ minicpm-sala.json``; the plain reference is
 ``benchmark/reference_sala.py``), for Brumby-14B-Base
 (``benchmark/configs/brumby-14b.json``, ``benchmark/
 reference_brumby.py``), for Xing4.0-29B-A4B (``benchmark/configs/
-xing4-29b-a4b.json``, ``benchmark/reference_xing.py``) and for
+xing4-29b-a4b.json``, ``benchmark/reference_xing.py``), for
 K-EXAONE-236B-A23B (``benchmark/configs/k-exaone-236b-a23b.json``,
-``benchmark/reference_kexaone.py``). The blocks (:class:`RMSNorm`,
+``benchmark/reference_kexaone.py``) and for granite-4.0-h-small
+(``benchmark/configs/granite-4.0-h-small.json``,
+``benchmark/reference_granite.py``). The blocks (:class:`RMSNorm`,
 :class:`GatedMlp`, :func:`rope`, :class:`BlockSparseAttention`,
 :class:`LightningAttention`, :class:`PowerRetention`,
 :class:`LatentAttention`, :class:`GroupedQueryAttention`,
-:class:`RoutedExperts`, :class:`HyperConnection`) are not tied to those
-models.
+:class:`StateSpace`, :class:`RoutedExperts`, :class:`HyperConnection`)
+are not tied to those models.
 
 Serving (``decode=True``) keeps a ``cache`` collection whose leaves all
 have the slot as axis 0; which kinds it holds depends on the mixers (a
@@ -52,7 +57,12 @@ model of power-retention layers alone has no leaf with a position axis):
   128) in the layout ``ops/pallas/power_retention`` reads;
 * ``latent`` ``(slots, kv_rank, max_seq)`` and ``rope_key`` ``(slots,
   rope_dim, max_seq)`` - a latent-attention layer's normed latent and
-  rotated key, positions last, nothing a head.
+  rotated key, positions last, nothing a head;
+* ``ssm_state`` ``(slots, heads, head_dim, d_state)`` float32 and
+  ``conv_state`` ``(slots, (d_conv - 1) x channels)`` - a state-space
+  layer's recurrent state and the last ``d_conv - 1`` rows before its
+  convolution, oldest first, side by side along the lanes; neither grows
+  with the context.
 
 An expert layer also keeps ``expert_counts`` ``(3, experts)`` uint32 there,
 a running count and the one leaf that is no slot's row (the engine adds a
@@ -65,7 +75,8 @@ cache and the ``positions`` where the pieces before ended, continues the
 prompt from there (``HybridDecoder.resumable_prefill``), so that the
 engine may run a prompt in pieces. A recurrence is not indifferent to
 padding as masked softmax is, so a prefill takes the true ``lengths``:
-the state it leaves is the state after ``lengths`` tokens, and with
+the state it leaves is the state after ``lengths`` tokens (a
+state-space layer's convolution tail the last true rows), and with
 ``lengths`` given the head runs on row ``lengths - 1`` alone.
 
 The sparse layer computes masked dense attention in blocks of queries
@@ -80,7 +91,10 @@ window layer's its ring, one lane tile a head at a window of 128, in
 XLA. The power-retention layer scans
 chunks too, and reads its state through two kernels
 (``ops/pallas/power_retention``): XLA would write every query's 8,256
-features to memory first.
+features to memory first. A state-space layer scans chunks in XLA
+(:func:`ssm_chunked`: one ``C B^T`` a group for all its heads) and its
+decode step is elementwise (:func:`ssm_step`), which XLA fuses into one
+pass that reads each state once and writes it once.
 """
 
 from __future__ import annotations
@@ -110,10 +124,15 @@ LATENT = "latent"
 # grouped-query softmax attention over every causal key, and the same over
 # the last ``window`` keys
 FULL, WINDOW = "full", "window"
+# a Mamba-2 state-space layer
+MAMBA2 = "mamba2"
 # where a sublayer's RMSNorm sits: ``h += F(norm(h))`` or ``h += norm(F(h))``
 NORM_INPUT, NORM_OUTPUT = "input", "output"
 # a layer's MLP: one gated MLP, or a router over experts beside a shared one
 DENSE_MLP, EXPERTS_MLP = "dense", "experts"
+# a router's scores: sigmoids normalised over the chosen (``noaux_tc``), or
+# a softmax over the chosen logits
+SIGMOID_ROUTER, SOFTMAX_ROUTER = "sigmoid", "softmax"
 # a masked score: finite, so that a row with nothing to see stays a number
 NEG_INF = -1e30
 
@@ -1085,11 +1104,12 @@ def ring_step_attention(q, keys, values, positions, window, scale, dtype):
 
 
 class GroupedQueryAttention(nn.Module):
-    """Grouped-query softmax attention with per-head QK-norm and no
-    gate: over every causal key (``window`` ``None``; no positional
-    encoding unless ``rotary``) or over the last ``window`` keys, the
-    query's own among them (rotary positions over the whole head width
-    where ``rotary``).
+    """Grouped-query softmax attention with per-head QK-norm (none
+    where ``qk_norm`` is false) and no gate: over every causal key
+    (``window`` ``None``; no positional encoding unless ``rotary``) or
+    over the last ``window`` keys, the query's own among them (rotary
+    positions over the whole head width where ``rotary``). The softmax
+    scale is ``head_dim^-0.5``, or ``scale`` where the model fixes one.
 
     With ``decode=True`` a full layer keeps ``cached_key`` /
     ``cached_value`` ``(batch, kv_heads, head_dim, max_cache_len)`` and a
@@ -1106,6 +1126,8 @@ class GroupedQueryAttention(nn.Module):
     window: Optional[int] = None
     rotary: bool = False
     rope_theta: float = 10000.0
+    qk_norm: bool = True
+    scale: Optional[float] = None
     eps: float = 1e-6
     decode: bool = False
     max_cache_len: int = 0
@@ -1121,17 +1143,17 @@ class GroupedQueryAttention(nn.Module):
                         param_dtype=self.param_dtype)
         norm = partial(RMSNorm, eps=self.eps, dtype=self.dtype,
                        param_dtype=self.param_dtype)
-        q = norm(name="q_norm")(
-            dense(heads * d, name="query")(x).reshape(batch, seq, heads, d))
-        k = norm(name="k_norm")(
-            dense(groups * d, name="key")(x).reshape(batch, seq, groups, d))
+        q = dense(heads * d, name="query")(x).reshape(batch, seq, heads, d)
+        k = dense(groups * d, name="key")(x).reshape(batch, seq, groups, d)
         v = dense(groups * d, name="value")(x).reshape(batch, seq, groups, d)
+        if self.qk_norm:
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
         if self.rotary:
             at = positions[:, None] \
                 + jnp.arange(seq, dtype=jnp.int32)[None, :]
             q = rope(q, at, self.rope_theta).astype(self.dtype)
             k = rope(k, at, self.rope_theta).astype(self.dtype)
-        scale = d ** -0.5
+        scale = d ** -0.5 if self.scale is None else self.scale
         if self.decode:
             keys, values = (self.variable(
                 "cache", name, jnp.zeros,
@@ -1180,41 +1202,265 @@ class GroupedQueryAttention(nn.Module):
         return dense(d_model, name="out")(o.reshape(batch, seq, heads * d))
 
 
+# -------------------------------------------------------------- state space
+
+def ssm_conv(rows, kernel, bias):
+    """The causal depthwise convolution before the recurrence, then SiLU:
+    ``silu(sum_j kernel[j] * rows[j] + bias)``, ``rows[j]`` the input
+    ``k - 1 - j`` positions back (the last one the position's own).
+    ``rows``: ``k`` arrays (..., channels); ``kernel``: (k, channels);
+    float32 out."""
+    return nn.silu(sum(kernel[j].astype(F32) * row.astype(F32)
+                       for j, row in enumerate(rows)) + bias.astype(F32))
+
+
+def ssm_step(state, x, dt, a, b, c, d_skip):
+    """One token of the Mamba-2 recurrence: ``S = exp(dt A) S + dt x
+    B^T``, ``y = S C + D x``, the state read once and written once.
+    ``state``: (batch, heads, p, n) float32; ``x``: (batch, heads, p);
+    ``dt``: (batch, heads) float32, after its softplus; ``a``/``d_skip``:
+    (heads,) float32, ``a`` negative; ``b``/``c``: (batch, groups, n),
+    head ``h`` reading pair ``h // (heads / groups)``. Everything is
+    float32: the sums are elementwise (a product would read the new state
+    a second time). Returns the state and ``y`` (batch, heads, p)."""
+    batch, heads, p, n = state.shape
+    groups = b.shape[1]
+    x, b, c = (t.astype(F32) for t in (x, b, c))
+    wide = lambda t: t[:, :, None, None, :]                # (b, g, 1, 1, n)
+    s = state.reshape(batch, groups, heads // groups, p, n)
+    keep = jnp.exp(dt * a).reshape(batch, groups, -1, 1, 1)
+    put = (dt[..., None] * x).reshape(batch, groups, -1, p, 1)
+    s = keep * s + put * wide(b)
+    y = jnp.sum(s * wide(c), axis=-1).reshape(batch, heads, p)
+    return s.reshape(state.shape), y + d_skip[:, None] * x
+
+
+def ssm_chunked(x, dt, a, b, c, d_skip, chunk=256, dtype=jnp.bfloat16):
+    """The same recurrence over a whole sequence from a zero state, by
+    chunks (the Mamba-2 paper's SSD form, arXiv:2405.21060): inside a
+    chunk the masked products ``(C_i . B_j) exp(s_i - s_j) dt_j`` with
+    ``s`` the running sum of ``dt A``, one ``C B^T`` a group for all its
+    heads; between chunks the state. ``x``: (batch, seq, heads, p);
+    ``dt``: (batch, seq, heads) float32, after its softplus, **0 at a
+    position that is padding**: such a position neither decays the state
+    nor enters it, so the state returned is the state after the true
+    tokens; ``b``/``c``: (batch, seq, groups, n).
+
+    Matrix operands are ``dtype`` (the state of the between-chunk product
+    among them) and every sum, decay and the carried state float32; every
+    exponent is at most 0. Returns the outputs (batch, seq, heads, p) in
+    ``dtype`` and the state (batch, heads, p, n) float32."""
+    batch, seq, heads, p = x.shape
+    groups, n = b.shape[2:]
+    per = heads // groups
+    chunk = min(chunk, seq)
+    pad = -seq % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad))
+                               + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, b, c))
+    count = (seq + pad) // chunk
+    cut = lambda t, inner: jnp.moveaxis(
+        t.reshape((batch, count, chunk) + inner), 1, 0)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    a = a.reshape(groups, per)
+    d_skip = d_skip.reshape(groups, per)
+
+    def one(state, xs):
+        x_c, dt_c, b_c, c_c = xs     # (b, j, g, r, p) (b, j, g, r) (b, j, g, n)
+        since = jnp.cumsum(dt_c * a, axis=1)               # s: (b, i, g, r)
+        whole = since[:, -1]                               # (b, g, r)
+        heads_first = lambda t: jnp.moveaxis(t, 1, -1)     # (b, g, r, i)
+        s_i = heads_first(since)
+        # inside the chunk
+        cb = jnp.einsum("bign,bjgn->bgij", c_c, b_c,
+                        preferred_element_type=F32)
+        apart = s_i[..., :, None] - s_i[..., None, :]      # (b, g, r, i, j)
+        weight = jnp.where(causal, jnp.exp(jnp.minimum(apart, 0.0)), 0.0) \
+            * heads_first(dt_c)[..., None, :]
+        y = jnp.einsum("bgrij,bjgrp->bigrp",
+                       (cb[:, :, None] * weight).astype(dtype), x_c,
+                       preferred_element_type=F32)
+        # what came before the chunk
+        y = y + jnp.exp(since)[..., None] * jnp.einsum(
+            "bign,bgrpn->bigrp", c_c, state.astype(dtype),
+            preferred_element_type=F32)
+        y = y + d_skip[..., None] * x_c.astype(F32)
+        # the state after the chunk
+        left = jnp.exp(whole[:, None] - since) * dt_c      # (b, j, g, r)
+        state = jnp.exp(whole)[..., None, None] * state + jnp.einsum(
+            "bjgrp,bjgn->bgrpn",
+            (x_c.astype(F32) * left[..., None]).astype(dtype), b_c,
+            preferred_element_type=F32)
+        return state, y.astype(dtype)
+
+    state, out = jax.lax.scan(
+        one, jnp.zeros((batch, groups, per, p, n), F32),
+        (cut(x, (groups, per, p)), cut(dt, (groups, per)),
+         cut(b, (groups, n)), cut(c, (groups, n))))
+    out = jnp.moveaxis(out, 0, 1).reshape(batch, count * chunk, heads, p)
+    return out[:, :seq], state.reshape(batch, heads, p, n)
+
+
+class StateSpace(nn.Module):
+    """A Mamba-2 mixer (arXiv:2405.21060, as Hugging Face's ``bamba`` /
+    ``granitemoehybrid`` code has it): ``[z | xBC | dt] = x W_in``; a
+    causal depthwise convolution of ``d_conv`` taps and SiLU over ``xBC``;
+    ``x`` (heads x head_dim), ``B`` and ``C`` (``d_state`` each a group,
+    one pair for all the heads of a group) split from it; ``dt =
+    softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` a head; the
+    recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t =
+    S_t C_t + D x_t``; ``out = RMSNorm(y * silu(z)) W_out``, the norm
+    over all heads at once with a learned scale. No positions at all.
+
+    A prompt is scanned by chunks (:func:`ssm_chunked`) and takes the
+    true ``lengths``; a decode step is :func:`ssm_step`. With
+    ``decode=True`` the ``cache`` collection holds ``ssm_state`` (batch,
+    heads, head_dim, d_state) float32 and ``conv_state`` (batch,
+    (d_conv - 1) x channels): the last ``d_conv - 1`` rows of ``xBC``
+    before the convolution, oldest first, side by side along the lanes
+    (zeros where the prompt had fewer tokens). ``dt``, the decays, the
+    state and the gated norm are float32, matrix operands ``dtype``."""
+
+    num_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = 256
+    eps: float = 1e-6
+    decode: bool = False
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = F32
+
+    @nn.compact
+    def __call__(self, x, positions, lengths=None):
+        del positions          # a recurrence: the order is the position
+        batch, seq, d_model = x.shape
+        heads, p, n, groups = (self.num_heads, self.head_dim, self.d_state,
+                               self.n_groups)
+        inner, taps = heads * p, self.d_conv
+        channels = inner + 2 * groups * n
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        per_head = lambda name, init: self.param(
+            name, init, (heads,), self.param_dtype).astype(F32)
+        with jax.named_scope("ssm"):
+            proj = dense(inner + channels + heads, name="in_proj")(x)
+            z, xbc = proj[..., :inner], proj[..., inner:inner + channels]
+            kernel = self.param("conv_kernel", nn.initializers.normal(0.02),
+                                (taps, channels), self.param_dtype)
+            bias = self.param("conv_bias", nn.initializers.zeros,
+                              (channels,), self.param_dtype)
+            dt = jax.nn.softplus(
+                proj[..., inner + channels:].astype(F32)
+                + per_head("dt_bias", nn.initializers.zeros))
+            a = -jnp.exp(per_head("A_log", nn.initializers.zeros))
+            d_skip = per_head("D", nn.initializers.ones)
+            split = lambda t: (
+                t[..., :inner].reshape(t.shape[:-1] + (heads, p)),
+                t[..., inner:inner + groups * n].reshape(
+                    t.shape[:-1] + (groups, n)),
+                t[..., inner + groups * n:].reshape(
+                    t.shape[:-1] + (groups, n)))
+            if self.decode:
+                state = self.variable("cache", "ssm_state", jnp.zeros,
+                                      (batch, heads, p, n), F32)
+                tail = self.variable("cache", "conv_state", jnp.zeros,
+                                     (batch, (taps - 1) * channels),
+                                     self.dtype)
+            if self.decode and seq == 1:
+                with jax.named_scope("ssm_step"):
+                    rows = [tail.value[:, j * channels:(j + 1) * channels]
+                            for j in range(taps - 1)] + [xbc[:, 0]]
+                    tail.value = jnp.concatenate(rows[1:], axis=-1)
+                    xs, b, c = split(ssm_conv(rows, kernel, bias).astype(
+                        self.dtype))
+                    state.value, y = ssm_step(state.value, xs, dt[:, 0], a,
+                                              b, c, d_skip)
+                y = y[:, None]
+            else:
+                true = jnp.full((batch,), seq, jnp.int32) \
+                    if lengths is None else lengths
+                at = jnp.arange(seq, dtype=jnp.int32)
+                dt = jnp.where((at[None, :] < true[:, None])[..., None],
+                               dt, 0.0)
+                # zeros before the sequence
+                padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+                xs, b, c = split(ssm_conv(
+                    [padded[:, j:j + seq] for j in range(taps)], kernel,
+                    bias).astype(self.dtype))
+                with jax.named_scope("ssm_scan"):
+                    y, last = ssm_chunked(xs, dt, a, b, c, d_skip,
+                                          self.chunk, self.dtype)
+                if self.decode:
+                    state.value = last
+                    # the last true rows, zeros before the prompt's start
+                    rows = true[:, None] - (taps - 1) \
+                        + jnp.arange(taps - 1, dtype=jnp.int32)[None, :]
+                    kept = jnp.take_along_axis(
+                        xbc, jnp.clip(rows, 0, seq - 1)[..., None], axis=1)
+                    tail.value = jnp.where(
+                        (rows >= 0)[..., None], kept, 0).reshape(
+                            batch, (taps - 1) * channels).astype(self.dtype)
+            gated = y.reshape(batch, seq, inner).astype(F32) \
+                * nn.silu(z.astype(F32))
+            gated = RMSNorm(eps=self.eps, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name="norm")(gated)
+            return dense(d_model, name="out_proj")(gated)
+
+
 # ----------------------------------------------------------- routed experts
 
 # at or under this many (token, expert) pairs a held expert (of the pairs
 # expected here: a layer that holds a share of the experts gets that share
 # of a step's pairs), every held expert multiplies every row under a 0/1
 # weight (experts_masked); above it the pairs are sorted and grouped
-# (experts_grouped, experts_grouped_held). The one point
-# measured on the chip is 64 rows x top-4 over 64 experts, 4 pairs an
-# expert, where the masked product runs within 8-16% of the time of
-# reading the experts (PERF.md, PR 34), and 32 rows x top-8 over 8 of 128
-# experts, 2 pairs an expert of the 16 expected here, where it runs at
-# the time of reading them (0.84 ms a layer for 681 MB; PERF.md, PR 39);
-# nothing smaller can gain from grouping, and nothing larger has been
-# measured in the masked form.
-MASKED_PAIRS = 4
+# (experts_grouped, experts_grouped_held). Three points are measured on
+# the chip, each a decode step of 32-64 rows, and the masked product won
+# at each: 64 rows x top-4 over 64 experts, 4 pairs an expert, within
+# 8-16% of the time of reading the experts (PERF.md, PR 34); 32 rows x
+# top-8 over 8 of 128 experts, 2 pairs an expert of the 16 expected
+# here, at the time of reading them (0.84 ms a layer for 681 MB; PERF.md,
+# PR 39); 64 rows x top-10 over 36 of 72 experts of width 768, 8.9 pairs
+# an expert, 21.4 ms a step masked against 28.7 grouped (ten layers,
+# 6.8 GB of experts; PERF.md, PR 41): sorting, gathering and scattering
+# 640 rows cost more than multiplying 64 rows by every held expert. The
+# masked product's arithmetic grows with rows x held experts and passes
+# the time of reading them near 100-200 rows; nothing above 64 rows has
+# been measured masked, and 9 keeps every prompt's bucket (17.8 pairs an
+# expert at the least) grouped.
+MASKED_PAIRS = 9
 # tokens of a prompt that a layer holding a share of the experts groups at
 # once (experts_grouped_held)
 HELD_TOKENS = 4096
 
-def route(x, router, bias, top_k, scaling):
-    """DeepSeek-V3's ``noaux_tc`` router without group limits: scores
-    ``g = sigmoid(x W_r)``, the ``top_k`` largest of ``g + bias`` chosen,
-    their weights ``scaling g_i / (sum of the chosen g + 1e-20)``. The
-    product and the scores are float32 at the highest precision: the
-    fourth and the fifth score can lie closer than bfloat16 resolves.
+def route(x, router, bias, top_k, scaling, scoring=SIGMOID_ROUTER):
+    """A top-k router. ``scoring="sigmoid"`` is DeepSeek-V3's ``noaux_tc``
+    without group limits: scores ``g = sigmoid(x W_r)``, the ``top_k``
+    largest of ``g + bias`` chosen, their weights ``scaling g_i / (sum of
+    the chosen g + 1e-20)``. ``scoring="softmax"`` chooses the ``top_k``
+    largest logits ``g = x W_r`` themselves (``+ bias``, if there is one)
+    and weighs them ``scaling softmax(the chosen g)``. The product and the
+    scores are float32 at the highest precision: the last score chosen
+    and the first left out can lie closer than bfloat16 resolves.
 
-    ``x``: (..., d); ``router``: (d, experts); ``bias``: (experts,).
-    Returns the chosen experts (..., top_k) int32 and their weights
-    (..., top_k) float32."""
-    g = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.astype(F32),
-                               precision=HIGHEST))
-    _, chosen = jax.lax.top_k(g + bias.astype(F32), top_k)
+    ``x``: (..., d); ``router``: (d, experts); ``bias``: (experts,) or
+    ``None``. Returns the chosen experts (..., top_k) int32 and their
+    weights (..., top_k) float32."""
+    g = jnp.dot(x.astype(F32), router.astype(F32), precision=HIGHEST)
+    if scoring == SIGMOID_ROUTER:
+        g = jax.nn.sigmoid(g)
+    elif scoring != SOFTMAX_ROUTER:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    _, chosen = jax.lax.top_k(g if bias is None else g + bias.astype(F32),
+                              top_k)
     picked = jnp.take_along_axis(g, chosen, axis=-1)
-    weights = scaling * picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), weights
+    if scoring == SIGMOID_ROUTER:
+        weights = picked / (picked.sum(axis=-1, keepdims=True) + 1e-20)
+    else:
+        weights = jax.nn.softmax(picked, axis=-1)
+    return chosen.astype(jnp.int32), scaling * weights
 
 
 def experts_masked(x, weights, gate, up, down):
@@ -1319,9 +1565,11 @@ def experts_grouped_held(x, chosen, weights, gate, up, down, share):
 
 class RoutedExperts(nn.Module):
     """A router over ``num_experts`` SiLU-gated MLPs of width ``d_ff``,
-    ``top_k`` of them a token (:func:`route`), beside ``shared`` experts
-    that every token takes (one :class:`GatedMlp` of ``shared * d_ff``).
-    No token is dropped and there is no capacity factor.
+    ``top_k`` of them a token (:func:`route`, ``scoring`` its form; the
+    softmax form has no correction bias), beside ``shared`` experts that
+    every token takes (one :class:`GatedMlp` of ``shared * d_ff``, or of
+    ``shared_d_ff`` where the shared MLP has a width of its own). No
+    token is dropped and there is no capacity factor.
 
     The layer holds experts ``first .. first + count - 1`` (``count``
     ``None``: all of them): it routes over all ``num_experts`` router
@@ -1343,7 +1591,9 @@ class RoutedExperts(nn.Module):
     top_k: int
     d_ff: int
     shared: int = 1
+    shared_d_ff: Optional[int] = None
     scaling: float = 1.0
+    scoring: str = SIGMOID_ROUTER
     first: int = 0
     count: Optional[int] = None
     decode: bool = False
@@ -1359,13 +1609,19 @@ class RoutedExperts(nn.Module):
         router = self.param("router", nn.initializers.normal(d ** -0.5),
                             (d, self.num_experts), self.param_dtype)
         bias = self.param("router_bias", nn.initializers.zeros,
-                          (self.num_experts,), self.param_dtype)
+                          (self.num_experts,), self.param_dtype) \
+            if self.scoring == SIGMOID_ROUTER else None
         gate, up = (self.param(name, init, (count, d, self.d_ff),
                                self.param_dtype).astype(self.dtype)
                     for name in ("experts_gate", "experts_up"))
         down = self.param("experts_down", init, (count, self.d_ff, d),
                           self.param_dtype).astype(self.dtype)
-        chosen, weights = route(x, router, bias, self.top_k, self.scaling)
+        # the sigmoid form is called with the five arguments it always
+        # had: the benchmark's own tests swap ``route`` for a function of
+        # those five (benchmark/tests/test_serve_xing.py)
+        chosen, weights = route(
+            x, router, bias, self.top_k, self.scaling,
+            *(() if self.scoring == SIGMOID_ROUTER else (self.scoring,)))
         counted = jnp.ones((batch, seq), bool)
         if lengths is not None:
             counted = jnp.arange(seq, dtype=jnp.int32)[None, :] \
@@ -1401,7 +1657,8 @@ class RoutedExperts(nn.Module):
                 count / self.num_experts)
         y = y.reshape(batch, seq, d)
         if self.shared:
-            y = y + GatedMlp(self.shared * self.d_ff, dtype=self.dtype,
+            y = y + GatedMlp(self.shared_d_ff or self.shared * self.d_ff,
+                             dtype=self.dtype,
                              param_dtype=self.param_dtype,
                              name="shared")(x).astype(F32)
         return y.astype(self.dtype)
@@ -1535,7 +1792,8 @@ class HybridLayer(nn.Module):
                  POWER_RETENTION: PowerRetention,
                  LATENT: LatentAttention,
                  FULL: GroupedQueryAttention,
-                 WINDOW: GroupedQueryAttention}[self.kind](
+                 WINDOW: GroupedQueryAttention,
+                 MAMBA2: StateSpace}[self.kind](
                      name="mixer", **dict(self.mixer_args), **common)
         norm = partial(RMSNorm, **common)
         if self.mlp == EXPERTS_MLP:
@@ -1585,14 +1843,17 @@ class HybridLayer(nn.Module):
 class HybridDecoder(nn.Module):
     """Embedding, ``len(mixers)`` layers of the kinds ``mixers`` names
     (``"block_sparse"`` / ``"lightning"`` / ``"power_retention"`` /
-    ``"latent"`` / ``"full"`` / ``"window"``), final RMSNorm, untied head.
+    ``"latent"`` / ``"full"`` / ``"window"`` / ``"mamba2"``), final
+    RMSNorm, a head of its own or (``tied_head``) the embedding's matrix.
 
     ``mlps`` names each layer's MLP (``"dense"``, the default, or
     ``"experts"``: :class:`RoutedExperts` with the fields ``experts``);
     ``latent`` holds :class:`LatentAttention`'s ranks and widths;
+    ``ssm`` holds :class:`StateSpace`'s heads and sizes;
     ``window`` is the keys a ``"window"`` layer sees
     (:class:`GroupedQueryAttention`: rotary positions there and none on a
-    ``"full"`` layer); ``norms`` says where a sublayer's norm sits
+    ``"full"`` layer; ``qk_norm`` and ``attention_scale`` are its
+    ``qk_norm`` and ``scale``); ``norms`` says where a sublayer's norm sits
     (:class:`HybridLayer`); ``layer_barriers`` puts an optimisation
     barrier after every layer of a prompt, so that XLA does not run one
     layer's work under another's: without it the temporaries of four
@@ -1606,7 +1867,9 @@ class HybridDecoder(nn.Module):
     (a lightning layer's decay depends on it) and ``published_depth`` the
     published number of layers, which the residual scale and the decay
     keep when the depth is cut. The muP scalings are neutral at their
-    defaults, ``scale_depth=None`` meaning a residual scale of 1. ``causal``, ``max_seq``, ``vocab_size``
+    defaults, ``scale_depth=None`` meaning a residual scale of 1;
+    ``residual_multiplier``, where given, is the residual scale itself,
+    and ``logits_divisor`` divides the logits. ``causal``, ``max_seq``, ``vocab_size``
     and ``clone(decode=..., remat=..., attention_fn=...)`` are what
     ``serve.kv_cache.DecodeEngine`` asks of a model (``remat`` and
     ``attention_fn`` are accepted for that and not used)."""
@@ -1620,7 +1883,10 @@ class HybridDecoder(nn.Module):
     mixers: Tuple[str, ...]
     sparse: Any = None                  # a mapping; see BlockSparseAttention
     latent: Any = None                  # a mapping; see LatentAttention
+    ssm: Any = None                     # a mapping; see StateSpace
     window: Optional[int] = None        # keys a "window" layer sees
+    qk_norm: bool = True
+    attention_scale: Optional[float] = None
     norms: str = NORM_INPUT
     layer_barriers: bool = False
     mlps: Optional[Tuple[str, ...]] = None
@@ -1631,7 +1897,10 @@ class HybridDecoder(nn.Module):
     published_depth: Optional[int] = None
     scale_emb: float = 1.0
     scale_depth: Optional[float] = 1.0
+    residual_multiplier: Optional[float] = None
     dim_model_base: Optional[int] = None
+    logits_divisor: float = 1.0
+    tied_head: bool = False
     rope_theta: float = 10000.0
     eps: float = 1e-6
     max_seq: int = 2048
@@ -1693,7 +1962,10 @@ class HybridDecoder(nn.Module):
                         head_dim=self.head_dim,
                         window=self.window if kind == WINDOW else None,
                         rotary=kind == WINDOW, rope_theta=self.rope_theta,
+                        qk_norm=self.qk_norm, scale=self.attention_scale,
                         max_cache_len=self.max_seq, decode=self.decode)
+        if kind == MAMBA2:
+            return dict(decode=self.decode, **dict(self.ssm))
         raise ValueError(f"unknown mixer {kind!r}")
 
     def decode_positions_by_kind(self, positions):
@@ -1734,20 +2006,24 @@ class HybridDecoder(nn.Module):
         if lengths is not None:
             lengths = jnp.asarray(lengths, jnp.int32)
         depth = self.published_depth or len(self.mixers)
-        h = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     param_dtype=self.param_dtype,
-                     embedding_init=nn.initializers.normal(0.02),
-                     name="token_embed")(token_ids)
-        h = h * jnp.asarray(self.scale_emb, self.dtype)
+        if self.residual_multiplier is not None:
+            residual_scale = self.residual_multiplier
+        elif self.scale_depth is None:
+            residual_scale = 1.0
+        else:
+            residual_scale = self.scale_depth / math.sqrt(depth)
+        embed = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                         param_dtype=self.param_dtype,
+                         embedding_init=nn.initializers.normal(0.02),
+                         name="token_embed")
+        h = embed(token_ids) * jnp.asarray(self.scale_emb, self.dtype)
         if self.streams > 1:
             h = jnp.repeat(h[:, None], self.streams, axis=1)
         mlps = self.mlps or (DENSE_MLP,) * len(self.mixers)
         for i, kind in enumerate(self.mixers):
             h = HybridLayer(
                 kind=kind, mixer_args=self._mixer_args(i, kind),
-                d_ff=self.d_ff,
-                residual_scale=(1.0 if self.scale_depth is None
-                                else self.scale_depth / math.sqrt(depth)),
+                d_ff=self.d_ff, residual_scale=residual_scale,
                 norms=self.norms, mlp=mlps[i],
                 mlp_args=(dict(self.experts, decode=self.decode)
                           if mlps[i] == EXPERTS_MLP else None),
@@ -1769,8 +2045,17 @@ class HybridDecoder(nn.Module):
                 return h
             width = self.d_model / (self.dim_model_base or self.d_model)
             h = h / jnp.asarray(width, self.dtype)
-            kernel = self.param(
-                "head", nn.initializers.normal(0.02),
-                (self.d_model, self.vocab_size), self.param_dtype)
-            return jnp.einsum("bsd,dv->bsv", h, kernel.astype(self.dtype),
-                              preferred_element_type=F32)
+            if self.tied_head:
+                logits = jnp.einsum(
+                    "bsd,vd->bsv", h, embed.embedding.astype(self.dtype),
+                    preferred_element_type=F32)
+            else:
+                kernel = self.param(
+                    "head", nn.initializers.normal(0.02),
+                    (self.d_model, self.vocab_size), self.param_dtype)
+                logits = jnp.einsum(
+                    "bsd,dv->bsv", h, kernel.astype(self.dtype),
+                    preferred_element_type=F32)
+            if self.logits_divisor != 1.0:
+                logits = logits / self.logits_divisor
+            return logits

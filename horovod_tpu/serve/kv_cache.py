@@ -59,7 +59,12 @@ window-many positions (whole lane tiles of them), position ``p`` in
 column ``p mod ring``, and not in ``max_seq`` (kind ``ring``): a prefill
 leaves the prompt's last positions there and a decode step writes over
 the oldest column, both inside the model, so that a slot's row of a ring
-leaf is written and replaced like any other row. Nothing here asks a
+leaf is written and replaced like any other row. A state-space layer
+keeps a float32 state (``ssm_state``, kind ``state``) and the last rows
+before its convolution (``conv_state``, kind ``conv``: a tail of three
+rows, a state of its own that neither grows nor is float32), beside a
+full layer's rows in one slot: a prefill overwrites both with what the
+prompt's true tokens leave. Nothing here asks a
 leaf for more than the slot axis,
 with one exception: a leaf of kind ``counter`` (an expert layer's
 ``expert_counts``) is a running count and no slot's row, so a prefill
@@ -173,18 +178,21 @@ def prompt_bucket(prompt_len: int, max_seq: int,
 # every kind: one of recurrent layers alone holds states and nothing else.
 # A latent-attention layer's two leaves (one latent and one rotary key a
 # position, nothing a head) are ``latent``; a window layer's keys and
-# values, a ring of window-many positions, are ``ring``; an expert layer's
+# values, a ring of window-many positions, are ``ring``; a state-space
+# layer's state is ``state`` like the other recurrent states and the tail
+# of its convolution ``conv``; an expert layer's
 # running counts are ``counter``: no slot's row (module docstring)
 CACHE_KINDS = {"cached_key": "kv", "cached_value": "kv",
                "compressed_key": "compressed", "state": "state",
-               "state_norm": "state", "latent": "latent",
+               "state_norm": "state", "ssm_state": "state",
+               "conv_state": "conv", "latent": "latent",
                "rope_key": "latent", "ring_key": "ring",
                "ring_value": "ring", "expert_counts": "counter"}
 
 
 def leaf_kind(path) -> str:
-    """``kv``, ``compressed``, ``state``, ``latent``, ``ring`` or
-    ``counter`` for a cache leaf's tree path (``other`` for a name :data:`CACHE_KINDS` does
+    """``kv``, ``compressed``, ``state``, ``conv``, ``latent``, ``ring``
+    or ``counter`` for a cache leaf's tree path (``other`` for a name :data:`CACHE_KINDS` does
     not know)."""
     name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
     return CACHE_KINDS.get(str(name), "other")
@@ -311,6 +319,10 @@ class DecodeEngine:
             if by_kind and by_kind(np.zeros((1,), np.int64)) else None
         self.positions_by_kind: Dict[str, int] = {}
         self._cache = self._allocate_cache()
+        # bytes of the recurrent states (kind ``state``) all the slots'
+        # rows hold: what a decode step, which runs every row, rewrites,
+        # and a prefill a slot's share of (the spans' ``state_bytes``)
+        self._state_bytes = self.cache_bytes_by_kind()["state"]
         # the next token of every row, on the device (module docstring)
         self._feed = jnp.zeros((self.num_slots,), jnp.int32)
         # by bucket, or by name where a prompt runs in pieces
@@ -569,7 +581,7 @@ class DecodeEngine:
         self.prefill_tokens += len(prompt)
         return PendingPrefill(token, max_abs, t0, dict(
             bucket=bucket, chunks=chunks, prompt_len=len(prompt), slot=slot,
-            sparse=sparse))
+            sparse=sparse, state_bytes=self._state_bytes // self.num_slots))
 
     def decode(self, slots: List[int], tokens: Optional[List[int]],
                positions: List[int]) -> PendingDecode:
@@ -601,7 +613,7 @@ class DecodeEngine:
                 raise ValueError(
                     f"decode: slot {slot} position {step_pos[slot]} >= "
                     f"max_seq {self.max_seq} (admission cap violated)")
-            attrs = {}
+            attrs = {"state_bytes": self._state_bytes}
             if self._reads_live_tiles or self._reads_live_groups:
                 # what the kernel will fetch: a row that is not active
                 # runs at position 0 and costs one tile

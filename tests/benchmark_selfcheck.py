@@ -43,6 +43,10 @@ XING_FAULTS = ("test_the_faults_are_planted_in_the_reference_and_leave_it_"
 KEXAONE_UNTRACED = ("test_an_untraced_rehearsal_reads_the_end_to_end_metrics",
                     "test_the_float8_control_and_the_planted_faults_fail_a_"
                     "limit")
+# ``benchmark/tests/test_serve_granite.py``: the same cut (its functions
+# have the same names: ten toy layers' rehearsals, and the controls'
+# five forwards of the reference)
+GRANITE_UNTRACED = KEXAONE_UNTRACED
 
 
 def test_functions(modules, only=(), without=()):
@@ -122,6 +126,8 @@ REHEARSALS = {
     ("benchmark.tests.test_serve_xing",
      "test_a_sound_traced_rehearsal_is_correct_and_reads_its_metrics"),
     ("benchmark.tests.test_serve_kexaone",
+     "test_a_sound_traced_rehearsal_is_correct_and_reads_its_metrics"),
+    ("benchmark.tests.test_serve_granite",
      "test_a_sound_traced_rehearsal_is_correct_and_reads_its_metrics"),
 }
 # the line pytest marks as the one that failed, in the test's own body

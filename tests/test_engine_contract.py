@@ -1,6 +1,6 @@
 """What every model behind the dense serving engine promises, once for
-all of them: the four families of ``tests/toy_models.py`` (MiniCPM-SALA,
-Brumby, Xing, K-EXAONE: each a ``HybridDecoder`` with a cache of its own kinds,
+all of them: the five families of ``tests/toy_models.py`` (MiniCPM-SALA,
+Brumby, Xing, K-EXAONE, granite: each a ``HybridDecoder`` with a cache of its own kinds,
 against its plain reference's one forward in float32). A new family is
 one more entry of ``toy_models.family`` and of its ``FAMILIES``, not a
 copy of these cases; what only one family has (its mixers, its cache's
@@ -60,7 +60,10 @@ def engines():
     ("xing", 203), ("xing", 61),
     # 203 lies past K-EXAONE's ring of 128 (its last 128 positions wrap
     # into it), 61 under its window of 64
-    ("kexaone", 203), ("kexaone", 61)])
+    ("kexaone", 203), ("kexaone", 61),
+    # 203 is three of granite's chunks (64) and 11, 61 under one; 2 lies
+    # under its convolution's reach (a tail of three rows, one of zeros)
+    ("granite", 203), ("granite", 61), ("granite", 2)])
 def test_prefill_then_decode_is_the_references_one_forward(pieces, engines,
                                                            name, prompt_len):
     """A padded prefill (a bucket, or pieces that carry the slot's state),
@@ -113,7 +116,7 @@ def test_a_slot_is_reused_after_a_longer_occupant(name):
     assert engine.stats()["cache_donated"]
 
 
-@pytest.mark.parametrize("name", ["sala", "brumby"])
+@pytest.mark.parametrize("name", ["sala", "brumby", "granite"])
 def test_the_state_after_the_padding_would_be_seen(name):
     """The broken path the true length guards against: a prefill that
     hands the model the bucket in place of the prompt's length leaves the
@@ -135,7 +138,7 @@ def test_the_state_after_the_padding_would_be_seen(name):
     assert np.abs(got - want[203]).max() > 100 * fam.tol
 
 
-@pytest.mark.parametrize("name", ["sala", "brumby"])
+@pytest.mark.parametrize("name", ["sala", "brumby", "granite"])
 def test_the_paged_engine_refuses_a_model_without_pages(name):
     from horovod_tpu.serve.paging import PagedDecodeEngine
 

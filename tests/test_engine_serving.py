@@ -1,5 +1,5 @@
 """Every model family behind the public entry point, ``hvd.serve()``, and
-what the dense engine's prefill promises whatever the model: the four
+what the dense engine's prefill promises whatever the model: the five
 families of ``tests/toy_models.py`` and the toy GPT-2 trunk (the
 engine's own contract, on logits, is ``tests/test_engine_contract.py``:
 a file is what tier-1's ``--dist loadfile`` schedules).
@@ -66,6 +66,26 @@ def test_serving_through_hvd_serve(name):
                 assert read["ring"] == 6 * (sum(min(c, 64) for c in contexts)
                                             + inactive)
                 assert 0 < engine["decode_kv_read_share"] <= 1.0
+            if name == "granite":
+                # nine state-space layers' float32 states and convolution
+                # tails beside the one full layer's max_seq-long rows, and
+                # the experts' counts of all ten layers in one slot cache
+                by_kind = engine["cache_bytes_by_kind"]
+                ssm = fam.cfg["ssm"]
+                inner = ssm["num_heads"] * ssm["head_dim"]
+                assert by_kind["state"] == 9 * 2 * inner * ssm["d_state"] * 4
+                assert by_kind["conv"] == 9 * 2 * 3 * (
+                    inner + 2 * ssm["d_state"]) * 4
+                assert by_kind["kv"] == 2 * 2 * fam.cfg["max_seq"] \
+                    * fam.cfg["num_kv_heads"] * fam.cfg["head_dim"] * 4
+                counts = np.asarray(engine["expert_counts"])
+                assert counts.shape == (10, 3, fam.cfg["experts_count"])
+                # this chip's half of the experts: about half of the pairs
+                pairs = (150 + 37 + 260 + 3 * 7) * fam.cfg["top_k"]
+                assert (counts[:, 0].sum(axis=1) < pairs).all()
+                assert 0.3 * pairs < counts[:, 0].sum(axis=1).mean() \
+                    < 0.7 * pairs
+                assert engine["decode_positions_by_kind"]["ring"] == 0
             if fam.no_pages:
                 with pytest.raises(ValueError, match=fam.no_pages):
                     hvd.serve(fam.model, fam.params, slots=2, paged=True)
@@ -91,13 +111,14 @@ def test_gpt2_toy_serving_is_unchanged_by_the_one_row_head(prompt_len):
     assert abs(max_abs - np.abs(want[-1]).max()) < 1e-5
 
 
-@pytest.mark.parametrize("which", ["transformer", "sala", "xing"])
+@pytest.mark.parametrize("which", ["transformer", "sala", "xing", "granite"])
 def test_a_model_that_cannot_resume_keeps_its_bucket_programs(monkeypatch,
                                                               which):
     """Only a model that says its prefill resumes from its cache
     (``resumable_prefill``: every mixer a power retention) has its
     prompts run in pieces. The dense trunk lacks the property, a model
-    with block-sparse, lightning or latent layers answers false: each
+    with block-sparse, lightning or latent layers, or with a full layer
+    among its state-space layers, answers false: each
     keeps one ``prefill_<bucket>`` program a bucket, one program a
     prompt, however small the piece would be."""
     monkeypatch.setattr(kv_cache, "PREFILL_CHUNK", 32)

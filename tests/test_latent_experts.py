@@ -19,12 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark import reference_granite as gref
 from benchmark import reference_kexaone as kref
 from benchmark import reference_xing as xref
 from benchmark import weights_xing
 from benchmark.runners import serve_xing
 from horovod_tpu.models import hybrid
-from toy_models import (REPO, SEED, kexaone, tokens, xing, xing_cfg,
+from toy_models import (REPO, SEED, granite, kexaone, tokens, xing, xing_cfg,
                         xing_reference as xreference)
 
 F32_TOL = 2e-5
@@ -171,7 +172,7 @@ def _is_grouped(layer, variables, x):
 
 
 def _held(p, first, count, shared=True):
-    part = {k: p[k] for k in ("router", "router_bias")}
+    part = {k: p[k] for k in ("router", "router_bias") if k in p}
     part.update({k: p[k][first:first + count]
                  for k in ("experts_gate", "experts_up", "experts_down")})
     if shared:
@@ -185,26 +186,41 @@ def _layout(name):
     if name == "8-experts-top-4":
         cfg, params, _ = xing()
         return cfg, params["layer_1"]["moe"], xref, 4
-    # K-EXAONE's router at its published count: 128 experts, top-8,
-    # scaling 2.5, sixteen shares of 8 (the toy's own widths)
-    cfg, params, _ = kexaone(num_experts=128, experts_count=128, top_k=8)
-    return cfg, params["layer_1"]["moe"], kref, 8
+    if name == "128-experts-top-8":
+        # K-EXAONE's router at its published count: 128 experts, top-8,
+        # scaling 2.5, sixteen shares of 8 (the toy's own widths)
+        cfg, params, _ = kexaone(num_experts=128, experts_count=128,
+                                 top_k=8)
+        return cfg, params["layer_1"]["moe"], kref, 8
+    # granite's router at its published count: 72 experts, top-10, a
+    # softmax over the chosen logits, two shares of 36, a shared MLP of a
+    # width of its own (the toy's own widths)
+    cfg, params, _ = granite(num_experts=72, experts_count=72, top_k=10)
+    return cfg, params["layer_1"]["moe"], gref, 36
 
 
-@pytest.mark.parametrize("layout", ["8-experts-top-4", "128-experts-top-8"])
-@pytest.mark.parametrize("seq", [50, 2], ids=["grouped", "masked"])
+@pytest.mark.parametrize("layout", ["8-experts-top-4", "128-experts-top-8",
+                                    "72-experts-top-10-softmax"])
+@pytest.mark.parametrize("seq", [100, 2], ids=["grouped", "masked"])
 def test_the_expert_layers_shares_add_up(seq, layout):
     """The toy experts held in shares (8 as (0, 4) + (4, 4); 128 as
-    sixteen shares of 8) and whole: the routed parts summed, with the
-    shared expert counted once, are the whole layer of the reference;
-    each share routes over all the router's outputs. Both forms of the
-    product, each reached by its size: 100 tokens are 400 (800) pairs,
-    4 tokens 16 (32), at or under ``MASKED_PAIRS`` an expert of the pairs
-    a share expects."""
+    sixteen shares of 8; 72 as two of 36) and whole: the routed parts
+    summed, with the shared expert counted once, are the whole layer of
+    the reference; each share routes over all the router's outputs. Both
+    forms of the product, each reached by its size: 200 tokens are 800
+    (1,600; 2,000) pairs, 4 tokens 16 (32; 40), at or under
+    ``MASKED_PAIRS`` an expert of the pairs a share expects."""
     cfg, p, ref, count = _layout(layout)
     experts = cfg["num_experts"]
 
     def layer(first, count, shared):
+        if ref is gref:
+            return hybrid.RoutedExperts(
+                num_experts=experts, top_k=cfg["top_k"],
+                d_ff=cfg["expert_d_ff"], shared=shared,
+                shared_d_ff=cfg["shared_d_ff"],
+                scoring=hybrid.SOFTMAX_ROUTER, first=first, count=count,
+                dtype=jnp.float32)
         return hybrid.RoutedExperts(
             num_experts=experts, top_k=cfg["top_k"],
             d_ff=cfg["expert_d_ff"], shared=shared,
@@ -215,7 +231,7 @@ def test_the_expert_layers_shares_add_up(seq, layout):
                     jnp.float32)
     for held in (experts, count):
         assert _is_grouped(layer(0, held, 1),
-                           {"params": _held(p, 0, held)}, x) == (seq == 50)
+                           {"params": _held(p, 0, held)}, x) == (seq == 100)
     want = np.asarray(ref.routed(ref._matmul("f32"), x.reshape(-1, 128),
                                  p, ref.frozen(cfg))).reshape(x.shape)
     whole = layer(0, experts, 1).apply({"params": _held(p, 0, experts)}, x)

@@ -602,6 +602,111 @@ def test_window_full_engine_fits_and_updates_its_cache_in_place(
         assert text.count("tpu_custom_call") >= full
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_4096"])
+def test_state_space_engine_fits_and_updates_its_cache_in_place(
+        chip, monkeypatch, capsys, program):
+    """granite-4.0-h-small as the benchmark runs it (benchmark/configs/
+    granite-4.0-h-small.json: layers 0-9 at full width, nine Mamba-2
+    layers to one full grouped-query layer, 36 of 72 experts on every
+    layer, a tied head, 64 slots of 4,096 positions, bfloat16 weights):
+    the 64-row decode step and the prefill of the largest bucket compile
+    for one v5e chip, arguments plus temporaries stay under its 16 GB
+    (printed: run with ``-s``), and the result aliases every leaf of the
+    donated cache - a float32 state and a convolution tail a state-space
+    layer, ``max_seq``-long keys and values on the full layer, every
+    layer's expert counter - and the token feed. A decode step's
+    state-space layer reads its state once and writes it once: XLA fuses
+    the update and the read into one multi-output fusion a layer (a
+    product for the read would be a third pass). The step's 640 pairs
+    over 72 experts are at ``MASKED_PAIRS`` an expert: every held expert
+    multiplies every row and no pair is sorted; the prefill's pairs that
+    are here are grouped through ``ragged_dot`` and its full layer
+    attends through the flash kernel."""
+    import json
+
+    from benchmark import weights_granite
+    from benchmark.runners.serve_granite import build_model
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-small.json")) as f:
+        cfg = json.load(f)["as_run"]
+    ssm_layers, slots, bucket, seq = 9, 64, 4096, 4096
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+                           )["params"]))
+    assert sum(x.size for x in jax.tree.leaves(params)) \
+        == weights_granite.count(cfg) == 4_757_211_776
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+    assert eng.cache_bytes_by_kind() == {
+        "kv": 2 * slots * seq * 8 * 128 * 2,                     # 1.07 GB
+        "compressed": 0,
+        "state": ssm_layers * slots * 128 * 64 * 128 * 4,        # 2.42 GB
+        "conv": ssm_layers * slots * 3 * 8448 * 2,               # 0.03 GB
+        "counter": (ssm_layers + 1) * 3 * 36 * 4}
+    assert eng._reads_live_groups and eng._counts
+    assert not eng._reads_live_tiles and not eng._reads_live_latents
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, i32(slots),
+                                       i32(slots))
+    else:
+        lowered = eng._prefill_fn(bucket).lower(
+            params, eng._cache, i32(slots), i32(1, bucket), i32(), i32())
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\ngranite {program}: arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{memory.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{memory.alias_size_in_bytes / 1e9:.3f} GB")
+    # the feed, and ten counters of 432 bytes in a tile each
+    assert 0 < memory.alias_size_in_bytes - eng.cache_bytes() <= 32768
+    assert _feed_is_aliased(text, params, eng._cache)
+    assert 12.9e9 < memory.argument_size_in_bytes < 13.1e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16e9)
+    state, tail, row = (rf"f32\[{slots},128,64,128\]",
+                        rf"bf16\[{slots},25344\]",
+                        rf"bf16\[{slots},8,128,{seq}\]")
+    for leaf, leaves in ((state, ssm_layers), (tail, ssm_layers), (row, 2)):
+        assert len(re.findall(rf"= {leaf}\S* parameter\(\d+\), sharding",
+                              text)) == leaves, leaf
+        assert not re.findall(rf"= {leaf}\S* copy(-start)?\(", text), leaf
+    if program == "decode":
+        assert memory.temp_size_in_bytes < 0.1e9
+        # the state's update and its read are one fusion a layer: the
+        # new state and the reduced output leave it together
+        assert len(re.findall(
+            rf"fusion[.\d]* = \(f32\[{slots},128,64\]\S*, {state}\S*\) "
+            rf"fusion\(", text)) == ssm_layers
+        # two column writes and one attention on the full layer, no
+        # other kernel and no grouped product
+        assert text.count("tpu_custom_call") == 3
+        assert len(re.findall(
+            r"%grouped_decode_attention[.\d]* = [^\n]*? custom-call\(",
+            text)) == 1
+        assert not re.findall(r"%ragged-dot", text)
+    else:
+        assert memory.temp_size_in_bytes < 1.8e9
+        # one flash kernel; three grouped products an expert layer,
+        # inside the loop over the pairs that are here
+        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
+                              text)) >= 3
+        assert text.count("tpu_custom_call") >= 1
+
+
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
     """What ``training.make_train_step`` builds on a four-chip host: one
     jit over the global mesh, batch sharded. XLA cannot partition a

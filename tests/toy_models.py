@@ -20,6 +20,13 @@ seeded weights, each beside its plain reference under ``benchmark/``
   ``benchmark/reference_kexaone.py`` (``benchmark/configs/
   k-exaone-236b-a23b.json``'s toy sizes, all eight layers: a window of
   64 in a ring of 128); about 1.0.
+* ``granite``: granite-4.0-h-small, Mamba-2 state-space layers nine to
+  one beside a position-free grouped-query layer, a share of the routed
+  experts (softmax router) on every layer and a tied head, against
+  ``benchmark/reference_granite.py`` (``benchmark/configs/
+  granite-4.0-h-small.json``'s toy sizes, all ten layers: a state and a
+  convolution tail a state-space layer beside the full layer's rows);
+  about 1.6.
 
 A new family adds its entry to :func:`family` and so joins every case of
 ``tests/test_engine_contract.py``; it does not copy them.
@@ -34,12 +41,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import (reference_brumby, reference_kexaone, reference_sala,
-                       reference_xing)
-from benchmark import (weights_brumby, weights_kexaone, weights_sala,
-                       weights_xing)
-from benchmark.runners import (serve_brumby, serve_kexaone, serve_sala,
-                               serve_xing)
+from benchmark import (reference_brumby, reference_granite,
+                       reference_kexaone, reference_sala, reference_xing)
+from benchmark import (weights_brumby, weights_granite, weights_kexaone,
+                       weights_sala, weights_xing)
+from benchmark.runners import (serve_brumby, serve_granite, serve_kexaone,
+                               serve_sala, serve_xing)
 from horovod_tpu import tracing
 from horovod_tpu.models.transformer import Transformer
 
@@ -161,6 +168,34 @@ def kexaone_reference(cfg, params, toks, precision="f32", fault=None):
         precision, None, fault))
 
 
+# --------------------------------------------------------------- granite
+
+def granite_cfg(dtype="float32", **changes):
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "granite-4.0-h-small.json")) as f:
+        published = json.load(f)
+    cfg = dict(published["as_run"], **published["rehearse"])
+    cfg.update(max_seq=512, dtype=dtype, param_dtype=dtype)
+    cfg.update(changes)
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def granite(**changes):
+    cfg = granite_cfg(**changes)
+    return cfg, weights_granite.make_params(cfg, SEED), \
+        serve_granite.build_model(cfg)
+
+
+_granite_forward = jax.jit(reference_granite.forward, static_argnums=(2, 3))
+
+
+def granite_reference(cfg, params, toks, precision="f32"):
+    return np.asarray(_granite_forward(
+        params, jnp.asarray(toks, jnp.int32), reference_granite.frozen(cfg),
+        precision))
+
+
 # ------------------------------------------------- what the engine serves
 
 # ``reference(toks, precision="f32")``: the plain reference's logits for
@@ -168,7 +203,7 @@ def kexaone_reference(cfg, params, toks, precision="f32", fault=None):
 # what the paged engine says of a cache it cannot page (None: untested)
 Family = collections.namedtuple(
     "Family", "name cfg params model reference tol no_pages")
-FAMILIES = ("sala", "brumby", "xing", "kexaone")
+FAMILIES = ("sala", "brumby", "xing", "kexaone", "granite")
 
 
 @functools.lru_cache(maxsize=None)
@@ -193,6 +228,11 @@ def family(name):
         return Family(name, cfg, params, model,
                       functools.partial(kexaone_reference, cfg, params),
                       5e-5, None)
+    if name == "granite":
+        cfg, params, model = granite()
+        return Family(name, cfg, params, model,
+                      functools.partial(granite_reference, cfg, params),
+                      5e-5, "key/value models only")
     raise KeyError(name)
 
 
