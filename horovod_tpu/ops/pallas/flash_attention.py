@@ -1,20 +1,37 @@
 """Fused blockwise (flash) attention as a Pallas TPU kernel.
 
-The hot op of the transformer model family. Online-softmax attention that
-never materialises the ``(seq, seq)`` score matrix: the grid walks
-(batch, head, q-block, k-block) with the k-block axis innermost, so exactly
-one ``(block, head_dim)`` tile of each of q/k/v is resident in VMEM at a
-time while a running (max, sum, accumulator) triple lives in VMEM scratch —
-the MXU does the two matmuls, the VPU the rescaling. A custom VJP provides
-the matching blockwise backward kernels (dq; dk/dv), so both compute and
-VMEM stay O(block² + block·head_dim) per grid step end to end, independent
-of sequence length.
+The hot op of the transformer model family. Attention that never puts the
+``(seq, seq)`` score matrix in HBM, with a custom VJP of matching backward
+kernels (dq; dk/dv), in two forms chosen from the shapes:
+
+* **One side resident** (a key sequence no longer than ``block_k``; for
+  dk/dv a query sequence no longer than ``bwd_block_q``: every training
+  cell of the benchmark): the grid is (batch, head, block of the other
+  side), the resident side's q/k/v (and, for dk/dv, do, lse and delta)
+  stay in VMEM, the softmax is direct and nothing lives in scratch. When
+  causal, the kernel walks its score matrix in square tiles and runs only
+  those that hold an element at or under the diagonal, masking only the
+  ones the diagonal crosses (``_key_tiles``, ``_query_tiles``). Read on
+  the chip at GPT-2-small's (16, 12, 1024, 64) in bfloat16 (my chip
+  runs, PR 42; device time of the kernel alone): forward 0.556 -> 0.489
+  ms, dq 0.683 -> 0.552, dk/dv 1.243 -> 0.746 against the kernels that
+  ran 0.75 / 0.75 / 1.0 of the square; time follows the area computed,
+  because with head_dim 64 half filling the matrix unit's contraction
+  the products themselves are most of it (PERF.md section 6, PR 42).
+* **General** (longer sequences): the grid walks (batch, head, q-block,
+  k-block) with the k-block axis innermost, one ``(block, head_dim)``
+  tile of each of q/k/v in VMEM at a time while a running (max, sum,
+  accumulator) triple lives in VMEM scratch; compute and VMEM stay
+  O(block^2 + block x head_dim) a grid step whatever the sequence length.
+  Causal calls skip the blocks in a q block's future.
 
 This kernel is also the *local* building block of ring attention
 (horovod_tpu/parallel/ring.py): it accepts dynamic ``q_offset``/``k_offset``
 global position scalars and returns the per-row log-sum-exp, so partial
 results computed against one shard of keys/values can be merged exactly
-across ppermute steps (see ``merge_partials``).
+across ppermute steps (see ``merge_partials``). Python-zero offsets (the
+models' call) build the causal schedule at trace time; traced ones choose
+among bodies built for each number of live tiles (``_on_rungs``).
 
 The reference framework has no attention kernels at all (it is a pure
 data-parallel gradient-averaging layer — SURVEY.md §5.7); this module is part
@@ -35,6 +52,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.metrics import registry as _metrics
 from horovod_tpu.ops.pallas._backend import use_interpret
 
 NEG_INF = float("-inf")
@@ -47,6 +65,30 @@ LANES = 128
 # VPU): scores are pre-scaled by log2(e), the log-sum-exp converts back on
 # the way out.
 LOG2E = float(np.log2(np.e))
+
+# Sides of the square sub-tiles a causal kernel with one side resident walks
+# its score matrix in, first fit (``_causal_plan``). Read on the chip at
+# (16, 12, 1024, 64) (my chip runs, PR 42): 256 is the fastest or within 5%
+# of it in all three kernels; 128 runs fewer elements (0.5625 of the square
+# against 0.625) in smaller products and is slower in the forward and dk/dv.
+_TILE_SIDES = (256, 128)
+
+# A causal call whose offsets are Python zeros rides the whole of a side no
+# longer than this in one grid step, so that every tile's place against the
+# diagonal is known at trace time.
+_WHOLE_ROWS = 1024
+
+# With traced offsets a body is built for each number of tiles that can run
+# (``_on_rungs``); past this many tiles a side the ladder's code outgrows what
+# it saves (8 a side read 6.0 ms against 0.93 at 4: my chip runs, PR 42).
+_MAX_RUNGS = 4
+
+_LIVE_TILE_SHARE = {
+    kind: _metrics().gauge(
+        f"flash.live_tile_share.{kind}",
+        f"Tiles of the score matrix the last traced flash_{kind} call runs "
+        "over the tiles of the whole square (1.0 when not causal).")
+    for kind in ("fwd", "dq", "dkv")}
 
 
 def _vma(*arrays) -> frozenset:
@@ -71,6 +113,296 @@ def _compiler_params(grid_len: int):
     # carries the online-softmax accumulator in scratch.
     sem = ("parallel",) * (grid_len - 1) + ("arbitrary",)
     return pltpu.CompilerParams(dimension_semantics=sem)
+
+
+# ---------------------------------------------------------------------------
+# The causal schedule: which tiles of the score matrix hold a live element
+# ---------------------------------------------------------------------------
+#
+# With one side of the score matrix resident in VMEM, a causal kernel walks
+# it in (tile_q, tile_k) sub-tiles and runs only those that hold an element
+# at or under the diagonal; only the tiles the diagonal crosses are masked.
+# ``_key_tiles`` and ``_query_tiles`` are that schedule. They take Python
+# ints (the counter ``live_tile_share``, and a call whose offsets are Python
+# zeros: the kernel is built for the one schedule) or traced int32 scalars
+# (the ring's offsets: ``_on_rungs`` builds a body for each number of tiles and
+# the kernel chooses among them), so the counter cannot drift from the
+# kernels.
+
+
+def _floor_tiles(x, tile):
+    """``floor(x / tile)`` where ``x >= 0`` and 0 below."""
+    if isinstance(x, int):
+        return max(x, 0) // tile
+    return jax.lax.div(jnp.maximum(x, 0), jnp.int32(tile))
+
+
+def _at_most(x, n):
+    return min(x, n) if isinstance(x, int) else jnp.minimum(x, n)
+
+
+def _key_tiles(gap, tile_q, tile_k, n_k):
+    """For a tile of query rows whose first row lies ``gap`` positions
+    after the first key: ``(plain, live)``. Key tiles ``[0, plain)`` lie
+    wholly at or under the diagonal and need no mask, ``[plain, live)``
+    are crossed by it, and the rest hold no live element."""
+    plain = _at_most(_floor_tiles(gap + 1, tile_k), n_k)
+    live = _at_most(_floor_tiles(gap + tile_q - 1 + tile_k, tile_k), n_k)
+    return plain, live
+
+
+def _query_tiles(gap, tile_q, tile_k, n_q):
+    """For a tile of keys whose first key lies ``gap`` positions after
+    the first query row: ``(first, plain)``. Query tiles ``[first,
+    plain)`` are crossed by the diagonal, ``[plain, n_q)`` lie wholly at
+    or under it, and those before ``first`` hold no live element."""
+    first = _at_most(_floor_tiles(gap, tile_q), n_q)
+    plain = _at_most(
+        _floor_tiles(gap + tile_k - 1 + tile_q - 1, tile_q), n_q)
+    return first, plain
+
+
+def live_tile_share(kind, q_seq, kv_seq, causal, tile) -> float:
+    """Tiles of the score matrix a kernel runs over the tiles of the
+    whole square, at offsets 0. ``kind`` is ``"fwd"``, ``"dq"`` or
+    ``"dkv"``; ``tile`` a side or ``(tile_q, tile_k)``. The kernels take
+    their loop bounds from the same two functions."""
+    if not causal:
+        return 1.0
+    tile_q, tile_k = (tile, tile) if isinstance(tile, int) else tile
+    n_q, n_k = q_seq // tile_q, kv_seq // tile_k
+    if kind == "dkv":
+        run = sum(n_q - _query_tiles(c * tile_k, tile_q, tile_k, n_q)[0]
+                  for c in range(n_k))
+    else:
+        run = sum(_key_tiles(r * tile_q, tile_q, tile_k, n_k)[1]
+                  for r in range(n_q))
+    return run / (n_q * n_k)
+
+
+def _on_rungs(lo, hi, n, tile, ride, body):
+    """``body(lo, hi)`` with static ints, under the condition that they
+    are the ones to run. ``lo``/``hi`` are ``_key_tiles``' pair (``ride ==
+    "q"``: tiles ``[0, lo)`` run plain and ``[lo, hi)`` masked) or
+    ``_query_tiles``' pair (``ride == "k"``: ``[lo, hi)`` masked, ``[hi,
+    n)`` plain); ``tile`` is ``(riding, resident)``. Python ints are the
+    one pair, which holds for the riding side's one block. Traced ones
+    give a ladder of rungs, one for each number of tiles that run: a rung
+    masks the ``reach`` tiles at the diagonal's end of its range (no more can be crossed
+    inside one riding tile, and the mask leaves a tile that is not crossed
+    as it is), and one more rung runs the whole range plain where every
+    key lies in every row's past (most of a ring's steps)."""
+    if isinstance(lo, int) and isinstance(hi, int):
+        # always true on the one-block grid. It also keeps the body
+        # inside a ``cond`` like every rung's: under ``shard_map`` the CPU
+        # interpreter (jax 0.9.0) refuses a kernel's top-level loads,
+        # whose constant indices do not vary as the blocks do
+        rungs = [(pl.program_id(2) == 0, lo, hi)]
+    else:
+        reach = -(-tile[0] // tile[1]) + 1
+        if ride == "q":
+            rungs = [(jnp.logical_and(hi == e, lo < n), max(e - reach, 0), e)
+                     for e in range(n + 1)] + [(lo >= n, n, n)]
+        else:
+            rungs = [(jnp.logical_and(lo == e, hi > 0), e, min(e + reach, n))
+                     for e in range(n + 1)] + [(hi <= 0, 0, 0)]
+    for when, lo, hi in rungs:
+        pl.when(when)(functools.partial(body, lo, hi))
+
+
+def _key_pieces(plain, live, tile_k, gap):
+    """``[(columns, mask_from)]`` for a tile of query rows (``_key_tiles``'
+    static pair): the plain key tiles as one piece with no mask, each
+    crossed one with the ``_row_less_col`` value its live elements start
+    from."""
+    pieces = [(pl.ds(0, plain * tile_k), None)] if plain else []
+    return pieces + [(pl.ds(j * tile_k, tile_k), j * tile_k - gap)
+                     for j in range(plain, live)]
+
+
+def _row_less_col(tile_q, tile_k):
+    """Row index less column index over a tile. A tile whose first key
+    lies ``g`` positions after its first query row keeps the elements
+    where this is ``>= g``."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 1))
+
+
+def _first_positions(q_off_ref, k_off_ref, ride, block, offsets_zero):
+    """Global positions of this grid step's first query row and first
+    key. ``ride`` names the side the grid's last axis walks in blocks of
+    ``block`` rows; the other side is resident. Python zeros where the
+    caller's offsets were and the grid has one block."""
+    if offsets_zero:
+        return 0, 0
+    step = pl.program_id(2) * block
+    q_first, k_first = q_off_ref[0], k_off_ref[0]
+    return (q_first + step, k_first) if ride == "q" else (
+        q_first, k_first + step)
+
+
+def _fwd_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
+                       lse_ref, *, sm_scale, block_q, tile, offsets_zero):
+    """Causal forward with the whole key sequence resident. Each
+    ``tile_q`` rows of the q block take a direct softmax over the keys
+    their rows reach: the tiles wholly under the diagonal as one product,
+    those it crosses tile by tile under the mask. Rows no key reaches (the
+    ring's future shard) get zeros and ``lse = -inf``."""
+    tile_q, tile_k = tile
+    n_k = k_ref.shape[2] // tile_k
+    q_first, k_first = _first_positions(
+        q_off_ref, k_off_ref, "q", block_q, offsets_zero)
+    row_less_col = _row_less_col(tile_q, tile_k)
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+
+    for r in range(block_q // tile_q):
+        rows = pl.ds(r * tile_q, tile_q)
+        gap = q_first + r * tile_q - k_first
+
+        def run(plain, live, rows=rows, gap=gap):
+            if not live:
+                o_ref[0, 0, rows, :] = jnp.zeros((tile_q, o_ref.shape[3]),
+                                                 o_ref.dtype)
+                lse_ref[0, 0, rows, :] = jnp.full((tile_q, LANES), NEG_INF,
+                                                  jnp.float32)
+                return
+            q = q_ref[0, 0, rows, :].astype(jnp.float32) * (
+                sm_scale * LOG2E)
+            pieces = _key_pieces(plain, live, tile_k, gap)
+            scores = []
+            for cols, mask_from in pieces:
+                s = dot(q, k_ref[0, 0, cols, :].astype(jnp.float32),
+                        (((1,), (1,)), ((), ())))
+                if mask_from is not None:
+                    s = jnp.where(row_less_col >= mask_from, s, NEG_INF)
+                scores.append(s)
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(s, axis=-1, keepdims=True) for s in scores])
+            # rows no key reaches: m = -inf; shift by 0 so p is 0, not NaN
+            m_safe = jnp.where(m == NEG_INF, 0.0, m)
+            l = acc = 0.0
+            for s, (cols, _) in zip(scores, pieces):
+                p = jnp.exp2(s - m_safe)
+                l = l + jnp.sum(p, axis=-1, keepdims=True)
+                acc = acc + dot(p, v_ref[0, 0, cols, :].astype(jnp.float32),
+                                (((1,), (0,)), ((), ())))
+            empty = l == 0.0
+            l_safe = jnp.where(empty, 1.0, l)
+            o_ref[0, 0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
+            lse = jnp.where(empty, NEG_INF,
+                            m_safe * (1.0 / LOG2E) + jnp.log(l_safe))
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(lse, (tile_q, LANES))
+
+        plain, live = _key_tiles(gap, tile_q, tile_k, n_k)
+        _on_rungs(plain, live, n_k, (tile_q, tile_k), "q", run)
+
+
+def _bwd_tile(q, k, v, do, lse_safe, delta, mask_from, row_less_col,
+              sm_scale):
+    """``(p, ds)`` of one piece of the score matrix, recomputed from the
+    forward's row statistics. ``mask_from`` is ``None`` for a piece wholly
+    at or under the diagonal."""
+    s = (sm_scale * LOG2E) * jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if mask_from is not None:
+        s = jnp.where(row_less_col >= mask_from, s, NEG_INF)
+    p = jnp.exp2(s - lse_safe)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * sm_scale
+
+
+def _row_stats(lse_ref, delta_ref, rows):
+    """The rows' ``(lse, delta)`` as ``(rows, 1)`` columns, the first in
+    base 2. Rows that saw no key have ``lse = -inf`` and every ``s =
+    -inf``: shifting by 0 keeps ``exp2(s - lse)`` at 0 and not NaN."""
+    lse = lse_ref[0, 0, rows, :][:, :1]
+    delta = delta_ref[0, 0, rows, :][:, :1]
+    return jnp.where(lse == NEG_INF, 0.0, lse) * LOG2E, delta
+
+
+def _bwd_dq_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dq_ref, *, sm_scale, block_q,
+                          tile, offsets_zero):
+    """Causal dq with the whole key sequence resident: each ``tile_q``
+    rows sum in float32 over the keys their rows reach (the tiles wholly
+    under the diagonal as one piece, those it crosses tile by tile under
+    the mask) and write their block once."""
+    tile_q, tile_k = tile
+    n_k = k_ref.shape[2] // tile_k
+    q_first, k_first = _first_positions(
+        q_off_ref, k_off_ref, "q", block_q, offsets_zero)
+    row_less_col = _row_less_col(tile_q, tile_k)
+
+    for r in range(block_q // tile_q):
+        rows = pl.ds(r * tile_q, tile_q)
+        gap = q_first + r * tile_q - k_first
+
+        def run(plain, live, rows=rows, gap=gap):
+            q = q_ref[0, 0, rows, :].astype(jnp.float32)
+            do = do_ref[0, 0, rows, :].astype(jnp.float32)
+            lse_safe, delta = _row_stats(lse_ref, delta_ref, rows)
+            dq = jnp.zeros(q.shape, jnp.float32)
+            for cols, mask_from in _key_pieces(plain, live, tile_k, gap):
+                k = k_ref[0, 0, cols, :].astype(jnp.float32)
+                v = v_ref[0, 0, cols, :].astype(jnp.float32)
+                _, ds = _bwd_tile(q, k, v, do, lse_safe, delta, mask_from,
+                                  row_less_col, sm_scale)
+                dq = dq + jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+
+        plain, live = _key_tiles(gap, tile_q, tile_k, n_k)
+        _on_rungs(plain, live, n_k, (tile_q, tile_k), "q", run)
+
+
+def _bwd_dkv_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
+                           lse_ref, delta_ref, dk_ref, dv_ref, *, sm_scale,
+                           block_k, tile, offsets_zero):
+    """Causal dk/dv with q, do, lse and delta resident: each ``tile_k``
+    keys of the k block sum in float32 over the query rows from the
+    diagonal down (the tiles it crosses tile by tile under the mask, the
+    rows below them as one piece) and write their blocks once; keys in
+    every row's future get zeros."""
+    tile_q, tile_k = tile
+    n_q = q_ref.shape[2] // tile_q
+    q_first, k_first = _first_positions(
+        q_off_ref, k_off_ref, "k", block_k, offsets_zero)
+    row_less_col = _row_less_col(tile_q, tile_k)
+
+    for c in range(block_k // tile_k):
+        cols = pl.ds(c * tile_k, tile_k)
+        gap = k_first + c * tile_k - q_first
+
+        def run(first, plain, cols=cols, gap=gap):
+            k = k_ref[0, 0, cols, :].astype(jnp.float32)
+            v = v_ref[0, 0, cols, :].astype(jnp.float32)
+            dk = dv = jnp.zeros(k.shape, jnp.float32)
+            pieces = [(pl.ds(i * tile_q, tile_q), gap - i * tile_q)
+                      for i in range(first, plain)]
+            if plain < n_q:
+                pieces.append(
+                    (pl.ds(plain * tile_q, (n_q - plain) * tile_q), None))
+            for rows, mask_from in pieces:
+                q = q_ref[0, 0, rows, :].astype(jnp.float32)
+                do = do_ref[0, 0, rows, :].astype(jnp.float32)
+                lse_safe, delta = _row_stats(lse_ref, delta_ref, rows)
+                p, ds = _bwd_tile(q, k, v, do, lse_safe, delta, mask_from,
+                                  row_less_col, sm_scale)
+                dv = dv + jax.lax.dot_general(
+                    p, do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dk = dk + jax.lax.dot_general(
+                    ds, q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
+
+        first, plain = _query_tiles(gap, tile_q, tile_k, n_q)
+        _on_rungs(first, plain, n_q, (tile_k, tile_q), "k", run)
 
 
 # ---------------------------------------------------------------------------
@@ -157,86 +489,31 @@ def _fwd_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
-                       lse_ref, *, sm_scale, causal, block_q, block_k):
-    """Single-k-block forward: the whole key sequence is resident, so the
-    softmax is direct — no m/l/acc scratch, no revolving online-softmax
-    arithmetic, no @pl.when machinery. Measured r5 (B8 H16 S512 D64,
-    docs/perf_experiments.md): 0.130 ms/call vs 0.321 ms for the general
-    online-softmax kernel at the same shape — 2.5x — with the general
-    kernel already 2.8x faster than the stock pallas flash kernel and
-    1.3x faster than unfused XLA attention. The win is the removed
-    scratch traffic and per-block bookkeeping, NOT the MXU (a 2-head
-    128-deep-contraction packing variant measured the same 0.12 ms)."""
-    qi = pl.program_id(2)
-    q_start = q_off_ref[0] + qi * block_q
-    k_start = k_off_ref[0]
-    last_q = q_start + block_q - 1
-
-    def compute(bk):
-        # bk: static k extent — the causal wedge passes block_k//2 so
-        # q blocks whose rows never see the upper half of the keys skip
-        # half the dots and half the softmax arithmetic
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * (sm_scale * LOG2E)
-        k = k_ref[0, 0, :bk, :].astype(jnp.float32)
-        v = v_ref[0, 0, :bk, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            q_ids = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            k_ids = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        m = jnp.max(s, axis=-1)
-        # fully-masked rows: m = -inf; shift by 0 so p is 0, not NaN
-        m_safe = jnp.where(m == NEG_INF, 0.0, m)
-        p = jnp.exp2(s - m_safe[:, None])
-        l = jnp.sum(p, axis=-1)
-        empty = l == 0.0
-        l_safe = jnp.where(empty, 1.0, l)
-        o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        o_ref[0, 0, :, :] = (o / l_safe[:, None]).astype(o_ref.dtype)
-        lse = jnp.where(empty, NEG_INF,
-                        m_safe * (1.0 / LOG2E) + jnp.log(l_safe))
-        lse_ref[0, 0, :, :] = jax.lax.broadcast_in_dim(
-            lse, (block_q, LANES), (0,))
-
-    if causal:
-        # kv shards entirely in this q block's future are no-ops — the
-        # ring-attention contract (parallel/ring.py: causal ring does
-        # ~half the FLOPs because future shards self-skip). Offsets are
-        # dynamic scalars, so predicate rather than prune the grid.
-        relevant = k_start <= last_q
-        half = block_k // 2
-        if half and block_k % 2 == 0 and half % 128 == 0:
-            # causal wedge: rows that never reach the keys' upper half
-            # run the half-extent body — for in-model causal attention
-            # (offsets 0) the first half of the q blocks take this
-            # branch, cutting ~25% of the attention MACs and softmax
-            # arithmetic overall
-            needs_hi = last_q >= k_start + half
-
-            @pl.when(needs_hi)
-            def _():
-                compute(block_k)
-
-            @pl.when(jnp.logical_and(relevant,
-                                     jnp.logical_not(needs_hi)))
-            def _():
-                compute(half)
-        else:
-            @pl.when(relevant)
-            def _():
-                compute(block_k)
-
-        @pl.when(jnp.logical_not(relevant))
-        def _():
-            o_ref[0, 0, :, :] = jnp.zeros_like(o_ref[0, 0, :, :])
-            lse_ref[0, 0, :, :] = jnp.full_like(lse_ref[0, 0, :, :],
-                                                NEG_INF)
-    else:
-        compute(block_k)
+                       lse_ref, *, sm_scale, block_q):
+    """Non-causal forward with the whole key sequence resident: the
+    softmax is direct, with no m/l/acc scratch and no online rescaling.
+    This is BERT-Large's S=512 body (both ``bertl-train`` cells): the
+    three non-causal bodies together read 33.8% of their roofline there
+    (ledger, PR 41; my chip runs, PR 42)."""
+    q = q_ref[0, 0, :, :].astype(jnp.float32) * (sm_scale * LOG2E)
+    k = k_ref[0, 0, :, :].astype(jnp.float32)
+    v = v_ref[0, 0, :, :].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    m = jnp.max(s, axis=-1)
+    # fully-masked rows: m = -inf; shift by 0 so p is 0, not NaN
+    m_safe = jnp.where(m == NEG_INF, 0.0, m)
+    p = jnp.exp2(s - m_safe[:, None])
+    l = jnp.sum(p, axis=-1)
+    empty = l == 0.0
+    l_safe = jnp.where(empty, 1.0, l)
+    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    o_ref[0, 0, :, :] = (o / l_safe[:, None]).astype(o_ref.dtype)
+    lse = jnp.where(empty, NEG_INF,
+                    m_safe * (1.0 / LOG2E) + jnp.log(l_safe))
+    lse_ref[0, 0, :, :] = jax.lax.broadcast_in_dim(
+        lse, (block_q, LANES), (0,))
 
 
 def _single_specs(block_q, block_k, dim, ride):
@@ -269,40 +546,76 @@ def _make_specs(block_q, block_k, dim):
 _OFF_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def _causal_plan(ride, ride_seq, ride_block, resident, offsets_zero):
+    """How a causal kernel with ``resident`` rows of one side in VMEM walks
+    the other (``ride``: ``"q"`` or ``"k"``, ``ride_seq`` long): ``(block,
+    (tile_q, tile_k), static)``. ``static``: the offsets are Python zeros
+    and the riding side is one block, so the kernel is built for the one
+    schedule; else its rungs choose by the offsets and the block's id.
+    The tile is the first of ``_TILE_SIDES`` that divides both sides (and
+    keeps a ladder within ``_MAX_RUNGS``), else the whole of each."""
+    static = offsets_zero and ride_seq <= _WHOLE_ROWS
+    block = ride_seq if static else ride_block
+    tile = block, resident
+    for side in _TILE_SIDES:
+        if block % side == 0 and resident % side == 0 and (
+                static or max(block, resident) // side <= _MAX_RUNGS):
+            tile = side, side
+            break
+    return block, tile if ride == "q" else tile[::-1], static
+
+
+def _record_share(kind, q_seq, kv_seq, causal, tile):
+    """At trace time: the share of the square this call's kernel runs."""
+    _LIVE_TILE_SHARE[kind].set(
+        live_tile_share(kind, q_seq, kv_seq, causal, tile))
+
+
 def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
-               block_q, block_k, interpret):
+               block_q, block_k, interpret, offsets_zero=False):
     batch, heads, q_seq, dim = q.shape
     kv_seq = k.shape[2]
     block_q = _pick_block(q_seq, block_q)
     block_k = _pick_block(kv_seq, block_k)
-    grid = (batch, heads, q_seq // block_q, kv_seq // block_k)
-    q_spec, k_spec, qrow_spec = _make_specs(block_q, block_k, dim)
     vma = _vma(q, k, v, q_offset, k_offset)
+    out_shape = [
+        jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+        # lse lane-broadcast: (B, H, S, LANES)
+        jax.ShapeDtypeStruct((batch, heads, q_seq, LANES), jnp.float32,
+                             vma=vma),
+    ]
 
     if kv_seq == block_k:
-        # whole key sequence in one block: direct softmax, no scratch
-        # (see _fwd_single_kernel — measured 2.5x at the bench shapes)
+        # whole key sequence resident: one grid step a (batch, head, q
+        # block), no scratch
+        tile = None
+        if causal:
+            block_q, tile, static = _causal_plan(
+                "q", q_seq, block_q, kv_seq, offsets_zero)
+            kernel = functools.partial(
+                _fwd_causal_kernel, sm_scale=sm_scale, block_q=block_q,
+                tile=tile, offsets_zero=static)
+        else:
+            kernel = functools.partial(
+                _fwd_single_kernel, sm_scale=sm_scale, block_q=block_q)
+        _record_share("fwd", q_seq, kv_seq, causal, tile)
         sq_spec, sk_spec, srow_spec = _single_specs(
             block_q, block_k, dim, ride="q")
-        o, lse = pl.pallas_call(
-            functools.partial(
-                _fwd_single_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k),
-            grid=grid[:3],
+        return pl.pallas_call(
+            kernel,
+            grid=(batch, heads, q_seq // block_q),
             in_specs=[_OFF_SPEC, _OFF_SPEC, sq_spec, sk_spec, sk_spec],
             out_specs=[sq_spec, srow_spec],
-            out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
-                jax.ShapeDtypeStruct((batch, heads, q_seq, LANES),
-                                     jnp.float32, vma=vma),
-            ],
+            out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",) * 3),
             interpret=interpret,
             name="flash_fwd",
         )(q_offset, k_offset, q, k, v)
-        return o, lse
 
+    _record_share("fwd", q_seq, kv_seq, causal, (block_q, block_k))
+    grid = (batch, heads, q_seq // block_q, kv_seq // block_k)
+    q_spec, k_spec, qrow_spec = _make_specs(block_q, block_k, dim)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k)
@@ -312,11 +625,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
         grid=grid,
         in_specs=[_OFF_SPEC, _OFF_SPEC, q_spec, k_spec, k_spec],
         out_specs=[q_spec, qrow_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((batch, heads, q_seq, LANES), jnp.float32,
-                                 vma=vma),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, dim), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
@@ -326,7 +635,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
         interpret=interpret,
         name="flash_fwd",
     )(q_offset, k_offset, q, k, v)
-    return o, lse  # lse lane-broadcast: (B, H, S, LANES)
+    return o, lse
 
 
 # ---------------------------------------------------------------------------
@@ -456,127 +765,58 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _bwd_dq_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
-                          do_ref, lse_ref, delta_ref, dq_ref,
-                          *, sm_scale, causal, block_q, block_k):
-    """Single-k-block dq: the general kernel's accumulator scratch and
-    per-k-block @pl.when machinery removed (same specialization as
-    _fwd_single_kernel), with the causal wedge — q blocks whose rows
-    never reach the keys' upper half run half-extent dots."""
-    qi = pl.program_id(2)
-    q_start = q_off_ref[0] + qi * block_q
-    k_start = k_off_ref[0]
-    last_q = q_start + block_q - 1
-
-    def compute(bk):
-        cast = lambda r, n: r[0, 0, :n, :].astype(jnp.float32)
-        q = cast(q_ref, block_q)
-        do = cast(do_ref, block_q)
-        k = cast(k_ref, bk)
-        v = cast(v_ref, bk)
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
-        lse_safe = jnp.where(lse == NEG_INF, 0.0, lse) * LOG2E
-        s = (sm_scale * LOG2E) * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            q_ids = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            k_ids = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        p = jnp.exp2(s - lse_safe[:, None])
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq_ref[0, 0, :, :] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-
-    if causal:
-        relevant = k_start <= last_q
-        half = block_k // 2
-        if half and block_k % 2 == 0 and half % 128 == 0:
-            needs_hi = last_q >= k_start + half
-
-            @pl.when(needs_hi)
-            def _():
-                compute(block_k)
-
-            @pl.when(jnp.logical_and(relevant,
-                                     jnp.logical_not(needs_hi)))
-            def _():
-                compute(half)
-        else:
-            @pl.when(relevant)
-            def _():
-                compute(block_k)
-
-        @pl.when(jnp.logical_not(relevant))
-        def _():
-            dq_ref[0, 0, :, :] = jnp.zeros_like(dq_ref[0, 0, :, :])
-    else:
-        compute(block_k)
+                          do_ref, lse_ref, delta_ref, dq_ref, *, sm_scale):
+    """Non-causal dq with the whole key sequence resident: no
+    accumulator scratch, one pass (BERT-Large's body; see
+    ``_fwd_single_kernel``)."""
+    cast = lambda r: r[0, 0, :, :].astype(jnp.float32)
+    q = cast(q_ref)
+    do = cast(do_ref)
+    k = cast(k_ref)
+    v = cast(v_ref)
+    lse = lse_ref[0, 0, :, 0]
+    delta = delta_ref[0, 0, :, 0]
+    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse) * LOG2E
+    s = (sm_scale * LOG2E) * jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    p = jnp.exp2(s - lse_safe[:, None])
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta[:, None]) * sm_scale
+    dq_ref[0, 0, :, :] = jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_single_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref,
                            do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                           *, sm_scale, causal, block_q, block_k):
-    """Single-q-block dk/dv: scratch-free like _bwd_dq_single_kernel.
-    (No wedge here — the causal cut for dk/dv runs along k COLUMNS,
-    which does not map to a uniform static extent slice of the q
-    operand.)"""
-    ki = pl.program_id(2)
-    k_start = k_off_ref[0] + ki * block_k
-    q_start = q_off_ref[0]
-    last_q = q_start + block_q - 1
-
-    def compute():
-        cast = lambda r: r[0, 0, :, :].astype(jnp.float32)
-        q = cast(q_ref)
-        k = cast(k_ref)
-        v = cast(v_ref)
-        do = cast(do_ref)
-        lse = lse_ref[0, 0, :, 0]
-        delta = delta_ref[0, 0, :, 0]
-        lse_safe = jnp.where(lse == NEG_INF, 0.0, lse) * LOG2E
-        s = (sm_scale * LOG2E) * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            q_ids = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_ids = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        p = jnp.exp2(s - lse_safe[:, None])
-        dv_ref[0, 0, :, :] = jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dk_ref[0, 0, :, :] = jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-
-    if causal:
-        # a kv shard entirely in the future of every q row gets no
-        # gradient (ring contract, mirror of the forward predication)
-        relevant = k_start <= last_q
-
-        @pl.when(relevant)
-        def _():
-            compute()
-
-        @pl.when(jnp.logical_not(relevant))
-        def _():
-            dk_ref[0, 0, :, :] = jnp.zeros_like(dk_ref[0, 0, :, :])
-            dv_ref[0, 0, :, :] = jnp.zeros_like(dv_ref[0, 0, :, :])
-    else:
-        compute()
+                           *, sm_scale):
+    """Non-causal dk/dv with the whole query sequence resident:
+    scratch-free like ``_bwd_dq_single_kernel``."""
+    cast = lambda r: r[0, 0, :, :].astype(jnp.float32)
+    q = cast(q_ref)
+    k = cast(k_ref)
+    v = cast(v_ref)
+    do = cast(do_ref)
+    lse = lse_ref[0, 0, :, 0]
+    delta = delta_ref[0, 0, :, 0]
+    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse) * LOG2E
+    s = (sm_scale * LOG2E) * jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    p = jnp.exp2(s - lse_safe[:, None])
+    dv_ref[0, 0, :, :] = jax.lax.dot_general(
+        p, do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta[:, None]) * sm_scale
+    dk_ref[0, 0, :, :] = jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
 
 
 def compute_delta(o, do) -> jax.Array:
@@ -589,18 +829,11 @@ def compute_delta(o, do) -> jax.Array:
 
 
 def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
-               block_q, block_k, interpret, delta=None):
+               block_q, block_k, interpret, delta=None, offsets_zero=False):
     batch, heads, q_seq, dim = q.shape
     kv_seq = k.shape[2]
     block_q = _pick_block(q_seq, block_q)
     block_k = _pick_block(kv_seq, block_k)
-    if (causal and kv_seq == block_k and block_q == q_seq
-            and q_seq >= 1024 and (q_seq // 2) % 128 == 0):
-        # single-k-block causal: two q blocks let the dq wedge skip the
-        # first block's upper-half dots (measured r5 at the GPT-2
-        # shape: fwd+bwd 1.697 -> 1.555 ms, incl. the dkv kernel
-        # falling back to the general path).
-        block_q = q_seq // 2
 
     if delta is None:
         delta = compute_delta(o, do)
@@ -608,37 +841,58 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
     q_spec, k_spec, qrow_spec = _make_specs(block_q, block_k, dim)
 
     vma = _vma(q, k, v, do, q_offset, k_offset)
+    single = pltpu.CompilerParams(dimension_semantics=("parallel",) * 3)
 
     if kv_seq == block_k:
-        # scratch-free single-k-block dq (with causal wedge), any nq
+        # whole key sequence resident: scratch-free dq, any number of q
+        # blocks
+        ride, dq_tile = block_q, None
+        if causal:
+            ride, dq_tile, static = _causal_plan(
+                "q", q_seq, block_q, kv_seq, offsets_zero)
+            kernel = functools.partial(
+                _bwd_dq_causal_kernel, sm_scale=sm_scale, block_q=ride,
+                tile=dq_tile, offsets_zero=static)
+        else:
+            kernel = functools.partial(_bwd_dq_single_kernel,
+                                       sm_scale=sm_scale)
+        _record_share("dq", q_seq, kv_seq, causal, dq_tile)
         sq_spec, sk_spec, srow_spec = _single_specs(
-            block_q, block_k, dim, ride="q")
+            ride, block_k, dim, ride="q")
         dq = pl.pallas_call(
-            functools.partial(
-                _bwd_dq_single_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k),
-            grid=(batch, heads, q_seq // block_q),
+            kernel,
+            grid=(batch, heads, q_seq // ride),
             in_specs=[_OFF_SPEC, _OFF_SPEC, sq_spec, sk_spec, sk_spec,
                       sq_spec, srow_spec, srow_spec],
             out_specs=sq_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",) * 3),
+            compiler_params=single,
             interpret=interpret,
             name="flash_dq",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
     else:
+        _record_share("dq", q_seq, kv_seq, causal, (block_q, block_k))
         dq = None
 
     if q_seq == block_q:
-        # scratch-free single-q-block dk/dv, any nk
+        # whole query sequence (q, do, lse, delta) resident: scratch-free
+        # dk/dv, any number of k blocks
+        ride, dkv_tile = block_k, None
+        if causal:
+            ride, dkv_tile, static = _causal_plan(
+                "k", kv_seq, block_k, q_seq, offsets_zero)
+            kernel = functools.partial(
+                _bwd_dkv_causal_kernel, sm_scale=sm_scale, block_k=ride,
+                tile=dkv_tile, offsets_zero=static)
+        else:
+            kernel = functools.partial(_bwd_dkv_single_kernel,
+                                       sm_scale=sm_scale)
+        _record_share("dkv", q_seq, kv_seq, causal, dkv_tile)
         gq_spec, gk_spec, grow_spec = _single_specs(
-            block_q, block_k, dim, ride="k")
+            block_q, ride, dim, ride="k")
         dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_dkv_single_kernel, sm_scale=sm_scale,
-                causal=causal, block_q=block_q, block_k=block_k),
-            grid=(batch, heads, kv_seq // block_k),
+            kernel,
+            grid=(batch, heads, kv_seq // ride),
             in_specs=[_OFF_SPEC, _OFF_SPEC, gq_spec, gk_spec, gk_spec,
                       gq_spec, grow_spec, grow_spec],
             out_specs=[gk_spec, gk_spec],
@@ -646,12 +900,12 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
                 jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
                 jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
             ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",) * 3),
+            compiler_params=single,
             interpret=interpret,
             name="flash_dkv",
         )(q_offset, k_offset, q, k, v, do, lse, delta)
     else:
+        _record_share("dkv", q_seq, kv_seq, causal, (block_q, block_k))
         dk = dv = None
 
     if dq is None:
@@ -711,30 +965,31 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, q_offset, k_offset, sm_scale, causal, block_q, block_k,
-           bwd_block_q, bwd_block_k):
+           bwd_block_q, bwd_block_k, offsets_zero):
     o, _ = _flash_fwd(q, k, v, q_offset, k_offset, sm_scale=sm_scale,
                       causal=causal, block_q=block_q, block_k=block_k,
-                      interpret=use_interpret())
+                      interpret=use_interpret(), offsets_zero=offsets_zero)
     return o
 
 
 def _flash_vjp_fwd(q, k, v, q_offset, k_offset, sm_scale, causal,
-                   block_q, block_k, bwd_block_q, bwd_block_k):
+                   block_q, block_k, bwd_block_q, bwd_block_k, offsets_zero):
     o, lse = _flash_fwd(q, k, v, q_offset, k_offset, sm_scale=sm_scale,
                         causal=causal, block_q=block_q, block_k=block_k,
-                        interpret=use_interpret())
+                        interpret=use_interpret(), offsets_zero=offsets_zero)
     return o, (q, k, v, o, lse, q_offset, k_offset)
 
 
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, bwd_block_q,
-                   bwd_block_k, res, do):
+                   bwd_block_k, offsets_zero, res, do):
     q, k, v, o, lse, q_offset, k_offset = res
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset,
                             sm_scale=sm_scale, causal=causal,
                             block_q=bwd_block_q, block_k=bwd_block_k,
-                            interpret=use_interpret())
+                            interpret=use_interpret(),
+                            offsets_zero=offsets_zero)
     zero = jnp.zeros((1,), jnp.int32)
     return dq, dk, dv, zero, zero
 
@@ -744,6 +999,12 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def _as_offset(x) -> jax.Array:
     return jnp.asarray(x, jnp.int32).reshape((1,))
+
+
+def _python_zeros(*offsets) -> bool:
+    """Whether every offset is the Python number 0 at trace time (the
+    in-model call), as against a traced or nonzero position (the ring)."""
+    return all(isinstance(x, (int, np.integer)) and x == 0 for x in offsets)
 
 
 def flash_attention(
@@ -767,9 +1028,11 @@ def flash_attention(
     sequence shard and the causal mask depends on global, not local, indices.
     They may be traced scalars (e.g. derived from ``lax.axis_index``).
 
-    Block-size defaults are tuned on v5e (head_dim 128): the forward prefers
-    tall k blocks, the backward square 1024 blocks. Sequences shorter than a
-    block fall back to the largest divisor automatically.
+    A key sequence no longer than ``block_k`` (a query sequence no longer
+    than ``bwd_block_q`` for dk/dv) stays resident and the kernel takes one
+    grid step a (batch, head, block); longer ones take the general
+    online-softmax kernels block by block. Sequences shorter than a block
+    fall back to the largest divisor automatically.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError("flash_attention expects (batch, heads, seq, dim)")
@@ -777,7 +1040,8 @@ def flash_attention(
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     return _flash(q, k, v, _as_offset(q_offset), _as_offset(k_offset),
                   float(sm_scale), bool(causal), int(block_q), int(block_k),
-                  int(bwd_block_q), int(bwd_block_k))
+                  int(bwd_block_q), int(bwd_block_k),
+                  _python_zeros(q_offset, k_offset))
 
 
 def flash_attention_partial(
@@ -796,7 +1060,8 @@ def flash_attention_partial(
     o, lse = _flash_fwd(q, k, v, _as_offset(q_offset), _as_offset(k_offset),
                         sm_scale=float(sm_scale), causal=bool(causal),
                         block_q=int(block_q), block_k=int(block_k),
-                        interpret=use_interpret())
+                        interpret=use_interpret(),
+                        offsets_zero=_python_zeros(q_offset, k_offset))
     return o, lse[..., 0]
 
 

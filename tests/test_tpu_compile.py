@@ -75,18 +75,21 @@ def _kernels_in(fn, chip, *specs):
     return text.count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("shape,causal", [
-    ((16, 12, 1024, 64), True),    # GPT-2-small bench: batch 16, seq 1024
-    ((8, 16, 512, 64), False),     # BERT-Large bench: batch 8, seq 512
-], ids=["gpt2", "bert-large"])
-def test_flash_attention_forward_and_backward(chip, shape, causal):
-    def loss(q, k, v):
-        return flash_attention(q, k, v, causal=causal).astype(F32).sum()
+@pytest.mark.parametrize("shape,causal,traced", [
+    ((16, 12, 1024, 64), True, False),   # GPT-2-small bench: batch 16, seq 1024
+    ((8, 16, 512, 64), False, False),    # BERT-Large bench: batch 8, seq 512
+    # the ring's call: traced offsets, the causal kernels' ladder of rungs
+    ((2, 12, 1024, 64), True, True),
+], ids=["gpt2", "bert-large", "gpt2-traced-offsets"])
+def test_flash_attention_forward_and_backward(chip, shape, causal, traced):
+    def loss(q, k, v, at=0):
+        return flash_attention(q, k, v, causal=causal, q_offset=at,
+                               k_offset=at).astype(F32).sum()
 
     spec = (shape, BF16)
     # forward (residual-saving) + the dq and dk/dv backward kernels
     assert _kernels_in(jax.value_and_grad(loss, argnums=(0, 1, 2)), chip,
-                       spec, spec, spec) >= 3
+                       spec, spec, spec, *[((), jnp.int32)] * traced) >= 3
 
 
 def _inception_bn_act_shapes():
