@@ -13,7 +13,9 @@ to 50304), random weights from ``--seed``, through the public entry
 points. ``hvd.init()``; three AdamW steps of the jitted
 ``training.make_train_step`` program at bf16 / sequence 1024 / batch 16;
 then the same parameters behind ``hvd.serve()`` at 512 positions, dense
-engine and paged engine. Every phase checks what it produced and any
+engine and paged engine; then a block-sparse layer beside a lightning
+layer (``models/hybrid.py``) behind the same call, a 10,000-token prompt
+through the sparse prompt kernel. Every phase checks what it produced and any
 failure exits non-zero with no result line. Without an accelerator (and
 without ``--rehearse``) that is a failure too. The times and bytes
 printed on the way are facts of this run on the device named beside
@@ -54,14 +56,28 @@ FULL = dict(
     model=dict(vocab_size=50304, d_model=768, num_layers=12, num_heads=12,
                d_ff=3072),
     seq=1024, batch=16, serve_positions=512, new_tokens=32,
-    prompt_ids=(40, 100), prompt_chars=(11, 52))
+    prompt_ids=(40, 100), prompt_chars=(11, 52),
+    # a block-sparse layer (MiniCPM4's sizes) beside a lightning layer,
+    # heads of 128 sixteen to two key/value heads: one prompt past
+    # dense_len in the 16,384 bucket, one under it
+    hybrid=dict(vocab_size=512, d_model=1024, d_ff=2048, num_heads=16,
+                num_kv_heads=2, head_dim=128, max_seq=16384,
+                sparse=dict(kernel=32, stride=16, block_size=64, topk=64,
+                            init_blocks=1, window_size=2048,
+                            dense_len=8192)),
+    hybrid_prompts=(10000, 300))
 # --rehearse: same code path, toy widths (head_dim stays 64 so the flash
 # kernel's block logic is the real one, in interpret mode)
 TINY = dict(
     model=dict(vocab_size=512, d_model=128, num_layers=2, num_heads=2,
                d_ff=256),
     seq=128, batch=4, serve_positions=64, new_tokens=6,
-    prompt_ids=(20, 40), prompt_chars=(5, 30))
+    prompt_ids=(20, 40), prompt_chars=(5, 30),
+    hybrid=dict(vocab_size=512, d_model=64, d_ff=128, num_heads=4,
+                num_kv_heads=2, head_dim=16, max_seq=512,
+                sparse=dict(kernel=8, stride=4, block_size=16, topk=6,
+                            init_blocks=1, window_size=32, dense_len=128)),
+    hybrid_prompts=(300, 60))
 
 
 def say(msg: str) -> None:
@@ -305,6 +321,63 @@ def serve(hvd, cfg, params, seed) -> None:
         f"({sum(map(len, dense))} tokens)")
 
 
+def serve_hybrid(hvd, cfg, seed) -> None:
+    """A block-sparse layer beside a lightning layer behind
+    ``hvd.serve()``: a prompt past ``dense_len`` and one under it, greedy
+    output the same twice, the prompt kernel in the prefill programs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.metrics import registry
+    from horovod_tpu.models.hybrid import (BLOCK_SPARSE, LIGHTNING,
+                                           HybridDecoder)
+
+    model = HybridDecoder(mixers=(BLOCK_SPARSE, LIGHTNING),
+                          param_dtype=jnp.bfloat16, **cfg["hybrid"])
+    params = jax.jit(lambda key: model.init(
+        key, jnp.zeros((1, 8), jnp.int32))["params"])(
+            jax.random.PRNGKey(seed))
+    rng = np.random.default_rng(seed + 2)
+    prompts = [rng.integers(1, model.vocab_size, n).tolist()
+               for n in cfg["hybrid_prompts"]]
+    n_new = cfg["new_tokens"]
+    t0 = time.perf_counter()
+    # admission bounded by the slots, not by committed tokens: the
+    # default budget (4,096) never admits the long prompt
+    handle = hvd.serve(model, params, tokenizer=ByteTokenizer(), replicas=1,
+                       max_new_tokens=n_new, slots=2,
+                       max_batch_tokens=2 * model.max_seq)
+    try:
+        outs = [handle.generate(p, timeout=300.0) for p in prompts]
+        again = handle.generate(prompts[0], timeout=300.0)
+        seconds = time.perf_counter() - t0
+        if [len(o.tokens) for o in outs] != [n_new] * len(outs):
+            raise AssertionError(
+                f"serve[hybrid]: {[len(o.tokens) for o in outs]} tokens, "
+                f"wanted {n_new} each")
+        if again.tokens != outs[0].tokens:
+            raise AssertionError(
+                f"serve[hybrid]: greedy output changed between two "
+                f"submissions of one prompt: {outs[0].tokens} then "
+                f"{again.tokens}")
+        engine = handle.stats()["replicas"][0]["engine"]
+        if engine["prefill_sparse_kernel"] is not True:
+            raise AssertionError(
+                "serve[hybrid]: the prefill programs hold no "
+                f"sparse_prompt_attention kernel: {engine}")
+        share = registry().snapshot()["sparse.live_block_share"][
+            "values"][0]["value"]
+        say(f"serve[hybrid]: prompts {[o.prompt_len for o in outs]} x "
+            f"{n_new} new tokens and the first again in {seconds:.2f} s "
+            f"incl. {engine['compiles_total']} compiles; "
+            f"decode_write_fused {engine['decode_write_fused']}, "
+            f"prefill_sparse_kernel {engine['prefill_sparse_kernel']}, "
+            f"sparse.live_block_share {share:.4f}")
+    finally:
+        handle.close()
+
+
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
@@ -357,6 +430,7 @@ def phase_main(args, cfg) -> dict:
                           expect_kernels=not args.rehearse)
     report_training(device, losses, facts)
     serve(hvd, cfg, facts.pop("params"), args.seed)
+    serve_hybrid(hvd, cfg, args.seed)
     hvd.shutdown()
     say(f"compile cache: {counts['cache_hits']} hits, "
         f"{counts['cache_misses']} misses this run")

@@ -79,11 +79,13 @@ the state it leaves is the state after ``lengths`` tokens (a
 state-space layer's convolution tail the last true rows), and with
 ``lengths`` given the head runs on row ``lengths - 1`` alone.
 
-The sparse layer computes masked dense attention in blocks of queries
-(each query's selected key blocks are a mask over all causal keys) and
-the lightning layer scans chunks, both in XLA; PERF.md says what that
-costs and what a kernel would save. A window layer's prompt is banded
-blocks in XLA (each block of ``window`` queries against its own keys and
+The sparse layer selects each query's key blocks in XLA, a block of
+queries at a time, and attends a prompt under that table of bits through
+one kernel (``ops/pallas/sparse_attention``: scores, mask and softmax
+never leave VMEM, nothing above the diagonal is computed); its decode
+step is masked dense attention over the row in XLA. The lightning layer
+scans chunks in XLA; PERF.md says what that costs. A window layer's
+prompt is banded blocks in XLA (each block of ``window`` queries against its own keys and
 the block's before it: work in ``seq x 2 window``, not ``seq^2``), a
 full layer's the flash kernel; a full layer's decode step reads its
 rows' live tiles through ``ops/pallas/grouped_decode_attention`` and a
@@ -110,7 +112,8 @@ import numpy as np
 
 from horovod_tpu.models.transformer import write_cache_rows
 from horovod_tpu.ops.pallas import (grouped_decode_attention,
-                                    latent_attention, power_retention)
+                                    latent_attention, power_retention,
+                                    sparse_attention)
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
 from horovod_tpu.ops.pallas.kv_cache_write import LANES, write_token
 
@@ -136,12 +139,12 @@ SIGMOID_ROUTER, SOFTMAX_ROUTER = "sigmoid", "softmax"
 # a masked score: finite, so that a row with nothing to see stays a number
 NEG_INF = -1e30
 
-# queries a block of the sparse layer's prompt attention: the float32
-# scores of one block are (heads, QUERY_BLOCK, keys)
+# queries a block of the sparse layer's prompt selection: the float32
+# scores of one block are (heads, QUERY_BLOCK, key windows)
 QUERY_BLOCK = 128
 # the prompt's query blocks run in at most this many groups, each against
-# the keys up to its own end (static), so that about half of the causal
-# triangle's upper part is never computed
+# the key windows up to its own end (static), so that about half of the
+# windows after a query are never scored
 KEY_EXTENTS = 8
 # query blocks of a window layer's prompt that are scored at once: the
 # float32 scores of one turn are (heads, BAND_BLOCKS, block, 2 block)
@@ -671,71 +674,88 @@ def _token_mask(selected, q_pos, size, keys):
     return mask & (jnp.arange(keys, dtype=jnp.int32) <= q_pos[..., None])
 
 
+def prompt_block_choice(q, compressed, sparse, dtype):
+    """The key blocks every query of a prompt attends, as the table
+    ``ops/pallas/sparse_attention`` reads: (batch, kv_heads, seq, blocks)
+    int8, 1 where :func:`select_blocks` set the block (every causal
+    block at or under ``dense_len``).
+
+    ``q``: (batch, seq, kv_heads, heads of the group, d), ``seq`` a
+    multiple of ``QUERY_BLOCK`` or under it; ``compressed``:
+    :func:`compress_keys` of the same ``seq`` keys. A block of
+    ``QUERY_BLOCK`` queries at a time, each group of blocks against the
+    windows up to its own end."""
+    batch, seq, groups, per, d = q.shape
+    size, scale = sparse["block_size"], 1.0 / math.sqrt(d)
+    q_block = min(QUERY_BLOCK, seq)
+    blocks = seq // q_block
+    q = q.reshape(batch, blocks, q_block, groups, per, d)
+    extents = max(e for e in range(1, KEY_EXTENTS + 1) if blocks % e == 0)
+    each = blocks // extents
+    rows = []
+    for e in range(extents):
+        first = e * each * q_block
+        end = first + each * q_block          # keys this group can see
+        n_blocks = -(-end // size)
+        if end <= sparse["dense_len"] or n_blocks <= sparse["topk"]:
+            # every causal block, no scores
+            at = jnp.arange(first, end, dtype=jnp.int32)[:, None]
+            chosen = jnp.broadcast_to(
+                jnp.arange(n_blocks, dtype=jnp.int32) <= at // size,
+                (batch, groups, end - first, n_blocks)).astype(jnp.int8)
+        else:
+            kc_e = compressed[:, :count_windows(end, sparse)].astype(dtype)
+
+            def one(xs):  # mapped below, inside this turn of the loop
+                q_b, start = xs               # (batch, q_block, g, per, d)
+                q_pos = start + jnp.arange(q_block, dtype=jnp.int32)
+                q_pos = jnp.broadcast_to(q_pos, (batch, 1, q_block))
+                s = jnp.einsum("btgrd,bjgd->bgtrj", q_b, kc_e,
+                               preferred_element_type=F32) * scale
+                return select_blocks(
+                    block_scores(s, q_pos, n_blocks, sparse), q_pos,
+                    sparse).astype(jnp.int8)   # (batch, g, t, n_blocks)
+
+            chosen = jax.lax.map(one, (
+                q[:, e * each:(e + 1) * each].transpose(1, 0, 2, 3, 4, 5),
+                first + jnp.arange(each, dtype=jnp.int32) * q_block))
+            # (each, batch, g, q_block, blocks) -> (batch, g, positions,
+            # blocks)
+            chosen = chosen.transpose(1, 2, 0, 3, 4).reshape(
+                batch, groups, end - first, n_blocks)
+        rows.append(jnp.pad(chosen, ((0, 0), (0, 0), (0, 0),
+                                     (0, -(-seq // size) - n_blocks))))
+    return jnp.concatenate(rows, axis=2)
+
+
 def sparse_prompt_attention(q, k, v, sparse, dtype):
     """Block-sparse causal attention of a whole prompt from position 0.
 
     ``q``: (batch, seq, heads, d); ``k``/``v``: (batch, seq, kv_heads, d).
-    Returns (batch, seq, heads, d) in ``dtype`` and the compressed keys
-    (batch, windows, kv_heads, d) float32.
+    Returns (batch, seq, heads, d) in ``dtype``, the compressed keys
+    (batch, windows, kv_heads, d) float32 and the kernel's live share of
+    key blocks (a float32 scalar).
 
-    Masked dense attention a block of ``QUERY_BLOCK`` queries at a time:
-    each query's key blocks (:func:`select_blocks`) become a mask over
-    the keys up to the end of its group of blocks."""
+    Each query's key blocks (:func:`select_blocks`, in XLA) become a
+    table of bits, and one kernel (``ops/pallas/sparse_attention``)
+    computes the attention under it: any sequence length, padded here to
+    a multiple of ``QUERY_BLOCK``."""
     batch, seq, heads, d = q.shape
-    groups, size = k.shape[2], sparse["block_size"]
-    per = heads // groups
-    scale = 1.0 / math.sqrt(d)
-    q_block = min(QUERY_BLOCK, seq)
-    unit = q_block * size // math.gcd(q_block, size)
-    pad = -seq % unit
+    groups = k.shape[2]
+    pad = -seq % min(QUERY_BLOCK, seq)
     if pad:
         q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for t in (q, k, v))
-    total = seq + pad
     compressed = compress_keys(k, sparse["kernel"], sparse["stride"])
-    q = q.reshape(batch, total // q_block, q_block, groups, per, d)
-    blocks = total // q_block
-    extents = max(e for e in range(1, KEY_EXTENTS + 1) if blocks % e == 0)
-    each = blocks // extents
-    outs = []
-    for e in range(extents):
-        end = (e + 1) * each * q_block        # keys this group can see
-        n_blocks = -(-end // size)
-        k_e, v_e = k[:, :end], v[:, :end]
-        kc_e = compressed[:, :count_windows(end, sparse)].astype(dtype)
-        select = end > sparse["dense_len"] and n_blocks > sparse["topk"]
-
-        def one(xs):      # mapped below, inside this turn of the loop
-            q_b, start = xs                   # (batch, q_block, g, per, d)
-            q_pos = start + jnp.arange(q_block, dtype=jnp.int32)
-            q_pos = jnp.broadcast_to(q_pos, (batch, 1, q_block))
-            if select:
-                with jax.named_scope("sparse_select"):
-                    s = jnp.einsum("btgrd,bjgd->bgtrj", q_b, kc_e,
-                                   preferred_element_type=F32) * scale
-                    chosen = select_blocks(
-                        block_scores(s, q_pos, n_blocks, sparse), q_pos,
-                        sparse)                # (batch, g, t, n_blocks)
-            else:
-                chosen = jnp.ones((batch, 1, q_block, n_blocks), bool)
-            with jax.named_scope("sparse_attn"):
-                mask = _token_mask(chosen, q_pos, size, end)
-                s = jnp.einsum("btgrd,bsgd->bgrts", q_b, k_e,
-                               preferred_element_type=F32) * scale
-                s = jnp.where(mask[:, :, None], s, NEG_INF)
-                p = jax.nn.softmax(s, axis=-1).astype(dtype)
-                # heads before queries, as the product leaves them: the
-                # loop then stacks its blocks without a strided write
-                return jnp.einsum("bgrts,bsgd->bgrtd", p, v_e)
-
-        starts = (e * each + jnp.arange(each, dtype=jnp.int32)) * q_block
-        outs.append(jax.lax.map(
-            one, (q[:, e * each:(e + 1) * each].transpose(
-                1, 0, 2, 3, 4, 5), starts)))
-    # (blocks, batch, g, per, q_block, d) -> (batch, positions, heads, d)
-    out = jnp.concatenate(outs, axis=0).transpose(1, 0, 4, 2, 3, 5)
-    out = out.reshape(batch, total, heads, d)[:, :seq]
-    return out, compressed[:, :count_windows(seq, sparse)]
+    with jax.named_scope("sparse_select"):
+        chosen = prompt_block_choice(
+            q.reshape(batch, seq + pad, groups, heads // groups, d),
+            compressed, sparse, dtype)
+    with jax.named_scope("sparse_attn"):
+        out, share = sparse_attention.sparse_prompt_attention(
+            q, k, v, chosen, block_size=sparse["block_size"])
+    return (out[:, :seq].astype(dtype),
+            compressed[:, :count_windows(seq, sparse)], share)
 
 
 def sparse_step_attention(q, keys, values, compressed, positions, sparse,
@@ -839,7 +859,11 @@ class BlockSparseAttention(nn.Module):
                 q[:, 0], keys.value, values.value, compressed.value,
                 positions, sparse, self.dtype)[:, None]
         else:
-            o, kc = sparse_prompt_attention(q, k, v, sparse, self.dtype)
+            o, kc, share = sparse_prompt_attention(q, k, v, sparse,
+                                                   self.dtype)
+            # what the prompt kernel ran of the blocks under the diagonal:
+            # the serving engine reads it with the prefill's first token
+            self.sow("kernel_stats", "live_block_share", share)
             if self.decode and kc.shape[1]:
                 # windows that reach past the true length hold padding:
                 # the decode step that completes one rewrites it

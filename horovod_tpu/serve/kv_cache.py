@@ -124,7 +124,7 @@ from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
 from horovod_tpu.ops.pallas import (decode_attention,
                                     grouped_decode_attention,
-                                    latent_attention)
+                                    latent_attention, sparse_attention)
 from horovod_tpu.ops.pallas._backend import kernels_in
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
 
@@ -223,7 +223,10 @@ class Pending:
 
 
 class PendingPrefill(Pending):
-    """``collect()`` -> (first generated token id, max |logit|)."""
+    """``collect()`` -> (first generated token id, max |logit|).
+    ``max_abs`` is the program's last result: the scalar, or (max |logit|,
+    live share of key blocks) where its sparse layers ran their prompt
+    kernel, which then goes to ``sparse.live_block_share`` and the span."""
 
     def __init__(self, token, max_abs, t0: float, attrs: dict):
         self._token, self._max_abs = token, max_abs
@@ -234,7 +237,12 @@ class PendingPrefill(Pending):
         # was the later of the two
         with tracing.span("engine.prefill.wait",   # blocked on the device
                           ready=int(self.ready())):
-            out = int(self._token), float(self._max_abs)
+            token, readings = int(self._token), np.asarray(self._max_abs)
+        if readings.ndim:
+            share = float(readings[1])
+            sparse_attention.note_live_block_share(share)
+            self._attrs["live_block_share"] = round(share, 4)
+        out = token, float(readings.flat[0])
         # the start of the dispatch to the first token on the host
         tracing.record("engine.prefill", self._t0, time.time() - self._t0,
                        **self._attrs)
@@ -300,6 +308,11 @@ class DecodeEngine:
         # where the program holds no such kernel)
         self._reads_live_tiles = False
         self._write_fused = None
+        # do a block-sparse model's prefill programs attend through
+        # ops/pallas/sparse_attention (stats()["prefill_sparse_kernel"];
+        # None without such a layer or before a prefill was traced): set
+        # where a program is traced, from what its layers handed up
+        self._sparse_kernel = None
         self.kv_tiles_read = 0
         self.kv_tiles_held = 0
         # the same for a latent cache read through ops/pallas/
@@ -455,7 +468,12 @@ class DecodeEngine:
         logits, mutated = self._model.apply(
             {"params": params}, tokens,
             positions=jnp.zeros((1,), jnp.int32), lengths=prompt_len[None],
-            train=False, mutable=["cache"])
+            train=False, mutable=["cache", "kernel_stats"])
+        # a sparse layer's prompt kernel hands up the share of key blocks
+        # it ran; it rides to the host beside max |logit|
+        shares = jax.tree.leaves(mutated.get("kernel_stats", {}))
+        if self._dense_len is not None:
+            self._sparse_kernel = bool(shares)
         # ...written into the slot row at a traced index (in place: the
         # big cache is donated), so every prompt of this bucket reuses
         # one program regardless of slot; a counter is no slot's row: the
@@ -469,7 +487,10 @@ class DecodeEngine:
         token = jnp.argmax(last).astype(jnp.int32)
         # the slot's first decode step reads its token from the feed
         feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
-        return cache, feed, token, jnp.max(jnp.abs(last))
+        max_abs = jnp.max(jnp.abs(last))
+        if shares:
+            max_abs = jnp.stack([max_abs, jnp.mean(jnp.stack(shares))])
+        return cache, feed, token, max_abs
 
     def _piece(self, params, cache, tokens, offset, length, slot, output):
         """One piece of ``slot``'s prompt, ``tokens`` (1, PREFILL_CHUNK)
@@ -713,6 +734,10 @@ class DecodeEngine:
                     # value columns itself (the decode program holds no
                     # kv_cache_write); None where it holds no such kernel
                     "decode_write_fused": self._write_fused,
+                    # a block-sparse model's prefill programs attend
+                    # through the prompt kernel (None: no such layer, or
+                    # no prefill traced yet)
+                    "prefill_sparse_kernel": self._sparse_kernel,
                     # positions the latent kernel's steps attended (a row
                     # that is not active: one), None without that kernel
                     "decode_positions_read": (self.positions_read

@@ -333,8 +333,42 @@ def test_hybrid_engine_fits_and_updates_its_cache_in_place(chip, monkeypatch,
         assert re.search(leaf + r"\S* parameter\(", text), leaf
         assert not re.findall(rf"= {leaf}\S* copy\(", text), leaf
     # the decode step writes one key, one value and one compressed key a
-    # row through the in-place kernel; the prefill writes slices
-    assert text.count("tpu_custom_call") == (3 if program == "decode" else 0)
+    # row through the in-place kernel; the prefill writes slices, and its
+    # one sparse layer attends through the prompt kernel: no loop of the
+    # program carries a block of float32 scores against every key
+    assert text.count("tpu_custom_call") == (3 if program == "decode" else 1)
+    if program != "decode":
+        assert len(re.findall(
+            r"%sparse_prompt_attention[.\d]* = [^\n]*? custom-call\(",
+            text)) == 1
+        assert not re.search(rf"f32\[[\d,]*128,{seq}\]", text)
+        assert eng.stats()["prefill_sparse_kernel"] is True
+
+
+@pytest.mark.parametrize("seq,heads,kv_heads,head_dim,block_size,dtype", [
+    (16384, 32, 2, 128, 64, BF16),    # sala-serve-long-c1's two buckets
+    (32768, 32, 2, 128, 64, BF16),
+    (1, 1, 1, 8, 8, BF16),            # the smallest shape takes_kernel admits
+    (301, 4, 2, 32, 16, F32)],        # the toy model's, no whole tile
+    ids=["sala_16384", "sala_32768", "smallest", "toy_float32"])
+def test_sparse_prompt_attention(chip, seq, heads, kv_heads, head_dim,
+                                 block_size, dtype):
+    """The block-sparse prompt kernel at the corners of its envelope
+    (stated beside ``sparse_attention.takes_kernel``): Mosaic takes the
+    int8 table's conversion, the roll by a traced number of lanes and the
+    data-dependent block indices, in 2 MB of float32 scores a step."""
+    from horovod_tpu.ops.pallas import sparse_attention
+
+    assert sparse_attention.takes_kernel(heads, kv_heads, head_dim,
+                                         block_size)
+    kernels = _kernels_in(
+        lambda q, k, v, bits: sparse_attention.sparse_prompt_attention(
+            q, k, v, bits, block_size=block_size),
+        chip, ((1, seq, heads, head_dim), dtype),
+        ((1, seq, kv_heads, head_dim), dtype),
+        ((1, seq, kv_heads, head_dim), dtype),
+        ((1, kv_heads, seq, -(-seq // block_size)), jnp.int8))
+    assert kernels == 1
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk",
