@@ -32,19 +32,16 @@ K-EXAONE-236B-A23B (``benchmark/configs/k-exaone-236b-a23b.json``,
 are not tied to those models.
 
 Serving (``decode=True``) keeps a ``cache`` collection whose leaves all
-have the slot as axis 0; which kinds it holds depends on the mixers (a
-model of power-retention layers alone has no leaf with a position axis):
+have the slot as axis 0; which variables a kind keeps, and their kinds, is
+``MIXERS[kind].cache`` (a model of power-retention layers alone has no
+leaf with a position axis):
 
 * ``cached_key`` / ``cached_value`` ``(slots, kv_heads, head_dim,
   max_seq)`` - a sparse or a full layer's keys and values, positions
   last, the layout ``ops/pallas/kv_cache_write`` writes one token into;
 * ``ring_key`` / ``ring_value`` ``(slots, kv_heads, head_dim, ring)`` - a
-  window layer's keys and values, a ring of the smallest whole number of
-  lane tiles that holds the window, position ``p`` in column ``p mod
-  ring``: a prefill leaves the prompt's last positions there, a decode
-  step writes over the oldest column, and the step's mask sees a column
-  only where the position it holds is this request's and inside the
-  window;
+  window layer's, position ``p`` in column ``p mod ring``
+  (:class:`GroupedQueryAttention`);
 * ``compressed_key`` ``(slots, kv_heads, head_dim, windows)`` - the
   means of the key windows that block selection scores;
 * ``state`` ``(slots, heads, head_dim, head_dim)`` float32 - a lightning
@@ -58,15 +55,11 @@ model of power-retention layers alone has no leaf with a position axis):
 * ``latent`` ``(slots, kv_rank, max_seq)`` and ``rope_key`` ``(slots,
   rope_dim, max_seq)`` - a latent-attention layer's normed latent and
   rotated key, positions last, nothing a head;
-* ``ssm_state`` ``(slots, heads, head_dim, d_state)`` float32 and
-  ``conv_state`` ``(slots, (d_conv - 1) x channels)`` - a state-space
-  layer's recurrent state and the last ``d_conv - 1`` rows before its
-  convolution, oldest first, side by side along the lanes; neither grows
-  with the context.
-
-An expert layer also keeps ``expert_counts`` ``(3, experts)`` uint32 there,
-a running count and the one leaf that is no slot's row (the engine adds a
-prefill's counts to it and reads it for ``stats()`` alone).
+* ``ssm_state`` float32 and ``conv_state`` - a state-space layer's
+  recurrent state and the rows before its convolution
+  (:class:`StateSpace`); neither grows with the context;
+* ``expert_counts`` ``(3, experts)`` uint32 - an expert layer's running
+  count, the one leaf that is no slot's row (:class:`RoutedExperts`).
 
 A call with one token a row is a decode step; a call with more is a
 prefill from position 0, which computes the prompt without the cache and
@@ -79,37 +72,28 @@ the state it leaves is the state after ``lengths`` tokens (a
 state-space layer's convolution tail the last true rows), and with
 ``lengths`` given the head runs on row ``lengths - 1`` alone.
 
-The sparse layer selects each query's key blocks in XLA, a block of
-queries at a time, and attends a prompt under that table of bits through
-one kernel (``ops/pallas/sparse_attention``: scores, mask and softmax
-never leave VMEM, nothing above the diagonal is computed); its decode
-step is masked dense attention over the row in XLA. The lightning layer
-scans chunks in XLA; PERF.md says what that costs. A window layer's
-prompt is banded blocks in XLA (each block of ``window`` queries against its own keys and
-the block's before it: work in ``seq x 2 window``, not ``seq^2``), a
-full layer's the flash kernel; a full layer's decode step reads its
-rows' live tiles through ``ops/pallas/grouped_decode_attention`` and a
-window layer's its ring, one lane tile a head at a window of 128, in
-XLA. The power-retention layer scans
-chunks too, and reads its state through two kernels
-(``ops/pallas/power_retention``): XLA would write every query's 8,256
-features to memory first. A state-space layer scans chunks in XLA
-(:func:`ssm_chunked`: one ``C B^T`` a group for all its heads) and its
-decode step is elementwise (:func:`ssm_step`), which XLA fuses into one
-pass that reads each state once and writes it once.
+Each kind's prompt form and step form are documented where they are
+written: :func:`sparse_prompt_attention` and
+:func:`sparse_step_attention`, :func:`lightning_chunked`,
+:func:`retention_chunked` (and ``ops/pallas/power_retention``),
+:func:`latent_prompt_attention` and :func:`latent_step_attention`,
+:func:`window_prompt_attention`, :func:`full_prompt_attention` and
+:func:`ring_step_attention`, :func:`ssm_chunked` and :func:`ssm_step`.
 """
 
 from __future__ import annotations
 
+import collections
 import math
 from functools import partial
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from horovod_tpu.models.serving import ServingContract
 from horovod_tpu.models.transformer import write_cache_rows
 from horovod_tpu.ops.pallas import (grouped_decode_attention,
                                     latent_attention, power_retention,
@@ -1776,6 +1760,84 @@ def hyper_update(post, res, streams, y):
     return jnp.stack(out, axis=1).astype(streams.dtype)
 
 
+# ---------------------------------------------------------- the layer kinds
+
+# One kind of layer, declared once: every decision about a kind is a column
+# here, and nothing outside this table and the kind's own module compares a
+# kind with a constant. ``module``: its flax class; ``fields(d, i)``: that
+# module's fields for layer ``i`` of the :class:`HybridDecoder` ``d``;
+# ``cache``: the ``cache`` variables the module declares under
+# ``decode=True``, each with its kind of leaf (``ServingContract.
+# cache_kinds``); ``resumes``: a prefill that is handed the slot's cache
+# continues from it; ``reads``: (kind of leaf, ``(d, seen) -> positions``)
+# one layer's decode step attends of that leaf, ``seen`` each row's position
+# + 1; ``dense_len(d)``: prompts longer than this select key blocks.
+Mixer = collections.namedtuple(
+    "Mixer", "module fields cache resumes reads dense_len",
+    defaults=(False, None, None))
+
+
+def _grouped_query(windowed, cache, reads):
+    return Mixer(GroupedQueryAttention, lambda d, i: dict(
+        num_heads=d.num_heads, num_kv_heads=d.num_kv_heads,
+        head_dim=d.head_dim, window=d.window if windowed else None,
+        rotary=windowed, rope_theta=d.rope_theta, qk_norm=d.qk_norm,
+        scale=d.attention_scale, max_cache_len=d.max_seq, decode=d.decode),
+        cache, reads=reads)
+
+
+# a plain mapping, no registration API: a new kind is one module above and
+# one entry here (tests/test_engine_contract.py serves one of its own)
+MIXERS = {
+    LIGHTNING: Mixer(LightningAttention, lambda d, i: dict(
+        num_heads=d.num_heads, head_dim=d.head_dim,
+        layer_index=d.layer_indices[i] if d.layer_indices else i,
+        published_depth=d.published_depth or len(d.mixers),
+        rope_theta=d.rope_theta, decode=d.decode), {"state": "state"}),
+    BLOCK_SPARSE: Mixer(BlockSparseAttention, lambda d, i: dict(
+        num_heads=d.num_heads, num_kv_heads=d.num_kv_heads,
+        head_dim=d.head_dim, sparse=d.sparse, max_cache_len=d.max_seq,
+        decode=d.decode),
+        {"cached_key": "kv", "cached_value": "kv",
+         "compressed_key": "compressed"},
+        dense_len=lambda d: dict(d.sparse)["dense_len"]),
+    # carries its state and its normaliser from piece to piece; the other
+    # kinds start every prefill from an empty cache
+    POWER_RETENTION: Mixer(PowerRetention, lambda d, i: dict(
+        num_heads=d.num_heads, num_kv_heads=d.num_kv_heads,
+        head_dim=d.head_dim, rope_theta=d.rope_theta, decode=d.decode),
+        {"state": "state", "state_norm": "state"}, resumes=True),
+    LATENT: Mixer(LatentAttention, lambda d, i: dict(
+        num_heads=d.num_heads, rope_theta=d.rope_theta,
+        max_cache_len=d.max_seq, decode=d.decode, **dict(d.latent)),
+        {"latent": "latent", "rope_key": "latent"}),
+    # every position up to the row's own
+    FULL: _grouped_query(False, {"cached_key": "kv", "cached_value": "kv"},
+                         ("kv", lambda d, seen: seen.sum())),
+    # the window's at most
+    WINDOW: _grouped_query(
+        True, {"ring_key": "ring", "ring_value": "ring"},
+        ("ring", lambda d, seen: np.minimum(seen, d.window or 0).sum())),
+    MAMBA2: Mixer(StateSpace,
+                  lambda d, i: dict(decode=d.decode, **dict(d.ssm)),
+                  {"ssm_state": "state", "conv_state": "conv"}),
+}
+
+
+def mixer_of(kind) -> Mixer:
+    if kind not in MIXERS:
+        raise ValueError(f"unknown mixer {kind!r}")
+    return MIXERS[kind]
+
+
+# a layer's MLP, the same way: the ``cache`` variables it declares and
+# whether its decode step wants ``active`` (:class:`RoutedExperts` counts
+# the pairs it routes, and only rows that hold a request)
+Mlp = collections.namedtuple("Mlp", "cache wants_active")
+MLPS = {DENSE_MLP: Mlp({}, False),
+        EXPERTS_MLP: Mlp({"expert_counts": "counter"}, True)}
+
+
 # ------------------------------------------------------------------- trunk
 
 class HybridLayer(nn.Module):
@@ -1811,14 +1873,8 @@ class HybridLayer(nn.Module):
     def __call__(self, h, positions, lengths=None, active=None):
         common = dict(eps=self.eps, dtype=self.dtype,
                       param_dtype=self.param_dtype)
-        mixer = {LIGHTNING: LightningAttention,
-                 BLOCK_SPARSE: BlockSparseAttention,
-                 POWER_RETENTION: PowerRetention,
-                 LATENT: LatentAttention,
-                 FULL: GroupedQueryAttention,
-                 WINDOW: GroupedQueryAttention,
-                 MAMBA2: StateSpace}[self.kind](
-                     name="mixer", **dict(self.mixer_args), **common)
+        mixer = mixer_of(self.kind).module(
+            name="mixer", **dict(self.mixer_args), **common)
         norm = partial(RMSNorm, **common)
         if self.mlp == EXPERTS_MLP:
             scope = "moe"
@@ -1893,10 +1949,9 @@ class HybridDecoder(nn.Module):
     keep when the depth is cut. The muP scalings are neutral at their
     defaults, ``scale_depth=None`` meaning a residual scale of 1;
     ``residual_multiplier``, where given, is the residual scale itself,
-    and ``logits_divisor`` divides the logits. ``causal``, ``max_seq``, ``vocab_size``
-    and ``clone(decode=..., remat=..., attention_fn=...)`` are what
-    ``serve.kv_cache.DecodeEngine`` asks of a model (``remat`` and
-    ``attention_fn`` are accepted for that and not used)."""
+    and ``logits_divisor`` divides the logits. ``causal``, ``max_seq``,
+    ``vocab_size`` and :meth:`serving` are what
+    ``serve.kv_cache.DecodeEngine`` asks of a model."""
 
     vocab_size: int
     d_model: int
@@ -1932,79 +1987,58 @@ class HybridDecoder(nn.Module):
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = F32
     decode: bool = False
-    remat: bool = False
-    attention_fn: Optional[Callable] = None
+
+    def _mixers(self):
+        """(the table's entry, layers of it) for each kind in ``mixers``."""
+        return [(mixer_of(kind), layers) for kind, layers
+                in collections.Counter(self.mixers).items()]
 
     @property
     def dense_len(self):
-        """Prompts longer than this select key blocks (``None`` when no
-        layer is block sparse)."""
-        if BLOCK_SPARSE not in self.mixers:
-            return None
-        return dict(self.sparse)["dense_len"]
+        """Prompts longer than this select key blocks (or ``None``)."""
+        return next((mixer.dense_len(self) for mixer, _ in self._mixers()
+                     if mixer.dense_len), None)
 
     @property
     def resumable_prefill(self):
-        """Whether a prefill that is handed a slot's cache continues
-        from it (``positions`` the piece's offset), so that a prompt may
-        be run in pieces: true when every mixer can. A power-retention
-        layer carries its state and its normaliser from piece to piece;
-        the other kinds start every prefill from an empty cache."""
-        return all(kind == POWER_RETENTION for kind in self.mixers)
+        """Whether a prefill that is handed a slot's cache continues from
+        it (``positions`` the piece's offset): when every mixer can."""
+        return all(mixer.resumes for mixer, _ in self._mixers())
 
     @property
     def counts_active_rows(self):
-        """Whether a decode step wants ``active`` (the rows that hold a
-        request): the expert layers count the pairs they route."""
-        return EXPERTS_MLP in (self.mlps or ())
-
-    def _mixer_args(self, i, kind):
-        depth = self.published_depth or len(self.mixers)
-        if kind == LIGHTNING:
-            index = self.layer_indices[i] if self.layer_indices else i
-            return dict(num_heads=self.num_heads, head_dim=self.head_dim,
-                        layer_index=index, published_depth=depth,
-                        rope_theta=self.rope_theta, decode=self.decode)
-        if kind == BLOCK_SPARSE:
-            return dict(num_heads=self.num_heads,
-                        num_kv_heads=self.num_kv_heads,
-                        head_dim=self.head_dim, sparse=self.sparse,
-                        max_cache_len=self.max_seq, decode=self.decode)
-        if kind == POWER_RETENTION:
-            return dict(num_heads=self.num_heads,
-                        num_kv_heads=self.num_kv_heads,
-                        head_dim=self.head_dim, rope_theta=self.rope_theta,
-                        decode=self.decode)
-        if kind == LATENT:
-            return dict(num_heads=self.num_heads,
-                        rope_theta=self.rope_theta,
-                        max_cache_len=self.max_seq, decode=self.decode,
-                        **dict(self.latent))
-        if kind in (FULL, WINDOW):
-            return dict(num_heads=self.num_heads,
-                        num_kv_heads=self.num_kv_heads,
-                        head_dim=self.head_dim,
-                        window=self.window if kind == WINDOW else None,
-                        rotary=kind == WINDOW, rope_theta=self.rope_theta,
-                        qk_norm=self.qk_norm, scale=self.attention_scale,
-                        max_cache_len=self.max_seq, decode=self.decode)
-        if kind == MAMBA2:
-            return dict(decode=self.decode, **dict(self.ssm))
-        raise ValueError(f"unknown mixer {kind!r}")
+        """Whether a decode step wants ``active``, the rows in use."""
+        return any(MLPS[mlp].wants_active for mlp in self.mlps or ())
 
     def decode_positions_by_kind(self, positions):
         """Positions a decode step at ``positions`` (numpy, (rows,), a row
         that is not active at 0) attends, all rows and all layers of a
-        kind together, by the kind of cache leaf they are read from:
-        ``kv`` (a full layer: every position up to the row's own) and
-        ``ring`` (a window layer: the window's at most). ``None`` for a
-        model with neither kind of layer."""
-        full, ring = self.mixers.count(FULL), self.mixers.count(WINDOW)
-        if not full and not ring:
+        kind together, by the kind of cache leaf they are read from (every
+        kind a ``reads`` names); ``None`` where no layer has a ``reads``."""
+        readers = [(*mixer.reads, layers) for mixer, layers
+                   in self._mixers() if mixer.reads]
+        if not readers:
             return None
         seen = np.asarray(positions, np.int64) + 1
-        return {"kv": full * int(seen.sum()),
-                "ring": ring * int(np.minimum(seen, self.window or 0).sum())}
+        out = {mixer.reads[0]: 0 for mixer in MIXERS.values() if mixer.reads}
+        for leaf, attended, layers in readers:
+            out[leaf] += layers * int(attended(self, seen))
+        return out
+
+    def serving(self) -> ServingContract:
+        """What ``serve.kv_cache.DecodeEngine`` asks of a model, folded
+        from :data:`MIXERS` and :data:`MLPS` over this model's layers."""
+        layers = [mixer for mixer, _ in self._mixers()] \
+            + [MLPS[mlp] for mlp in self.mlps or ()]
+        return ServingContract(
+            model=self.clone(decode=True),
+            cache_kinds={name: kind for layer in layers
+                         for name, kind in layer.cache.items()},
+            dense_len=self.dense_len, resumable=self.resumable_prefill,
+            wants_active=self.counts_active_rows,
+            step_reads=(self.decode_positions_by_kind
+                        if any(mixer.reads for mixer, _ in self._mixers())
+                        else None))
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
@@ -2046,7 +2080,7 @@ class HybridDecoder(nn.Module):
         mlps = self.mlps or (DENSE_MLP,) * len(self.mixers)
         for i, kind in enumerate(self.mixers):
             h = HybridLayer(
-                kind=kind, mixer_args=self._mixer_args(i, kind),
+                kind=kind, mixer_args=mixer_of(kind).fields(self, i),
                 d_ff=self.d_ff, residual_scale=residual_scale,
                 norms=self.norms, mlp=mlps[i],
                 mlp_args=(dict(self.experts, decode=self.decode)

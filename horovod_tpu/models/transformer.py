@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from horovod_tpu.models.serving import ServingContract
 from horovod_tpu.ops.pallas._backend import shard_over_batch
 from horovod_tpu.ops.pallas.decode_attention import (decode_attention,
                                                      takes_kernel)
@@ -336,6 +337,12 @@ class Transformer(nn.Module):
     paged: bool = False
     num_pages: int = 0
     page_tokens: int = 0
+
+    def serving(self) -> ServingContract:
+        """For ``serve.kv_cache.DecodeEngine``: keys and values alone."""
+        return ServingContract(
+            model=self.clone(decode=True, remat=False, attention_fn=None),
+            cache_kinds={"cached_key": "kv", "cached_value": "kv"})
 
     @nn.compact
     def __call__(self, token_ids, train: bool = True, pos_offset=0,

@@ -10,6 +10,8 @@ Which of the two a process got is visible in the compiled program text
 
 from __future__ import annotations
 
+import collections
+
 import jax
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
@@ -30,6 +32,23 @@ def use_interpret() -> bool:
     """``HOROVOD_PALLAS_INTERPRET`` is the tests' switch; unset, interpret
     mode is used exactly when the backend is not a TPU."""
     return env_mod._get_bool("HOROVOD_PALLAS_INTERPRET", not on_tpu())
+
+
+# What the serving engine's counters read of a kernel, declared once beside
+# the kernel under the name it gives ``pallas_call``: the engine names no
+# kernel and asks :func:`kernels_in` which of them its decode program holds.
+# ``live_tiles(positions, cache_len)``: (tiles read, tiles of all rows,
+# positions attended) of one leaf for a decode step at ``positions`` (numpy;
+# a row at -1 is not active and runs at 0); ``writes_step``: the kernel puts
+# the step's new columns into the cache (without ``live_tiles``: that
+# alone); ``counts_positions``: its roofline counts bytes by the positions.
+ServedKernel = collections.namedtuple(
+    "ServedKernel", "live_tiles writes_step counts_positions",
+    defaults=(None, False, False))
+SERVED_KERNELS: dict = {}
+# a reading a kernel's caller sows into ``kernel_stats``, by the name it is
+# sown under -> where the value goes once the engine has it on the host
+KERNEL_STATS: dict = {}
 
 
 def kernels_in(jaxpr) -> list:

@@ -51,10 +51,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.pallas._backend import use_interpret
+from horovod_tpu.ops.pallas._backend import (SERVED_KERNELS, ServedKernel,
+                                             use_interpret)
 from horovod_tpu.ops.pallas.flash_attention import NEG_INF
 from horovod_tpu.ops.pallas.kv_cache_write import LANES
 
+KERNEL = "decode_attention"
 # tile buffers in the ring: one is being computed on, the others' copies
 # are in flight
 RING = 8
@@ -67,10 +69,14 @@ def takes_kernel(new_tokens: int, cache_len: int) -> bool:
 
 
 def live_tiles(positions, cache_len: int):
-    """Lane tiles a decode step at ``positions`` reads of one leaf, and
-    the tiles of all its rows (numpy, for the engine's counter)."""
+    """Lane tiles a decode step at ``positions`` reads of one leaf, the
+    tiles of all its rows, the positions it attends (``ServedKernel``)."""
     pos = np.clip(np.asarray(positions), 0, cache_len - 1)
-    return int((pos // LANES + 1).sum()), pos.size * (cache_len // LANES)
+    return (int((pos // LANES + 1).sum()), pos.size * (cache_len // LANES),
+            int((pos + 1).sum()))
+
+
+SERVED_KERNELS[KERNEL] = ServedKernel(live_tiles, writes_step=True)
 
 
 def _attention_kernel(pos_ref, new_ref, k_hbm, v_hbm, o_ref, k_out, v_out,
@@ -290,6 +296,6 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, positions, *,
         # the ring's copies run on from one row's step into the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret, name="decode_attention",
+        interpret=interpret, name=KERNEL,
     )(positions, new.transpose(0, 2, 1), k_cache, v_cache)
     return out.transpose(0, 2, 1), k_cache, v_cache

@@ -33,7 +33,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.ops.pallas import latent_attention
-from horovod_tpu.ops.pallas._backend import use_interpret
+from horovod_tpu.ops.pallas._backend import (SERVED_KERNELS, ServedKernel,
+                                             use_interpret)
 from horovod_tpu.ops.pallas.flash_attention import NEG_INF
 from horovod_tpu.ops.pallas.kv_cache_write import LANES
 
@@ -41,6 +42,7 @@ from horovod_tpu.ops.pallas.kv_cache_write import LANES
 # block is 1 MB a leaf, what the chip moves in the time of about four
 # grid steps; both leaves, double buffered, are 4 MB of VMEM
 TILE = 512
+KERNEL = "grouped_decode_attention"
 
 
 def tile_of(cache_len: int) -> int:
@@ -50,11 +52,15 @@ def tile_of(cache_len: int) -> int:
 
 
 def live_tiles(positions, cache_len: int):
-    """Position tiles a decode step at ``positions`` reads of one leaf
-    and the tiles of all its rows (numpy, for the engine's counters)."""
+    """Position tiles a decode step at ``positions`` reads of one leaf, the
+    tiles of all its rows, the positions it attends (``ServedKernel``)."""
     tile = tile_of(cache_len)
     pos = np.clip(np.asarray(positions), 0, cache_len - 1)
-    return int((pos // tile + 1).sum()), pos.size * (cache_len // tile)
+    return (int((pos // tile + 1).sum()), pos.size * (cache_len // tile),
+            int((pos + 1).sum()))
+
+
+SERVED_KERNELS[KERNEL] = ServedKernel(live_tiles)
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc, peak, total, *, scale,
@@ -143,5 +149,5 @@ def _grouped_decode_attention(q, k_cache, v_cache, positions, *, scale,
         out_shape=jax.ShapeDtypeStruct(q.shape, v_cache.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret, name="grouped_decode_attention",
+        interpret=interpret, name=KERNEL,
     )(positions, q.astype(k_cache.dtype), k_cache, v_cache)

@@ -28,9 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.pallas._backend import use_interpret
+from horovod_tpu.ops.pallas._backend import (SERVED_KERNELS, ServedKernel,
+                                             use_interpret)
 
 LANES = 128
+# writes a decode step's new column and attends nothing
+KERNEL = "kv_cache_write"
+SERVED_KERNELS[KERNEL] = ServedKernel(writes_step=True)
 
 
 def _write_kernel(pos_ref, new_ref, cache_ref, out_ref):
@@ -77,5 +81,5 @@ def _write_token(cache, new, positions, *, interpret):
             out_specs=tile),
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},
-        interpret=interpret, name="kv_cache_write",
+        interpret=interpret, name=KERNEL,
     )(positions, new.transpose(0, 2, 1), cache)

@@ -34,13 +34,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.pallas._backend import use_interpret
+from horovod_tpu.ops.pallas._backend import (SERVED_KERNELS, ServedKernel,
+                                             use_interpret)
 from horovod_tpu.ops.pallas.flash_attention import NEG_INF
 from horovod_tpu.ops.pallas.kv_cache_write import LANES
 
 # positions a grid step: a (512 + 64) x 1024 bfloat16 block is 1.2 MB,
 # about what the chip moves in the time a grid step costs ten times over
 TILE = 1024
+KERNEL = "latent_decode_attention"
 
 
 def tile_of(cache_len: int, tile: int = TILE) -> int:
@@ -63,6 +65,9 @@ def live_tiles(positions, cache_len: int):
     pos = np.clip(np.asarray(positions), 0, cache_len - 1)
     return (int((pos // tile + 1).sum()), pos.size * (cache_len // tile),
             int((pos + 1).sum()))
+
+
+SERVED_KERNELS[KERNEL] = ServedKernel(live_tiles, counts_positions=True)
 
 
 def _kernel(pos_ref, qt_ref, qr_ref, latent_ref, rope_ref, o_ref, acc, peak,
@@ -157,6 +162,6 @@ def _latent_decode_attention(qt, q_rope, latent, rope_key, positions, *,
         out_shape=jax.ShapeDtypeStruct((rows, heads, rank), latent.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret, name="latent_decode_attention",
+        interpret=interpret, name=KERNEL,
     )(positions, qt.astype(latent.dtype), q_rope.astype(latent.dtype),
       latent, rope_key)

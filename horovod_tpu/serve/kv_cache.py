@@ -1,111 +1,52 @@
-"""Per-slot KV-cache management + the serving program caches.
+"""Per-slot cache management + the serving program caches.
 
-:class:`DecodeEngine` owns everything jax about one replica:
+:class:`DecodeEngine` owns everything jax about one replica. Of a model
+it knows one object, ``model.serving()`` (``models/serving.py``): the
+decode clone, the kind of every cache variable, four facts about its
+programs. It names no layer kind, no cache variable and no kernel: a
+layer kind is declared in ``models/hybrid.py``'s ``MIXERS``, a kernel's
+counters beside the kernel (``ops/pallas/_backend.py``). Its own rules:
 
-* the decode clone of the user's model (``model.clone(decode=True)`` —
-  same params, plus a ``cache`` variable collection: for a softmax layer
-  ``(slots, heads, head_dim, max_seq)`` key/value tensors, positions
-  last, the layout attention reads; for a recurrent layer its state,
-  which has no position axis);
-* ONE jitted decode program over ALL slots every step — the shape never
-  changes (inactive rows run masked garbage at position 0, overwritten
-  by the next prefill), so steady-state decode never recompiles;
-* one jitted prefill program PER PROMPT-LENGTH BUCKET, batch 1, which
-  writes the prompt's KV into a fresh single-row cache and writes that
-  row into the requested slot at a traced index. Bucketing reuses the
-  runtime's size-bucket policy (``fusion_buffer.bucket_elems``: identity
-  up to the quantum, then power-of-two multiples), floored at the
-  quantum so short prompts share one program — the bucket set is
-  O(log(max_seq)) and after one request per bucket the program cache is
-  warm: zero steady-state compiles;
-* for a model whose prefill can resume from its cache
-  (``resumable_prefill``: layers that carry a state and nothing with a
-  position axis), two programs of one shape in place of those:
-  a prompt is cut into pieces of :data:`PREFILL_CHUNK` tokens, enqueued
-  back to back. ``prefill_chunk`` (every piece but the last) reads the
-  slot's row of every leaf - zeros for the first piece - continues the
-  model from it at the piece's offset and writes the row back, with no
-  head; ``prefill_last`` does the same with the true length of the last
-  piece and the head on its last true row. A prompt pads to the next
-  piece, not to the next power of two, and a call stays one call: one
-  pending result, one ``engine.prefill`` span (``chunks``).
+**Programs.** ONE jitted decode program over ALL slots every step: the
+shape never changes (a row without a request runs token 0 at position 0,
+which the next prefill overwrites), so steady-state decode never
+recompiles. One jitted prefill program PER PROMPT-LENGTH BUCKET, batch 1
+(``fusion_buffer.bucket_elems`` floored at the quantum: O(log(max_seq))
+of them), or, where the contract says ``resumable``, two programs of one
+shape that run a prompt in pieces (:meth:`DecodeEngine.prefill`).
 
-The cache is updated IN PLACE: every program donates its cache argument
-(the result aliases it, one cache lives on the device and no program
-copies it), the decode step writes one position per row through
-``ops/pallas/kv_cache_write`` and a prefill writes its row as one slice.
-``self._cache`` is rebound from every call's result; the arrays it held
-before are deleted, so a caller must not hold ``engine._cache`` across a
-call. ``stats()["cache_donated"]`` says whether the runtime took the
-donations (it may decline one and copy instead).
+**Donation.** Every program donates its cache argument: one cache lives
+on the device and no program copies it. ``self._cache`` is rebound from
+every call's result and the arrays it held are deleted, so do not hold
+``engine._cache`` across a call; ``stats()["cache_donated"]`` says
+whether the runtime took the donations.
 
-Prefill padding is safe without length bookkeeping for keys and values:
-padded positions' garbage KV sits at positions ``>= prompt_len``, which
-``models.transformer.cached_attention`` masks for every query that has
-not reached them - and decode overwrites each one before its query
-arrives. Slot reuse is safe the same way (stale rows of the previous
-occupant are never attendable); tests/test_serve.py pins both down
-against the uncached ``apply``.
+**Rows.** The cache is whatever pytree the model's ``cache`` collection
+declares, every leaf with the slot as axis 0; nothing here asks a leaf
+for more. A prefill overwrites its slot's row of every leaf and hands
+the model the true ``lengths`` (padding past them is masked where a leaf
+has positions and must not enter a recurrence; the head runs on the last
+true row alone, so (bucket, vocab) logits never exist). A row that is
+not active still runs every step; what it leaves stays finite until the
+next prefill replaces it. Two kinds of leaf the engine treats itself:
+``state``, whose bytes are the spans' ``state_bytes``, and ``counter``, a
+running count and no slot's row: a prefill adds to it, no step reads it
+back, ``stats()`` alone copies it to the host, and a model that counts
+(``wants_active``) is told a decode step's ``active`` rows.
 
-The cache is whatever pytree the model's ``cache`` collection declares,
-every leaf with the slot as axis 0: ``models/hybrid.py`` keeps keys and
-values, compressed keys and float32 recurrent states side by side, or
-states alone - a model of power-retention layers has no leaf with a
-position axis at all, and its decode step rewrites its whole cache
-(:data:`CACHE_KINDS`, ``cache_bytes_by_kind``). A latent-attention
-layer keeps one latent and one rotary key a position and nothing a head
-(kind ``latent``). A window layer keeps its keys and values in a ring of
-window-many positions (whole lane tiles of them), position ``p`` in
-column ``p mod ring``, and not in ``max_seq`` (kind ``ring``): a prefill
-leaves the prompt's last positions there and a decode step writes over
-the oldest column, both inside the model, so that a slot's row of a ring
-leaf is written and replaced like any other row. A state-space layer
-keeps a float32 state (``ssm_state``, kind ``state``) and the last rows
-before its convolution (``conv_state``, kind ``conv``: a tail of three
-rows, a state of its own that neither grows nor is float32), beside a
-full layer's rows in one slot: a prefill overwrites both with what the
-prompt's true tokens leave. Nothing here asks a
-leaf for more than the slot axis,
-with one exception: a leaf of kind ``counter`` (an expert layer's
-``expert_counts``) is a running count and no slot's row, so a prefill
-adds its fresh counts to it where it overwrites a row of every other
-leaf; it rides in the donated cache, no step reads it back, and
-``stats()`` alone copies it to the host. A model that counts
-(``counts_active_rows``) is told a decode step's ``active`` rows, so
-that rows without a request are not counted. A recurrence is not indifferent to padding,
-so a prefill hands the model the true ``lengths``: the state it leaves
-is the state after the prompt, and a prefill overwrites every leaf's row
-of its slot, the state included. A row that is not active still runs
-(token 0 at position 0, every step): its keys land where the next
-prefill overwrites them, and its state is a gated running sum of one
-token's features, which stays finite, until the next prefill replaces
-it.
-With ``lengths`` the model applies its head to the last prompt row
-alone - the (bucket, vocab) logits never exist.
+**Feed.** Sampling is greedy, in-graph. The next token of every row
+stays on the device, a ``(slots,)`` int32 array donated with the cache:
+a prefill writes its first token there, a decode step reads and writes
+it, and the host sends a step one int32 a row (the position, -1 for a
+row that is not active) and reads back ids and one max |logit| a row.
 
-Sampling is greedy (argmax in-graph; only the winning token ids leave
-the device each step, plus one max-|logit| scalar per slot for the
-integrity guard).
-
-The token FEED stays on the device: a ``(slots,)`` int32 array beside
-the cache, donated with it. A prefill writes its first token into its
-slot's entry, the decode step reads every active row's token from the
-feed and writes its argmax back, so no step waits for the ids of the one
-before it to cross to the host and back. What the host sends a decode
-step is one int32 a row: the position, or -1 for a row that is not
-active (known by count, without reading an id).
-
-Every call therefore has two halves. :meth:`DecodeEngine.prefill` and
+**Look-ahead.** :meth:`DecodeEngine.prefill` and
 :meth:`DecodeEngine.decode` launch the program and return a
-:class:`Pending` result whose arrays are still on the device (their copy
-to the host is started at once); its ``collect()`` blocks until they are
-here, and its ``ready()`` says without blocking whether the device has
-finished them (the ``starved`` flag of the loop's ``serve.step`` and
-the ``ready`` of the ``wait`` spans). The serving loop enqueues step k+1
-before it collects step k (serve/replica.py). A pending result also unpacks like the tuple it
-stands for, collecting first, so ``token, max_abs = engine.prefill(...)``
-and ``ids, max_abs = engine.decode(...)`` are the blocking calls they
-always were, for tests and tools.
+:class:`Pending` result still on the device (its copy to the host
+started); ``collect()`` blocks, ``ready()`` does not. The serving loop
+enqueues step k+1 before it collects step k (serve/replica.py).
+Unpacking a pending result collects it: ``token, max_abs =
+engine.prefill(...)`` is the blocking call it always was.
 """
 
 from __future__ import annotations
@@ -122,17 +63,15 @@ import numpy as np
 from horovod_tpu import tracing
 from horovod_tpu.analysis import witness
 from horovod_tpu.metrics import registry as _metrics
-from horovod_tpu.ops.pallas import (decode_attention,
-                                    grouped_decode_attention,
-                                    latent_attention, sparse_attention)
-from horovod_tpu.ops.pallas._backend import kernels_in
+from horovod_tpu.ops.pallas._backend import (KERNEL_STATS, SERVED_KERNELS,
+                                             kernels_in)
 from horovod_tpu.runtime.fusion_buffer import bucket_elems
 
 # prompt-length bucket quantum (tokens). Not a knob: the policy is the
 # runtime's, only the unit differs (tokens, not bytes).
 PREFILL_BUCKET_QUANTUM = 16
 # tokens a piece of a prompt, where the model's prefill can resume from
-# the slot's cache (``resumable_prefill``): a prompt pads to the next
+# the slot's cache (``resumable``): a prompt pads to the next
 # piece, not to the next bucket. A multiple of a recurrent mixer's own
 # chunk (256), and rows enough that a piece's matrix products stay bound
 # by compute beside the layers' weights it reads again (PERF.md section 6
@@ -171,33 +110,6 @@ def prompt_bucket(prompt_len: int, max_seq: int,
     return min(max_seq, bucket_elems(max(prompt_len, quantum), 1, quantum))
 
 
-# what a cache leaf holds, by the name its model gave the variable: keys
-# and values that grow with the context, compressed keys that a sparse
-# layer selects blocks by, a recurrent state (and a normalised one's
-# running sum of features) that does not grow. A model need not have
-# every kind: one of recurrent layers alone holds states and nothing else.
-# A latent-attention layer's two leaves (one latent and one rotary key a
-# position, nothing a head) are ``latent``; a window layer's keys and
-# values, a ring of window-many positions, are ``ring``; a state-space
-# layer's state is ``state`` like the other recurrent states and the tail
-# of its convolution ``conv``; an expert layer's
-# running counts are ``counter``: no slot's row (module docstring)
-CACHE_KINDS = {"cached_key": "kv", "cached_value": "kv",
-               "compressed_key": "compressed", "state": "state",
-               "state_norm": "state", "ssm_state": "state",
-               "conv_state": "conv", "latent": "latent",
-               "rope_key": "latent", "ring_key": "ring",
-               "ring_value": "ring", "expert_counts": "counter"}
-
-
-def leaf_kind(path) -> str:
-    """``kv``, ``compressed``, ``state``, ``conv``, ``latent``, ``ring``
-    or ``counter`` for a cache leaf's tree path (``other`` for a name :data:`CACHE_KINDS` does
-    not know)."""
-    name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
-    return CACHE_KINDS.get(str(name), "other")
-
-
 class Pending:
     """The results of an enqueued program: on the device until
     :meth:`collect` has them. ``on_host`` is what the serving loop looks
@@ -225,12 +137,13 @@ class Pending:
 class PendingPrefill(Pending):
     """``collect()`` -> (first generated token id, max |logit|).
     ``max_abs`` is the program's last result: the scalar, or (max |logit|,
-    live share of key blocks) where its sparse layers ran their prompt
-    kernel, which then goes to ``sparse.live_block_share`` and the span."""
+    a reading for each of ``stats``) where the program's kernels handed
+    readings up (``_backend.KERNEL_STATS``'s sinks, and the span)."""
 
-    def __init__(self, token, max_abs, t0: float, attrs: dict):
+    def __init__(self, token, max_abs, t0: float, attrs: dict,
+                 stats: Tuple[str, ...]):
         self._token, self._max_abs = token, max_abs
-        self._t0, self._attrs = t0, attrs
+        self._t0, self._attrs, self._stats = t0, attrs, stats
 
     def _read(self) -> Tuple[int, float]:
         # ``ready``: the value was there when the wait began, so the host
@@ -239,9 +152,9 @@ class PendingPrefill(Pending):
                           ready=int(self.ready())):
             token, readings = int(self._token), np.asarray(self._max_abs)
         if readings.ndim:
-            share = float(readings[1])
-            sparse_attention.note_live_block_share(share)
-            self._attrs["live_block_share"] = round(share, 4)
+            for name, value in zip(self._stats, readings[1:].tolist()):
+                KERNEL_STATS[name](value)
+                self._attrs[name] = round(value, 4)
         out = token, float(readings.flat[0])
         # the start of the dispatch to the first token on the host
         tracing.record("engine.prefill", self._t0, time.time() - self._t0,
@@ -280,57 +193,35 @@ class DecodeEngine:
     """Model programs + the slot cache and token feed for one replica."""
 
     def __init__(self, model, params, num_slots: int, name: str = "r0"):
-        if not getattr(model, "causal", True):
+        if not model.causal:
             raise ValueError("hvd.serve() needs a causal (decoder) model")
         self.name = name
         self.num_slots = int(num_slots)
         self.max_seq = int(model.max_seq)
-        self.vocab_size = int(model.vocab_size)
+        self.vocab_size = int(model.vocab_size)   # benchmark/tests read it
         self._params = params
-        self._model = model.clone(decode=True, remat=False,
-                                  attention_fn=None)
-        # a model with block-sparse layers selects key blocks for prompts
-        # past this length (the ``sparse`` attribute of ``engine.prefill``)
-        self._dense_len = getattr(model, "dense_len", None)
-        # a model whose expert layers count what they route is told a
-        # decode step's active rows
-        self._counts = bool(getattr(model, "counts_active_rows", False))
-        # a model whose prefill continues from the slot's cache has its
-        # prompts run in pieces of PREFILL_CHUNK, through two programs
-        self._resumes = bool(getattr(model, "resumable_prefill", False))
+        # everything the engine knows of the model (module docstring)
+        contract = model.serving()
+        self._model = contract.model
+        self.leaf_kind = contract.leaf_kind
+        self._dense_len = contract.dense_len
+        self._counts = contract.wants_active
+        self._resumes = contract.resumable
         self._chunk = min(PREFILL_CHUNK, self.max_seq)
-        # does the decode program read its key/value rows through
-        # ops/pallas/decode_attention (set by _cache_shapes, from the
-        # program itself), and the lane tiles its steps read of a leaf
-        # over the tiles of all rows (stats()["decode_kv_read_share"]);
-        # and does that kernel write the new columns itself: no
-        # kv_cache_write beside it (stats()["decode_write_fused"]; None
-        # where the program holds no such kernel)
-        self._reads_live_tiles = False
-        self._write_fused = None
-        # do a block-sparse model's prefill programs attend through
-        # ops/pallas/sparse_attention (stats()["prefill_sparse_kernel"];
-        # None without such a layer or before a prefill was traced): set
-        # where a program is traced, from what its layers handed up
-        self._sparse_kernel = None
+        # -> stats()["decode_positions_by_kind"] (None: one kind of leaf)
+        self._step_reads = contract.step_reads
+        self.positions_by_kind: Dict[str, int] = {}
+        # the tiles the steps read of a leaf over the tiles of all rows
+        # (stats()["decode_kv_read_share"]) and the positions they attended
+        # (["decode_positions_read"], where a kernel's roofline counts
+        # bytes by them), through the readers _cache_shapes finds
         self.kv_tiles_read = 0
         self.kv_tiles_held = 0
-        # the same for a latent cache read through ops/pallas/
-        # latent_attention (its tiles are wider), and the positions its
-        # steps attended (stats()["decode_positions_read"]): what a
-        # roofline of that kernel counts bytes by
-        self._reads_live_latents = False
         self.positions_read = 0
-        # the same for key/value leaves read through ops/pallas/
-        # grouped_decode_attention; and, for a model whose layers keep
-        # different kinds of cache (``decode_positions_by_kind``), the
-        # positions its steps attended by the kind of leaf they were
-        # read from (stats()["decode_positions_by_kind"])
-        self._reads_live_groups = False
-        by_kind = getattr(model, "decode_positions_by_kind", None)
-        self._positions_by_kind = by_kind \
-            if by_kind and by_kind(np.zeros((1,), np.int64)) else None
-        self.positions_by_kind: Dict[str, int] = {}
+        # did a traced prefill's kernels hand readings up (None: no
+        # ``dense_len``, none traced yet), and the readings' names
+        self._sparse_kernel = None
+        self._kernel_stats: Tuple[str, ...] = ()
         self._cache = self._allocate_cache()
         # bytes of the recurrent states (kind ``state``) all the slots'
         # rows hold: what a decode step, which runs every row, rewrites,
@@ -375,7 +266,10 @@ class DecodeEngine:
     def _cache_shapes(self):
         """The decode program's cache pytree as shapes (one abstract
         trace: nothing compiles, nothing is allocated). The same trace
-        says whether the program holds the decode-attention kernel."""
+        says which kernels the program holds (``decode_kernels``: a silent
+        fall back to whole rows shows there), the registry what to count
+        of them and whether the one that attends also writes the step's
+        columns (``_write_fused``; None where none does)."""
         tokens = jax.ShapeDtypeStruct((self.num_slots, 1), jnp.int32)
         pos = jax.ShapeDtypeStruct((self.num_slots,), jnp.int32)
         program, (_, shapes) = jax.make_jaxpr(
@@ -383,12 +277,14 @@ class DecodeEngine:
                 {"params": p}, t, positions=q, train=False,
                 mutable=["cache"]), return_shape=True)(
                     self._params, tokens, pos)
-        kernels = kernels_in(program)
-        self._reads_live_tiles = "decode_attention" in kernels
-        self._write_fused = ("kv_cache_write" not in kernels
-                             if self._reads_live_tiles else None)
-        self._reads_live_latents = "latent_decode_attention" in kernels
-        self._reads_live_groups = "grouped_decode_attention" in kernels
+        self.decode_kernels = tuple(dict.fromkeys(kernels_in(program)))
+        held = [SERVED_KERNELS[name] for name in self.decode_kernels
+                if name in SERVED_KERNELS]
+        self._step_readers = [k.live_tiles for k in held if k.live_tiles]
+        self._counts_positions = any(k.counts_positions for k in held)
+        self._write_fused = not any(
+            k.writes_step and not k.live_tiles for k in held) \
+            if any(k.live_tiles and k.writes_step for k in held) else None
         return shapes["cache"]
 
     def _allocate_cache(self):
@@ -399,12 +295,12 @@ class DecodeEngine:
         return sum(self.cache_bytes_by_kind().values())
 
     def cache_bytes_by_kind(self) -> Dict[str, int]:
-        """Resident cache bytes by kind of leaf (:func:`leaf_kind`):
+        """Resident cache bytes by kind of leaf (``leaf_kind``):
         ``kv``, ``compressed`` and ``state`` always, another kind where
         the model has such a leaf."""
         out = {"kv": 0, "compressed": 0, "state": 0}
         for path, x in jax.tree_util.tree_leaves_with_path(self._cache):
-            kind = leaf_kind(path)
+            kind = self.leaf_kind(path)
             out[kind] = out.get(kind, 0) \
                 + int(np.prod(x.shape)) * x.dtype.itemsize
         return out
@@ -460,27 +356,28 @@ class DecodeEngine:
         return rest
 
     def _prefill_impl(self, params, cache, feed, tokens, prompt_len, slot):
-        # batch-1 run over the padded prompt builds a fresh (1, max_seq)
-        # cache (flax creates the zero cache inside the traced apply); the
-        # model is told the true length, so that a recurrent state is the
-        # state after the prompt and not after the padding, and applies
-        # its head to the last prompt row alone: logits is (1, 1, vocab)...
+        # batch-1 run over the padded prompt builds a fresh one-row cache
+        # (zeros, inside the traced apply) and (1, 1, vocab) logits...
         logits, mutated = self._model.apply(
             {"params": params}, tokens,
             positions=jnp.zeros((1,), jnp.int32), lengths=prompt_len[None],
             train=False, mutable=["cache", "kernel_stats"])
-        # a sparse layer's prompt kernel hands up the share of key blocks
-        # it ran; it rides to the host beside max |logit|
-        shares = jax.tree.leaves(mutated.get("kernel_stats", {}))
+        # what the layers' prompt kernels handed up, by the name it was
+        # sown under; the layers' mean rides beside max |logit|
+        handed: Dict[str, list] = {}
+        for path, x in jax.tree_util.tree_leaves_with_path(
+                mutated.get("kernel_stats", {})):
+            name = [k.key for k in path if hasattr(k, "key")][-1]
+            handed.setdefault(name, []).append(x)
         if self._dense_len is not None:
-            self._sparse_kernel = bool(shares)
+            self._sparse_kernel = bool(handed)
         # ...written into the slot row at a traced index (in place: the
         # big cache is donated), so every prompt of this bucket reuses
         # one program regardless of slot; a counter is no slot's row: the
         # prompt's counts are added to it
         cache = jax.tree_util.tree_map_with_path(
             lambda path, big, one: big + one
-            if leaf_kind(path) == "counter"
+            if self.leaf_kind(path) == "counter"
             else jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
         last = logits[0, 0]
@@ -488,8 +385,11 @@ class DecodeEngine:
         # the slot's first decode step reads its token from the feed
         feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
         max_abs = jnp.max(jnp.abs(last))
-        if shares:
-            max_abs = jnp.stack([max_abs, jnp.mean(jnp.stack(shares))])
+        if handed:
+            self._kernel_stats = tuple(sorted(handed))
+            max_abs = jnp.stack([max_abs, *(
+                jnp.mean(jnp.stack(handed[name]))
+                for name in self._kernel_stats)])
         return cache, feed, token, max_abs
 
     def _piece(self, params, cache, tokens, offset, length, slot, output):
@@ -500,7 +400,7 @@ class DecodeEngine:
         request left - and the row it leaves is written back in place. A
         counter is no slot's row: the model adds to it as it stands."""
         def row(path, big):
-            if leaf_kind(path) == "counter":
+            if self.leaf_kind(path) == "counter":
                 return big
             one = jax.lax.dynamic_index_in_dim(big, slot, axis=0)
             return jnp.where(offset == 0, 0, one)
@@ -511,7 +411,7 @@ class DecodeEngine:
             tokens, positions=offset[None], lengths=length[None],
             train=False, mutable=["cache"], output=output)
         cache = jax.tree_util.tree_map_with_path(
-            lambda path, big, one: one if leaf_kind(path) == "counter"
+            lambda path, big, one: one if self.leaf_kind(path) == "counter"
             else jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
         return out, cache
@@ -553,12 +453,12 @@ class DecodeEngine:
     # -- serving ops -------------------------------------------------------
     def prefill(self, slot: int, prompt: List[int]) -> PendingPrefill:
         """Launch the prompt's prefill, which fills ``slot``'s cache rows
-        and puts the first generated token (it comes from prefill
-        itself) into the slot's feed entry: one program of the prompt's
-        bucket, or, where the model's prefill resumes from its cache,
-        the prompt's pieces of ``PREFILL_CHUNK`` tokens back to back
-        through ``prefill_chunk`` and, the last one, ``prefill_last``.
-        The result collects to (first generated token id, max |logit|)."""
+        and puts the first generated token into the slot's feed entry:
+        one program of the prompt's bucket, or, where the model's prefill
+        resumes, the prompt's pieces of ``PREFILL_CHUNK`` tokens back to
+        back through ``prefill_chunk`` (the slot's row of every leaf in,
+        zeros for the first piece; no head) and ``prefill_last``. The
+        result collects to (first generated token id, max |logit|)."""
         if not 0 < len(prompt) <= self.max_seq:
             # callers (ServeHandle.submit, Replica._reject) screen this
             # out; fail loudly rather than let the padded copy below
@@ -602,19 +502,19 @@ class DecodeEngine:
         self.prefill_tokens += len(prompt)
         return PendingPrefill(token, max_abs, t0, dict(
             bucket=bucket, chunks=chunks, prompt_len=len(prompt), slot=slot,
-            sparse=sparse, state_bytes=self._state_bytes // self.num_slots))
+            sparse=sparse, state_bytes=self._state_bytes // self.num_slots),
+            self._kernel_stats)
 
     def decode(self, slots: List[int], tokens: Optional[List[int]],
                positions: List[int]) -> PendingDecode:
         """Launch one decode step over ALL cache rows (fixed shape — the
         one compiled decode program). Active rows take their token from
-        the feed at their real position; inactive rows run token 0 at
-        position 0, whose cache write lands where the next prefill
-        overwrites it. ``tokens`` is ``None`` where the rows' tokens are
-        in the feed (the serving loop, whose last programs put them
-        there); a caller that has them on the host instead passes them,
-        and they replace the feed first (one transfer more). The result
-        collects to (ids, max |logit|s) of ``slots``."""
+        the feed at their real position; the others run token 0 at
+        position 0. ``tokens`` is ``None`` where the rows' tokens are in
+        the feed (the serving loop, whose last programs put them there);
+        a caller that has them on the host passes them, and they replace
+        the feed first (one transfer more). The result collects to (ids,
+        max |logit|s) of ``slots``."""
         if tokens is not None:
             feed = np.zeros((self.num_slots,), np.int32)
             feed[slots] = tokens
@@ -635,24 +535,18 @@ class DecodeEngine:
                     f"decode: slot {slot} position {step_pos[slot]} >= "
                     f"max_seq {self.max_seq} (admission cap violated)")
             attrs = {"state_bytes": self._state_bytes}
-            if self._reads_live_tiles or self._reads_live_groups:
-                # what the kernel will fetch: a row that is not active
+            if self._step_readers:
+                # what the kernels will fetch: a row that is not active
                 # runs at position 0 and costs one tile
-                kernel = decode_attention if self._reads_live_tiles \
-                    else grouped_decode_attention
-                read, held = kernel.live_tiles(step_pos, self.max_seq)
-                self.kv_tiles_read += read
-                self.kv_tiles_held += held
-                attrs["kv_read_share"] = round(read / held, 4)
-            elif self._reads_live_latents:
-                read, held, attended = latent_attention.live_tiles(
-                    step_pos, self.max_seq)
+                read, held, attended = map(sum, zip(*(
+                    live_tiles(step_pos, self.max_seq)
+                    for live_tiles in self._step_readers)))
                 self.kv_tiles_read += read
                 self.kv_tiles_held += held
                 self.positions_read += attended
                 attrs["kv_read_share"] = round(read / held, 4)
-            by_kind = self._positions_by_kind(np.maximum(step_pos, 0)) \
-                if self._positions_by_kind else {}
+            by_kind = self._step_reads(np.maximum(step_pos, 0)) \
+                if self._step_reads else {}
             for kind, attended in by_kind.items():
                 self.positions_by_kind[kind] = \
                     self.positions_by_kind.get(kind, 0) + attended
@@ -673,15 +567,13 @@ class DecodeEngine:
                              else 0.9 * self.step_ms_ewma + 0.1 * ms)
 
     def expert_counts(self) -> Optional[np.ndarray]:
-        """The expert layers' running counts, (layers, 3, experts)
-        uint32 in layer order (``models/hybrid.py`` ``RoutedExperts``:
-        pairs routed by both programs, decode steps that hit the expert,
-        decode steps); ``None`` for a model that counts nothing. The one
-        place a counter is read. The counts run modulo 2**32: subtract
-        two readings as uint32. Under the lock only a copy on the device
-        is enqueued (no program: nothing compiles), behind the step in
-        flight; this thread then waits for it without holding the
-        engine's next dispatch."""
+        """The ``counter`` leaves, (layers, 3, experts) uint32 in layer
+        order (pairs routed by both programs, decode steps that hit the
+        expert, decode steps; modulo 2**32: subtract two readings as
+        uint32); ``None`` for a model that counts nothing. The one place a
+        counter is read: under the lock only a copy on the device is
+        enqueued, behind the step in flight (no program: nothing
+        compiles); this thread then waits for it outside the lock."""
         return self._expert_counts()[0]
 
     def _expert_counts(self) -> Tuple[Optional[np.ndarray], float]:
@@ -695,7 +587,7 @@ class DecodeEngine:
             found = [(jax.tree_util.keystr(path),
                       jax.device_put(x, may_alias=False)) for path, x
                      in jax.tree_util.tree_leaves_with_path(self._cache)
-                     if leaf_kind(path) == "counter"]
+                     if self.leaf_kind(path) == "counter"]
         by_layer = sorted(found, key=lambda kv: [
             int(n) for n in re.findall(r"\d+", kv[0])])
         return np.stack([np.asarray(x) for _, x in by_layer]), waited
@@ -710,12 +602,12 @@ class DecodeEngine:
             span.set(lock_ms=round(waited * 1e3, 4))
             with self._lock:
                 compiles = dict(self._compiles)
+            # read by name (the benchmark; tests/test_engine_contract.py
+            # lists the keys); __init__ says what each counter holds
             return {"compiles": compiles,
                     "compiles_total": sum(compiles.values()),
                     "decode_steps": self.decode_steps,
                     "decode_step_ms_ewma": round(self.step_ms_ewma, 3),
-                    # prefill programs enqueued, positions they computed and
-                    # the prompts' own tokens (positions / tokens: the padding)
                     "prefill_chunks": self.prefill_chunks,
                     "prefill_positions": self.prefill_positions,
                     "prefill_tokens": self.prefill_tokens,
@@ -723,36 +615,20 @@ class DecodeEngine:
                     "cache_bytes_by_kind": self.cache_bytes_by_kind(),
                     "cache_donated": (self._donated.get("prefill", False)
                                       and self._donated.get("decode", False)),
-                    # lane tiles of a key/value leaf the decode steps read
-                    # over the tiles of all rows; None where the decode
-                    # program reads whole rows (no decode-attention kernel
-                    # in it), holds no keys or values at all, or has not run
+                    # None, never 1.0, where no step ran through a reader
                     "decode_kv_read_share": (
                         round(self.kv_tiles_read / self.kv_tiles_held, 4)
                         if self.kv_tiles_held else None),
-                    # the attention kernel writes the step's new key and
-                    # value columns itself (the decode program holds no
-                    # kv_cache_write); None where it holds no such kernel
                     "decode_write_fused": self._write_fused,
-                    # a block-sparse model's prefill programs attend
-                    # through the prompt kernel (None: no such layer, or
-                    # no prefill traced yet)
                     "prefill_sparse_kernel": self._sparse_kernel,
-                    # positions the latent kernel's steps attended (a row
-                    # that is not active: one), None without that kernel
                     "decode_positions_read": (self.positions_read
-                                              if self._reads_live_latents
+                                              if self._counts_positions
                                               else None),
-                    # positions the decode steps attended, all layers of
-                    # a kind together, by the kind of leaf they were read
-                    # from (``kv``, ``ring``); None for a model whose
-                    # layers all keep one kind
                     "decode_positions_by_kind": (
                         dict(self.positions_by_kind)
-                        if self._positions_by_kind else None),
-                    # (layers, 3, experts) as nested lists: pairs, decode
-                    # steps that hit the expert, decode steps, each modulo
-                    # 2**32 (None: the model has no expert layer)
+                        if self._step_reads else None),
+                    # (layers, 3, experts) as nested lists, each modulo
+                    # 2**32 (None: the model counts nothing)
                     "expert_counts": (None if counts is None
                                       else counts.tolist()),
                     "slots": self.num_slots}

@@ -10,13 +10,17 @@ behind ``hvd.serve()`` in ``tests/test_engine_serving.py``.
 
 import time
 
+import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.models import hybrid
 from horovod_tpu.serve import kv_cache
 from horovod_tpu.serve.kv_cache import DecodeEngine, prompt_bucket
-from toy_models import FAMILIES, family, prefill_spans, step_logits, tokens
+from toy_models import (FAMILIES, family, prefill_spans, step_logits, tokens,
+                        toy_transformer, uncached_greedy)
 
 # A model that resumes (Brumby) has its prompts cut into pieces of
 # PREFILL_CHUNK, set here to the toy mixer's own chunk, and the last one
@@ -145,3 +149,87 @@ def test_the_paged_engine_refuses_a_model_without_pages(name):
     fam = family(name)
     with pytest.raises(ValueError, match=fam.no_pages):
         PagedDecodeEngine(fam.model, fam.params, num_slots=2)
+
+
+# what ``DecodeEngine.stats()`` publishes, written out: the benchmark's
+# runners and layer metrics read these by name
+STATS_KEYS = {
+    "compiles", "compiles_total", "decode_steps", "decode_step_ms_ewma",
+    "prefill_chunks", "prefill_positions", "prefill_tokens", "cache_bytes",
+    "cache_bytes_by_kind", "cache_donated", "decode_kv_read_share",
+    "decode_write_fused", "prefill_sparse_kernel", "decode_positions_read",
+    "decode_positions_by_kind", "expert_counts", "slots"}
+
+
+@pytest.mark.parametrize("name", ["trunk", *FAMILIES])
+def test_every_cache_leaf_has_a_declared_kind(engines, name):
+    """The contract's ``cache_kinds`` covers the model's whole cache (a
+    variable no layer declared would count as ``other``), and ``stats()``
+    has the keys it always had, no more and no fewer."""
+    engine = DecodeEngine(*toy_transformer(128), num_slots=2) \
+        if name == "trunk" else engines(name)
+    leaves = jax.tree_util.tree_leaves_with_path(engine._cache)
+    kinds = {engine.leaf_kind(path) for path, _ in leaves}
+    assert leaves and "other" not in kinds
+    stats = engine.stats()
+    assert set(stats) == STATS_KEYS
+    assert kinds <= set(stats["cache_bytes_by_kind"])
+    assert all(stats["cache_bytes_by_kind"][kind] > 0 for kind in kinds)
+
+
+class RunningMean(nn.Module):
+    """A layer kind that lives in this file alone: the mean of a value
+    projection over the tokens so far, whose whole cache is one running
+    sum a slot."""
+
+    decode: bool = False
+    eps: float = 1e-6
+    dtype: object = jnp.float32
+    param_dtype: object = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, positions, lengths=None):
+        batch, seq, width = x.shape
+        value = nn.Dense(width, use_bias=False, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="value")(x)
+        if self.decode and seq == 1:
+            total = self.variable("cache", "running_sum", jnp.zeros,
+                                  (batch, width), jnp.float32)
+            total.value = total.value + value[:, 0]
+            return total.value[:, None] / (positions + 1)[:, None, None]
+        sums = jnp.cumsum(value, axis=1)
+        if self.decode:         # the sum after the prompt's true tokens
+            last = (seq if lengths is None else lengths) - 1
+            self.variable("cache", "running_sum", jnp.zeros, (batch, width),
+                          jnp.float32).value = jnp.take_along_axis(
+                              sums, jnp.broadcast_to(last, (batch,))[
+                                  :, None, None], axis=1)[:, 0]
+        return sums / jnp.arange(1, seq + 1)[None, :, None]
+
+
+def test_a_layer_kind_declared_outside_the_package_is_served(monkeypatch):
+    """One entry of ``hybrid.MIXERS`` is all a new kind of layer needs:
+    nothing under ``horovod_tpu/serve/`` knows it, and the engine reports
+    its cache under the kind it declared and decodes what the uncached
+    forward does."""
+    monkeypatch.setitem(hybrid.MIXERS, "running_mean", hybrid.Mixer(
+        RunningMean, lambda decoder, i: dict(decode=decoder.decode),
+        {"running_sum": "tally"}))
+    model = hybrid.HybridDecoder(
+        vocab_size=61, d_model=32, d_ff=64, num_heads=2, num_kv_heads=2,
+        head_dim=16, mixers=("running_mean", "running_mean"), max_seq=64,
+        dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(3),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = DecodeEngine(model, params, num_slots=2)
+    assert engine.cache_bytes_by_kind() == {
+        "kv": 0, "compressed": 0, "state": 0, "tally": 2 * 2 * 32 * 4}
+    prompt = [5, 17, 3, 44, 9]
+    got = [engine.prefill(1, prompt).collect()[0]]
+    for step in range(7):
+        got.append(engine.decode([1], None, [len(prompt) + step])
+                   .collect()[0][0])
+    assert got == uncached_greedy(model, params, prompt, 8)
+    assert engine.stats()["cache_bytes_by_kind"]["tally"] == 512
+    with pytest.raises(ValueError, match="unknown mixer 'running_sum'"):
+        hybrid.mixer_of("running_sum")

@@ -197,7 +197,7 @@ def test_hybrid_decode_reports_no_kv_read_share(served):
 
     _, params, model = served
     engine = DecodeEngine(model, params, num_slots=2)
-    assert model.max_seq % 128 == 0 and not engine._reads_live_tiles
+    assert model.max_seq % 128 == 0 and "decode_attention" not in engine.decode_kernels
     program = jax.make_jaxpr(engine._decode_impl)(
         params, engine._cache, engine._feed, jnp.zeros((2,), jnp.int32))
     kernels = set(kernels_in(program))

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.runners import serve_xing
-from horovod_tpu.serve.kv_cache import DecodeEngine, leaf_kind
+from horovod_tpu.serve.kv_cache import DecodeEngine
 from toy_models import family, tokens, xing
 
 
@@ -31,7 +31,8 @@ def test_the_latent_kernels_tiles_and_the_engines_counters():
     assert latent_attention.live_tiles([0, 5000, -1], 8192) == (7, 24, 5003)
     cfg, params, model = xing()
     engine = DecodeEngine(model, params, num_slots=2)
-    assert engine._reads_live_latents and not engine._reads_live_tiles
+    assert "latent_decode_attention" in engine.decode_kernels
+    assert "decode_attention" not in engine.decode_kernels
     assert engine.stats()["decode_positions_read"] == 0
     first, _ = engine.prefill(0, tokens(41).tolist())
     engine.decode([0], [first], [41]).collect()
@@ -87,7 +88,7 @@ def test_the_expert_counter_wraps_and_differences_stay_right():
     engine = DecodeEngine(model, params, num_slots=2)
     engine._cache = jax.tree_util.tree_map_with_path(
         lambda path, x: jnp.full_like(x, 2 ** 32 - 7)
-        if leaf_kind(path) == "counter" else x, engine._cache)
+        if engine.leaf_kind(path) == "counter" else x, engine._cache)
     compiles = harness.CompileCounter()
     before = engine.expert_counts()
     assert before.dtype == np.uint32 and (before == 2 ** 32 - 7).all()

@@ -159,7 +159,8 @@ def test_the_cache_holds_states_and_nothing_else(served):
     leaves = jax.tree_util.tree_leaves_with_path(engine._cache)
     assert sorted(x.shape for _, x in leaves) == sorted(
         [(2, GROUPS, TURNS, D, D), (2, GROUPS, TURNS, D)] * 2)
-    assert not engine._reads_live_tiles and engine._dense_len is None
+    assert "decode_attention" not in engine.decode_kernels
+    assert engine._dense_len is None
     first, _ = engine.prefill(0, tokens(41).tolist())
     engine.decode([0], [first], [41]).collect()
     with warnings.catch_warnings():

@@ -677,12 +677,12 @@ def test_dense_engine_decodes_as_the_masked_path(tiled_lm, monkeypatch):
         return out
 
     kernel = DecodeEngine(model, params, num_slots=4)
-    assert kernel._reads_live_tiles
+    assert "decode_attention" in kernel.decode_kernels
     assert kernel.stats()["decode_write_fused"] is True
     got = serve(kernel)
     monkeypatch.setattr(transformer, "takes_kernel", lambda *_: False)
     masked = DecodeEngine(model, params, num_slots=4)
-    assert not masked._reads_live_tiles
+    assert "decode_attention" not in masked.decode_kernels
     assert masked.stats()["decode_kv_read_share"] is None
     assert masked.stats()["decode_write_fused"] is None
     assert serve(masked) == got

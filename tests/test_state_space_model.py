@@ -21,7 +21,7 @@ import pytest
 from benchmark import controls_granite, flops_granite, weights_granite
 from horovod_tpu import tracing
 from horovod_tpu.models import hybrid
-from horovod_tpu.serve.kv_cache import DecodeEngine, leaf_kind
+from horovod_tpu.serve.kv_cache import DecodeEngine
 from toy_models import granite, granite_reference, step_logits, tokens
 
 F32_TOL = 5e-5
@@ -108,14 +108,14 @@ def test_a_reused_slot_never_sees_its_earlier_occupant():
     assert first == want[1].argmax()
     after = jax.tree.map(np.asarray, engine._cache)
     for path, leaf in jax.tree_util.tree_leaves_with_path(after):
-        kind = leaf_kind(path)
+        kind = engine.leaf_kind(path)
         if kind in ("state", "conv"):
             old = dict(jax.tree_util.tree_leaves_with_path(before))[path]
             assert np.abs(leaf[1] - old[1]).max() > 1e-3, path
 
     def poisoned(position):
         def one(path, leaf):
-            kind = leaf_kind(path)
+            kind = engine.leaf_kind(path)
             if kind == "kv":      # nothing past ``position`` is ours
                 return jnp.where(
                     jnp.arange(leaf.shape[-1]) > position, 1e4, leaf)
@@ -230,7 +230,7 @@ def test_the_slot_cache_holds_states_tails_rows_and_counters():
     engine = DecodeEngine(model, params, num_slots=3)
     kinds = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(engine._cache):
-        kinds.setdefault(leaf_kind(path), []).append(leaf)
+        kinds.setdefault(engine.leaf_kind(path), []).append(leaf)
     assert {k: len(v) for k, v in kinds.items()} == {
         "state": 9, "conv": 9, "kv": 2, "counter": 10}
     assert all(x.shape == (3, HEADS, P, N) and x.dtype == jnp.float32

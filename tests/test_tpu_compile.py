@@ -413,7 +413,7 @@ def test_retention_engine_fits_and_rewrites_its_cache_in_place(
     assert eng.cache_bytes_by_kind() == {
         "kv": 0, "compressed": 0,
         "state": layers * slots * 8 * turns * 128 * (128 + 1) * 4}
-    assert not eng._reads_live_tiles
+    assert "decode_attention" not in eng.decode_kernels
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
@@ -508,8 +508,9 @@ def test_latent_experts_engine_fits_and_updates_its_cache_in_place(
         "kv": 0, "compressed": 0, "state": 0,
         "latent": layers * slots * seq * (512 + 64) * 2,     # 3.62 GB
         "counter": (layers - 1) * 3 * 64 * 4}
-    assert not eng._reads_live_tiles and eng._counts
-    assert eng._reads_live_latents
+    assert eng._counts
+    assert "latent_decode_attention" in eng.decode_kernels
+    assert "decode_attention" not in eng.decode_kernels
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
@@ -595,8 +596,9 @@ def test_window_full_engine_fits_and_updates_its_cache_in_place(
         "compressed": 0, "state": 0,
         "ring": window * 2 * slots * 128 * 8 * 128 * 2,     # 0.10 GB
         "counter": (full + window - 1) * 3 * 8 * 4}
-    assert eng._reads_live_groups and eng._counts
-    assert not eng._reads_live_tiles and not eng._reads_live_latents
+    assert eng._counts and "grouped_decode_attention" in eng.decode_kernels
+    assert not {"decode_attention", "latent_decode_attention"} \
+        & set(eng.decode_kernels)
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
@@ -689,8 +691,9 @@ def test_state_space_engine_fits_and_updates_its_cache_in_place(
         "state": ssm_layers * slots * 128 * 64 * 128 * 4,        # 2.42 GB
         "conv": ssm_layers * slots * 3 * 8448 * 2,               # 0.03 GB
         "counter": (ssm_layers + 1) * 3 * 36 * 4}
-    assert eng._reads_live_groups and eng._counts
-    assert not eng._reads_live_tiles and not eng._reads_live_latents
+    assert eng._counts and "grouped_decode_attention" in eng.decode_kernels
+    assert not {"decode_attention", "latent_decode_attention"} \
+        & set(eng.decode_kernels)
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)

@@ -23,7 +23,7 @@ from benchmark import reference_kexaone as kref
 from benchmark import weights_kexaone
 from horovod_tpu.models import hybrid
 from horovod_tpu.ops.pallas import grouped_decode_attention as gda
-from horovod_tpu.serve.kv_cache import DecodeEngine, leaf_kind
+from horovod_tpu.serve.kv_cache import DecodeEngine
 from toy_models import kexaone, kexaone_reference, step_logits, tokens
 
 F32_TOL = 5e-5
@@ -103,7 +103,7 @@ def test_a_shorter_request_never_reads_the_slots_earlier_occupant():
         return jax.tree_util.tree_map_with_path(
             lambda path, leaf: jnp.where(
                 jnp.arange(leaf.shape[-1]) > position, 1e4, leaf)
-            if leaf_kind(path) in ("kv", "ring") else leaf, engine._cache)
+            if engine.leaf_kind(path) in ("kv", "ring") else leaf, engine._cache)
 
     engine._cache = poisoned_past(39)
     for t in range(40, 60):
@@ -222,7 +222,8 @@ def test_grouped_decode_kernel_reads_the_live_tiles_alone(dtype, tol):
     again = gda.grouped_decode_attention(q, k + stale, v + stale, pos,
                                          d ** -0.5)
     assert np.array_equal(np.asarray(f32(again)), np.asarray(f32(got)))
-    assert gda.live_tiles(np.asarray(pos), seq) == (1 + 1 + 2 + 3 + 4, 20)
+    assert gda.live_tiles(np.asarray(pos), seq) == (
+        1 + 1 + 2 + 3 + 4, 20, int(np.asarray(pos).sum()) + 5)
 
 
 def _moe(cfg, params, first, count):
@@ -276,7 +277,8 @@ def test_the_decode_span_says_what_was_read_of_each_kind():
 
     _, params, model = kexaone()
     engine = DecodeEngine(model, params, num_slots=2)
-    assert engine._reads_live_groups and not engine._reads_live_tiles
+    assert "grouped_decode_attention" in engine.decode_kernels \
+        and "decode_attention" not in engine.decode_kernels
     assert engine.stats()["decode_positions_by_kind"] == {}
     first, _ = engine.prefill(0, tokens(141).tolist())
     began = time.time()
