@@ -8,8 +8,10 @@ state-space layers ("mamba2": a selective scan, the decay a token a
 head from the input), on a modern trunk -
 RMSNorm on a sublayer's input or on its output, a SiLU-gated MLP or a
 top-k router (sigmoid or softmax) over such MLPs ("experts", all of
-them or this chip's share) beside a shared one, rotary positions (plain
-or YaRN) or none, grouped key/value heads, per-head QK-norm or none,
+them or this chip's share) beside a shared one or none, rotary positions
+(plain or YaRN) or none, a causal mask or one that is causal over blocks
+and open inside them (``block_len``: generation by diffusion over
+blocks), grouped key/value heads, per-head QK-norm or none,
 sigmoid output gates, one residual stream or several hyper-connected
 ones, a head of its own or the embedding's, and (optional) muP scalings
 or fixed multipliers.
@@ -22,9 +24,11 @@ minicpm-sala.json``; the plain reference is
 reference_brumby.py``), for Xing4.0-29B-A4B (``benchmark/configs/
 xing4-29b-a4b.json``, ``benchmark/reference_xing.py``), for
 K-EXAONE-236B-A23B (``benchmark/configs/k-exaone-236b-a23b.json``,
-``benchmark/reference_kexaone.py``) and for granite-4.0-h-small
+``benchmark/reference_kexaone.py``), for granite-4.0-h-small
 (``benchmark/configs/granite-4.0-h-small.json``,
-``benchmark/reference_granite.py``). The blocks (:class:`RMSNorm`,
+``benchmark/reference_granite.py``) and for SDAR-30B-A3B-Chat
+(``benchmark/configs/sdar-30b-a3b.json``,
+``benchmark/reference_sdar.py``). The blocks (:class:`RMSNorm`,
 :class:`GatedMlp`, :func:`rope`, :class:`BlockSparseAttention`,
 :class:`LightningAttention`, :class:`PowerRetention`,
 :class:`LatentAttention`, :class:`GroupedQueryAttention`,
@@ -61,7 +65,9 @@ leaf with a position axis):
 * ``expert_counts`` ``(3, experts)`` uint32 - an expert layer's running
   count, the one leaf that is no slot's row (:class:`RoutedExperts`).
 
-A call with one token a row is a decode step; a call with more is a
+A call with one token a row is a decode step (``block_len`` tokens a
+row where the model generates by blocks: one pass over every row's
+block, :class:`GroupedQueryAttention`); a call with more is a
 prefill from position 0, which computes the prompt without the cache and
 then fills it; a model of power-retention layers alone, handed a slot's
 cache and the ``positions`` where the pieces before ended, continues the
@@ -99,7 +105,8 @@ from horovod_tpu.ops.pallas import (grouped_decode_attention,
                                     latent_attention, power_retention,
                                     sparse_attention)
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
-from horovod_tpu.ops.pallas.kv_cache_write import LANES, write_token
+from horovod_tpu.ops.pallas.kv_cache_write import (LANES, write_block,
+                                                   write_token)
 
 Dtype = Any
 F32 = jnp.float32
@@ -1076,17 +1083,18 @@ def window_prompt_attention(q, k, v, window, scale, dtype):
     return out[:, :seq]
 
 
-def full_prompt_attention(q, k, v, scale):
+def full_prompt_attention(q, k, v, scale, block_len=1):
     """Causal attention of a whole prompt from position 0 through the
     flash kernel, which has one key/value head a query head: each group's
     keys and values are repeated to its queries. ``q``: (batch, seq,
-    heads, d); ``k``/``v``: (batch, seq, kv_heads, d). Returns (batch,
-    seq, heads, d)."""
+    heads, d); ``k``/``v``: (batch, seq, kv_heads, d). ``block_len`` > 1:
+    causal over blocks of that many positions and open inside one.
+    Returns (batch, seq, heads, d)."""
     per = q.shape[2] // k.shape[2]
     heads_first = lambda t: t.transpose(0, 2, 1, 3)
     wide = lambda t: jnp.repeat(heads_first(t), per, axis=1)
     o = flash_attention(heads_first(q), wide(k), wide(v), causal=True,
-                        sm_scale=scale)
+                        sm_scale=scale, block_len=block_len)
     return o.transpose(0, 2, 1, 3)
 
 
@@ -1126,13 +1134,24 @@ class GroupedQueryAttention(nn.Module):
     ``ring_key`` / ``ring_value`` ``(batch, kv_heads, head_dim, ring)``,
     position ``p`` in column ``p mod ring`` (:func:`ring_len`): a prefill
     leaves the prompt's last ``ring`` positions there (``lengths``: the
-    true length) and a decode step writes over the oldest column."""
+    true length) and a decode step writes over the oldest column.
+
+    With ``block_len`` > 1 (a full layer) the mask is causal over blocks
+    of that many positions and open inside one: a prompt goes through the
+    flash kernel's block form, and a call of ``block_len`` tokens a row is
+    one pass over a block that starts at ``positions``. It writes the
+    block's keys and values over the block's columns of the cache and
+    every query attends positions ``0 .. positions + block_len - 1``
+    (``grouped_decode_attention.grouped_block_attention``). The columns
+    are provisional: the next pass over the same block writes them again,
+    and what the block's last pass wrote is what later blocks read."""
 
     num_heads: int
     num_kv_heads: int
     head_dim: int
     window: Optional[int] = None
     rotary: bool = False
+    block_len: int = 1
     rope_theta: float = 10000.0
     qk_norm: bool = True
     scale: Optional[float] = None
@@ -1170,9 +1189,17 @@ class GroupedQueryAttention(nn.Module):
                 self.dtype) for name in (
                     ("ring_key", "ring_value") if window
                     else ("cached_key", "cached_value")))
+        if self.block_len > 1 and window:
+            raise ValueError("a window layer has no block form")
         with jax.named_scope("window_attention" if window
                              else "full_attention"):
-            if self.decode and seq == 1:
+            if self.decode and seq == self.block_len > 1:
+                keys.value = write_block(keys.value, k, positions)
+                values.value = write_block(values.value, v, positions)
+                o = grouped_decode_attention.grouped_block_attention(
+                    q.reshape(batch, seq, groups, heads // groups, d),
+                    keys.value, values.value, positions, scale)
+            elif self.decode and seq == 1:
                 column = positions % keys.value.shape[-1] if window \
                     else positions
                 keys.value = write_token(keys.value, k[:, 0], column)
@@ -1202,7 +1229,7 @@ class GroupedQueryAttention(nn.Module):
                             axis=-1), 0).astype(self.dtype)
                     keys.value, values.value = pick(k), pick(v)
             else:
-                o = full_prompt_attention(q, k, v, scale)
+                o = full_prompt_attention(q, k, v, scale, self.block_len)
                 if self.decode:
                     keys.value = write_cache_rows(keys.value, k, positions)
                     values.value = write_cache_rows(values.value, v,
@@ -1424,21 +1451,26 @@ class StateSpace(nn.Module):
 # expected here: a layer that holds a share of the experts gets that share
 # of a step's pairs), every held expert multiplies every row under a 0/1
 # weight (experts_masked); above it the pairs are sorted and grouped
-# (experts_grouped, experts_grouped_held). Three points are measured on
-# the chip, each a decode step of 32-64 rows, and the masked product won
-# at each: 64 rows x top-4 over 64 experts, 4 pairs an expert, within
+# (experts_grouped, experts_grouped_held). Four points are measured on
+# the chip, each a decode step, and the masked product won at each: 64 rows x top-4 over 64 experts, 4 pairs an expert, within
 # 8-16% of the time of reading the experts (PERF.md, PR 34); 32 rows x
 # top-8 over 8 of 128 experts, 2 pairs an expert of the 16 expected
 # here, at the time of reading them (0.84 ms a layer for 681 MB; PERF.md,
 # PR 39); 64 rows x top-10 over 36 of 72 experts of width 768, 8.9 pairs
 # an expert, 21.4 ms a step masked against 28.7 grouped (ten layers,
 # 6.8 GB of experts; PERF.md, PR 41): sorting, gathering and scattering
-# 640 rows cost more than multiplying 64 rows by every held expert. The
-# masked product's arithmetic grows with rows x held experts and passes
-# the time of reading them near 100-200 rows; nothing above 64 rows has
-# been measured masked, and 9 keeps every prompt's bucket (17.8 pairs an
-# expert at the least) grouped.
-MASKED_PAIRS = 9
+# 640 rows cost more than multiplying 64 rows by every held expert; 256
+# rows (64 rows' blocks of 4) x top-8 over all 128 experts of width 768,
+# 16 pairs an expert, 15.8 ms a pass masked against 32.0 grouped (six
+# layers, 7.25 GB of experts; the three grouped products 1.49 ms each a
+# layer against 1.2 ms of reading them, and the masked product within
+# 17% of that read: PERF.md, PR 46). The masked product's arithmetic
+# grows with rows x held experts (309 GFLOP a layer at 256 x 128: 1.6
+# ms at the chip's peak, about the time of the read) and passes it
+# beyond; nothing above 256 rows has been measured masked, and 16 keeps
+# every accepted cell's prompt buckets (17.8 pairs an expert at the
+# least) grouped.
+MASKED_PAIRS = 16
 # tokens of a prompt that a layer holding a share of the experts groups at
 # once (experts_grouped_held)
 HELD_TOKENS = 4096
@@ -1589,8 +1621,9 @@ class RoutedExperts(nn.Module):
     (3, count) uint32, a running count and no slot's row: ``[0]`` the
     (token, expert) pairs routed to each held expert by both programs,
     ``[1]`` the decode steps in which some counted row chose it, ``[2]``
-    the decode steps. A prompt's padding (``lengths``) and a decode
-    step's rows outside ``active`` are not counted. The counts are never
+    the decode steps (a call of ``block_len`` tokens a row, every one of
+    which is routed and counted). A prompt's padding (``lengths``) and a
+    decode step's rows outside ``active`` are not counted. The counts are never
     reset and run modulo 2**32 (weeks of serving at a thousand pairs a
     second an expert): a reader takes the difference of two readings in
     uint32, which is right across a wrap."""
@@ -1605,6 +1638,7 @@ class RoutedExperts(nn.Module):
     first: int = 0
     count: Optional[int] = None
     decode: bool = False
+    block_len: int = 1     # tokens a row of a decode step
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = F32
 
@@ -1644,7 +1678,8 @@ class RoutedExperts(nn.Module):
             counts = self.variable("cache", "expert_counts", jnp.zeros,
                                    (3, count), jnp.uint32)
             pairs = jnp.sum(hit, axis=(0, 1, 2), dtype=jnp.uint32)
-            step = jnp.full((count,), int(seq == 1), jnp.uint32)
+            step = jnp.full((count,), int(seq == self.block_len),
+                            jnp.uint32)
             counts.value = counts.value + jnp.stack(
                 [pairs, jnp.minimum(pairs, 1) * step, step])
         flat = x.reshape(batch * seq, d)
@@ -1770,20 +1805,24 @@ def hyper_update(post, res, streams, y):
 # ``decode=True``, each with its kind of leaf (``ServingContract.
 # cache_kinds``); ``resumes``: a prefill that is handed the slot's cache
 # continues from it; ``reads``: (kind of leaf, ``(d, seen) -> positions``)
-# one layer's decode step attends of that leaf, ``seen`` each row's position
-# + 1; ``dense_len(d)``: prompts longer than this select key blocks.
+# one layer's decode step attends of that leaf, ``seen`` the positions each
+# row has once the step's own are written (its position + the step's
+# tokens); ``dense_len(d)``: prompts longer than this select key blocks;
+# ``blocks``: the module has a form for a step of ``block_len`` > 1 tokens
+# a row (:attr:`HybridDecoder.block_len`).
 Mixer = collections.namedtuple(
-    "Mixer", "module fields cache resumes reads dense_len",
-    defaults=(False, None, None))
+    "Mixer", "module fields cache resumes reads dense_len blocks",
+    defaults=(False, None, None, False))
 
 
-def _grouped_query(windowed, cache, reads):
+def _grouped_query(kind, cache, reads):
     return Mixer(GroupedQueryAttention, lambda d, i: dict(
         num_heads=d.num_heads, num_kv_heads=d.num_kv_heads,
-        head_dim=d.head_dim, window=d.window if windowed else None,
-        rotary=windowed, rope_theta=d.rope_theta, qk_norm=d.qk_norm,
-        scale=d.attention_scale, max_cache_len=d.max_seq, decode=d.decode),
-        cache, reads=reads)
+        head_dim=d.head_dim, window=d.window if kind == WINDOW else None,
+        rotary=kind in d.rotary, rope_theta=d.rope_theta,
+        qk_norm=d.qk_norm, scale=d.attention_scale,
+        max_cache_len=d.max_seq, decode=d.decode, block_len=d.block_len),
+        cache, reads=reads, blocks=kind == FULL)
 
 
 # a plain mapping, no registration API: a new kind is one module above and
@@ -1812,11 +1851,11 @@ MIXERS = {
         max_cache_len=d.max_seq, decode=d.decode, **dict(d.latent)),
         {"latent": "latent", "rope_key": "latent"}),
     # every position up to the row's own
-    FULL: _grouped_query(False, {"cached_key": "kv", "cached_value": "kv"},
+    FULL: _grouped_query(FULL, {"cached_key": "kv", "cached_value": "kv"},
                          ("kv", lambda d, seen: seen.sum())),
     # the window's at most
     WINDOW: _grouped_query(
-        True, {"ring_key": "ring", "ring_value": "ring"},
+        WINDOW, {"ring_key": "ring", "ring_value": "ring"},
         ("ring", lambda d, seen: np.minimum(seen, d.window or 0).sum())),
     MAMBA2: Mixer(StateSpace,
                   lambda d, i: dict(decode=d.decode, **dict(d.ssm)),
@@ -1931,8 +1970,9 @@ class HybridDecoder(nn.Module):
     ``latent`` holds :class:`LatentAttention`'s ranks and widths;
     ``ssm`` holds :class:`StateSpace`'s heads and sizes;
     ``window`` is the keys a ``"window"`` layer sees
-    (:class:`GroupedQueryAttention`: rotary positions there and none on a
-    ``"full"`` layer; ``qk_norm`` and ``attention_scale`` are its
+    (:class:`GroupedQueryAttention`; ``rotary`` names the grouped-query
+    kinds whose layers rotate queries and keys, by default the window
+    layers alone; ``qk_norm`` and ``attention_scale`` are its
     ``qk_norm`` and ``scale``); ``norms`` says where a sublayer's norm sits
     (:class:`HybridLayer`); ``layer_barriers`` puts an optimisation
     barrier after every layer of a prompt, so that XLA does not run one
@@ -1951,7 +1991,18 @@ class HybridDecoder(nn.Module):
     ``residual_multiplier``, where given, is the residual scale itself,
     and ``logits_divisor`` divides the logits. ``causal``, ``max_seq``,
     ``vocab_size`` and :meth:`serving` are what
-    ``serve.kv_cache.DecodeEngine`` asks of a model."""
+    ``serve.kv_cache.DecodeEngine`` asks of a model.
+
+    ``block_len`` > 1 is generation by diffusion over blocks (SDAR,
+    arXiv:2510.06303): attention is causal over blocks of that many
+    positions and open inside one, a position predicts its OWN token, a
+    decode step is one pass over a block of ``block_len`` tokens a row
+    (``[MASK]``, ``mask_id``, where the block is still masked) and
+    unmasks ``block_len / denoising_steps`` of its positions, those the
+    model is surest of. The model only declares this (:meth:`serving`);
+    the passes, the choice and the schedule are the engine's
+    (``serve/kv_cache.py``). Every mixer must have a block form
+    (``MIXERS[kind].blocks``: the full grouped-query layers)."""
 
     vocab_size: int
     d_model: int
@@ -1964,6 +2015,7 @@ class HybridDecoder(nn.Module):
     latent: Any = None                  # a mapping; see LatentAttention
     ssm: Any = None                     # a mapping; see StateSpace
     window: Optional[int] = None        # keys a "window" layer sees
+    rotary: Tuple[str, ...] = (WINDOW,)  # grouped-query kinds that rotate
     qk_norm: bool = True
     attention_scale: Optional[float] = None
     norms: str = NORM_INPUT
@@ -1984,6 +2036,9 @@ class HybridDecoder(nn.Module):
     eps: float = 1e-6
     max_seq: int = 2048
     causal: bool = True
+    block_len: int = 1                  # > 1: diffusion over blocks
+    mask_id: Optional[int] = None       # the [MASK] token's id
+    denoising_steps: int = 1            # passes that unmask a whole block
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = F32
     decode: bool = False
@@ -2019,7 +2074,7 @@ class HybridDecoder(nn.Module):
                    in self._mixers() if mixer.reads]
         if not readers:
             return None
-        seen = np.asarray(positions, np.int64) + 1
+        seen = np.asarray(positions, np.int64) + self.block_len
         out = {mixer.reads[0]: 0 for mixer in MIXERS.values() if mixer.reads}
         for leaf, attended, layers in readers:
             out[leaf] += layers * int(attended(self, seen))
@@ -2030,6 +2085,16 @@ class HybridDecoder(nn.Module):
         from :data:`MIXERS` and :data:`MLPS` over this model's layers."""
         layers = [mixer for mixer, _ in self._mixers()] \
             + [MLPS[mlp] for mlp in self.mlps or ()]
+        blocks = {}
+        if self.block_len > 1:
+            if not all(mixer.blocks for mixer, _ in self._mixers()):
+                raise ValueError("block_len > 1 needs mixers with a block "
+                                 f"form; got {self.mixers}")
+            if self.mask_id is None or self.block_len % self.denoising_steps:
+                raise ValueError("block_len > 1 needs a mask_id and "
+                                 "denoising_steps that divide block_len")
+            blocks = dict(block_len=self.block_len, mask_id=self.mask_id,
+                          unmask=self.block_len // self.denoising_steps)
         return ServingContract(
             model=self.clone(decode=True),
             cache_kinds={name: kind for layer in layers
@@ -2038,7 +2103,7 @@ class HybridDecoder(nn.Module):
             wants_active=self.counts_active_rows,
             step_reads=(self.decode_positions_by_kind
                         if any(mixer.reads for mixer, _ in self._mixers())
-                        else None))
+                        else None), **blocks)
 
     @nn.compact
     def __call__(self, token_ids, train: bool = False, positions=None,
@@ -2083,13 +2148,14 @@ class HybridDecoder(nn.Module):
                 kind=kind, mixer_args=mixer_of(kind).fields(self, i),
                 d_ff=self.d_ff, residual_scale=residual_scale,
                 norms=self.norms, mlp=mlps[i],
-                mlp_args=(dict(self.experts, decode=self.decode)
+                mlp_args=(dict(self.experts, decode=self.decode,
+                               block_len=self.block_len)
                           if mlps[i] == EXPERTS_MLP else None),
                 streams=self.streams, hyper_args=self.hyper,
                 eps=self.eps, dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name=f"layer_{i}")(h, positions, lengths, active)
-            if self.layer_barriers and seq > 1:
+            if self.layer_barriers and seq > self.block_len:
                 h = jax.lax.optimization_barrier(h)
         if self.streams > 1:
             h = h.astype(F32).sum(axis=1).astype(self.dtype)

@@ -23,7 +23,15 @@ class ServingContract:
     (the rows that hold a request). ``step_reads``: ``None``, or
     ``positions -> {kind of leaf: positions attended}`` for one decode
     step at ``positions`` (numpy, (rows,), a row that is not active at 0),
-    all layers of a kind together."""
+    all layers of a kind together.
+
+    ``block_len`` > 1: the model generates by diffusion over blocks. A
+    decode step is then one pass over ``block_len`` tokens a row from the
+    block's first position, the logits at a position are of that
+    position's own token, ``mask_id`` stands where a position is still
+    masked, and a pass that unmasks takes ``unmask`` positions (fewer
+    where fewer are left). That is all the engine is told; how it keeps
+    the block, chooses and commits is ``serve/kv_cache.py``'s."""
 
     model: Any
     cache_kinds: Mapping[str, str]
@@ -31,6 +39,9 @@ class ServingContract:
     resumable: bool = False
     wants_active: bool = False
     step_reads: Optional[Callable] = None
+    block_len: int = 1
+    mask_id: Optional[int] = None
+    unmask: int = 1
 
     def leaf_kind(self, path) -> str:
         """The declared kind of the cache leaf at a tree ``path``

@@ -25,6 +25,16 @@ kernels (dq; dk/dv), in two forms chosen from the shapes:
   O(block^2 + block x head_dim) a grid step whatever the sequence length.
   Causal calls skip the blocks in a q block's future.
 
+Both forms take a ``block_len``: the causal mask over blocks of that many
+positions and open inside one (query ``i`` sees key ``j`` where ``j //
+block_len <= i // block_len``; a model that generates by diffusion over
+blocks prefills under it). A tile is a whole number of blocks, so the
+tiles that run and the ones the mask cuts are the causal ones
+(``_key_tiles``, ``_query_tiles``) and only the mask inside the diagonal's
+tiles differs: a row counts as the last row of its block
+(``_row_less_col``, ``_block_end``). ``block_len`` 1 is the causal
+kernels as they were, jaxpr for jaxpr.
+
 This kernel is also the *local* building block of ring attention
 (horovod_tpu/parallel/ring.py): it accepts dynamic ``q_offset``/``k_offset``
 global position scalars and returns the per-row log-sum-exp, so partial
@@ -220,11 +230,23 @@ def _key_pieces(plain, live, tile_k, gap):
                      for j in range(plain, live)]
 
 
-def _row_less_col(tile_q, tile_k):
+def _block_end(ids, block_len):
+    """The last position of the block of ``block_len`` positions that
+    holds each of ``ids`` (``ids`` itself at a block length of 1): a
+    block-causal query sees every key up to there."""
+    if block_len == 1:
+        return ids
+    return ids + (block_len - 1 - jax.lax.rem(ids, jnp.int32(block_len)))
+
+
+def _row_less_col(tile_q, tile_k, block_len=1):
     """Row index less column index over a tile. A tile whose first key
     lies ``g`` positions after its first query row keeps the elements
-    where this is ``>= g``."""
-    return (jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 0)
+    where this is ``>= g``. With ``block_len`` > 1 a row counts as the
+    last row of its block (the tile's first row starts a block): the mask
+    is then causal over blocks and open inside one."""
+    return (_block_end(jax.lax.broadcasted_iota(
+        jnp.int32, (tile_q, tile_k), 0), block_len)
             - jax.lax.broadcasted_iota(jnp.int32, (tile_q, tile_k), 1))
 
 
@@ -242,7 +264,8 @@ def _first_positions(q_off_ref, k_off_ref, ride, block, offsets_zero):
 
 
 def _fwd_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
-                       lse_ref, *, sm_scale, block_q, tile, offsets_zero):
+                       lse_ref, *, sm_scale, block_q, tile, offsets_zero,
+                       block_len=1):
     """Causal forward with the whole key sequence resident. Each
     ``tile_q`` rows of the q block take a direct softmax over the keys
     their rows reach: the tiles wholly under the diagonal as one product,
@@ -252,7 +275,7 @@ def _fwd_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref,
     n_k = k_ref.shape[2] // tile_k
     q_first, k_first = _first_positions(
         q_off_ref, k_off_ref, "q", block_q, offsets_zero)
-    row_less_col = _row_less_col(tile_q, tile_k)
+    row_less_col = _row_less_col(tile_q, tile_k, block_len)
     dot = functools.partial(jax.lax.dot_general,
                             preferred_element_type=jnp.float32)
 
@@ -325,7 +348,7 @@ def _row_stats(lse_ref, delta_ref, rows):
 
 def _bwd_dq_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                           lse_ref, delta_ref, dq_ref, *, sm_scale, block_q,
-                          tile, offsets_zero):
+                          tile, offsets_zero, block_len=1):
     """Causal dq with the whole key sequence resident: each ``tile_q``
     rows sum in float32 over the keys their rows reach (the tiles wholly
     under the diagonal as one piece, those it crosses tile by tile under
@@ -334,7 +357,7 @@ def _bwd_dq_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
     n_k = k_ref.shape[2] // tile_k
     q_first, k_first = _first_positions(
         q_off_ref, k_off_ref, "q", block_q, offsets_zero)
-    row_less_col = _row_less_col(tile_q, tile_k)
+    row_less_col = _row_less_col(tile_q, tile_k, block_len)
 
     for r in range(block_q // tile_q):
         rows = pl.ds(r * tile_q, tile_q)
@@ -361,7 +384,7 @@ def _bwd_dq_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_dkv_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                            lse_ref, delta_ref, dk_ref, dv_ref, *, sm_scale,
-                           block_k, tile, offsets_zero):
+                           block_k, tile, offsets_zero, block_len=1):
     """Causal dk/dv with q, do, lse and delta resident: each ``tile_k``
     keys of the k block sum in float32 over the query rows from the
     diagonal down (the tiles it crosses tile by tile under the mask, the
@@ -371,7 +394,7 @@ def _bwd_dkv_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
     n_q = q_ref.shape[2] // tile_q
     q_first, k_first = _first_positions(
         q_off_ref, k_off_ref, "k", block_k, offsets_zero)
-    row_less_col = _row_less_col(tile_q, tile_k)
+    row_less_col = _row_less_col(tile_q, tile_k, block_len)
 
     for c in range(block_k // tile_k):
         cols = pl.ds(c * tile_k, tile_k)
@@ -411,7 +434,8 @@ def _bwd_dkv_causal_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _fwd_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, sm_scale, causal, block_q, block_k):
+                acc_ref, m_ref, l_ref, *, sm_scale, causal, block_q, block_k,
+                block_len=1):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -440,7 +464,8 @@ def _fwd_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_ids = k_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+            s = jnp.where(_block_end(q_ids, block_len) >= k_ids, s,
+                          NEG_INF)
         m_prev = m_ref[:, 0]
         l_prev = l_ref[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -571,12 +596,28 @@ def _record_share(kind, q_seq, kv_seq, causal, tile):
         live_tile_share(kind, q_seq, kv_seq, causal, tile))
 
 
+def _check_block_len(block_len, causal, offsets_zero, *sides):
+    """A block length over 1 is a block-causal mask (``flash_attention``):
+    it needs a causal call from position 0 (the kernels take a tile's
+    first row for a block's first) whose blocks and tiles are whole
+    numbers of such blocks."""
+    if block_len == 1:
+        return
+    if not causal or not offsets_zero:
+        raise ValueError("block_len > 1 needs causal=True and offsets "
+                         "that are the Python number 0")
+    if any(side % block_len for side in sides):
+        raise ValueError(f"block_len {block_len} does not divide the "
+                         f"sequence blocks {sides}")
+
+
 def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
-               block_q, block_k, interpret, offsets_zero=False):
+               block_q, block_k, interpret, offsets_zero=False, block_len=1):
     batch, heads, q_seq, dim = q.shape
     kv_seq = k.shape[2]
     block_q = _pick_block(q_seq, block_q)
     block_k = _pick_block(kv_seq, block_k)
+    _check_block_len(block_len, causal, offsets_zero, block_q, block_k)
     vma = _vma(q, k, v, q_offset, k_offset)
     out_shape = [
         jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
@@ -592,9 +633,10 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
         if causal:
             block_q, tile, static = _causal_plan(
                 "q", q_seq, block_q, kv_seq, offsets_zero)
+            _check_block_len(block_len, causal, offsets_zero, *tile)
             kernel = functools.partial(
                 _fwd_causal_kernel, sm_scale=sm_scale, block_q=block_q,
-                tile=tile, offsets_zero=static)
+                tile=tile, offsets_zero=static, block_len=block_len)
         else:
             kernel = functools.partial(
                 _fwd_single_kernel, sm_scale=sm_scale, block_q=block_q)
@@ -618,7 +660,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
     q_spec, k_spec, qrow_spec = _make_specs(block_q, block_k, dim)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, block_len=block_len)
 
     o, lse = pl.pallas_call(
         kernel,
@@ -645,7 +687,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, sm_scale, causal,
 
 def _bwd_dq_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_acc_ref,
-                   *, sm_scale, causal, block_q, block_k):
+                   *, sm_scale, causal, block_q, block_k, block_len=1):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -677,7 +719,8 @@ def _bwd_dq_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_ids = k_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+            s = jnp.where(_block_end(q_ids, block_len) >= k_ids, s,
+                          NEG_INF)
         p = jnp.exp2(s - lse_safe[:, None])
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -703,7 +746,8 @@ def _bwd_dq_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_dkv_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc_ref,
-                    dv_acc_ref, *, sm_scale, causal, block_q, block_k):
+                    dv_acc_ref, *, sm_scale, causal, block_q, block_k,
+                    block_len=1):
     ki = pl.program_id(2)
     qj = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -734,7 +778,8 @@ def _bwd_dkv_kernel(q_off_ref, k_off_ref, q_ref, k_ref, v_ref, do_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_ids = k_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+            s = jnp.where(_block_end(q_ids, block_len) >= k_ids, s,
+                          NEG_INF)
         p = jnp.exp2(s - lse_safe[:, None])
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -829,11 +874,13 @@ def compute_delta(o, do) -> jax.Array:
 
 
 def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
-               block_q, block_k, interpret, delta=None, offsets_zero=False):
+               block_q, block_k, interpret, delta=None, offsets_zero=False,
+               block_len=1):
     batch, heads, q_seq, dim = q.shape
     kv_seq = k.shape[2]
     block_q = _pick_block(q_seq, block_q)
     block_k = _pick_block(kv_seq, block_k)
+    _check_block_len(block_len, causal, offsets_zero, block_q, block_k)
 
     if delta is None:
         delta = compute_delta(o, do)
@@ -850,9 +897,10 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
         if causal:
             ride, dq_tile, static = _causal_plan(
                 "q", q_seq, block_q, kv_seq, offsets_zero)
+            _check_block_len(block_len, causal, offsets_zero, *dq_tile)
             kernel = functools.partial(
                 _bwd_dq_causal_kernel, sm_scale=sm_scale, block_q=ride,
-                tile=dq_tile, offsets_zero=static)
+                tile=dq_tile, offsets_zero=static, block_len=block_len)
         else:
             kernel = functools.partial(_bwd_dq_single_kernel,
                                        sm_scale=sm_scale)
@@ -881,9 +929,10 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
         if causal:
             ride, dkv_tile, static = _causal_plan(
                 "k", kv_seq, block_k, q_seq, offsets_zero)
+            _check_block_len(block_len, causal, offsets_zero, *dkv_tile)
             kernel = functools.partial(
                 _bwd_dkv_causal_kernel, sm_scale=sm_scale, block_k=ride,
-                tile=dkv_tile, offsets_zero=static)
+                tile=dkv_tile, offsets_zero=static, block_len=block_len)
         else:
             kernel = functools.partial(_bwd_dkv_single_kernel,
                                        sm_scale=sm_scale)
@@ -913,7 +962,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
         dq = pl.pallas_call(
             functools.partial(
                 _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k),
+                block_q=block_q, block_k=block_k, block_len=block_len),
             grid=(batch, heads, q_seq // block_q, kv_seq // block_k),
             in_specs=[_OFF_SPEC, _OFF_SPEC, q_spec, k_spec, k_spec,
                       q_spec, qrow_spec, qrow_spec],
@@ -939,7 +988,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
         dk, dv = pl.pallas_call(
             functools.partial(
                 _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k),
+                block_q=block_q, block_k=block_k, block_len=block_len),
             grid=(batch, heads, kv_seq // block_k, q_seq // block_q),
             in_specs=[_OFF_SPEC, _OFF_SPEC, kq_q_spec, kq_k_spec,
                       kq_k_spec, kq_q_spec, kq_qrow_spec, kq_qrow_spec],
@@ -965,31 +1014,35 @@ def _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset, *, sm_scale, causal,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, q_offset, k_offset, sm_scale, causal, block_q, block_k,
-           bwd_block_q, bwd_block_k, offsets_zero):
+           bwd_block_q, bwd_block_k, offsets_zero, block_len):
     o, _ = _flash_fwd(q, k, v, q_offset, k_offset, sm_scale=sm_scale,
                       causal=causal, block_q=block_q, block_k=block_k,
-                      interpret=use_interpret(), offsets_zero=offsets_zero)
+                      interpret=use_interpret(), offsets_zero=offsets_zero,
+                      block_len=block_len)
     return o
 
 
 def _flash_vjp_fwd(q, k, v, q_offset, k_offset, sm_scale, causal,
-                   block_q, block_k, bwd_block_q, bwd_block_k, offsets_zero):
+                   block_q, block_k, bwd_block_q, bwd_block_k, offsets_zero,
+                   block_len):
     o, lse = _flash_fwd(q, k, v, q_offset, k_offset, sm_scale=sm_scale,
                         causal=causal, block_q=block_q, block_k=block_k,
-                        interpret=use_interpret(), offsets_zero=offsets_zero)
+                        interpret=use_interpret(), offsets_zero=offsets_zero,
+                        block_len=block_len)
     return o, (q, k, v, o, lse, q_offset, k_offset)
 
 
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, bwd_block_q,
-                   bwd_block_k, offsets_zero, res, do):
+                   bwd_block_k, offsets_zero, block_len, res, do):
     q, k, v, o, lse, q_offset, k_offset = res
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, q_offset, k_offset,
                             sm_scale=sm_scale, causal=causal,
                             block_q=bwd_block_q, block_k=bwd_block_k,
                             interpret=use_interpret(),
-                            offsets_zero=offsets_zero)
+                            offsets_zero=offsets_zero, block_len=block_len)
     zero = jnp.zeros((1,), jnp.int32)
     return dq, dk, dv, zero, zero
 
@@ -1020,8 +1073,16 @@ def flash_attention(
     block_k: int = 1024,
     bwd_block_q: int = 1024,
     bwd_block_k: int = 1024,
+    block_len: int = 1,
 ) -> jax.Array:
     """Fused attention over ``(batch, heads, seq, head_dim)`` inputs.
+
+    ``block_len`` > 1 (with ``causal``, from position 0) makes the mask
+    block-causal: query ``i`` sees key ``j`` where ``j // block_len <= i //
+    block_len``, every key of its own block and of the blocks before it.
+    The tiles that run are the causal ones (a tile is a whole number of
+    blocks, so the diagonal's tiles are the only ones the mask cuts);
+    ``block_len`` 1 is the causal mask and the same kernels as without it.
 
     ``q_offset``/``k_offset`` are the global sequence positions of the first
     query/key row — used by ring attention, where each device holds one
@@ -1041,7 +1102,7 @@ def flash_attention(
     return _flash(q, k, v, _as_offset(q_offset), _as_offset(k_offset),
                   float(sm_scale), bool(causal), int(block_q), int(block_k),
                   int(bwd_block_q), int(bwd_block_k),
-                  _python_zeros(q_offset, k_offset))
+                  _python_zeros(q_offset, k_offset), int(block_len))
 
 
 def flash_attention_partial(
@@ -1081,8 +1142,9 @@ def merge_partials(o_a, lse_a, o_b, lse_b):
 
 
 def attention_reference(q, k, v, *, causal=False, sm_scale=None,
-                        q_offset=0, k_offset=0):
-    """Naive O(seq²) attention — ground truth for kernel tests."""
+                        q_offset=0, k_offset=0, block_len=1):
+    """Naive O(seq²) attention — ground truth for kernel tests
+    (``block_len`` > 1: the block-causal mask of ``flash_attention``)."""
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(q.shape[-1]))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
@@ -1090,7 +1152,7 @@ def attention_reference(q, k, v, *, causal=False, sm_scale=None,
     if causal:
         q_ids = q_offset + jnp.arange(q.shape[2])[:, None]
         k_ids = k_offset + jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+        s = jnp.where(q_ids // block_len >= k_ids // block_len, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows
     return jnp.einsum("bhqk,bhkd->bhqd", p,

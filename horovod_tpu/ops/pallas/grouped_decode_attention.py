@@ -20,6 +20,15 @@ the tiles ``0 .. positions[b] // TILE`` of both leaves and a grid step
 for each tile past them. The new token's columns are written before the
 call (``kv_cache_write``), as the other ``models/hybrid.py`` layers
 write theirs.
+
+A model that generates by blocks runs ``block`` positions a row each
+pass, every one of them seeing the row's cached keys and the block's own
+(:func:`grouped_block_attention`): the same kernel with ``block x per``
+query rows a key/value head (32 at a block of 4 and 8 queries a head,
+still far under the 240 operations a byte at which the chip's matrix
+unit would bound it) and a live length that ends at the block's end. The
+block's columns are in the cache before the call, provisional until the
+pass that leaves them there (``kv_cache_write.write_block``).
 """
 
 from __future__ import annotations
@@ -121,6 +130,24 @@ def grouped_decode_attention(q, k_cache, v_cache, positions, scale):
     return _grouped_decode_attention(q, k_cache, v_cache, positions,
                                      scale=float(scale),
                                      interpret=use_interpret())
+
+
+def grouped_block_attention(q, k_cache, v_cache, starts, scale):
+    """One pass of a block: ``softmax(q . keys * scale) . values`` over
+    positions ``0 .. starts[b] + block - 1`` of each row for every query
+    of the block alike (nothing inside a block is masked): (rows, block,
+    groups, per, head_dim).
+
+    ``q``: (rows, block, groups, per, head_dim); ``k_cache``/``v_cache``:
+    (rows, groups, head_dim, cache_len), the block's own columns already
+    in them; ``starts``: (rows,) int32, the block's first position."""
+    rows, block, groups, per, head_dim = q.shape
+    o = grouped_decode_attention(
+        q.transpose(0, 2, 1, 3, 4).reshape(rows, groups, block * per,
+                                           head_dim),
+        k_cache, v_cache, starts + (block - 1), scale)
+    return o.reshape(rows, groups, block, per, head_dim).transpose(
+        0, 2, 1, 3, 4)
 
 
 # jitted so that a model's layers share one trace and one Mosaic

@@ -56,7 +56,10 @@ class ActiveRequest:
     step) and decides ``done`` on that count: a request ends by length
     alone, so the slot is free for the next admission before the token's
     value has reached the host. Values land in ``generated`` when the
-    step is collected, one pass later (serve/replica.py)."""
+    step is collected, one pass later (serve/replica.py). What the
+    loop calls round a step - :attr:`from_prefill`, :meth:`dispatched`,
+    :meth:`take`, :meth:`answer` - is one token a step here;
+    :class:`BlockRequest` is the other schedule."""
 
     slot: int
     request: Request
@@ -75,6 +78,9 @@ class ActiveRequest:
     queue_wait_s: float = 0.0
     prefill_s: float = 0.0
 
+    from_prefill = 1         # tokens a prefill yields (counted when it
+    #                          is enqueued)
+
     def __post_init__(self):
         if self.max_tokens <= 0:
             self.max_tokens = self.request.max_new_tokens
@@ -88,8 +94,126 @@ class ActiveRequest:
         return self.prompt_len + self.max_tokens
 
     @property
+    def target(self) -> int:
+        """Tokens that have to be enqueued, and then received, before
+        the request is over."""
+        return self.max_tokens
+
+    @property
     def done(self) -> bool:
-        return self.enqueued >= self.max_tokens
+        return self.enqueued >= self.target
+
+    @property
+    def received(self) -> int:
+        """Token values that have reached the host."""
+        return len(self.generated)
+
+    def dispatched(self) -> int:
+        """A decode step over this row is enqueued: the tokens it will
+        deliver, counted now."""
+        self.enqueued += 1
+        self.position += 1
+        return 1
+
+    def take(self, ids) -> int:
+        """The step's value for this row is on the host."""
+        self.generated.append(ids)
+        return 1
+
+    def answer(self) -> dict:
+        """The fields of the :class:`~horovod_tpu.serve.queue.Completion`
+        that hold what was generated."""
+        return {"tokens": list(self.generated)}
+
+
+@dataclasses.dataclass
+class BlockRequest(ActiveRequest):
+    """A slot of a model that generates by diffusion over blocks of
+    ``block_len`` positions (serve/kv_cache.py, Blocks). ``position`` is
+    the first position of the block the slot is working on: the prompt's
+    whole blocks are prefilled, so it starts at ``prompt_len`` rounded
+    down, with the prompt's last ``prompt_len mod block_len`` tokens
+    known in it. A PASS over the block unmasks ``unmask`` of its masked
+    positions (fewer where fewer are left); when none is left, the next
+    pass is the commit, which unmasks nothing and moves ``position`` on
+    by ``block_len``. The engine chooses WHICH positions by the logits,
+    but how MANY a pass unmasks is fixed by this schedule and the
+    lengths, so ``enqueued`` still counts at dispatch, ``done`` is still
+    by length alone and the loop still runs a pass ahead of its readback.
+
+    Whole blocks are generated: ``target`` is ``prompt_len + max_tokens``
+    rounded up to a block, less the prompt. The answer is the first
+    ``max_tokens`` of them; the rest of the last block is ``cut``. No
+    commit follows the last block: nothing would read its columns.
+    ``generated`` holds the positions' ids in position order once all are
+    here, ``passes`` for each the pass of its block (0, 1, ...) that
+    unmasked it."""
+
+    block_len: int = 1
+    unmask: int = 1
+    masked: int = 0          # masked positions left in the block, as of
+    #                          the passes enqueued
+    block_pass: int = 0      # passes enqueued over the block so far
+    passes: List[int] = dataclasses.field(default_factory=list)
+    # (block start, pass of its block) of the passes enqueued and not
+    # collected, oldest first (the loop runs one ahead: at most two)
+    _in_flight: List[tuple] = dataclasses.field(default_factory=list)
+    _received: int = 0
+
+    from_prefill = 0         # the prompt's whole blocks fill the cache
+
+    def __post_init__(self):
+        super().__post_init__()
+        known = self.prompt_len % self.block_len
+        self.position = self.prompt_len - known
+        self.masked = self.block_len - known
+        self.generated = [None] * self.target
+        self.passes = [None] * self.target
+
+    @property
+    def target(self) -> int:
+        whole = -(-(self.prompt_len + self.max_tokens) // self.block_len)
+        return whole * self.block_len - self.prompt_len
+
+    @property
+    def committed_tokens(self) -> int:
+        return self.prompt_len + self.target
+
+    @property
+    def received(self) -> int:
+        return self._received
+
+    def next_unmask(self) -> int:
+        """Positions the next pass over this row unmasks (0: a commit)."""
+        return min(self.unmask, self.masked)
+
+    def dispatched(self) -> int:
+        count = self.next_unmask()
+        self._in_flight.append((self.position, self.block_pass))
+        if count:
+            self.enqueued += count
+            self.masked -= count
+            self.block_pass += 1
+        else:
+            self.position += self.block_len
+            self.masked, self.block_pass = self.block_len, 0
+        return count
+
+    def take(self, ids) -> int:
+        start, block_pass = self._in_flight.pop(0)
+        count = 0
+        for j, token in enumerate(ids):
+            if token >= 0:
+                at = start + j - self.prompt_len
+                self.generated[at], self.passes[at] = token, block_pass
+                count += 1
+        self._received += count
+        return count
+
+    def answer(self) -> dict:
+        return {"tokens": self.generated[:self.max_tokens],
+                "cut": self.generated[self.max_tokens:],
+                "passes": list(self.passes)}
 
 
 class ContinuousBatcher:
@@ -100,8 +224,10 @@ class ContinuousBatcher:
                  max_seq: Optional[int] = None,
                  page_tokens: Optional[int] = None,
                  pool_pages: Optional[int] = None,
-                 prefix_probe=None):
+                 prefix_probe=None, block_len: int = 1, unmask: int = 1):
         self.num_slots = num_slots
+        # > 1: the engine's model generates by blocks (BlockRequest)
+        self.block_len, self.unmask = block_len, unmask
         self.max_batch_tokens = max_batch_tokens
         self.admission_s = admission_ms / 1000.0
         self.decode_block = max(1, decode_block)
@@ -174,7 +300,13 @@ class ContinuousBatcher:
         while self._waiting and self._free:
             req, _ = self._waiting[0]
             max_tokens = req.max_new_tokens
-            if self.max_seq is not None:
+            if self.max_seq is not None and self.block_len > 1:
+                # whole blocks are generated and every one is written:
+                # prompt_len + max_tokens, rounded up to a block, must
+                # fit the cache
+                room = self.max_seq - self.max_seq % self.block_len
+                max_tokens = max(1, min(max_tokens, room - len(req.prompt)))
+            elif self.max_seq is not None:
                 # last generated token is returned, never written, so
                 # prompt_len + max_tokens - 1 must fit the cache
                 max_tokens = max(
@@ -196,19 +328,22 @@ class ContinuousBatcher:
                     1, -(-written // self.page_tokens) - discount)
                 if pages + page_cost > self.pool_pages:
                     break   # pool committed — wait for retires
-            cost = len(req.prompt) + max_tokens
+            # whole blocks are committed (a block of 1: the tokens)
+            cost = -(-(len(req.prompt) + max_tokens) // self.block_len) \
+                * self.block_len
             if budget + cost > self.max_batch_tokens:
                 break   # hard cap — the deadline never overrides it
             self._waiting.popleft()
             slot = self._free.pop()
             self._admission_seq += 1
-            active = ActiveRequest(slot=slot, request=req,
-                                   prompt_len=len(req.prompt),
-                                   position=len(req.prompt),
-                                   max_tokens=max_tokens,
-                                   page_cost=page_cost,
-                                   admit_seq=self._admission_seq,
-                                   admitted_s=now)
+            fields = dict(slot=slot, request=req,
+                          prompt_len=len(req.prompt),
+                          position=len(req.prompt), max_tokens=max_tokens,
+                          page_cost=page_cost,
+                          admit_seq=self._admission_seq, admitted_s=now)
+            active = ActiveRequest(**fields) if self.block_len == 1 \
+                else BlockRequest(block_len=self.block_len,
+                                  unmask=self.unmask, **fields)
             self._active[slot] = active
             admitted.append(active)
             budget += cost

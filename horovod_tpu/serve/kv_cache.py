@@ -40,6 +40,32 @@ a prefill writes its first token there, a decode step reads and writes
 it, and the host sends a step one int32 a row (the position, -1 for a
 row that is not active) and reads back ids and one max |logit| a row.
 
+**Blocks.** Where the contract declares a ``block_len`` over 1 (a model
+that generates by diffusion over blocks) a step does not yield one token
+a row. The feed is then a row's block, ``ids`` ``(slots, block_len)``
+int32 and ``masked`` (the same, bool: a position's id means nothing
+until it is unmasked, whatever the id), donated like the other. A step
+is one PASS: every row runs its block's ``block_len`` positions (the
+mask id where masked) from the block's first position, which writes the
+block's keys and values over the block's own columns of the cache,
+provisional until the block moves on, and sees the cache up to the
+block's end. The host sends a pass two int32 a row: the block's first
+position (-1: not active) and how many positions to unmask. In the graph
+(scope ``unmask``): at every masked position the argmax of its logits
+and that token's softmax probability in float32, and the ``count``
+positions of highest probability among the row's masked ones (ties to
+the lower position) take their argmax; the pass returns a row's block
+with the ids it unmasked and -1 elsewhere. A count of 0 is the COMMIT
+pass over a block that has no mask left: it unmasks nothing, the columns
+it writes are the block's final ones, it resets the row's block to all
+masked, and the host moves the row's position on by ``block_len``. So
+the cache advances at a commit and at nothing else, and the step program
+is one program of one shape whatever its rows are doing. A prefill fills
+the prompt's whole blocks under the block-causal mask, runs no head,
+yields no token (it collects to ``(None, max |hidden|)``) and writes the
+row's first block into the feed: the prompt's last ``len mod block_len``
+tokens, known, and masks.
+
 **Look-ahead.** :meth:`DecodeEngine.prefill` and
 :meth:`DecodeEngine.decode` launch the program and return a
 :class:`Pending` result still on the device (its copy to the host
@@ -135,7 +161,8 @@ class Pending:
 
 
 class PendingPrefill(Pending):
-    """``collect()`` -> (first generated token id, max |logit|).
+    """``collect()`` -> (first generated token id, max |logit|); the id is
+    ``None`` where the prefill yields no token (a block model).
     ``max_abs`` is the program's last result: the scalar, or (max |logit|,
     a reading for each of ``stats``) where the program's kernels handed
     readings up (``_backend.KERNEL_STATS``'s sinks, and the span)."""
@@ -151,6 +178,7 @@ class PendingPrefill(Pending):
         with tracing.span("engine.prefill.wait",   # blocked on the device
                           ready=int(self.ready())):
             token, readings = int(self._token), np.asarray(self._max_abs)
+            token = None if token < 0 else token
         if readings.ndim:
             for name, value in zip(self._stats, readings[1:].tolist()):
                 KERNEL_STATS[name](value)
@@ -163,7 +191,9 @@ class PendingPrefill(Pending):
 
 
 class PendingDecode(Pending):
-    """``collect()`` -> (ids, max |logit|s) of the step's rows."""
+    """``collect()`` -> (ids, max |logit|s) of the step's rows; of a block
+    model's pass a row's ids are its block's, -1 where the pass unmasked
+    nothing."""
 
     def __init__(self, engine: "DecodeEngine", slots: List[int], ids,
                  max_abs, t0: float, number: int, attrs: dict):
@@ -211,6 +241,27 @@ class DecodeEngine:
         # -> stats()["decode_positions_by_kind"] (None: one kind of leaf)
         self._step_reads = contract.step_reads
         self.positions_by_kind: Dict[str, int] = {}
+        # a step's tokens a row, the id fed where one is masked and the
+        # positions a pass unmasks (module docstring, Blocks)
+        self.block_len = int(contract.block_len)
+        self._mask_id = contract.mask_id
+        self.unmask = int(contract.unmask)
+        if self.block_len > 1 and (
+                contract.resumable
+                or self.block_len >= PREFILL_BUCKET_QUANTUM
+                or PREFILL_BUCKET_QUANTUM % self.block_len):
+            # a model tells a pass from a prompt by its length
+            raise ValueError(
+                f"block_len {self.block_len}: a block model's prompt runs "
+                f"as one bucket, a whole number of blocks and longer than "
+                f"one (quantum {PREFILL_BUCKET_QUANTUM})")
+        # active rows over the steps, those of them that unmasked nothing,
+        # the tokens the steps yielded, the blocks whose columns became
+        # final (one token a row-pass and no commits where block_len is 1)
+        self.row_passes = 0
+        self.commit_row_passes = 0
+        self.tokens_unmasked = 0
+        self.blocks_committed = 0
         # the tiles the steps read of a leaf over the tiles of all rows
         # (stats()["decode_kv_read_share"]) and the positions they attended
         # (["decode_positions_read"], where a kernel's roofline counts
@@ -227,8 +278,13 @@ class DecodeEngine:
         # rows hold: what a decode step, which runs every row, rewrites,
         # and a prefill a slot's share of (the spans' ``state_bytes``)
         self._state_bytes = self.cache_bytes_by_kind()["state"]
-        # the next token of every row, on the device (module docstring)
-        self._feed = jnp.zeros((self.num_slots,), jnp.int32)
+        # the next token of every row, or its block, on the device
+        # (module docstring)
+        self._feed = jnp.zeros((self.num_slots,), jnp.int32) \
+            if self.block_len == 1 else {
+                "ids": jnp.zeros((self.num_slots, self.block_len),
+                                 jnp.int32),
+                "masked": jnp.ones((self.num_slots, self.block_len), bool)}
         # by bucket, or by name where a prompt runs in pieces
         self._prefill_fns: Dict[object, object] = {}  # guarded-by: <replica-thread>
         self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1, 2))
@@ -270,7 +326,8 @@ class DecodeEngine:
         fall back to whole rows shows there), the registry what to count
         of them and whether the one that attends also writes the step's
         columns (``_write_fused``; None where none does)."""
-        tokens = jax.ShapeDtypeStruct((self.num_slots, 1), jnp.int32)
+        tokens = jax.ShapeDtypeStruct((self.num_slots, self.block_len),
+                                      jnp.int32)
         pos = jax.ShapeDtypeStruct((self.num_slots,), jnp.int32)
         program, (_, shapes) = jax.make_jaxpr(
             lambda p, t, q: self._model.apply(
@@ -355,13 +412,17 @@ class DecodeEngine:
             x.copy_to_host_async()
         return rest
 
-    def _prefill_impl(self, params, cache, feed, tokens, prompt_len, slot):
+    def _prefill_impl(self, params, cache, feed, tokens, prompt_len, slot,
+                      *block):
         # batch-1 run over the padded prompt builds a fresh one-row cache
-        # (zeros, inside the traced apply) and (1, 1, vocab) logits...
+        # (zeros, inside the traced apply) and (1, 1, vocab) logits (of a
+        # block model: ``prompt_len`` is the prompt's whole blocks, and no
+        # head runs)...
         logits, mutated = self._model.apply(
             {"params": params}, tokens,
             positions=jnp.zeros((1,), jnp.int32), lengths=prompt_len[None],
-            train=False, mutable=["cache", "kernel_stats"])
+            train=False, mutable=["cache", "kernel_stats"],
+            **({"output": "hidden"} if block else {}))
         # what the layers' prompt kernels handed up, by the name it was
         # sown under; the layers' mean rides beside max |logit|
         handed: Dict[str, list] = {}
@@ -381,10 +442,21 @@ class DecodeEngine:
             else jax.lax.dynamic_update_index_in_dim(
                 big, one[0], slot, axis=0), cache, mutated["cache"])
         last = logits[0, 0]
-        token = jnp.argmax(last).astype(jnp.int32)
-        # the slot's first decode step reads its token from the feed
-        feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
-        max_abs = jnp.max(jnp.abs(last))
+        if block:
+            # no token: the row's first block (ids, masked) as the host
+            # cut it from the prompt's end; ``max_abs`` is of the last
+            # hidden row, which the guard reads as it reads a logit's
+            token = jnp.asarray(-1, jnp.int32)
+            feed = jax.tree.map(
+                lambda big, row: jax.lax.dynamic_update_index_in_dim(
+                    big, row, slot, axis=0), feed,
+                dict(zip(("ids", "masked"), block)))
+        else:
+            token = jnp.argmax(last).astype(jnp.int32)
+            # the slot's first decode step reads its token from the feed
+            feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot,
+                                                       axis=0)
+        max_abs = jnp.max(jnp.abs(last)).astype(jnp.float32)
         if handed:
             self._kernel_stats = tuple(sorted(handed))
             max_abs = jnp.stack([max_abs, *(
@@ -435,7 +507,43 @@ class DecodeEngine:
         feed = jax.lax.dynamic_update_index_in_dim(feed, token, slot, axis=0)
         return cache, feed, token, jnp.max(jnp.abs(last))
 
+    def _block_pass(self, params, cache, feed, step):
+        """One pass of every row's block (module docstring, Blocks).
+        ``step``: (2, slots) int32, a row's block start (-1: not active,
+        it runs zeros at position 0) and the positions to unmask (0: the
+        commit pass)."""
+        starts, counts = step[0], step[1]
+        active = starts >= 0
+        ids, masked = feed["ids"], feed["masked"]
+        tokens = jnp.where(active[:, None],
+                           jnp.where(masked, self._mask_id, ids), 0)
+        counted = {"active": active} if self._counts else {}
+        logits, mutated = self._model.apply(
+            {"params": params, "cache": cache}, tokens,
+            positions=jnp.maximum(starts, 0), train=False,
+            mutable=["cache"], **counted)
+        with jax.named_scope("unmask"):
+            logits = logits.astype(jnp.float32)
+            best = jnp.max(logits, axis=-1)
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # softmax(logits)[argmax], float32
+            sure = 1.0 / jnp.sum(jnp.exp(logits - best[..., None]), axis=-1)
+            sure = jnp.where(masked, sure, -jnp.inf)
+            at = jnp.arange(self.block_len)
+            ahead = (sure[:, None, :] > sure[:, :, None]) | (
+                (sure[:, None, :] == sure[:, :, None])
+                & (at[None, :] < at[:, None]))          # [row, i, j]: j first
+            take = masked & active[:, None] \
+                & (jnp.sum(ahead, axis=-1) < counts[:, None])
+            commit = active & (counts == 0)
+            feed = {"ids": jnp.where(take, first, ids),
+                    "masked": (masked & ~take) | commit[:, None]}
+            return (mutated["cache"], feed, jnp.where(take, first, -1),
+                    jnp.max(jnp.abs(logits), axis=(1, 2)))
+
     def _decode_impl(self, params, cache, feed, positions):
+        if self.block_len > 1:
+            return self._block_pass(params, cache, feed, positions)
         # a row the host sends -1 for is not active: it runs token 0 at
         # position 0 and leaves its feed entry alone
         active = positions >= 0
@@ -458,7 +566,10 @@ class DecodeEngine:
         resumes, the prompt's pieces of ``PREFILL_CHUNK`` tokens back to
         back through ``prefill_chunk`` (the slot's row of every leaf in,
         zeros for the first piece; no head) and ``prefill_last``. The
-        result collects to (first generated token id, max |logit|)."""
+        result collects to (first generated token id, max |logit|). Of a
+        block model: the prompt's whole blocks fill the cache and its
+        last ``len mod block_len`` tokens open the slot's block in the
+        feed; the result collects to (``None``, max |hidden|)."""
         if not 0 < len(prompt) <= self.max_seq:
             # callers (ServeHandle.submit, Replica._reject) screen this
             # out; fail loudly rather than let the padded copy below
@@ -492,6 +603,14 @@ class DecodeEngine:
                     "prefill", self._piece_fn("prefill_last"),
                     padded[:, last:], np.int32(last),
                     np.int32(len(prompt) - last), np.int32(slot))
+            elif self.block_len > 1:
+                whole = len(prompt) - len(prompt) % self.block_len
+                ids = np.full((self.block_len,), self._mask_id, np.int32)
+                ids[:len(prompt) - whole] = prompt[whole:]
+                token, max_abs = self._run_donating(
+                    "prefill", self._prefill_fn(bucket), padded,
+                    np.int32(whole), np.int32(slot), ids,
+                    np.arange(self.block_len) >= len(prompt) - whole)
             else:
                 token, max_abs = self._run_donating(
                     "prefill", self._prefill_fn(bucket), padded,
@@ -506,7 +625,8 @@ class DecodeEngine:
             self._kernel_stats)
 
     def decode(self, slots: List[int], tokens: Optional[List[int]],
-               positions: List[int]) -> PendingDecode:
+               positions: List[int],
+               unmask: Optional[List[int]] = None) -> PendingDecode:
         """Launch one decode step over ALL cache rows (fixed shape — the
         one compiled decode program). Active rows take their token from
         the feed at their real position; the others run token 0 at
@@ -514,8 +634,16 @@ class DecodeEngine:
         the feed (the serving loop, whose last programs put them there);
         a caller that has them on the host passes them, and they replace
         the feed first (one transfer more). The result collects to (ids,
-        max |logit|s) of ``slots``."""
-        if tokens is not None:
+        max |logit|s) of ``slots``. Of a block model a step is a pass:
+        ``positions`` are the rows' block starts, ``unmask`` how many
+        positions each row's pass unmasks (0: the commit pass), the
+        blocks are in the feed (``tokens`` is ``None``), and a row's ids
+        are its block's, -1 where the pass unmasked nothing."""
+        if self.block_len > 1:
+            if tokens is not None or unmask is None:
+                raise ValueError("a block model's pass takes its tokens "
+                                 "from the feed and an unmask count a row")
+        elif tokens is not None:
             feed = np.zeros((self.num_slots,), np.int32)
             feed[slots] = tokens
             self._feed = jnp.asarray(feed)
@@ -526,7 +654,7 @@ class DecodeEngine:
         with tracing.span("engine.decode.prep"):
             step_pos = np.full((self.num_slots,), -1, np.int32)
             step_pos[slots] = positions
-            if slots and step_pos.max() >= self.max_seq:
+            if slots and step_pos.max() + self.block_len > self.max_seq:
                 # admission caps max_tokens so no write lands past the
                 # cache (batcher.ActiveRequest); overrunning silently
                 # would overwrite the last KV row and serve garbage
@@ -535,11 +663,24 @@ class DecodeEngine:
                     f"decode: slot {slot} position {step_pos[slot]} >= "
                     f"max_seq {self.max_seq} (admission cap violated)")
             attrs = {"state_bytes": self._state_bytes}
+            self.row_passes += len(slots)
+            if unmask is None:
+                self.tokens_unmasked += len(slots)
+            else:
+                commits, unmasked = unmask.count(0), sum(unmask)
+                self.commit_row_passes += commits
+                self.blocks_committed += commits
+                self.tokens_unmasked += unmasked
+                attrs.update(unmasked=unmasked, committed=commits,
+                             block_len=self.block_len)
+            # the last position a row's step attends (a row that is not
+            # active runs at position 0)
+            last = np.maximum(step_pos, 0) + (self.block_len - 1)
             if self._step_readers:
                 # what the kernels will fetch: a row that is not active
                 # runs at position 0 and costs one tile
                 read, held, attended = map(sum, zip(*(
-                    live_tiles(step_pos, self.max_seq)
+                    live_tiles(last, self.max_seq)
                     for live_tiles in self._step_readers)))
                 self.kv_tiles_read += read
                 self.kv_tiles_held += held
@@ -551,6 +692,10 @@ class DecodeEngine:
                 self.positions_by_kind[kind] = \
                     self.positions_by_kind.get(kind, 0) + attended
                 attrs[f"{kind}_positions_read"] = attended
+            if unmask is not None:
+                counts = np.zeros((self.num_slots,), np.int32)
+                counts[slots] = unmask
+                step_pos = np.stack([step_pos, counts])
         with tracing.span("engine.decode.dispatch") as dispatch:
             self._lock_wait_s = 0.0
             ids, max_abs = self._run_donating("decode", self._decode_fn,
@@ -631,4 +776,9 @@ class DecodeEngine:
                     # 2**32 (None: the model counts nothing)
                     "expert_counts": (None if counts is None
                                       else counts.tolist()),
+                    "block_len": self.block_len,
+                    "row_passes": self.row_passes,
+                    "commit_row_passes": self.commit_row_passes,
+                    "tokens_unmasked": self.tokens_unmasked,
+                    "blocks_committed": self.blocks_committed,
                     "slots": self.num_slots}

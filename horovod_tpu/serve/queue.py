@@ -105,6 +105,12 @@ class Completion:
     finish: str = "length"
     trace_id: str = ""       # trace context echoed back to the submitter
     requeues: int = 0
+    # of a model that generates by blocks (serve/batcher.BlockRequest):
+    # the rest of the answer's last block, generated and not part of the
+    # answer, and for each position of ``tokens + cut`` the pass of its
+    # block (0, 1, ...) that unmasked it
+    cut: Optional[List[int]] = None
+    passes: Optional[List[int]] = None
 
     def to_json(self) -> bytes:
         return json.dumps(dataclasses.asdict(self)).encode()
@@ -118,7 +124,8 @@ class Completion:
                    latency_s=float(d.get("latency_s", 0.0)),
                    finish=d.get("finish", "length"),
                    trace_id=d.get("trace_id", ""),
-                   requeues=int(d.get("requeues", 0)))
+                   requeues=int(d.get("requeues", 0)),
+                   cut=d.get("cut"), passes=d.get("passes"))
 
 
 class RequestQueue:

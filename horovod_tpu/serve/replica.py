@@ -13,7 +13,11 @@ ContinuousBatcher` on a single thread. The loop each pass:
 3. enqueues ONE fixed-shape decode step over all slots, counts its
    tokens and frees the slots of the requests it ends (a request ends
    by length, so this needs no token value: a retiring request frees
-   its slot for the very next admission check, not a batch boundary);
+   its slot for the very next admission check, not a batch boundary).
+   A step yields one token a row, or, where the model generates by
+   blocks, what the row's schedule says its pass unmasks - none (the
+   commit) to several (``batcher.BlockRequest``); either way the count
+   is known at dispatch;
 4. only now COLLECTS: the ids and guard values of the decode step
    enqueued in the pass before, then this pass's first tokens. The
    device always has the step of (3) queued while the host reads,
@@ -271,7 +275,12 @@ class Replica:
             max_seq=engine.max_seq,
             page_tokens=engine.page_tokens if self.paged else None,
             pool_pages=engine.pool.allocatable if self.paged else None,
-            prefix_probe=engine.probe_prefix if self.paged else None)
+            prefix_probe=engine.probe_prefix if self.paged else None,
+            block_len=getattr(engine, "block_len", 1),
+            unmask=getattr(engine, "unmask", 1))
+        # the model generates by blocks: a step is a pass that yields
+        # from no token to several a row (serve/kv_cache.py, Blocks)
+        self._blocks = self.batcher.block_len > 1
         self.guard = guard
         self.quarantined = False
         self.completed = 0
@@ -307,17 +316,18 @@ class Replica:
         # one decode span per request, first token to last (the
         # serve.step spans carry which iterations it lived through)
         decode_dur = max(now - active.first_token_s, 0.0)
-        decode_steps = len(active.generated) - 1
+        answer = active.answer()
+        decode_steps = len(answer["tokens"]) - 1
         tracing.record(
             "request.decode", epoch_now - decode_dur, decode_dur,
             trace_id=req.trace_id, uid=req.uid, slot=active.slot,
-            tokens=len(active.generated),
+            tokens=len(answer["tokens"]),
             blocks=-(-decode_steps // self.policy.decode_block))
         # "cache_limit" (not "length") when the KV cache, not the
         # request, bounded the generation — callers must be able to
         # tell a fulfilled budget from a truncated one
         completion = Completion(
-            uid=req.uid, tokens=list(active.generated),
+            uid=req.uid, **answer,
             prompt_len=active.prompt_len, rank=self.rank,
             ttft_s=active.first_token_s - req.submitted_s,
             latency_s=now - req.submitted_s,
@@ -332,7 +342,7 @@ class Replica:
             "request.serve", epoch_now - serve_dur, serve_dur,
             trace_id=req.trace_id, uid=req.uid, slot=active.slot,
             finish=completion.finish, requeues=req.requeues,
-            tokens=len(active.generated),
+            tokens=len(completion.tokens),
             ttft_ms=round(completion.ttft_s * 1000.0, 3),
             latency_ms=round(completion.latency_s * 1000.0, 3))
         tracing.slo().record_request(
@@ -413,12 +423,12 @@ class Replica:
         # goodput ledger: the victim's decoded-so-far tokens are work the
         # preemption threw away — re-attributed from productive to
         # serve_preempted badput at the EWMA per-token decode cost
-        goodput.note_serve_preempted(len(victim.generated))
+        goodput.note_serve_preempted(victim.received)
         flight_recorder.emit(
             "serve_preempt", replica=self.name, rank=self.rank,
             uid=victim.request.uid, slot=victim.slot,
             trace_id=victim.request.trace_id,
-            generated=len(victim.generated),
+            generated=victim.received,
             requeues=victim.request.requeues)
         log.warning("serve: replica %s preempted request %s (pool "
                     "exhausted); requeued at front", self.name,
@@ -578,9 +588,13 @@ class Replica:
         # has it in its feed
         before = self._before_enqueue()
         in_flight = self._ahead is not None or bool(self._first_tokens)
-        tokens = None if in_flight else [a.generated[-1] for a in rows]
-        pending = _pending(self.engine.decode(
-            [a.slot for a in rows], tokens, [a.position for a in rows]))
+        if self._blocks:     # a row's block lives in the engine's feed
+            args = None, [a.position for a in rows], \
+                [a.next_unmask() for a in rows]
+        else:
+            args = (None if in_flight else [a.generated[-1] for a in rows],
+                    [a.position for a in rows])
+        pending = _pending(self.engine.decode([a.slot for a in rows], *args))
         self._after_enqueue(before)
         return pending
 
@@ -611,7 +625,7 @@ class Replica:
                     trace_id=req.trace_id, uid=req.uid, slot=active.slot,
                     prompt_len=active.prompt_len, preempted=True)
                 continue
-            active.enqueued = 1
+            active.enqueued = active.from_prefill
             self._admitted += 1
             self._first_tokens.append((active, pending, p0))
             if pending.on_host and not self._collect_first_tokens():
@@ -634,7 +648,7 @@ class Replica:
         the host (each appended after its step passed the guard)."""
         waiting, delivered = [], 0
         for retired in self._unread:
-            if len(retired.generated) < retired.max_tokens:
+            if retired.received < retired.target:
                 waiting.append(retired)
             else:
                 self._finish(retired, now)
@@ -664,9 +678,8 @@ class Replica:
                 self._quarantine("non-finite prefill logits")
                 return False
             req = active.request
-            active.generated.append(token)
-            active.first_token_s = time.monotonic()
-            active.prefill_s = active.first_token_s - p0
+            collected_s = time.monotonic()
+            active.prefill_s = collected_s - p0
             # dispatch to first token on the host (after the fact: other
             # prefills and a decode step are enqueued in between)
             tracing.record(
@@ -677,9 +690,15 @@ class Replica:
             # preemption exchange rate stays a pure decode cost)
             self._productive(p0)
             _TOKENS.labels(kind="prefill").inc(active.prompt_len)
-            _LATENCY.labels(phase="ttft").observe(
-                active.first_token_s - req.submitted_s)
+            if token is not None:    # None: the first pass that unmasks
+                active.generated.append(token)
+                self._first_token(active, collected_s)
         return True
+
+    def _first_token(self, active: ActiveRequest, now: float) -> None:
+        active.first_token_s = now
+        _LATENCY.labels(phase="ttft").observe(
+            now - active.request.submitted_s)
 
     def _collect_decode(self, step: _DecodeStep) -> bool:
         """Read a decode step's ids, put it before the guard and append
@@ -693,19 +712,26 @@ class Replica:
         if not all(verdicts):
             self._quarantine("non-finite decode logits")
             return False
-        for active, token in zip(step.rows, ids):
-            active.generated.append(token)
-        occupancy = len(step.rows)
+        # a step yields one token a row, or, of a model that generates by
+        # blocks, from none to several; such a request's first token is
+        # the first pass that unmasks one
+        occupancy, tokens = len(step.rows), 0
+        for active, value in zip(step.rows, ids):
+            took = active.take(value)
+            tokens += took
+            if took and not active.first_token_s:
+                self._first_token(active, time.monotonic())
         self.occupancy_sum += occupancy
         if self.paged:
             self.page_used_sum += self.engine.pool.used_count()
-        _TOKENS.labels(kind="decode").inc(occupancy)
+        _TOKENS.labels(kind="decode").inc(tokens)
         _OCCUPANCY.labels(replica=self.name).set(occupancy)
         _OCCUPANCY_HIST.observe(occupancy)
-        # goodput ledger: one decoded token per occupied slot is the
-        # serve plane's productive unit; the step wall also refreshes
-        # the EWMA per-token cost that prices preempted work
-        self._productive(step.dispatched_s, tokens=occupancy)
+        # goodput ledger: the tokens a step delivers (one per occupied
+        # slot unless the model generates by blocks) are the serve
+        # plane's productive unit; the step wall also refreshes the EWMA
+        # per-token cost that prices preempted work
+        self._productive(step.dispatched_s, tokens=tokens)
         return True
 
     def _prepare_pages(self, rows: List[ActiveRequest]
@@ -764,8 +790,7 @@ class Replica:
             step = _DecodeStep(self._enqueue_decode(rows), rows,
                                dispatched_s)
             for active in rows:      # counted at dispatch: by length alone
-                active.enqueued += 1
-                active.position += 1
+                active.dispatched()
             self.batcher.note_step()
             self._retire()
         # only now read: the device has this pass's programs queued while

@@ -47,6 +47,9 @@ KEXAONE_UNTRACED = ("test_an_untraced_rehearsal_reads_the_end_to_end_metrics",
 # have the same names: ten toy layers' rehearsals, and the controls'
 # five forwards of the reference)
 GRANITE_UNTRACED = KEXAONE_UNTRACED
+# ``benchmark/tests/test_serve_sdar.py``: the same cut (six toy layers'
+# rehearsals, and the controls' five forwards of the reference)
+SDAR_UNTRACED = KEXAONE_UNTRACED
 
 
 def test_functions(modules, only=(), without=()):

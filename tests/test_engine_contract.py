@@ -158,7 +158,8 @@ STATS_KEYS = {
     "prefill_chunks", "prefill_positions", "prefill_tokens", "cache_bytes",
     "cache_bytes_by_kind", "cache_donated", "decode_kv_read_share",
     "decode_write_fused", "prefill_sparse_kernel", "decode_positions_read",
-    "decode_positions_by_kind", "expert_counts", "slots"}
+    "decode_positions_by_kind", "expert_counts", "block_len", "row_passes",
+    "commit_row_passes", "tokens_unmasked", "blocks_committed", "slots"}
 
 
 @pytest.mark.parametrize("name", ["trunk", *FAMILIES])
@@ -233,3 +234,69 @@ def test_a_layer_kind_declared_outside_the_package_is_served(monkeypatch):
     assert engine.stats()["cache_bytes_by_kind"]["tally"] == 512
     with pytest.raises(ValueError, match="unknown mixer 'running_sum'"):
         hybrid.mixer_of("running_sum")
+
+
+def uncached_block_greedy(model, params, prompt, n):
+    """Reference for a block model: the published loop of generation by
+    diffusion over blocks with the model's own cache-free forward, the
+    whole sequence recomputed every pass (row ``t`` of a block-causal
+    model sees its own block and nothing after it). Returns the ``n``
+    generated ids, whole blocks of them."""
+    block, unmask = model.block_len, model.block_len // model.denoising_steps
+    forward = jax.jit(lambda params, toks: model.apply(
+        {"params": params}, toks, train=False))
+    total = -(-(len(prompt) + n) // block) * block
+    ids = np.full((total,), model.mask_id, np.int32)
+    ids[:len(prompt)] = prompt
+    masked = np.arange(total) >= len(prompt)
+    padded = np.zeros((1, model.max_seq), np.int32)
+    for start in range(len(prompt) - len(prompt) % block, total, block):
+        here = slice(start, start + block)
+        while masked[here].any():
+            padded[0, :total] = np.where(masked, model.mask_id, ids)
+            logits = np.asarray(forward(params, padded)[0, here],
+                                np.float64)
+            sure = np.where(masked[here], np.exp(
+                logits.max(-1) - np.log(np.exp(logits).sum(-1))), -np.inf)
+            for j in sorted(range(block), key=lambda j: (-sure[j], j))[
+                    :min(unmask, masked[here].sum())]:
+                ids[start + j] = logits[j].argmax()
+                masked[start + j] = False
+    return ids[len(prompt):].tolist()
+
+
+def test_a_block_model_of_its_own_is_served_through_the_contract():
+    """What the engine is told of a model that generates by blocks is
+    three fields of the contract (``block_len``, ``mask_id``, ``unmask``):
+    a decoder of dense full layers with a block of 8 that unmasks 4 a pass
+    - no size and no schedule of the benchmark's - decodes what the
+    cache-free loop over its own forward does, through ``hvd.serve()``."""
+    import horovod_tpu as hvd
+
+    model = hybrid.HybridDecoder(
+        vocab_size=61, d_model=32, d_ff=64, num_heads=4, num_kv_heads=2,
+        head_dim=8, mixers=("full", "full"), rotary=("full",), max_seq=64,
+        block_len=8, mask_id=60, denoising_steps=2, dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(5),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    contract = model.serving()
+    assert (contract.block_len, contract.mask_id, contract.unmask) \
+        == (8, 60, 4)
+    engine = DecodeEngine(model, params, num_slots=2)
+    assert engine.decode_kernels == ("kv_cache_write_block",
+                                     "grouped_decode_attention")
+    assert engine.cache_bytes_by_kind() == {
+        "kv": 2 * 2 * 2 * 2 * 8 * 64 * 4, "compressed": 0, "state": 0}
+    handle = hvd.serve(model, params, slots=2, max_new_tokens=32,
+                       max_batch_tokens=128)
+    try:
+        prompts = [[5, 17, 3, 44, 9], [7] * 16, [1, 2, 3]]
+        uids = [handle.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, (13, 8, 20))]
+        done = [handle.result(uid, timeout=120.0) for uid in uids]
+    finally:
+        handle.close()
+    for prompt, n, out in zip(prompts, (13, 8, 20), done):
+        want = uncached_block_greedy(model, params, prompt, n)
+        assert out.tokens == want[:n] and out.cut == want[n:]
+        assert set(out.passes) <= {0, 1}

@@ -201,15 +201,16 @@ def _layout(name):
 
 @pytest.mark.parametrize("layout", ["8-experts-top-4", "128-experts-top-8",
                                     "72-experts-top-10-softmax"])
-@pytest.mark.parametrize("seq", [100, 2], ids=["grouped", "masked"])
+@pytest.mark.parametrize("seq", [150, 2], ids=["grouped", "masked"])
 def test_the_expert_layers_shares_add_up(seq, layout):
     """The toy experts held in shares (8 as (0, 4) + (4, 4); 128 as
     sixteen shares of 8; 72 as two of 36) and whole: the routed parts
     summed, with the shared expert counted once, are the whole layer of
     the reference; each share routes over all the router's outputs. Both
-    forms of the product, each reached by its size: 200 tokens are 800
-    (1,600; 2,000) pairs, 4 tokens 16 (32; 40), at or under
-    ``MASKED_PAIRS`` an expert of the pairs a share expects."""
+    forms of the product, each reached by its size: 300 tokens are 1,200
+    (2,400; 3,000) pairs, past ``MASKED_PAIRS`` (16 since PR 46) an
+    expert, 4 tokens 16 (32; 40), at or under ``MASKED_PAIRS`` an expert
+    of the pairs a share expects."""
     cfg, p, ref, count = _layout(layout)
     experts = cfg["num_experts"]
 
@@ -231,7 +232,7 @@ def test_the_expert_layers_shares_add_up(seq, layout):
                     jnp.float32)
     for held in (experts, count):
         assert _is_grouped(layer(0, held, 1),
-                           {"params": _held(p, 0, held)}, x) == (seq == 100)
+                           {"params": _held(p, 0, held)}, x) == (seq == 150)
     want = np.asarray(ref.routed(ref._matmul("f32"), x.reshape(-1, 128),
                                  p, ref.frozen(cfg))).reshape(x.shape)
     whole = layer(0, experts, 1).apply({"params": _held(p, 0, experts)}, x)

@@ -747,6 +747,101 @@ def test_state_space_engine_fits_and_updates_its_cache_in_place(
         assert text.count("tpu_custom_call") >= 1
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_2048"])
+def test_block_diffusion_engine_fits_and_updates_its_cache_in_place(
+        chip, monkeypatch, capsys, program):
+    """SDAR-30B-A3B-Chat as the benchmark runs it (benchmark/configs/
+    sdar-30b-a3b.json: layers 0-5 at full width, every layer full
+    grouped-query attention with rotary positions and all 128 experts,
+    an untied head over 151,936, block_len 4, 64 slots of 4,096
+    positions, bfloat16 weights): the 64-row pass over 4 positions a row
+    and the prefill of the largest bucket compile for one v5e chip,
+    arguments plus temporaries stay under its 16 GB (printed: run with
+    ``-s``), and the result aliases every leaf of the donated cache and
+    both leaves of the feed (a row's block: ids and what is masked). A
+    pass is, a layer, two writes of the block's four columns and one
+    attention kernel over 32 query rows a key/value head; its 2,048 pairs
+    over 128 experts are at ``MASKED_PAIRS`` an expert: every expert
+    multiplies every row and no pair is sorted. The prefill attends
+    through the flash kernel's block-causal form, groups its pairs
+    through ``ragged_dot`` and runs no head."""
+    import json
+
+    from benchmark import weights_sdar
+    from benchmark.runners.serve_sdar import build_model
+    from horovod_tpu.serve.kv_cache import DecodeEngine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=chip), tree)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        cfg = json.load(f)["as_run"]
+    layers, slots, bucket, seq, block = 6, 64, 2048, 4096, 4
+    model = build_model(cfg)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+                           )["params"]))
+    assert sum(x.size for x in jax.tree.leaves(params)) \
+        == weights_sdar.count(cfg) == 4_361_055_744
+    monkeypatch.setattr(DecodeEngine, "_allocate_cache",
+                        lambda self: on_chip(self._cache_shapes()))
+    eng = DecodeEngine(model, params, num_slots=slots)
+    assert eng.cache_bytes_by_kind() == {
+        "kv": layers * 2 * slots * seq * 4 * 128 * 2,            # 3.22 GB
+        "compressed": 0, "state": 0, "counter": layers * 3 * 128 * 4}
+    assert eng.block_len == block and eng.unmask == 2
+    assert eng.decode_kernels == ("kv_cache_write_block",
+                                  "grouped_decode_attention")
+    feed = on_chip(jax.eval_shape(lambda: eng._feed))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if program == "decode":
+        lowered = eng._decode_fn.lower(params, eng._cache, feed,
+                                       i32(2, slots))
+    else:
+        lowered = eng._prefill_fn(bucket).lower(
+            params, eng._cache, feed, i32(1, bucket), i32(), i32(),
+            i32(block), jax.ShapeDtypeStruct((block,), jnp.bool_,
+                                             sharding=chip))
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nsdar {program}: arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+              f"{memory.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+              f"{memory.alias_size_in_bytes / 1e9:.3f} GB")
+    # the feed's two leaves, and six counters of 1.5 KB in tiles
+    assert 0 < memory.alias_size_in_bytes - eng.cache_bytes() <= 65536
+    # jit drops the head from the prefill's arguments: nothing reads it
+    unread = 0 if program == "decode" else 2048 * 151936 * 2
+    assert 11.8e9 < memory.argument_size_in_bytes + unread < 12.1e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16e9)
+    row = rf"bf16\[{slots},4,128,{seq}\]"
+    assert len(re.findall(rf"= {row}\S* parameter\(\d+\), sharding",
+                          text)) == 2 * layers
+    assert not re.findall(rf"= {row}\S* copy(-start)?\(", text)
+    if program == "decode":
+        assert memory.temp_size_in_bytes < 1.0e9
+        for kernel, calls in (("kv_cache_write_block", 2 * layers),
+                              ("grouped_decode_attention", layers)):
+            assert len(re.findall(
+                rf"%{kernel}[.\d]* = [^\n]*? custom-call\(", text)) == calls
+        assert not re.findall(r"%ragged-dot", text)
+    else:
+        assert memory.temp_size_in_bytes < 2.5e9
+        assert text.count("tpu_custom_call") >= layers
+        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
+                              text)) >= 3
+        # no head: nothing of the vocabulary's width is computed
+        assert not re.findall(r"f32\[\d+(,\d+)*,151936\]", text)
+
+
 def test_kernels_in_a_batch_sharded_step_on_four_chips(v5e):
     """What ``training.make_train_step`` builds on a four-chip host: one
     jit over the global mesh, batch sharded. XLA cannot partition a

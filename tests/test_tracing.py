@@ -235,10 +235,20 @@ def test_format_slo_report(monkeypatch):
 
 # ------------------------------------------------------------- HTTP routes
 
-def test_healthz_and_slo_routes(monkeypatch):
+@pytest.fixture
+def metrics_port():
+    """The registry's HTTP endpoint on an ephemeral port, stopped again:
+    left up, it fails ``tests/test_metrics.py``'s "no socket when the
+    environment names no port" wherever that file follows this one on a
+    worker (it did, once ``--dist loadfile`` had two more files to deal)."""
     from horovod_tpu.metrics import registry
 
-    port = registry().serve(0)
+    yield registry().serve(0)
+    registry().stop_server()
+
+
+def test_healthz_and_slo_routes(monkeypatch, metrics_port):
+    port = metrics_port
 
     def get(route):
         try:

@@ -28,6 +28,14 @@ seeded weights, each beside its plain reference under ``benchmark/``
   convolution tail a state-space layer beside the full layer's rows);
   about 1.6.
 
+* ``sdar``: SDAR-30B-A3B-Chat, generation by diffusion over blocks of 4
+  on full grouped-query layers (rotary, QK-norm) with all the routed
+  experts, against ``benchmark/reference_sdar.py`` (``benchmark/configs/
+  sdar-30b-a3b.json``'s toy sizes, all six layers). It is no entry of
+  :func:`family`: a step of its engine is a pass over a block and its
+  prefill yields no token, so ``tests/test_block_diffusion.py`` has its
+  cases and ``tests/test_engine_contract.py`` a block model of its own.
+
 A new family adds its entry to :func:`family` and so joins every case of
 ``tests/test_engine_contract.py``; it does not copy them.
 """
@@ -42,11 +50,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark import (reference_brumby, reference_granite,
-                       reference_kexaone, reference_sala, reference_xing)
+                       reference_kexaone, reference_sala, reference_sdar,
+                       reference_xing)
 from benchmark import (weights_brumby, weights_granite, weights_kexaone,
-                       weights_sala, weights_xing)
+                       weights_sala, weights_sdar, weights_xing)
 from benchmark.runners import (serve_brumby, serve_granite, serve_kexaone,
-                               serve_sala, serve_xing)
+                               serve_sala, serve_sdar, serve_xing)
 from horovod_tpu import tracing
 from horovod_tpu.models.transformer import Transformer
 
@@ -193,6 +202,34 @@ _granite_forward = jax.jit(reference_granite.forward, static_argnums=(2, 3))
 def granite_reference(cfg, params, toks, precision="f32"):
     return np.asarray(_granite_forward(
         params, jnp.asarray(toks, jnp.int32), reference_granite.frozen(cfg),
+        precision))
+
+
+# ------------------------------------------------------------------ SDAR
+
+def sdar_cfg(dtype="float32", **changes):
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        published = json.load(f)
+    cfg = dict(published["as_run"], **published["rehearse"])
+    cfg.update(max_seq=512, dtype=dtype, param_dtype=dtype)
+    cfg.update(changes)
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def sdar(**changes):
+    cfg = sdar_cfg(**changes)
+    return cfg, weights_sdar.make_params(cfg, SEED), \
+        serve_sdar.build_model(cfg)
+
+
+_sdar_forward = jax.jit(reference_sdar.forward, static_argnums=(2, 3))
+
+
+def sdar_reference(cfg, params, toks, precision="f32"):
+    return np.asarray(_sdar_forward(
+        params, jnp.asarray(toks, jnp.int32), reference_sdar.frozen(cfg),
         precision))
 
 
