@@ -65,6 +65,9 @@ FULL = dict(
                 sparse=dict(kernel=32, stride=16, block_size=64, topk=64,
                             init_blocks=1, window_size=2048,
                             dense_len=8192)),
+    # the lightning layer's MLP: 4 of 16 routed experts held, top-4
+    hybrid_experts=dict(num_experts=16, top_k=4, d_ff=256, first=0,
+                        count=4),
     hybrid_prompts=(10000, 300))
 # --rehearse: same code path, toy widths (head_dim stays 64 so the flash
 # kernel's block logic is the real one, in interpret mode)
@@ -77,6 +80,7 @@ TINY = dict(
                 num_kv_heads=2, head_dim=16, max_seq=512,
                 sparse=dict(kernel=8, stride=4, block_size=16, topk=6,
                             init_blocks=1, window_size=32, dense_len=128)),
+    hybrid_experts=dict(num_experts=8, top_k=2, d_ff=32, first=0, count=2),
     hybrid_prompts=(300, 60))
 
 
@@ -322,18 +326,23 @@ def serve(hvd, cfg, params, seed) -> None:
 
 
 def serve_hybrid(hvd, cfg, seed) -> None:
-    """A block-sparse layer beside a lightning layer behind
-    ``hvd.serve()``: a prompt past ``dense_len`` and one under it, greedy
-    output the same twice, the prompt kernel in the prefill programs."""
+    """A block-sparse layer beside a lightning layer, whose MLP is a
+    share of routed experts, behind ``hvd.serve()``: a prompt past
+    ``dense_len`` and one under it, greedy output the same twice, the
+    prompt kernel and the grouped experts' way back (``expert_combine``)
+    in the prefill programs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from horovod_tpu.metrics import registry
-    from horovod_tpu.models.hybrid import (BLOCK_SPARSE, LIGHTNING,
+    from horovod_tpu.models.hybrid import (BLOCK_SPARSE, DENSE_MLP,
+                                           EXPERTS_MLP, LIGHTNING,
                                            HybridDecoder)
 
     model = HybridDecoder(mixers=(BLOCK_SPARSE, LIGHTNING),
+                          mlps=(DENSE_MLP, EXPERTS_MLP),
+                          experts=cfg["hybrid_experts"],
                           param_dtype=jnp.bfloat16, **cfg["hybrid"])
     params = jax.jit(lambda key: model.init(
         key, jnp.zeros((1, 8), jnp.int32))["params"])(
@@ -366,6 +375,10 @@ def serve_hybrid(hvd, cfg, seed) -> None:
             raise AssertionError(
                 "serve[hybrid]: the prefill programs hold no "
                 f"sparse_prompt_attention kernel: {engine}")
+        if "expert_combine" not in engine["prefill_kernels"]:
+            raise AssertionError(
+                "serve[hybrid]: the prefill programs bring the grouped "
+                f"experts' products back without expert_combine: {engine}")
         share = registry().snapshot()["sparse.live_block_share"][
             "values"][0]["value"]
         say(f"serve[hybrid]: prompts {[o.prompt_len for o in outs]} x "
@@ -373,6 +386,7 @@ def serve_hybrid(hvd, cfg, seed) -> None:
             f"incl. {engine['compiles_total']} compiles; "
             f"decode_write_fused {engine['decode_write_fused']}, "
             f"prefill_sparse_kernel {engine['prefill_sparse_kernel']}, "
+            f"prefill_kernels {engine['prefill_kernels']}, "
             f"sparse.live_block_share {share:.4f}")
     finally:
         handle.close()

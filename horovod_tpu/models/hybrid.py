@@ -104,6 +104,7 @@ from horovod_tpu.models.transformer import write_cache_rows
 from horovod_tpu.ops.pallas import (grouped_decode_attention,
                                     latent_attention, power_retention,
                                     sparse_attention)
+from horovod_tpu.ops.pallas.expert_combine import expert_combine
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
 from horovod_tpu.ops.pallas.kv_cache_write import (LANES, write_block,
                                                    write_token)
@@ -1469,7 +1470,15 @@ class StateSpace(nn.Module):
 # ms at the chip's peak, about the time of the read) and passes it
 # beyond; nothing above 256 rows has been measured masked, and 16 keeps
 # every accepted cell's prompt buckets (17.8 pairs an expert at the
-# least) grouped.
+# least) grouped. Every grouped time above is of the way back as it was
+# until PR 47: one gather of the tokens' rows and one pass over the whole
+# sum for each of a token's top_k pairs, and a scatter for the inverse of
+# the sort. Since PR 47 the products are added to their tokens' sums in
+# one pass in the order they lie in (ops/pallas/expert_combine.py) and
+# nobody needs the inverse, so the grouped side of each comparison costs
+# less than it read here; the crossover is to be measured again (ROADMAP
+# S17 (b)), and 16 stays until then: changing it moves every decode
+# program.
 MASKED_PAIRS = 16
 # tokens of a prompt that a layer holding a share of the experts groups at
 # once (experts_grouped_held)
@@ -1516,34 +1525,63 @@ def experts_masked(x, weights, gate, up, down):
     return jnp.einsum("tef,efd->td", h, down, preferred_element_type=F32)
 
 
+def sorted_pairs(chosen, weights, experts):
+    """The permutation from token order to expert order: the (token,
+    expert) pairs of ``chosen`` (tokens, top_k) sorted by expert, stably,
+    so that an expert's pairs lie together in the order of their tokens
+    and the pairs that are not here (``chosen == experts``) last. Returns
+    ``token`` (pairs,), the token of the pair at each sorted place;
+    ``weight`` (pairs,), that pair's router weight; and ``counts``
+    (experts,), the pairs of each held expert. The one sort carries the
+    pairs' numbers and weights along: nothing is gathered one element at
+    a time, and nobody needs the way back from a pair to its sorted
+    place (:func:`~horovod_tpu.ops.pallas.expert_combine.expert_combine`
+    walks the products in sorted order)."""
+    pair_expert = chosen.reshape(-1)
+    _, pair, weight = jax.lax.sort(
+        (pair_expert, jnp.arange(pair_expert.shape[0], dtype=jnp.int32),
+         weights.reshape(-1)), num_keys=1, is_stable=True)
+    counts = jnp.sum(pair_expert[:, None] == jnp.arange(experts)[None, :],
+                     axis=0, dtype=jnp.int32)
+    return pair // chosen.shape[1], weight, counts
+
+
+def pair_rows(x, token, here):
+    """``x[token]``, the rows of the sorted pairs' tokens, gathered from a
+    copy of ``x`` made for the gather alone (``here``, a traced number
+    that is never negative, keeps the copy from being folded away). A
+    stopgap until the way in has a gather kernel of its own (ROADMAP S20
+    (b)): XLA holds a buffer in VMEM only while no Mosaic kernel runs,
+    and ``x`` lives on past :func:`expert_combine` (the shared MLP and a
+    later turn read it), so gathered from ``x`` itself the rows come
+    from HBM: 2.50 ms for 40,960 rows of 4,096 where from VMEM they take
+    0.51, and ``granite-serve-chat-c1`` serves +4.4% over the parent
+    without the copy and +7.2% with it (my chip runs, PR 47; PERF.md
+    section 6). The copy dies at the gather, and
+    ``tests/test_tpu_compile.py`` reads the compiled prefill for the
+    gather's operand in VMEM."""
+    return jnp.where(here >= 0, x, jnp.zeros_like(x))[token]
+
+
 def experts_grouped(x, chosen, weights, gate, up, down):
     """The same sum as a grouped product: the (token, expert) pairs
-    sorted by expert, each expert's rows multiplied by its own matrices
-    (``jax.lax.ragged_dot``: on the chip one kernel that visits each
-    group's row tiles), and the rows gathered back to their tokens. No
-    pair is dropped and none is padded to a capacity. ``chosen``:
-    (tokens, top_k) int32, an entry equal to the number of held experts
-    meaning "not here" (another chip's expert, or a padded token): such
-    pairs sort last, lie past the last group and cost no product.
+    sorted by expert (:func:`sorted_pairs`), each expert's rows
+    multiplied by its own matrices (``jax.lax.ragged_dot``: on the chip
+    one kernel that visits each group's row tiles), and the float32
+    products added to their tokens' sums, each times its weight, in one
+    pass over them in the order they lie in
+    (``ops/pallas/expert_combine.py``). No pair is dropped and none is
+    padded to a capacity. ``chosen``: (tokens, top_k) int32, an entry
+    equal to the number of held experts meaning "not here" (another
+    chip's expert, or a padded token): such pairs sort last, lie past the
+    last group, cost no product and are not read on the way back.
     Returns (tokens, d) float32."""
-    tokens, top_k = chosen.shape
-    experts = gate.shape[0]
-    pair_expert = chosen.reshape(-1)
-    order = jnp.argsort(pair_expert, stable=True)
-    sizes = jnp.sum(pair_expert[:, None] == jnp.arange(experts)[None, :],
-                    axis=0, dtype=jnp.int32)
-    rows = x[order // top_k]
+    token, weight, sizes = sorted_pairs(chosen, weights, gate.shape[0])
+    rows = x[token]
     h = nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
         * jax.lax.ragged_dot(rows, up, sizes)
     y = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=F32)
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype)).reshape(chosen.shape)
-    # a token's k-th pair at a time: rows gathered as rows, never laid out
-    # as (tokens, top_k, d); a pair that is not here reads a row past the
-    # last group, which is whatever the product left there
-    return sum(jnp.where(chosen[:, k, None] < experts,
-                         y[back[:, k]] * weights[:, k, None], 0.0)
-               for k in range(top_k))
+    return expert_combine(y, token, weight, jnp.sum(sizes), x.shape[0])
 
 
 def experts_grouped_held(x, chosen, weights, gate, up, down, share):
@@ -1551,56 +1589,58 @@ def experts_grouped_held(x, chosen, weights, gate, up, down, share):
     1) of the experts, where most pairs are not here: only the pairs that
     are here are gathered and multiplied. The sorted pairs are taken
     ``room`` at a time - twice the pairs an even router would send here,
-    in whole lane tiles - in a loop that runs as often as the pairs here
-    need: once, unless the router is very uneven. No pair is dropped;
+    in whole lane tiles - as often as the pairs here need: one turn,
+    and after it a loop of more where the router is very uneven. No pair
+    is dropped;
     the rows gathered, the hidden rows and the float32 products are
-    ``room`` long and not ``tokens x top_k``. A long prompt goes
-    ``HELD_TOKENS`` tokens at a time, so that none of that grows with its
-    length (each turn reads the held experts again: a millisecond).
-    Returns (tokens, d) float32."""
+    ``room`` long and not ``tokens x top_k``. A turn's products are
+    added to their tokens' sums as :func:`experts_grouped`'s are, those
+    of them that belong to a pair that is here, and a second turn adds
+    to the first's sums in place. A long prompt goes ``HELD_TOKENS``
+    tokens at a time, so that none of that grows with its length (each
+    turn reads the held experts again: a millisecond), and a chunk of it
+    with no pair here (the padding at the end of its bucket) runs
+    nothing. Returns (tokens, d) float32."""
     tokens, top_k = chosen.shape
-    experts = gate.shape[0]
     if tokens > HELD_TOKENS and tokens % HELD_TOKENS == 0:
         cut = lambda t: t.reshape((-1, HELD_TOKENS) + t.shape[1:])
+
+        def chunk(xs):
+            # a chunk with no pair here (all padding: the tail of a long
+            # prompt's bucket) runs nothing, as on the loop of old
+            return jax.lax.cond(
+                jnp.any(xs[1] < gate.shape[0]),
+                lambda: experts_grouped_held(*xs, gate, up, down, share),
+                lambda: jnp.zeros(xs[0].shape, F32))
         return jax.lax.map(
-            lambda xs: experts_grouped_held(*xs, gate, up, down, share),
-            (cut(x), cut(chosen), cut(weights))).reshape(tokens, -1)
+            chunk, (cut(x), cut(chosen), cut(weights))).reshape(tokens, -1)
     pairs = tokens * top_k
     room = min(pairs, -(-math.ceil(2 * pairs * share) // LANES) * LANES)
-    pair_expert = chosen.reshape(-1)
-    order = jnp.argsort(pair_expert, stable=True)
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(pairs, dtype=order.dtype)).reshape(chosen.shape)
-    here = jnp.sum(pair_expert < experts, dtype=jnp.int32)
-    order = jnp.pad(order, (0, -pairs % room))
+    token, weight, counts = sorted_pairs(chosen, weights, gate.shape[0])
+    token, weight = (jnp.pad(a, (0, -pairs % room)) for a in (token, weight))
+    ends = jnp.cumsum(counts)
+    here = ends[-1]
 
-    def some(i, out):
-        start = i * room
-        taken = jax.lax.dynamic_slice(order, (start,), (room,))
-        expert = jnp.where(start + jnp.arange(room) < here,
-                           pair_expert[taken], experts)
-        sizes = jnp.sum(expert[:, None] == jnp.arange(experts)[None, :],
-                        axis=0, dtype=jnp.int32)
-        rows = x[taken // top_k]
+    def turn(start, out):
+        taken = jax.lax.dynamic_slice(token, (start,), (room,))
+        # what each expert's run of sorted places has inside this turn
+        sizes = jnp.clip(ends - start, 0, room) \
+            - jnp.clip(ends - counts - start, 0, room)
+        rows = pair_rows(x, taken, here)
         h = nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
             * jax.lax.ragged_dot(rows, up, sizes)
         y = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=F32)
-        # a token's k-th pair at a time, as experts_grouped gathers them,
-        # but one after the other: together the gathered rows of a long
-        # prompt are gigabytes. A pair that is not here, or not in this
-        # turn, adds nothing
-        def kth(k, out):
-            at = jnp.take(back, k, axis=1) - start
-            mine = (jnp.take(chosen, k, axis=1) < experts) \
-                & (at >= 0) & (at < room)
-            return out + jnp.where(
-                mine[:, None], y[jnp.clip(at, 0, room - 1)]
-                * jnp.take(weights, k, axis=1)[:, None], 0.0)
+        return expert_combine(
+            y, taken, jax.lax.dynamic_slice(weight, (start,), (room,)),
+            here - start, tokens, out)
 
-        return jax.lax.fori_loop(0, top_k, kth, out)
-
-    return jax.lax.fori_loop(0, -(-here // room), some,
-                             jnp.zeros((tokens, x.shape[-1]), F32))
+    # the first turn has no sum to add to, and where ``room`` holds every
+    # pair there is no other
+    first = turn(0, None)
+    if room == pairs:
+        return first
+    return jax.lax.fori_loop(1, -(-here // room),
+                             lambda i, out: turn(i * room, out), first)
 
 
 class RoutedExperts(nn.Module):

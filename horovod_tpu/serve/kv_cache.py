@@ -287,6 +287,12 @@ class DecodeEngine:
                 "masked": jnp.ones((self.num_slots, self.block_len), bool)}
         # by bucket, or by name where a prompt runs in pieces
         self._prefill_fns: Dict[object, object] = {}  # guarded-by: <replica-thread>
+        # the kernels the prefill programs hold, as ``decode_kernels`` of
+        # the decode program: each program's jaxpr is read when it is
+        # first enqueued (the trace the call then reuses), so a grouped
+        # product that silently went back to XLA's gathers shows here
+        self.prefill_kernels: Tuple[str, ...] = ()
+        self._unread: set = set()                # guarded-by: <replica-thread>
         self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._decode_compiled = False
         # prefill programs enqueued, the positions they computed (padding
@@ -377,6 +383,7 @@ class DecodeEngine:
         if fn is None:
             fn = self._prefill_fns[key] = jax.jit(impl,
                                                   donate_argnums=(1, 2))
+            self._unread.add(fn)
             self._note_compile(name)
         return fn
 
@@ -401,6 +408,12 @@ class DecodeEngine:
         anything."""
         old = None if kind in self._donated \
             else jax.tree.leaves((self._cache, self._feed))
+        if fn in self._unread:
+            self._unread.discard(fn)
+            held = kernels_in(fn.trace(self._params, self._cache,
+                                       self._feed, *args).jaxpr)
+            self.prefill_kernels = tuple(dict.fromkeys(
+                self.prefill_kernels + tuple(held)))
         t0 = time.perf_counter()
         with self._cache_lock:
             self._lock_wait_s += time.perf_counter() - t0
@@ -766,6 +779,7 @@ class DecodeEngine:
                         if self.kv_tiles_held else None),
                     "decode_write_fused": self._write_fused,
                     "prefill_sparse_kernel": self._sparse_kernel,
+                    "prefill_kernels": list(self.prefill_kernels),
                     "decode_positions_read": (self.positions_read
                                               if self._counts_positions
                                               else None),

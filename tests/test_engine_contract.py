@@ -157,7 +157,8 @@ STATS_KEYS = {
     "compiles", "compiles_total", "decode_steps", "decode_step_ms_ewma",
     "prefill_chunks", "prefill_positions", "prefill_tokens", "cache_bytes",
     "cache_bytes_by_kind", "cache_donated", "decode_kv_read_share",
-    "decode_write_fused", "prefill_sparse_kernel", "decode_positions_read",
+    "decode_write_fused", "prefill_sparse_kernel", "prefill_kernels",
+    "decode_positions_read",
     "decode_positions_by_kind", "expert_counts", "block_len", "row_passes",
     "commit_row_passes", "tokens_unmasked", "blocks_committed", "slots"}
 

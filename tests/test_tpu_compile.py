@@ -371,6 +371,35 @@ def test_sparse_prompt_attention(chip, seq, heads, kv_heads, head_dim,
     assert kernels == 1
 
 
+@pytest.mark.parametrize("tokens,d,room", [
+    (4096, 4096, 40960),     # granite-serve-chat-c1's largest bucket, x 10
+    (4096, 6144, 4096)],     # kexaone-serve-mixed-c1's turn of a prompt
+    ids=["granite", "kexaone"])
+def test_expert_combine(chip, tokens, d, room):
+    """The way back from a grouped product at the two held-share cells'
+    shapes, accumulating as ``experts_grouped_held``'s loop calls it:
+    Mosaic takes the strided loads and stores at sublanes read from SMEM,
+    the loop of a traced number of rows and the block indices that
+    depend on the prefetched ``live``, with 1,024 columns of every
+    token's sum twice over in VMEM beside ``acc``'s (64 MB), as one
+    custom call that writes its sum where ``acc`` lay."""
+    from horovod_tpu.ops.pallas.expert_combine import expert_combine
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in (((room, d), F32), ((room,), jnp.int32),
+                                 ((room,), F32), ((), jnp.int32),
+                                 ((tokens, d), F32))]
+    text = jax.jit(
+        lambda y, token, weight, live, acc: expert_combine(
+            y, token, weight, live, tokens, acc),
+        donate_argnums=4).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(
+        r"%expert_combine[.\d]* = [^\n]*? custom-call\(", text)) == 1
+    # no copy of the sum: the kernel adds where ``acc`` lies
+    assert not re.search(rf"= f32\[{tokens},{d}\][^\n]*? copy\(", text)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk",
                                      "prefill_last"])
 def test_retention_engine_fits_and_rewrites_its_cache_in_place(
@@ -634,11 +663,16 @@ def test_window_full_engine_fits_and_updates_its_cache_in_place(
             r"%grouped_decode_attention[.\d]* = [^\n]*? custom-call\(",
             text)) == full
     else:
-        # one flash kernel a full layer; three grouped products an expert
-        # layer, inside the loop over the pairs that are here
+        # one flash kernel a full layer; three grouped products and one
+        # pass that brings them back an expert layer, 4,096 tokens of the
+        # prompt at a time, under a conditional that a chunk of padding
+        # does not take
         assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
                               text)) >= 3
         assert text.count("tpu_custom_call") >= full
+        assert re.search(r"%expert_combine[.\d]* = [^\n]*? custom-call\(",
+                         text)
+        assert re.search(r" conditional\(", text)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_4096"])
@@ -740,11 +774,30 @@ def test_state_space_engine_fits_and_updates_its_cache_in_place(
         assert not re.findall(r"%ragged-dot", text)
     else:
         assert memory.temp_size_in_bytes < 1.8e9
-        # one flash kernel; three grouped products an expert layer,
-        # inside the loop over the pairs that are here
+        # one flash kernel; three grouped products and one pass that
+        # brings them back to their tokens an expert layer: ``room`` holds
+        # every pair of a layer that holds half the experts, so there is
+        # one turn and no loop
         assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
                               text)) >= 3
-        assert text.count("tpu_custom_call") >= 1
+        assert len(re.findall(
+            r"%expert_combine[.\d]* = [^\n]*? custom-call\(", text)) \
+            == ssm_layers + 1
+        # the gather of the pairs' rows reads the tokens' activations
+        # from VMEM (memory space 1), as the parent's did in its loop:
+        # from HBM it takes 2.50 ms a layer against 0.51 (my chip runs,
+        # PR 47). XLA holds nothing in VMEM across a Mosaic kernel, and
+        # the activations live on past ``expert_combine``, so the rows
+        # are gathered from a copy that dies at the gather
+        # (models/hybrid.py ``pair_rows``: a stopgap that rests on this
+        # reading until the way in has a kernel of its own, ROADMAP S20)
+        shape = {m[1]: m[2] for m in re.finditer(
+            r"\n\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", text)}
+        first = [m[1] for m in re.finditer(
+            rf"= bf16\[{10 * bucket},4096\]\S* fusion\((%[\w.\-]+), [^\n]*?"
+            r'op_name="[^"]*/moe/moe/gather"', text)]
+        assert len(first) == ssm_layers + 1
+        assert all("S(1)" in shape[x] for x in first), first
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_2048"])
