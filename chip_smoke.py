@@ -329,8 +329,8 @@ def serve_hybrid(hvd, cfg, seed) -> None:
     """A block-sparse layer beside a lightning layer, whose MLP is a
     share of routed experts, behind ``hvd.serve()``: a prompt past
     ``dense_len`` and one under it, greedy output the same twice, the
-    prompt kernel and the grouped experts' way back (``expert_combine``)
-    in the prefill programs."""
+    prompt kernel, the grouped experts' products (``grouped_product``)
+    and their way back (``expert_combine``) in the prefill programs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -375,10 +375,11 @@ def serve_hybrid(hvd, cfg, seed) -> None:
             raise AssertionError(
                 "serve[hybrid]: the prefill programs hold no "
                 f"sparse_prompt_attention kernel: {engine}")
-        if "expert_combine" not in engine["prefill_kernels"]:
+        if not {"grouped_product", "expert_combine"} \
+                <= set(engine["prefill_kernels"]):
             raise AssertionError(
-                "serve[hybrid]: the prefill programs bring the grouped "
-                f"experts' products back without expert_combine: {engine}")
+                "serve[hybrid]: the prefill programs group their experts' "
+                f"pairs without grouped_product or expert_combine: {engine}")
         share = registry().snapshot()["sparse.live_block_share"][
             "values"][0]["value"]
         say(f"serve[hybrid]: prompts {[o.prompt_len for o in outs]} x "

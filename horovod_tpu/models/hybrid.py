@@ -106,6 +106,7 @@ from horovod_tpu.ops.pallas import (grouped_decode_attention,
                                     sparse_attention)
 from horovod_tpu.ops.pallas.expert_combine import expert_combine
 from horovod_tpu.ops.pallas.flash_attention import flash_attention
+from horovod_tpu.ops.pallas.grouped_product import gated_products
 from horovod_tpu.ops.pallas.kv_cache_write import (LANES, write_block,
                                                    write_token)
 
@@ -1566,8 +1567,11 @@ def pair_rows(x, token, here):
 def experts_grouped(x, chosen, weights, gate, up, down):
     """The same sum as a grouped product: the (token, expert) pairs
     sorted by expert (:func:`sorted_pairs`), each expert's rows
-    multiplied by its own matrices (``jax.lax.ragged_dot``: on the chip
-    one kernel that visits each group's row tiles), and the float32
+    multiplied by its own matrices (``ops/pallas/grouped_product.py``:
+    gate and up in one kernel, down in another, both walking one list
+    of (row tile, expert) visits; an expert's matrices stay in VMEM
+    across its row tiles, an expert with no pair is not read, the hidden
+    rows are rounded to bfloat16 once), and the float32
     products added to their tokens' sums, each times its weight, in one
     pass over them in the order they lie in
     (``ops/pallas/expert_combine.py``). No pair is dropped and none is
@@ -1577,10 +1581,7 @@ def experts_grouped(x, chosen, weights, gate, up, down):
     last group, cost no product and are not read on the way back.
     Returns (tokens, d) float32."""
     token, weight, sizes = sorted_pairs(chosen, weights, gate.shape[0])
-    rows = x[token]
-    h = nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
-        * jax.lax.ragged_dot(rows, up, sizes)
-    y = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=F32)
+    y = gated_products(x[token], gate, up, down, sizes)
     return expert_combine(y, token, weight, jnp.sum(sizes), x.shape[0])
 
 
@@ -1626,10 +1627,8 @@ def experts_grouped_held(x, chosen, weights, gate, up, down, share):
         # what each expert's run of sorted places has inside this turn
         sizes = jnp.clip(ends - start, 0, room) \
             - jnp.clip(ends - counts - start, 0, room)
-        rows = pair_rows(x, taken, here)
-        h = nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) \
-            * jax.lax.ragged_dot(rows, up, sizes)
-        y = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=F32)
+        y = gated_products(pair_rows(x, taken, here), gate, up, down,
+                           sizes)
         return expert_combine(
             y, taken, jax.lax.dynamic_slice(weight, (start,), (room,)),
             here - start, tokens, out)

@@ -25,6 +25,7 @@ from benchmark import reference_xing as xref
 from benchmark import weights_xing
 from benchmark.runners import serve_xing
 from horovod_tpu.models import hybrid
+from horovod_tpu.ops.pallas._backend import kernels_in
 from toy_models import (REPO, SEED, granite, kexaone, tokens, xing, xing_cfg,
                         xing_reference as xreference)
 
@@ -34,7 +35,7 @@ F32_TOL = 2e-5
 @pytest.mark.parametrize("length", [8, 301])
 def test_latent_experts_forward_matches_the_plain_reference(length):
     """8 tokens: the expert layers multiply every held expert by every
-    row under a 0/1 mask; 301: they group the pairs (``ragged_dot``)."""
+    row under a 0/1 mask; 301: they group the pairs (``grouped_product``)."""
     cfg, params, model = xing()
     toks = tokens(length)
     got = np.asarray(model.apply({"params": params},
@@ -168,7 +169,8 @@ def _routed_layer(cfg, first, count, shared):
 
 def _is_grouped(layer, variables, x):
     """Which form of the product the layer chose for ``x``'s size."""
-    return "ragged_dot" in str(jax.make_jaxpr(layer.apply)(variables, x))
+    return "grouped_product" in kernels_in(
+        jax.make_jaxpr(layer.apply)(variables, x))
 
 
 def _held(p, first, count, shared=True):

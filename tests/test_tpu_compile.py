@@ -75,6 +75,11 @@ def _kernels_in(fn, chip, *specs):
     return text.count("tpu_custom_call")
 
 
+def _calls(kernel, text):
+    """How often the compiled program calls the Mosaic kernel ``kernel``."""
+    return len(re.findall(rf"%{kernel}[.\d]* = [^\n]*? custom-call\(", text))
+
+
 @pytest.mark.parametrize("shape,causal,traced", [
     ((16, 12, 1024, 64), True, False),   # GPT-2-small bench: batch 16, seq 1024
     ((8, 16, 512, 64), False, False),    # BERT-Large bench: batch 8, seq 512
@@ -400,6 +405,77 @@ def test_expert_combine(chip, tokens, d, room):
     assert not re.search(rf"= f32\[{tokens},{d}\][^\n]*? copy\(", text)
 
 
+@pytest.mark.parametrize("form,d,d_ff,experts,count,top_k,tokens", [
+    # k-exaone-236b-a23b: a sixteenth held, the first turn and the loop's
+    # (granite's one turn is read in its engine's test above)
+    ("held-in-turns", 6144, 2048, 128, 8, 8, 1024),
+    # sdar-30b-a3b: every expert held
+    ("all", 2048, 768, 128, None, 8, 512)])
+def test_ten_expert_layers_hold_two_grouped_product_traces(
+        chip, form, d, d_ff, experts, count, top_k, tokens):
+    """A prompt's expert products in both grouped forms at two cells'
+    widths, ten layers of them in one program: Mosaic takes both kernels of
+    ``ops/pallas/grouped_product`` (gate and up fused; down in float32),
+    the compiled text calls them twice a layer's turn and holds no
+    ``ragged-dot``. And what a start pays for: the traced program holds
+    TWO distinct jitted callables round a ``grouped_product`` kernel and
+    one round the visits, however many layers and turns call them - each
+    is traced once a process and lowered once a module. A later edit
+    that splits a trace (an argument that differs between layers, or
+    between the first turn and the loop's body) fails here before it
+    costs a cell its ``setup_s`` (PERF.md section 6, PR 49)."""
+    from horovod_tpu.models.hybrid import RoutedExperts
+    from horovod_tpu.ops.pallas._backend import kernels_in
+
+    layers = 10
+    layer = RoutedExperts(num_experts=experts, top_k=top_k, d_ff=d_ff,
+                          shared=0, count=count, dtype=BF16)
+    x = jax.ShapeDtypeStruct((1, tokens, d), BF16, sharding=chip)
+    # one layer's experts under all ten: the traces are what is counted
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, d), BF16))["params"]))
+
+    def program(params, x):
+        for _ in range(layers):
+            x = x + layer.apply({"params": params}, x)
+        return x
+
+    traced = jax.jit(program).trace(params, x)
+    calls = kernels_in(traced.jaxpr).count("grouped_product")
+    turns = 2 if form == "held-in-turns" else 1
+    assert calls == 2 * turns * layers
+
+    def holders(jaxpr, found):
+        # the jitted functions whose own equations hold a grouped-product
+        # kernel, and the kernels' own jaxprs: what is traced and lowered
+        for eqn in jaxpr.eqns:
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if not hasattr(inner, "eqns"):
+                        continue
+                    kernels = [e for e in inner.eqns
+                               if e.primitive.name == "pallas_call"
+                               and e.params["name"] == "grouped_product"]
+                    if eqn.primitive.name in ("pjit", "jit") and kernels:
+                        found[id(inner)] = {id(e.params["jaxpr"])
+                                            for e in kernels}
+                    holders(inner, found)
+        return found
+
+    held = holders(traced.jaxpr.jaxpr, {})
+    assert len(held) == 1                      # one callable a layer
+    assert [len(k) for k in held.values()] == [2]   # the two kernels
+
+    text = traced.lower().compile().as_text()
+    assert _calls("grouped_product", text) == 2 * turns * layers
+    assert _calls("expert_combine", text) == turns * layers
+    assert not re.findall(r"%ragged-dot", text)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk",
                                      "prefill_last"])
 def test_retention_engine_fits_and_rewrites_its_cache_in_place(
@@ -510,8 +586,8 @@ def test_latent_experts_engine_fits_and_updates_its_cache_in_place(
     ``ops/pallas/latent_attention`` (Mosaic takes it at 32 heads, a
     512-wide latent and tiles of 1,024 positions); the prefill attends
     through the flash kernel at a padded width of 256
-    and groups its pairs through ``ragged_dot``, which the TPU compiler
-    turns into its own grouped-product kernel."""
+    and multiplies its sorted pairs through ``ops/pallas/grouped_product``,
+    gate and up in one kernel and down in another."""
     import json
 
     from benchmark.runners.serve_xing import build_model
@@ -574,10 +650,11 @@ def test_latent_experts_engine_fits_and_updates_its_cache_in_place(
             r"%latent_decode_attention[.\d]* = [^\n]*? custom-call\(",
             text)) == layers
     else:
-        # one flash kernel a layer; three grouped products an expert
-        # layer, each with the kernel that lays out its groups
-        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
-                              text)) >= 3 * (layers - 1)
+        # one flash kernel a layer; two grouped-product kernels (gate
+        # and up in one) and the pass that brings them back an expert layer
+        assert _calls("grouped_product", text) == 2 * (layers - 1)
+        assert _calls("expert_combine", text) == layers - 1
+        assert not re.findall(r"%ragged-dot", text)
         assert text.count("tpu_custom_call") >= layers + 3 * (layers - 1)
 
 
@@ -598,7 +675,8 @@ def test_window_full_engine_fits_and_updates_its_cache_in_place(
     ``ops/pallas/grouped_decode_attention`` (Mosaic takes it at 8 queries
     a key/value head and tiles of 512 positions); the prefill's full
     layers attend through the flash kernel, its window layers in XLA,
-    and its pairs that are here are grouped through ``ragged_dot``."""
+    and its pairs that are here are multiplied through
+    ``ops/pallas/grouped_product``."""
     import json
 
     from benchmark.runners.serve_kexaone import build_model
@@ -663,12 +741,12 @@ def test_window_full_engine_fits_and_updates_its_cache_in_place(
             r"%grouped_decode_attention[.\d]* = [^\n]*? custom-call\(",
             text)) == full
     else:
-        # one flash kernel a full layer; three grouped products and one
-        # pass that brings them back an expert layer, 4,096 tokens of the
-        # prompt at a time, under a conditional that a chunk of padding
-        # does not take
-        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
-                              text)) >= 3
+        # one flash kernel a full layer; two grouped-product kernels and
+        # one pass that brings them back an expert layer's turn, 4,096
+        # tokens of the prompt at a time, under a conditional that a chunk
+        # of padding does not take
+        assert _calls("grouped_product", text) >= 2
+        assert not re.findall(r"%ragged-dot", text)
         assert text.count("tpu_custom_call") >= full
         assert re.search(r"%expert_combine[.\d]* = [^\n]*? custom-call\(",
                          text)
@@ -693,7 +771,7 @@ def test_state_space_engine_fits_and_updates_its_cache_in_place(
     product for the read would be a third pass). The step's 640 pairs
     over 72 experts are at ``MASKED_PAIRS`` an expert: every held expert
     multiplies every row and no pair is sorted; the prefill's pairs that
-    are here are grouped through ``ragged_dot`` and its full layer
+    are here are multiplied through ``ops/pallas/grouped_product`` and its full layer
     attends through the flash kernel."""
     import json
 
@@ -774,15 +852,13 @@ def test_state_space_engine_fits_and_updates_its_cache_in_place(
         assert not re.findall(r"%ragged-dot", text)
     else:
         assert memory.temp_size_in_bytes < 1.8e9
-        # one flash kernel; three grouped products and one pass that
+        # one flash kernel; two grouped-product kernels and one pass that
         # brings them back to their tokens an expert layer: ``room`` holds
         # every pair of a layer that holds half the experts, so there is
         # one turn and no loop
-        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
-                              text)) >= 3
-        assert len(re.findall(
-            r"%expert_combine[.\d]* = [^\n]*? custom-call\(", text)) \
-            == ssm_layers + 1
+        assert _calls("grouped_product", text) == 2 * (ssm_layers + 1)
+        assert not re.findall(r"%ragged-dot", text)
+        assert _calls("expert_combine", text) == ssm_layers + 1
         # the gather of the pairs' rows reads the tokens' activations
         # from VMEM (memory space 1), as the parent's did in its loop:
         # from HBM it takes 2.50 ms a layer against 0.51 (my chip runs,
@@ -817,7 +893,7 @@ def test_block_diffusion_engine_fits_and_updates_its_cache_in_place(
     over 128 experts are at ``MASKED_PAIRS`` an expert: every expert
     multiplies every row and no pair is sorted. The prefill attends
     through the flash kernel's block-causal form, groups its pairs
-    through ``ragged_dot`` and runs no head."""
+    through ``ops/pallas/grouped_product`` and runs no head."""
     import json
 
     from benchmark import weights_sdar
@@ -889,8 +965,8 @@ def test_block_diffusion_engine_fits_and_updates_its_cache_in_place(
     else:
         assert memory.temp_size_in_bytes < 2.5e9
         assert text.count("tpu_custom_call") >= layers
-        assert len(re.findall(r"%ragged-dot[-\w.]* = [^\n]*? custom-call\(",
-                              text)) >= 3
+        assert _calls("grouped_product", text) == 2 * layers
+        assert not re.findall(r"%ragged-dot", text)
         # no head: nothing of the vocabulary's width is computed
         assert not re.findall(r"f32\[\d+(,\d+)*,151936\]", text)
 
